@@ -179,7 +179,7 @@ def classify(key: str) -> str:
 
 def flatten(obj, prefix: str = "") -> Dict[str, object]:
     """Nested dicts → dotted scalar leaves. Lists compare as JSON text
-    (order is meaningful in bench captures, e.g. `tunnel_queue`)."""
+    (order is meaningful in bench captures)."""
     out: Dict[str, object] = {}
     if isinstance(obj, dict):
         for k, v in obj.items():

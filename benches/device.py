@@ -24,10 +24,6 @@ import time
 sys.path.insert(0, ".")
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from _env import repin_jax_platforms  # noqa: E402
-
-repin_jax_platforms()
-
 import numpy as np
 
 from ytpu.core import Doc, Update
@@ -40,9 +36,8 @@ def capture(doc):
 
 
 def fused_lane_rate(make_state, stream, rank, n_docs, n_updates, validate):
-    """Measure the fused Pallas lane on the same stream (r5: the kernel is
-    silicon-correct after the aliased-output init fix; rung9_bisect.json).
-    Runs AFTER the XLA measure — crash order — and only on real devices
+    """Measure the fused Pallas lane on the same stream (not run on the
+    current machine). Runs AFTER the XLA measure — crash order — and only on real devices
     (interpret mode would take hours on CPU; set YTPU_CFG_FUSED=1 to
     force). Returns (updates_per_sec | None, error | None)."""
     import jax

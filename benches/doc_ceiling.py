@@ -363,9 +363,6 @@ def main(argv=None) -> int:
     for p in (here, os.path.dirname(here)):
         if p not in sys.path:
             sys.path.insert(0, p)
-    from _env import repin_jax_platforms
-
-    repin_jax_platforms()
     sweep = doc_ceiling_sweep(sub_batch=sub_batch)
     if sub_batch:
         sweep["sub_batch_scaling"] = sub_batch_scaling()

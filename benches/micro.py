@@ -26,10 +26,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from _env import repin_jax_platforms  # noqa: E402
-
-repin_jax_platforms()
-
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
 from ytpu.core import Doc  # noqa: E402

@@ -32,10 +32,6 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 )
 
-from _env import repin_jax_platforms  # noqa: E402
-
-repin_jax_platforms()
-
 
 def b4_prefix_updates(n_ops: int):
     import bench as bench_mod

@@ -2,14 +2,14 @@
 
 This container's jax interprets a trivial `pallas_call` fine but raises
 NotImplementedError on a primitive the fused integrate kernel uses (seed
-behavior — docs/known_backend_issues.md §3), so the breakage cannot be
+behavior), so the breakage cannot be
 probed cheaply up front: every test that tries pays the full multi-second
 kernel trace before the error surfaces.  The failure is environmental
 (per jax build, not per shape), so the FIRST failure is remembered and
 every later fused interpret test skips instantly — on a jax whose
 interpreter can run the kernel, nothing here triggers and the tests run
-in full.  Real-hardware parity is covered by the mosaic ladder and
-benches/flagship_fused_chunked.py.
+in full.  The kernel is compiled for a v5e by tests/test_chip_compile.py;
+it has not been run on the current machine.
 """
 
 import pytest

@@ -18,13 +18,10 @@ from functools import lru_cache
 import numpy as np
 import pytest
 
-from ytpu.native import available as native_available
 
 from _fused_interpret import run_or_skip
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable (plan pre-scan)"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 # (n_docs, capacity, chunk, d_block) — the one shape family of this file
 N_DOCS, CAPACITY, CHUNK, D_BLOCK = 2, 256, 16, 2

@@ -23,14 +23,11 @@ through `tests/_fused_interpret.run_or_skip` and runs LAST.
 import numpy as np
 import pytest
 
-from ytpu.native import available as native_available
 
 from _fused_interpret import run_or_skip
 from test_async_overlap import CAPACITY, CHUNK, D_BLOCK, N_DOCS, _workload
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable (plan pre-scan)"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 
 def _make(ingest: str, lane: str = "xla", interpret: bool = False, **kw):

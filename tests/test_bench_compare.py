@@ -23,7 +23,7 @@ def test_self_compare_is_zero_diff_and_rc0(tmp_path, capsys):
     cap = {
         "value": 1000.0,
         "soak": {"updates_per_s": 50.0, "apply_p99_ms": 3.0},
-        "tunnel_queue": ["a", "b"],
+        "owed_captures": ["a", "b"],
     }
     p = tmp_path / "cap.json"
     p.write_text(json.dumps(cap))
@@ -330,14 +330,33 @@ def test_cli_exit_codes_and_last_line_loading(tmp_path):
     assert res.returncode == 2
 
 
-def test_committed_capture_self_compares_clean():
-    """The freshest committed TPU capture is a valid input and a fixed
-    point of the tool."""
-    cap = os.path.join(ROOT, "BENCH_r05_midsession.json")
-    if not os.path.exists(cap):
-        pytest.skip("no committed capture in this checkout")
-    rc = bc.main([cap, cap])
-    assert rc == 0
+def test_capture_shaped_file_self_compares_clean(tmp_path):
+    """A file in the shape of a device capture (nested stats, per-config
+    sub-dicts, lists, notes) is a valid input and a fixed point of the
+    tool."""
+    cap = tmp_path / "capture.json"
+    cap.write_text(
+        json.dumps(
+            {
+                "metric": "updates_integrated_per_sec_full_b4_trace",
+                "platform": "tpu",
+                "device_kind": "TPU v5 lite",
+                "n_devices": 1,
+                "value": 50352.0,
+                "vs_native": 0.08,
+                "p50_apply_ms": 88.9,
+                "probe": {"probe_stage": "done", "devices_s": 1.5},
+                "configs": {
+                    "config3": {"updates_per_sec": 78747.0, "scan_p99": 337},
+                    "config5": {"docs_per_sec": 2274.0, "pipeline": {"n_sub": 4}},
+                },
+                "xla_full_stats": {"xla_chunks": 32, "xla_compactions": 5},
+                "ladder": {"failures": [3, 5, 9]},
+                "note": "fixture in the shape of a device capture",
+            }
+        )
+    )
+    assert bc.main([str(cap), str(cap)]) == 0
 
 
 @pytest.mark.slow

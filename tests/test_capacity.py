@@ -241,7 +241,7 @@ def test_forecaster_flags_degraded_before_grow_oom():
 
 def test_grow_oom_error_reports_attempted_vs_available_bytes():
     """The typed denial carries the numbers an operator needs, stays a
-    FaultError (site taxonomy), and stays on the checkpoint-resume
+    FaultError (site catalogue), and stays on the checkpoint-resume
     recovery path (`is_device_fault`)."""
     from ytpu.ops.integrate_kernel import (
         GrowOomError,

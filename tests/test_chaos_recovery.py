@@ -26,9 +26,7 @@ from ytpu.utils.faults import FaultError, FaultSpec, faults
 from _fused_interpret import run_or_skip
 from test_async_overlap import CAPACITY, CHUNK, D_BLOCK, N_DOCS, _workload
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable (plan pre-scan)"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 
 @pytest.fixture(autouse=True)

@@ -1,0 +1,228 @@
+"""Compile the served path's device programs for a described TPU v5e.
+
+No chip is attached here; the TPU compiler is, and it refuses what the chip
+would refuse (a kernel over its fast-memory limit, an unaligned slice, a
+program that does not fit HBM). Nothing runs, so these say nothing about
+results or times — `chip_smoke.py` on the chip does.
+
+Shapes are the ones `chip_smoke.py` drives: 1,024 rooms x capacity 4,096,
+and the decode / pack buckets its scenario produces (journal of a CPU run
+of the full scenario, PR 24). The fused Pallas kernel is compiled at the
+two tiles `bench.py` names; it is NOT part of the served path.
+
+Rules (on-chip-measurement guide, section 2): the topology is described
+inside the module-scoped fixture below and nowhere else; nothing here
+touches libtpu while a module is imported; no child process; ONE file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N_DOCS, CAPACITY = 1024, 4096
+N_CLIENTS = 2048  # the scenario's 2,048 preregistered session clients
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without the chip: keep the cache off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    """ShapeDtypeStructs on the described chip for a tree of arrays/shapes."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+    )
+
+
+def _state(one_chip, n_docs=N_DOCS, capacity=CAPACITY):
+    from ytpu.models.batch_doc import init_state
+
+    return _shapes(jax.eval_shape(lambda: init_state(n_docs, capacity)), one_chip)
+
+
+def _hbm_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes
+        + m.output_size_in_bytes
+        - m.alias_size_in_bytes
+        + m.temp_size_in_bytes
+    )
+
+
+V5E_HBM = 16 * 1024**3
+
+
+def test_served_integrate_step_fits_one_v5e(one_chip):
+    """`apply_update_batch` as `flush_device` dispatches it: every slot,
+    the 4-row / 4-delete bucket the scenario's updates land in."""
+    from ytpu.models.batch_doc import (
+        BatchEncoder,
+        _apply_update_batch_jit,
+        scan_tier_plan,
+    )
+
+    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, 4, 4)
+    compiled = _apply_update_batch_jit.lower(
+        _state(one_chip),
+        _shapes(batch, one_chip),
+        jax.ShapeDtypeStruct((N_CLIENTS,), jnp.int32, sharding=one_chip),
+        scan_tier_plan(),
+    ).compile()
+    # not donated: input and output state both live, plus temporaries
+    assert _hbm_bytes(compiled) < V5E_HBM // 2, compiled.memory_analysis()
+
+
+def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
+    """`chip_smoke.py --chips 4`: the state's doc axis over a 4-chip mesh,
+    the update batch as the host hands it over (unsharded). The step must
+    keep every output plane doc-sharded and never gather the state."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ytpu.models.batch_doc import (
+        BatchEncoder,
+        _apply_update_batch_jit,
+        init_state,
+        scan_tier_plan,
+    )
+    from ytpu.parallel.mesh import AXIS_BATCH
+
+    mesh = Mesh(np.array(topo.devices), (AXIS_BATCH,))
+    on = lambda a, spec: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
+    )
+    by_doc = lambda a: on(a, P(AXIS_BATCH, *([None] * (a.ndim - 1))))
+    state = jax.tree.map(by_doc, jax.eval_shape(lambda: init_state(N_DOCS, CAPACITY)))
+    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, 4, 4)
+    compiled = _apply_update_batch_jit.lower(
+        state,
+        jax.tree.map(lambda a: on(a, P()), batch),
+        on(jnp.zeros((N_CLIENTS,), jnp.int32), P()),
+        scan_tier_plan(),
+    ).compile()
+    assert "all-gather" not in compiled.as_text()
+    for out in jax.tree.leaves(compiled.output_shardings):
+        assert out.spec[0] == AXIS_BATCH, out
+    # per chip: a quarter of the state in and out, plus temporaries
+    assert _hbm_bytes(compiled) < V5E_HBM // 4, compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("lanes,max_sections", [(1, 2), (8, 2), (8, None)])
+def test_served_decode_compiles(one_chip, lanes, max_sections):
+    """`decode_updates_v1` over [S, 64] wire lanes with all four lookup
+    tables, as `_merge_fast_lane` calls it."""
+    from ytpu.ops.decode_kernel import _decode_updates_v1_jit
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = _decode_updates_v1_jit.lower(
+        jax.ShapeDtypeStruct((lanes, 64), jnp.uint8, sharding=one_chip),
+        i32(lanes),
+        max_rows=4,
+        max_dels=4,
+        n_steps=16,
+        client_table=(i32(N_CLIENTS), i32(N_CLIENTS)),
+        max_sections=max_sections,
+        key_table=(i32(1), i32(1)),
+        client_hash_table=(i32(0), i32(0)),
+        primary_root_hash=i32(lanes),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
+
+
+def test_served_diff_selection_compiles(one_chip):
+    from ytpu.models.batch_doc import _encode_diff_batch_jit
+
+    compiled = _encode_diff_batch_jit.lower(
+        _state(one_chip),
+        jax.ShapeDtypeStruct((N_DOCS, N_CLIENTS), jnp.int32, sharding=one_chip),
+        N_CLIENTS,
+    ).compile()
+    assert _hbm_bytes(compiled) < V5E_HBM // 2, compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("sub,rows", [(1, 8), (1, 4096), (512, 4096)])
+def test_served_diff_pack_compiles_donated(one_chip, sub, rows):
+    """The DONATED `compact_finisher_rows`: `_donation_usable()` is false on
+    the CPU, so no CPU test has ever taken this variant."""
+    from ytpu.models.batch_doc import _compact_rows_donated
+
+    state = _state(one_chip)
+    plane = lambda dt: jax.ShapeDtypeStruct((N_DOCS, CAPACITY), dt, sharding=one_chip)
+    compiled = _compact_rows_donated.lower(
+        state.blocks,
+        plane(jnp.bool_),
+        plane(jnp.int32),
+        plane(jnp.bool_),
+        jax.ShapeDtypeStruct((sub,), jnp.int32, sharding=one_chip),
+        rows,
+    ).compile()
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert out >= sub * 15 * rows * 4
+
+
+def test_batch_compaction_compiles_donated(one_chip):
+    """`compact_state` (donated): not on the served path today, but the only
+    way a full slot gets room back."""
+    from ytpu.ops.compaction import _compact_state_jit
+
+    compiled = _compact_state_jit.lower(_state(one_chip)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes > 0, m  # the donation took
+    assert _hbm_bytes(compiled) < V5E_HBM // 2, m
+
+
+# (docs, capacity, d_block): bench.py's quick lane, and its full-B4 lane
+# (d_block=8) at the largest capacity whose tile the compiler grants, two
+# grid steps each so the pipeline's double buffers are in the count. At
+# bench.py's C=65,536 even ONE grid step is refused: "Ran out of memory in
+# memory space vmem. Used 130.90M of 128.00M vmem" (CHANGES.md, PR 24).
+FUSED_TILES = [(256, 2048, 128), (16, 32768, 8)]
+
+
+@pytest.mark.parametrize("docs,capacity,d_block", FUSED_TILES)
+def test_fused_integrate_kernel_compiles(one_chip, docs, capacity, d_block):
+    """The one Pallas kernel. Interpret mode raises NotImplementedError in
+    this jax, so this is the only compiler the kernel meets off the chip.
+    Compiled, never run: the fused lane has no chip result."""
+    from ytpu.ops import integrate_kernel as ik
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = ik._run.lower(
+        i32(ik.NC, docs, capacity),
+        i32(docs, ik.M_PAD),
+        (i32(16, 4, 23), i32(16, 8, 4), i32(64)),
+        d_block,
+        False,  # interpret
+        3,
+        4,
+        ik.fused_vmem_mb(d_block, capacity),
+        ik.scan_tier_plan(),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= np.prod((ik.NC, docs, capacity)) * 4, m

@@ -19,13 +19,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ytpu.native import available as native_available
 from ytpu.utils import metrics
 from ytpu.utils.faults import faults
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native library unavailable"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 N_DOCS, CAPACITY = 4, 256  # the suite-wide device-server shape family
 SUB, DEPTH = 2, 2

@@ -12,7 +12,6 @@ import pytest
 from ytpu.core import Doc
 from ytpu.models.batch_doc import get_string
 from ytpu.models.ingest import BatchIngestor
-from ytpu.native import available as native_available
 
 
 def _edit_log(ops, client_id=1, root="text"):
@@ -38,9 +37,7 @@ def _flags_clean(ing):
     return (np.asarray(f) & FLAG_ERRORS == 0).all()
 
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 
 @needs_native

@@ -7,11 +7,9 @@ import numpy as np
 import pytest
 
 from ytpu.core import Doc, Update
-from ytpu.native import available, decode_update_columns
+from ytpu.native import decode_update_columns
 
-pytestmark = pytest.mark.skipif(
-    not available(), reason="native codec unavailable (no g++?)"
-)
+pytestmark = pytest.mark.usefixtures("native_lib")
 
 
 def flatten_python(update: Update):

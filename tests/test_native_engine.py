@@ -16,13 +16,10 @@ from ytpu.core import Doc
 from ytpu.native import (
     NativeEngine,
     NativeUnsupported,
-    engine_available,
     native_replay_v1,
 )
 
-needs_native = pytest.mark.skipif(
-    not engine_available(), reason="native engine unavailable"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 
 def _edit_log(ops, client_id=1):
@@ -202,3 +199,42 @@ def test_b4_trace_prefix_parity():
         ops = bench.synthetic_ops(3000)
     log, expect = bench.build_updates(ops)
     assert native_replay_v1(log) == expect
+
+
+def test_concurrent_loads_build_one_hash_named_library(tmp_path):
+    """Two fresh processes that find no library both build-or-wait and end
+    with the SAME whole, content-named file — the six-xdist-worker race
+    that used to leave a worker without the native lanes."""
+    import json
+    import shutil
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    import ytpu.native
+
+    src = Path(ytpu.native.__file__).parent
+    pkg = tmp_path / "native_copy"
+    shutil.copytree(
+        src, pkg, ignore=shutil.ignore_patterns("*.so", ".build.lock", "__pycache__")
+    )
+    assert not list(pkg.glob("*.so"))
+    prog = (
+        "import json, sys; sys.path.insert(0, sys.argv[1]); import native_copy as n;"
+        "lib = n.load(); ok = lib is not None and n.native_replay_v1([]) == '';"
+        "print(json.dumps({'path': lib and lib._name, 'ok': ok}))"
+    )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", prog, str(tmp_path)],
+            stdout=subprocess.PIPE,
+            text=True,
+        )
+        for _ in range(2)
+    ]
+    outs = [json.loads(p.communicate(timeout=300)[0].splitlines()[-1]) for p in procs]
+    assert all(o["ok"] for o in outs), outs
+    assert outs[0]["path"] == outs[1]["path"]
+    name = Path(outs[0]["path"]).name
+    assert name == Path(ytpu.native._lib_path()).name  # same sources, same hash
+    assert [p.name for p in pkg.glob("*.so")] == [name]  # no temp left behind

@@ -21,11 +21,8 @@ from ytpu.models.batch_doc import (
     finish_encode_diff_batch,
     init_state,
 )
-from ytpu.native import available as native_available
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native library unavailable"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 
 def build_device_docs(edit_fns, capacity=128, root="text"):
@@ -350,3 +347,52 @@ def test_multi_client_ordering_parity():
     assert (
         replica.get_text("text").get_string() == t1.get_string()
     )
+
+
+@needs_native
+@pytest.mark.parametrize("layout", ["row_major", "plane_major", "not_row_contiguous"])
+def test_finisher_reads_the_packed_tensor_through_its_own_strides(layout):
+    """The packed [d_pad, 15, R] tensor comes back from the CPU row-major
+    and from a TPU v5e plane-major (strides (4R, 4*d_pad*R, 4), PR 24);
+    `finish` must read either in place, and copy only what it cannot."""
+    import jax.numpy as jnp
+
+    from ytpu.models import batch_doc as bd
+
+    def edits(chunks):
+        def fn(d):
+            t = d.get_text("text")
+            for pos, chunk in chunks:
+                with d.transact() as txn:
+                    t.insert(txn, pos, chunk)
+            with d.transact() as txn:
+                t.remove_range(txn, 1, 2)
+
+        return fn
+
+    docs, state, enc = build_device_docs(
+        [edits([(0, "hello"), (5, " world")]), edits([(0, "abc"), (0, "xyz")]),
+         edits([(0, "one")]), edits([(0, "other"), (2, "--")])]
+    )
+    remote = np.zeros((len(docs), 8), dtype=np.int32)
+    ship, offsets, deleted = diff_arrays(state, enc, remote)
+    want = [
+        finish_encode_diff(state, d, ship, offsets, deleted, enc)
+        for d in range(len(docs))
+    ]
+    idx = jnp.arange(len(docs), dtype=jnp.int32)
+    packed = np.asarray(
+        bd.compact_finisher_rows(
+            state.blocks, jnp.asarray(ship), jnp.asarray(offsets),
+            jnp.asarray(deleted), idx, 16,
+        )
+    )
+    if layout == "plane_major":
+        packed = np.ascontiguousarray(packed.transpose(1, 0, 2)).transpose(1, 0, 2)
+        assert not packed.flags["C_CONTIGUOUS"] and packed.strides[2] == 4
+    elif layout == "not_row_contiguous":
+        packed = np.ascontiguousarray(packed.transpose(2, 0, 1)).transpose(1, 2, 0)
+        assert packed.strides[2] != 4
+    got = bd._FinisherContext(enc).finish(packed, len(docs), None, 1)
+    assert got == want
+    assert bd.LAST_FINISH_STATUSES == [0] * len(docs)  # native, not the fallback

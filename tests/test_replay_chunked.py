@@ -5,8 +5,8 @@ the shared CompactionPolicy, vs the unchunked XLA lane and the host oracle.
 The kernel-agnostic machinery (chunk slicing, occupancy bounds, policy,
 compact/grow, sticky-error drain) is exercised on the CPU-testable
 `lane="xla"` twin; the Pallas lane shares every line of the driver except
-the kernel dispatch and is parity-covered on real hardware by
-tests/test_pallas_kernel.py + benches/flagship_fused_chunked.py.
+the kernel dispatch, which tests/test_chip_compile.py compiles for a v5e
+(not run on the current machine).
 Interpret-mode Pallas raises NotImplementedError in this container's jax
 build (seed behavior) — the fused-lane smoke SKIPS on that, never fails.
 """

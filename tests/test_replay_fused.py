@@ -11,11 +11,8 @@ import numpy as np
 import pytest
 
 from ytpu.core import Doc
-from ytpu.native import available as native_available
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable (plan pre-scan)"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 
 from _fused_interpret import run_or_skip as _interpret_or_skip
@@ -24,9 +21,8 @@ from _fused_interpret import run_or_skip as _interpret_or_skip
 def run_or_skip(rep, log):
     """Drive a FusedReplay, SKIPPING when this container's jax cannot
     interpret Pallas TPU kernels (NotImplementedError from the
-    interpreter — environmental, present at seed; see
-    docs/known_backend_issues.md §3). Real-hardware parity is covered by
-    benches/flagship_fused_chunked.py and the mosaic ladder. The skip is
+    interpreter — environmental, present at seed). The kernel is compiled
+    for a v5e by tests/test_chip_compile.py and not run on the chip. The skip is
     memoized across files (tests/_fused_interpret.py) so only the first
     fused interpret test in the session pays the kernel trace."""
     return _interpret_or_skip(lambda: rep.run(log))

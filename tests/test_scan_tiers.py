@@ -32,7 +32,6 @@ from ytpu.models.batch_doc import (
     init_state,
     scan_tier_plan,
 )
-from ytpu.native import available as native_available
 from ytpu.ops import integrate_kernel as ik
 from ytpu.ops.integrate_kernel import replay_stream_fused
 from ytpu.utils.faults import faults
@@ -45,9 +44,7 @@ from _fused_interpret import run_or_skip
 # sys.path; benches/ is a namespace package)
 from benches.scan_tiers import build_conflict_stream
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable (plan pre-scan)"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 # the one shape family of this file (shared suite-wide)
 N_DOCS, CAPACITY, CHUNK, D_BLOCK = 2, 256, 16, 2

@@ -15,7 +15,6 @@ import pytest
 
 from ytpu.parallel.seq_shard import (
     HALO,
-    SHARD_MAP_AVAILABLE,
     apply_ops_sharded,
     build_op_stream,
     init_sharded,
@@ -28,14 +27,6 @@ N_SHARDS = 8
 
 @pytest.fixture(scope="module")
 def mesh():
-    if not SHARD_MAP_AVAILABLE:
-        # environmental, same spirit as tests/_fused_interpret: this jax
-        # build exposes neither jax.shard_map nor the experimental entry
-        # point, so the sp kernel cannot dispatch at all — skip, don't fail
-        pytest.skip(
-            "shard_map unavailable in this jax build "
-            "(no jax.shard_map / jax.experimental.shard_map)"
-        )
     if len(jax.devices()) < N_SHARDS:
         pytest.skip(f"needs {N_SHARDS} devices")
     return make_sp_mesh(N_SHARDS)

@@ -16,13 +16,10 @@ import asyncio
 import numpy as np
 import pytest
 
-from ytpu.native import available as native_available
 from ytpu.utils import metrics
 from ytpu.utils.faults import faults
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 N_DOCS, CAPACITY = 4, 256
 SEED = 5

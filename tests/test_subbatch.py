@@ -29,7 +29,6 @@ import pytest
 from ytpu.core import Doc, Update
 from ytpu.models.batch_doc import BatchEncoder, get_values, init_state
 from ytpu.models.replay import FusedReplay, plan_replay, plan_subbatches
-from ytpu.native import available as native_available
 from ytpu.ops import integrate_kernel as ik
 from ytpu.ops.integrate_kernel import packed_state_bytes
 from ytpu.parallel import mesh as pmesh
@@ -44,9 +43,7 @@ from _fused_interpret import run_or_skip
 # puts the repo root on sys.path; benches/ is a namespace package)
 from benches.scan_tiers import build_conflict_stream
 
-needs_native = pytest.mark.skipif(
-    not native_available(), reason="native codec unavailable (plan pre-scan)"
-)
+needs_native = pytest.mark.usefixtures("native_lib")
 
 # the one shape family of this file (shared suite-wide)
 N_DOCS, CAPACITY, CHUNK, D_BLOCK = 2, 256, 16, 2

@@ -313,8 +313,7 @@ class ChunkPlan:
 
     @property
     def feasible(self) -> bool:
-        """Every chunk's worst-case growth fits the policy budget — the
-        dry-run assertion of `benches/flagship_fused_chunked.py`."""
+        """Every chunk's worst-case growth fits the policy budget."""
         return self.max_chunk_adds <= self.budget
 
 

@@ -8,7 +8,11 @@ implementations in `ytpu.encoding` / `ytpu.core` are used instead —
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,10 +31,12 @@ __all__ = [
 ]
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "lib0_codec.cpp")
-_ENGINE_SRC = os.path.join(_HERE, "engine.cpp")
-_FINISHER_SRC = os.path.join(_HERE, "encode_finisher.cpp")
-_LIB = os.path.join(_HERE, "_libytpu.so")
+_SOURCES = tuple(
+    os.path.join(_HERE, name)
+    for name in ("lib0_codec.cpp", "engine.cpp", "encode_finisher.cpp")
+)
+_GXX = ("g++", "-O2", "-shared", "-fPIC", "-pthread", "-std=c++17")
+_BUILD_LOCK = os.path.join(_HERE, ".build.lock")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -58,28 +64,60 @@ _COLUMNS = [
 _DEL_COLUMNS = ["del_client", "del_start", "del_end"]
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    """`_libytpu-<hash>.so`, named by the sources on disk and the compiler
+    argv: a library under this name was built from exactly these files."""
+    h = hashlib.sha256()
+    for src in _SOURCES:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update("\0".join(_GXX).encode())
+    return os.path.join(_HERE, f"_libytpu-{h.hexdigest()[:12]}.so")
+
+
+@contextlib.contextmanager
+def _build_lock():
+    """One builder per directory at a time, across processes."""
+    with open(_BUILD_LOCK, "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        try:
+            yield
+        finally:
+            fcntl.flock(f, fcntl.LOCK_UN)
+
+
+def _compile(argv, out: str, timeout: int) -> bool:
+    """Run `argv -o <unique name beside out>` and rename the result into
+    place, so `out` is either absent or a whole library. Caller holds
+    `_build_lock`."""
+    tmp = f"{out[:-3]}.{os.getpid()}.tmp.so"
     try:
         subprocess.run(
-            [
-                "g++",
-                "-O2",
-                "-shared",
-                "-fPIC",
-                "-pthread",
-                "-std=c++17",
-                _SRC,
-                _ENGINE_SRC,
-                _FINISHER_SRC,
-                "-o",
-                _LIB,
-            ],
-            check=True,
-            capture_output=True,
-            timeout=120,
+            [*argv, "-o", tmp], check=True, capture_output=True, timeout=timeout
         )
+        os.replace(tmp, out)
         return True
     except Exception:
+        with contextlib.suppress(OSError):
+            os.unlink(tmp)
+        return False
+
+
+def _build(path: str) -> bool:
+    """Make sure `path` (from `_lib_path`) exists; drop libraries of older
+    sources. A process that waited on the lock finds the winner's file."""
+    try:
+        with _build_lock():
+            if os.path.exists(path):
+                return True
+            if not _compile([*_GXX, *_SOURCES], path, timeout=120):
+                return False
+            for old in glob.glob(os.path.join(_HERE, "_libytpu*.so")):
+                if old != path and not old.endswith(".tmp.so"):
+                    with contextlib.suppress(OSError):
+                        os.unlink(old)
+            return True
+    except OSError:
         return False
 
 
@@ -93,45 +131,42 @@ def build_capi(force: bool = False) -> Optional[str]:
     Embeds CPython: links against the running interpreter's libpython so
     arbitrary C programs can drive the engine (see include/ytpu.h).
     Returns the library path, or None if the toolchain is unavailable.
+    The name is fixed (C programs link against it); the file is built
+    under the same lock and renamed into place like `_libytpu-*.so`.
     """
     import sysconfig
 
     header = os.path.join(_HERE, "include", "ytpu.h")
     support = os.path.join(_HERE, "support.py")
     inputs = [p for p in (_CAPI_SRC, header, support) if os.path.exists(p)]
-    if (
-        not force
-        and os.path.exists(_CAPI_LIB)
-        and os.path.getmtime(_CAPI_LIB) >= max(os.path.getmtime(p) for p in inputs)
-    ):
-        return _CAPI_LIB
     include = sysconfig.get_paths()["include"]
     libdir = sysconfig.get_config_var("LIBDIR") or "/usr/local/lib"
     version = sysconfig.get_config_var("LDVERSION") or sysconfig.get_config_var(
         "VERSION"
     )
+    argv = [
+        "g++",
+        "-O2",
+        "-shared",
+        "-fPIC",
+        "-std=c++17",
+        _CAPI_SRC,
+        f"-I{include}",
+        f"-L{libdir}",
+        f"-lpython{version}",
+        f"-Wl,-rpath,{libdir}",
+    ]
     try:
-        subprocess.run(
-            [
-                "g++",
-                "-O2",
-                "-shared",
-                "-fPIC",
-                "-std=c++17",
-                _CAPI_SRC,
-                f"-I{include}",
-                f"-L{libdir}",
-                f"-lpython{version}",
-                f"-Wl,-rpath,{libdir}",
-                "-o",
-                _CAPI_LIB,
-            ],
-            check=True,
-            capture_output=True,
-            timeout=180,
-        )
-        return _CAPI_LIB
-    except Exception:
+        with _build_lock():
+            if (
+                not force
+                and os.path.exists(_CAPI_LIB)
+                and os.path.getmtime(_CAPI_LIB)
+                >= max(os.path.getmtime(p) for p in inputs)
+            ):
+                return _CAPI_LIB
+            return _CAPI_LIB if _compile(argv, _CAPI_LIB, timeout=180) else None
+    except OSError:
         return None
 
 
@@ -185,21 +220,19 @@ class FinishIn(ctypes.Structure):
 
 
 def load() -> Optional[ctypes.CDLL]:
+    """The library built from the sources on disk, or None where it cannot
+    be built. Only a failed compile is remembered; a process that waited
+    for another's build loads the file that build left."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
-        _tried = True
-        newest_src = max(
-            os.path.getmtime(_SRC),
-            os.path.getmtime(_ENGINE_SRC),
-            os.path.getmtime(_FINISHER_SRC),
-        )
-        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < newest_src:
-            if not _build():
-                return None
+        path = _lib_path()
+        if not os.path.exists(path) and not _build(path):
+            _tried = True
+            return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         lib.ytpu_decode_update_v1.restype = ctypes.c_void_p
@@ -287,31 +320,22 @@ def load() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_int64),
             ]
             lib.ytpu_finish_free.argtypes = [ctypes.c_void_p]
-            # ISSUE-10 additions: the strided packed-arena entry (one
-            # host tensor, zero per-plane copies) and the vectorized
-            # span/status readout. A stale .so that predates them (no
-            # compiler to rebuild) degrades to the classic per-column /
-            # per-doc path — `finisher_strided_ok` gates the callers.
-            try:
-                lib.ytpu_finish_batch_strided.restype = ctypes.c_void_p
-                lib.ytpu_finish_batch_strided.argtypes = [
-                    ctypes.POINTER(FinishIn),
-                    ctypes.c_int64,
-                    ctypes.c_int32,
-                ]
-                lib.ytpu_finish_total_len.restype = ctypes.c_int64
-                lib.ytpu_finish_total_len.argtypes = [ctypes.c_void_p]
-                lib.ytpu_finish_spans.argtypes = [
-                    ctypes.c_void_p,
-                    ctypes.POINTER(ctypes.c_int64),
-                    ctypes.POINTER(ctypes.c_int64),
-                    ctypes.POINTER(ctypes.c_int32),
-                ]
-                lib.finisher_strided_ok = True
-            except AttributeError:
-                lib.finisher_strided_ok = False
-        else:
-            lib.finisher_strided_ok = False
+            # the strided packed-arena entry (one host tensor, zero
+            # per-plane copies) and the vectorized span/status readout
+            lib.ytpu_finish_batch_strided.restype = ctypes.c_void_p
+            lib.ytpu_finish_batch_strided.argtypes = [
+                ctypes.POINTER(FinishIn),
+                ctypes.c_int64,
+                ctypes.c_int32,
+            ]
+            lib.ytpu_finish_total_len.restype = ctypes.c_int64
+            lib.ytpu_finish_total_len.argtypes = [ctypes.c_void_p]
+            lib.ytpu_finish_spans.argtypes = [
+                ctypes.c_void_p,
+                ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int32),
+            ]
         _lib = lib
         return _lib
 

@@ -36,20 +36,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh
 
-# `jax.shard_map` only became a public top-level alias after this
-# container's jax build; fall back to the experimental entry point (same
-# call signature) and record absence so callers/tests can skip with a
-# clear reason instead of dying on AttributeError mid-dispatch.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on the installed jax build
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError:
-        _shard_map = None
-
-SHARD_MAP_AVAILABLE = _shard_map is not None
-
 I32 = jnp.int32
 
 AXIS_SP = "sp"
@@ -251,12 +237,7 @@ def _apply_ops_impl(state, stream, *, mesh, rebalance_every, cap, max_ins):
         text, length, error = carry
         return text[None], length[None], error[None]
 
-    if _shard_map is None:
-        raise NotImplementedError(
-            "this jax build exposes neither jax.shard_map nor "
-            "jax.experimental.shard_map — sequence parallelism needs one"
-        )
-    text, length, error = _shard_map(
+    text, length, error = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(AXIS_SP), P(AXIS_SP), P(AXIS_SP), P(), P(), P(), P()),

@@ -37,7 +37,7 @@ treated as lost, forcing the checkpoint-resume path), ``ms=50`` sets the
 ``net.delay`` stall.  A site argument that names a *context* key the
 call site passes (e.g. ``lane``) must match for the pass to be eligible.
 
-Standard sites (see docs/robustness.md for the full taxonomy):
+Standard sites (see docs/robustness.md for the full catalogue):
 
 ====================  =======================================================
 ``update.corrupt``    truncate/flip one staged update's wire bytes — fires

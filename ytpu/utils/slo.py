@@ -11,7 +11,7 @@ registry (resetting would orphan every cached metric object).
 `slo_report` renders one window into the SLO dict the soak driver and
 bench.py embed: p50/p99 in milliseconds, both **raw** and with a measured
 RTT/echo **floor subtracted** (VERDICT Weak #7: the `sync.apply_update`
-series reports raw wall time, which on a tunneled backend is dominated by
+series reports raw wall time, which over a remote link is dominated by
 transport latency the server cannot control; the floor-subtracted number
 is the server-attributable latency).  Subtraction clamps at zero — a
 quantile below the measured floor means the floor estimate was noisy, not
