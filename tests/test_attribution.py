@@ -117,14 +117,43 @@ def test_compile_retrace_fault_site():
 # ---------------------------------------------------------------------------
 
 
-def test_profile_fractions_self_consistent():
-    rec = PhaseRecorder(enabled=True)
-    w = ProfileWindow(recorder=rec)
-    w.begin()
+def _synthetic_stages(rec):
     with rec.span("replay.stage"):
         time.sleep(0.02)
     with rec.span("encode.finish"):
         time.sleep(0.01)
+
+
+def _served_step(rec):
+    """The served step's own nesting (ISSUE-26): `sync.dispatch` ⊃
+    `ingest.apply` ⊃ `ingest.plan`/`ingest.merge` ⊃ leaves, with the
+    time in the leaves, as on the chip."""
+    with rec.span("sync.receive"):
+        with rec.span("sync.receive.fanout"):
+            time.sleep(0.002)
+    with rec.span("sync.dispatch"):
+        with rec.span("ingest.apply"):
+            with rec.span("ingest.plan"):
+                with rec.span("ingest.plan.prescan"):
+                    time.sleep(0.02)
+            with rec.span("ingest.merge"):
+                with rec.span("ingest.merge.scatter"):
+                    time.sleep(0.02)
+                with rec.span("decode.v1", key=((2, 64),)):
+                    time.sleep(0.005)  # first sighting: compile
+            with rec.span("integrate.xla_batch", key=((2, 256),)):
+                time.sleep(0.005)
+    rec.add_time("sync.queue_wait", 5.0)  # waiting is nobody's work
+    with rec.span("encode.finish"):
+        time.sleep(0.01)
+
+
+@pytest.mark.parametrize("stages", [_synthetic_stages, _served_step])
+def test_profile_fractions_self_consistent(stages):
+    rec = PhaseRecorder(enabled=True)
+    w = ProfileWindow(recorder=rec)
+    w.begin()
+    stages(rec)
     time.sleep(0.02)  # unattributed wall → idle bucket
     rep = w.report()
     assert abs(rep["fractions_sum"] - 1.0) < 1e-6, rep
@@ -134,6 +163,14 @@ def test_profile_fractions_self_consistent():
     assert rep["profile_finisher_fraction"] > 0.0
     assert rep["profile_idle_fraction"] > 0.0
     assert rep["seconds"]["staging"] == pytest.approx(0.02, abs=0.015)
+    # a container's time is not counted on top of its children's, and a
+    # queue wait is in no bucket: the buckets fit in the wall
+    assert rep["overcommit_s"] == 0.0, rep
+    if stages is _served_step:
+        # the merge's eager glue is host time, not device time
+        assert rep["seconds"]["host"] == pytest.approx(0.022, abs=0.015)
+        assert rep["seconds"]["compile"] == pytest.approx(0.010, abs=0.008)
+        assert rep["seconds"]["device"] < 0.004
 
 
 def test_profile_window_is_deltas_not_cumulative():

@@ -67,13 +67,17 @@ def test_compile_cache_dir_is_fixed_or_the_environments(monkeypatch, tmp_path):
     from ytpu.utils.compile_cache import enable_compile_cache
 
     was = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == was  # untouched
+        # a cached program must not hand a tree its predecessor's op names
+        assert jax.config.jax_compilation_cache_include_metadata_in_key is True
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         assert enable_compile_cache() == os.path.join(repo, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == os.path.join(repo, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", was)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)

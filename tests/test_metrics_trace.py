@@ -11,6 +11,22 @@ import pytest
 from ytpu.utils import MetricsRegistry, Tracer
 
 
+@pytest.fixture
+def fresh_registry():
+    """An empty process-wide registry for one test, the families put back
+    after it: `metrics.reset()` alone orphans every family a module cached
+    at import (`net.py`, `replica.py`, `integrate_kernel.py`, ...) for the
+    rest of the worker's life, and a later test that reads one through the
+    registry then sees a namesake at 0 (ROADMAP Design 13)."""
+    from ytpu.utils import metrics
+
+    saved = dict(metrics._families)
+    metrics.reset()
+    yield metrics
+    metrics._families.clear()
+    metrics._families.update(saved)
+
+
 def test_counter_and_histogram():
     reg = MetricsRegistry()
     c = reg.counter("ops")
@@ -60,13 +76,12 @@ def test_tracer_disabled_is_noop():
     assert json.loads(tr.export_chrome_trace())["traceEvents"] == []
 
 
-def test_server_records_apply_metrics():
+def test_server_records_apply_metrics(fresh_registry):
     from ytpu.core import Doc
     from ytpu.sync.server import SyncServer
     from ytpu.sync.protocol import Message, SyncMessage
-    from ytpu.utils import metrics
 
-    metrics.reset()
+    metrics = fresh_registry
     server = SyncServer()
     s1, _hello = server.connect("room")
     peer = Doc(client_id=7)
@@ -415,19 +430,19 @@ def test_instrumented_ingest_integrate_records_phase_spans():
     assert st["calls"] - st["compile_calls"] == len(log) - 1
     assert st["compile_s"] > 0 and st["execute_s"] > 0
     assert "ingest.plan" in snap and snap["ingest.plan"]["calls"] == len(log)
-    if ing.fast_docs:  # native lane present: wire bytes were counted
-        assert snap["decode.v1"]["h2d_bytes"] > 0
-        assert snap["ingest.fast_lane"]["h2d_bytes"] > 0
+    if ing.fast_docs:  # native lane present: wire bytes were counted,
+        # once, where they are uploaded (decode.v1 is handed device arrays)
+        assert snap["ingest.merge.h2d"]["h2d_bytes"] > 0
+        assert snap["decode.v1"]["h2d_bytes"] == 0
     phases.reset()
 
 
-def test_ingest_metrics_counters_mirror_lane_stats():
+def test_ingest_metrics_counters_mirror_lane_stats(fresh_registry):
     pytest.importorskip("jax")
     from ytpu.core import Doc
     from ytpu.models.ingest import BatchIngestor
-    from ytpu.utils import metrics
 
-    metrics.reset()
+    metrics = fresh_registry
     doc = Doc(client_id=9)
     log = []
     doc.observe_update_v1(lambda p, o, t: log.append(p))

@@ -459,11 +459,15 @@ def test_net_session_gauges_track_active_and_bad_frame_drops():
     from ytpu.sync.net import SyncClient, serve, write_frame
     from ytpu.sync.server import SyncServer
 
-    # the transport's OWN cached series (module-level in net.py): a
-    # fresh registry lookup would diverge after any metrics.reset()
-    # earlier in the suite (test_metrics_trace sorts before this file)
+    # the live-session gauge is the transport's OWN cached series
+    # (module-level in net.py): a fresh registry lookup would diverge
+    # after any metrics.reset() earlier in the suite (test_metrics_trace
+    # sorts before this file). The drop counter is looked up per drop,
+    # so the registry's family is the one that moves.
     active = net_mod._SESSIONS_ACTIVE
-    bad = net_mod._SESSIONS_DROPPED.labels("bad_frame")
+    bad = metrics.counter(
+        "net.sessions_dropped", labelnames=("reason",)
+    ).labels("bad_frame")
 
     async def main():
         base_active = active.value

@@ -51,12 +51,11 @@ def test_endpoints_serve_metrics_snapshot_healthz():
         with pytest.raises(urllib.error.HTTPError) as err:
             _get(t.port, "/nope")
         assert err.value.code == 404
-    # scrape self-accounting landed — read the module's OWN counter: an
-    # earlier module's `metrics.reset()` on this worker orphans it from
-    # the registry, and a fresh lookup would read a namesake at 0
-    from ytpu.utils import telemetry as _telemetry
-
-    assert _telemetry._SCRAPES.labels("metrics").value >= 1
+    # scrape self-accounting landed, in the registry's own family: the
+    # plane looks it up per scrape, so an earlier module's
+    # `metrics.reset()` on this worker cannot orphan it
+    scrapes = metrics.counter("telemetry.scrapes", labelnames=("endpoint",))
+    assert scrapes.labels("metrics").value >= 1
 
 
 def test_provider_sections_and_provider_errors_degrade():

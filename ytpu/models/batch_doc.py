@@ -477,6 +477,7 @@ def ensure_origin_slot(state: DocStateBatch) -> DocStateBatch:
     return state
 
 
+@jax.named_scope("split")
 def _split(state: DocStateBatch, i: jax.Array, off: jax.Array):
     """Split block `i` at `off` clock units; returns (state, right_slot).
 
@@ -860,12 +861,16 @@ def _conflict_scan(
         # exactly one candidate per trip, so width == trips here
         return (o >= 0) & (o != right_idx) & ~brk & (width < cheap_bound)
 
+    # named scopes (here and below) only label the HLO: the profiler's
+    # device ops carry them, so a trace says "conflict_scan/cheap", not
+    # "while.663"
     zeros = jnp.zeros((B,), bool)
-    carry = jax.lax.while_loop(
-        cheap_cond,
-        scan_step,
-        (o0, left_idx, zeros, zeros, jnp.array(False), I32(0)),
-    )
+    with jax.named_scope("conflict_scan/cheap"):
+        carry = jax.lax.while_loop(
+            cheap_cond,
+            scan_step,
+            (o0, left_idx, zeros, zeros, jnp.array(False), I32(0)),
+        )
 
     def wide_cond(carry):
         inner, wtrips = carry
@@ -878,9 +883,10 @@ def _conflict_scan(
             inner = scan_step(inner)
         return inner, wtrips + 1
 
-    (_, left_scanned, _, _, _, width), wide_trips = jax.lax.while_loop(
-        wide_cond, wide_body, (carry, I32(0))
-    )
+    with jax.named_scope("conflict_scan/wide"):
+        (_, left_scanned, _, _, _, width), wide_trips = jax.lax.while_loop(
+            wide_cond, wide_body, (carry, I32(0))
+        )
     return left_scanned, width, wide_trips
 
 
@@ -1325,6 +1331,7 @@ def _move_cycle(state: DocStateBatch, s) -> jax.Array:
     return d[jnp.maximum(s, 0)] & (s >= 0)
 
 
+@jax.named_scope("move_recompute")
 def _recompute_moves(
     state: DocStateBatch, dirty, client_rank: jax.Array
 ) -> DocStateBatch:
@@ -1445,9 +1452,10 @@ def _apply_update_one_doc(
         return st, dirty | d, _fold_scan_width(hist, w, wt, scan_plan[0])
 
     hist0 = jnp.zeros((SCAN_REC_WORDS,), I32)
-    state, moves_dirty, scan_hist = jax.lax.fori_loop(
-        0, U, blk_body, (state, jnp.array(False), hist0)
-    )
+    with jax.named_scope("integrate_rows"):
+        state, moves_dirty, scan_hist = jax.lax.fori_loop(
+            0, U, blk_body, (state, jnp.array(False), hist0)
+        )
 
     def del_body(r, carry):
         st, dirty = carry
@@ -1467,9 +1475,10 @@ def _apply_update_one_doc(
 
     # a tombstoned move row must release its range (and let shadowed moves
     # win again — the override-reintegration of moving.rs:229-280)
-    state, moves_dirty = jax.lax.fori_loop(
-        0, R, del_body, (state, moves_dirty)
-    )
+    with jax.named_scope("delete_pass"):
+        state, moves_dirty = jax.lax.fori_loop(
+            0, R, del_body, (state, moves_dirty)
+        )
     return _recompute_moves(state, moves_dirty, client_rank), scan_hist
 
 

@@ -532,142 +532,174 @@ class BatchIngestor:
         raw bytes to HBM and decode on device; the rest take the exact
         host lane (`_plan_doc`). Both lanes merge into one
         `apply_update_batch` dispatch, so mixed batches cost one step.
+
+        Host stages (docs/observability.md, "Inside a dispatch"):
+        `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan`, `.host_rows`),
+        `ingest.merge` (`_merge_fast_lane`), `ingest.rank_table`,
+        `integrate.xla_batch`, `ingest.flags`, `ingest.recover`.
         """
         if len(payloads) != self.n_docs:
             raise ValueError(f"expected {self.n_docs} payload slots")
-        self._last_fast_flags = None
-        from ytpu.native import available, decode_update_columns
         from ytpu.utils.phases import phases
 
-        # keyless span: phases.span() itself returns the shared no-op
-        # when disabled — no extra guard needed without a key tuple
-        plan_span = phases.span("ingest.plan")
-        plan_span.__enter__()
-        native = available()
-        fast_idx: List[int] = []
-        fast_payloads: List[bytes] = []
-        # recovery support: per fast doc, first-touch (client -> pre-step
-        # clock) deltas — cheaper than copying whole SVs on the hot path
-        fast_sv_deltas: Dict[int, Dict[int, int]] = {}
-        fast_has_str: List[bool] = []
-        slow_updates: List[Optional[Update]] = [None] * self.n_docs
-        max_fast_rows, max_fast_dels = 0, 0
-        max_sections, max_steps = 0, 0
-        for d, p in enumerate(payloads):
-            if p is None:
-                continue
-            cols = decode_update_columns(p) if native else None
-            if cols is not None and self._fast_eligible(d, cols):
-                fast_idx.append(d)
-                fast_payloads.append(p)
-                sv = self.svs[d]
-                deltas = fast_sv_deltas[d] = {}
-                rows_here = 0
-                str_here = 0
-                for i in range(cols.n_blocks):
-                    kind = int(cols.kind[i])
-                    if kind == 10:
-                        continue
-                    if kind in _WIRE_REF_KINDS and int(cols.length[i]) > 0:
-                        str_here += 1
-                    c = int(cols.client[i])
-                    self.enc.interner.intern(c)
-                    for arr, clk in (
-                        (cols.origin_client, cols.origin_clock),
-                        (cols.ror_client, cols.ror_clock),
-                    ):
-                        if int(clk[i]) >= 0:
-                            self.enc.interner.intern(int(arr[i]))
-                    deltas.setdefault(c, sv.get(c))
-                    sv.set_max(c, int(cols.clock[i]) + int(cols.length[i]))
-                    if int(cols.length[i]) > 0:
-                        rows_here += 1
-                for i in range(cols.n_dels):
-                    self.enc.interner.intern(int(cols.del_client[i]))
-                fast_has_str.append(str_here > 0)
-                max_fast_rows = max(max_fast_rows, rows_here)
-                max_fast_dels = max(max_fast_dels, cols.n_dels)
-                max_sections = max(max_sections, cols.n_client_sections)
-                max_steps = max(max_steps, steps_for_columns(cols))
-            else:
-                slow_updates[d] = Update.decode_v1(p)
-        self.fast_docs += len(fast_idx)
-        self.slow_docs += sum(1 for u in slow_updates if u is not None)
+        # keyless spans: phases.span() itself returns the shared no-op
+        # when disabled — no extra guard needed without a key tuple. The
+        # body stays in this frame: a helper frame between here and the
+        # jitted calls made every trace of a new shape dearer (10 s of a
+        # run's set-up on the chip, PERF.md §6, PR 26)
+        with phases.span("ingest.apply"):
+            self._last_fast_flags = None
+            from ytpu.native import available, decode_update_columns
 
-        all_rows, all_dels = [], []
-        for d, u in enumerate(slow_updates):
-            rows, dels = self._plan_doc(d, u)
-            all_rows.append(rows)
-            all_dels.append(dels)
-        n_rows = _bucket(max(max_fast_rows, 1, max(len(r) for r in all_rows)))
-        n_dels = _bucket(max(max_fast_dels, 1, max(len(d_) for d_ in all_dels)))
-        batch = self.enc.batch_from_rows(all_rows, all_dels, n_rows, n_dels)
-        # end of the host planning phase (an exception above simply drops
-        # the span — the recorder holds no resources)
-        plan_span.__exit__(None, None, None)
-        self._m_fast.inc(len(fast_idx))
-        self._m_slow.inc(sum(1 for u in slow_updates if u is not None))
+            with phases.span("ingest.plan"):
+                native = available()
+                fast_idx: List[int] = []
+                fast_payloads: List[bytes] = []
+                # recovery support: per fast doc, first-touch (client ->
+                # pre-step clock) deltas — cheaper than copying whole SVs on
+                # the hot path
+                fast_sv_deltas: Dict[int, Dict[int, int]] = {}
+                fast_has_str: List[bool] = []
+                slow_updates: List[Optional[Update]] = [None] * self.n_docs
+                max_fast_rows, max_fast_dels = 0, 0
+                max_sections, max_steps = 0, 0
+                with phases.span("ingest.plan.prescan"):
+                    for d, p in enumerate(payloads):
+                        if p is None:
+                            continue
+                        cols = decode_update_columns(p) if native else None
+                        if cols is None or not self._fast_eligible(d, cols):
+                            slow_updates[d] = Update.decode_v1(p)
+                            continue
+                        fast_idx.append(d)
+                        fast_payloads.append(p)
+                        sv = self.svs[d]
+                        deltas = fast_sv_deltas[d] = {}
+                        rows_here = 0
+                        str_here = 0
+                        for i in range(cols.n_blocks):
+                            kind = int(cols.kind[i])
+                            if kind == 10:
+                                continue
+                            if (
+                                kind in _WIRE_REF_KINDS
+                                and int(cols.length[i]) > 0
+                            ):
+                                str_here += 1
+                            c = int(cols.client[i])
+                            self.enc.interner.intern(c)
+                            for arr, clk in (
+                                (cols.origin_client, cols.origin_clock),
+                                (cols.ror_client, cols.ror_clock),
+                            ):
+                                if int(clk[i]) >= 0:
+                                    self.enc.interner.intern(int(arr[i]))
+                            deltas.setdefault(c, sv.get(c))
+                            sv.set_max(
+                                c, int(cols.clock[i]) + int(cols.length[i])
+                            )
+                            if int(cols.length[i]) > 0:
+                                rows_here += 1
+                        for i in range(cols.n_dels):
+                            self.enc.interner.intern(int(cols.del_client[i]))
+                        fast_has_str.append(str_here > 0)
+                        max_fast_rows = max(max_fast_rows, rows_here)
+                        max_fast_dels = max(max_fast_dels, cols.n_dels)
+                        max_sections = max(max_sections, cols.n_client_sections)
+                        max_steps = max(max_steps, steps_for_columns(cols))
+                self.fast_docs += len(fast_idx)
+                self.slow_docs += sum(1 for u in slow_updates if u is not None)
 
-        flags = None
-        chunk_base = None
-        if fast_idx:
-            # retain wire bytes only for lanes that actually emitted string
-            # rows (delete/GC-only payloads hold no device-referenced spans)
-            batch, flags, chunk_base = self._merge_fast_lane(
-                batch, fast_idx, fast_payloads, n_rows, n_dels,
-                retain_lanes=fast_has_str,
-                n_steps=16 * ((max_steps + 15) // 16) or None,
-                max_sections=_bucket(max_sections, 2) if max_sections else None,
-            )
-        self.state = apply_update_batch(
-            self.state, batch, self.enc.interner.rank_table()
-        )
-        if flags is not None:
-            # `_fast_eligible` proved these lanes decode clean, and flagged
-            # lanes integrate nothing (their rows are marked invalid), so a
-            # flag here means the device saw something the host pre-scan
-            # did not. Recover exactly: rewind the mirror SV and re-route
-            # the payload through the host lane in one follow-up step.
-            # (The readback overlaps the already-dispatched integrate step.)
-            from ytpu.ops.decode_kernel import FLAG_ERRORS
+                with phases.span("ingest.plan.host_rows"):
+                    all_rows, all_dels = [], []
+                    for d, u in enumerate(slow_updates):
+                        rows, dels = self._plan_doc(d, u)
+                        all_rows.append(rows)
+                        all_dels.append(dels)
+                    n_rows = _bucket(
+                        max(max_fast_rows, 1, max(len(r) for r in all_rows))
+                    )
+                    n_dels = _bucket(
+                        max(max_fast_dels, 1, max(len(d_) for d_ in all_dels))
+                    )
+                    batch = self.enc.batch_from_rows(
+                        all_rows, all_dels, n_rows, n_dels
+                    )
+            self._m_fast.inc(len(fast_idx))
+            self._m_slow.inc(sum(1 for u in slow_updates if u is not None))
 
-            f = np.asarray(flags)
-            if (f & FLAG_ERRORS).any():
-                bad_lanes = set(np.nonzero(f & FLAG_ERRORS)[0].tolist())
-                bad = [fast_idx[i] for i in bad_lanes]
-                self.fast_recoveries += len(bad)
-                self._m_recoveries.inc(len(bad))
-                # release the retained wire chunk if every string-bearing
-                # lane in it was flagged (their refs never went live); a
-                # partially-flagged chunk keeps the surviving lanes' bytes
-                # (the flagged lanes' share is stranded — rare, bounded by
-                # decoder-disagreement frequency)
-                if chunk_base is not None and all(
-                    i in bad_lanes
-                    for i, has in enumerate(fast_has_str)
-                    if has
-                ):
-                    self.payloads.drop_if_unreferenced(chunk_base)
-                recovery: List[Optional[Update]] = [None] * self.n_docs
-                for d in bad:
-                    clocks = self.svs[d].clocks
-                    for c, old in fast_sv_deltas[d].items():
-                        if old == 0:
-                            clocks.pop(c, None)
-                        else:
-                            clocks[c] = old
-                    recovery[d] = Update.decode_v1(payloads[d])
-                r_rows, r_dels = [], []
-                for d, u in enumerate(recovery):
-                    rows, dels = self._plan_doc(d, u)
-                    r_rows.append(rows)
-                    r_dels.append(dels)
-                rbatch = self.enc.batch_from_rows(r_rows, r_dels)
-                self.state = apply_update_batch(
-                    self.state, rbatch, self.enc.interner.rank_table()
+            flags = None
+            chunk_base = None
+            if fast_idx:
+                # retain wire bytes only for lanes that actually emitted string
+                # rows (delete/GC-only payloads hold no device-referenced spans)
+                batch, flags, chunk_base = self._merge_fast_lane(
+                    batch, fast_idx, fast_payloads, n_rows, n_dels,
+                    retain_lanes=fast_has_str,
+                    n_steps=16 * ((max_steps + 15) // 16) or None,
+                    max_sections=_bucket(max_sections, 2) if max_sections else None,
                 )
-            self._last_fast_flags = f
-        return self.state
+            with phases.span("ingest.rank_table"):
+                client_rank = self.enc.interner.rank_table()
+            self.state = apply_update_batch(self.state, batch, client_rank)
+            if flags is not None:
+                # `_fast_eligible` proved these lanes decode clean, and flagged
+                # lanes integrate nothing (their rows are marked invalid), so a
+                # flag here means the device saw something the host pre-scan
+                # did not. Recover exactly: rewind the mirror SV and re-route
+                # the payload through the host lane in one follow-up step.
+                # (The readback overlaps the already-dispatched integrate step.)
+                from ytpu.ops.decode_kernel import FLAG_ERRORS
+
+                with phases.span("ingest.flags"):
+                    f = np.asarray(flags)
+                if (f & FLAG_ERRORS).any():
+                    with phases.span("ingest.recover"):
+                        self._recover_flagged(
+                            payloads, f, fast_idx, fast_has_str,
+                            fast_sv_deltas, chunk_base,
+                        )
+                self._last_fast_flags = f
+            return self.state
+
+    def _recover_flagged(
+        self, payloads, f, fast_idx, fast_has_str, fast_sv_deltas, chunk_base
+    ) -> None:
+        """Re-route the fast lanes the device flagged through the host
+        lane, in one follow-up step (`apply_bytes`, `ingest.recover`)."""
+        from ytpu.ops.decode_kernel import FLAG_ERRORS
+
+        bad_lanes = set(np.nonzero(f & FLAG_ERRORS)[0].tolist())
+        bad = [fast_idx[i] for i in bad_lanes]
+        self.fast_recoveries += len(bad)
+        self._m_recoveries.inc(len(bad))
+        # release the retained wire chunk if every string-bearing
+        # lane in it was flagged (their refs never went live); a
+        # partially-flagged chunk keeps the surviving lanes' bytes
+        # (the flagged lanes' share is stranded — rare, bounded by
+        # decoder-disagreement frequency)
+        if chunk_base is not None and all(
+            i in bad_lanes for i, has in enumerate(fast_has_str) if has
+        ):
+            self.payloads.drop_if_unreferenced(chunk_base)
+        recovery: List[Optional[Update]] = [None] * self.n_docs
+        for d in bad:
+            clocks = self.svs[d].clocks
+            for c, old in fast_sv_deltas[d].items():
+                if old == 0:
+                    clocks.pop(c, None)
+                else:
+                    clocks[c] = old
+            recovery[d] = Update.decode_v1(payloads[d])
+        r_rows, r_dels = [], []
+        for d, u in enumerate(recovery):
+            rows, dels = self._plan_doc(d, u)
+            r_rows.append(rows)
+            r_dels.append(dels)
+        rbatch = self.enc.batch_from_rows(r_rows, r_dels)
+        self.state = apply_update_batch(
+            self.state, rbatch, self.enc.interner.rank_table()
+        )
 
     def _merge_fast_lane(
         self,
@@ -685,105 +717,121 @@ class BatchIngestor:
 
         from ytpu.ops.decode_kernel import (
             decode_updates_v1,
+            gather_raw_lanes,
+            key_hash_host,
             pack_updates,
         )
-
-        maxlen = max(len(p) for p in fast_payloads)
         from ytpu.utils.phases import phases
 
-        if self.ingest == "raw":
-            # RAW lane: ship the actual wire bytes + offsets, gather the
-            # padded [S, L] matrix on device (byte-identical to the
-            # packed matrix — gather_raw_lanes zero-masks past lens)
-            from ytpu.ops.decode_kernel import gather_raw_lanes
-
+        # the host stages of the merge, in order (docs/observability.md,
+        # "Inside a dispatch"): pack, h2d, gather, retain, tables,
+        # decode.v1, rebase, scatter — between them they are the host's
+        # share of a served step that is neither planning nor integrate
+        with phases.span("ingest.merge"):
+            maxlen = max(len(p) for p in fast_payloads)
             S = len(fast_payloads)
-            L = _bucket(maxlen + 16, 64)
-            lens = np.asarray(
-                [len(p) for p in fast_payloads], dtype=np.int32
-            )
-            offsets = np.zeros(S, dtype=np.int32)
-            if S > 1:
-                offsets[1:] = np.cumsum(lens[:-1])
-            flat = b"".join(fast_payloads)
-            # the gather specializes on the arena LENGTH: pad it to a
-            # bucket so a long soak's ever-varying flush sizes reuse a
-            # handful of compiled gathers (the zero tail is masked out,
-            # exactly like the padded matrix's row tails)
-            wire = np.zeros(_bucket(len(flat), 256), dtype=np.uint8)
-            wire[: len(flat)] = np.frombuffer(flat, dtype=np.uint8)
-            if phases.enabled:
-                phases.transfer(
-                    "ingest.fast_lane",
-                    wire.nbytes + offsets.nbytes + lens.nbytes,
-                    "h2d",
+            raw = self.ingest == "raw"
+            with phases.span("ingest.merge.pack"):
+                if raw:
+                    # RAW lane: ship the actual wire bytes + offsets,
+                    # gather the padded [S, L] matrix on device
+                    # (byte-identical to the packed matrix —
+                    # gather_raw_lanes zero-masks past lens)
+                    L = _bucket(maxlen + 16, 64)
+                    lens = np.asarray(
+                        [len(p) for p in fast_payloads], dtype=np.int32
+                    )
+                    offsets = np.zeros(S, dtype=np.int32)
+                    if S > 1:
+                        offsets[1:] = np.cumsum(lens[:-1])
+                    flat = b"".join(fast_payloads)
+                    # the gather specializes on the arena LENGTH: pad it
+                    # to a bucket so a long soak's ever-varying flush
+                    # sizes reuse a handful of compiled gathers (the zero
+                    # tail is masked out, exactly like the padded
+                    # matrix's row tails)
+                    wire = np.zeros(_bucket(len(flat), 256), dtype=np.uint8)
+                    wire[: len(flat)] = np.frombuffer(flat, dtype=np.uint8)
+                    host_arrays = (wire, offsets, lens)
+                else:
+                    buf, lens = pack_updates(
+                        fast_payloads, pad_to=_bucket(maxlen + 16, 64)
+                    )
+                    S, L = buf.shape
+                    host_arrays = (buf, lens)
+            with phases.span("ingest.merge.h2d"):
+                # the wire bytes' one trip to HBM, counted here and
+                # nowhere else (decode.v1 is handed device arrays)
+                dev_arrays = [jnp.asarray(a) for a in host_arrays]
+                dev_lens = dev_arrays[-1]
+                if phases.enabled:
+                    phases.transfer(
+                        "ingest.merge.h2d",
+                        sum(a.nbytes for a in host_arrays),
+                        "h2d",
+                    )
+            with phases.span("ingest.merge.gather"):
+                dev_buf = (
+                    gather_raw_lanes(*dev_arrays, L) if raw else dev_arrays[0]
                 )
-            dev_buf = gather_raw_lanes(
-                jnp.asarray(wire), jnp.asarray(offsets), jnp.asarray(lens), L
-            )
-        else:
-            buf, lens = pack_updates(
-                fast_payloads, pad_to=_bucket(maxlen + 16, 64)
-            )
-            S, L = buf.shape
-            if phases.enabled:
-                # padded wire matrix shipped to HBM (the fast lane's only
-                # host→device payload; decode.v1 counts it again at the
-                # jit boundary — this stage attributes it to ingest)
-                phases.transfer(
-                    "ingest.fast_lane", buf.nbytes + lens.nbytes, "h2d"
+            # Retain only the wire bytes of lanes that emitted string rows
+            # (lens-trimmed, concatenated) — refs are rebased from the
+            # padded s*L layout onto the compact one. Lanes without string
+            # rows have no device-referenced spans, so their bytes are
+            # never kept.
+            with phases.span("ingest.merge.retain"):
+                keep = (
+                    np.ones(S, dtype=bool)
+                    if retain_lanes is None
+                    else np.asarray(retain_lanes, dtype=bool)
                 )
-            dev_buf = jnp.asarray(buf)
-        # Retain only the wire bytes of lanes that emitted string rows
-        # (lens-trimmed, concatenated) — refs are rebased from the padded
-        # s*L layout onto the compact one. Lanes without string rows have
-        # no device-referenced spans, so their bytes are never kept.
-        keep = (
-            np.ones(S, dtype=bool)
-            if retain_lanes is None
-            else np.asarray(retain_lanes, dtype=bool)
-        )
-        kept_lens = np.where(keep, lens, 0).astype(np.int64)
-        prefix = np.zeros(S, dtype=np.int64)
-        prefix[1:] = np.cumsum(kept_lens[:-1])
-        base = 0
-        if keep.any():
-            compact = b"".join(
-                p for p, k in zip(fast_payloads, keep) if k
+                kept_lens = np.where(keep, lens, 0).astype(np.int64)
+                prefix = np.zeros(S, dtype=np.int64)
+                prefix[1:] = np.cumsum(kept_lens[:-1])
+                base = 0
+                if keep.any():
+                    compact = b"".join(
+                        p for p, k in zip(fast_payloads, keep) if k
+                    )
+                    base = self.payloads.add_chunk(
+                        np.frombuffer(compact, dtype=np.uint8)
+                    )
+            with phases.span("ingest.merge.tables"):
+                prim_hash = np.full(S, -1, dtype=np.int32)
+                for s_i, d in enumerate(fast_idx):
+                    name = self.primary_roots.get(d)
+                    if name is not None:
+                        prim_hash[s_i] = key_hash_host(name.encode("utf-8"))
+                tables = dict(
+                    client_table=self._client_table(),
+                    key_table=self._key_table(),
+                    client_hash_table=self._client_hash_table(),
+                    primary_root_hash=jnp.asarray(prim_hash),
+                )
+            stream, flags = decode_updates_v1(
+                dev_buf,
+                dev_lens,
+                n_rows,
+                n_dels,
+                n_steps=n_steps,
+                max_sections=max_sections,
+                **tables,
             )
-            base = self.payloads.add_chunk(
-                np.frombuffer(compact, dtype=np.uint8)
-            )
-        from ytpu.ops.decode_kernel import key_hash_host
-
-        prim_hash = np.full(S, -1, dtype=np.int32)
-        for s_i, d in enumerate(fast_idx):
-            name = self.primary_roots.get(d)
-            if name is not None:
-                prim_hash[s_i] = key_hash_host(name.encode("utf-8"))
-        stream, flags = decode_updates_v1(
-            dev_buf,
-            jnp.asarray(lens),
-            n_rows,
-            n_dels,
-            n_steps=n_steps,
-            client_table=self._client_table(),
-            max_sections=max_sections,
-            key_table=self._key_table(),
-            client_hash_table=self._client_hash_table(),
-            primary_root_hash=jnp.asarray(prim_hash),
-        )
-        is_str_ref = stream.valid & (stream.content_ref >= 0)
-        lane = jnp.arange(S, dtype=jnp.int32)[:, None]
-        local = stream.content_ref - lane * L
-        compact_ref = jnp.asarray(prefix.astype(np.int32))[:, None] + local
-        stream = stream._replace(
-            content_ref=jnp.where(
-                is_str_ref, -2 - base - compact_ref, stream.content_ref
-            )
-        )
-        idx = jnp.asarray(np.asarray(fast_idx, dtype=np.int32))
-        merged = jax.tree.map(
-            lambda full, fast: full.at[idx].set(fast), batch, stream
-        )
+            with phases.span("ingest.merge.rebase"):
+                is_str_ref = stream.valid & (stream.content_ref >= 0)
+                lane = jnp.arange(S, dtype=jnp.int32)[:, None]
+                local = stream.content_ref - lane * L
+                compact_ref = (
+                    jnp.asarray(prefix.astype(np.int32))[:, None] + local
+                )
+                stream = stream._replace(
+                    content_ref=jnp.where(
+                        is_str_ref, -2 - base - compact_ref, stream.content_ref
+                    )
+                )
+            with phases.span("ingest.merge.scatter"):
+                idx = jnp.asarray(np.asarray(fast_idx, dtype=np.int32))
+                merged = jax.tree.map(
+                    lambda full, fast: full.at[idx].set(fast), batch, stream
+                )
         return merged, flags, (base if keep.any() else None)

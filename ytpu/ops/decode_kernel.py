@@ -376,6 +376,7 @@ def steps_for_columns(cols) -> int:
     )
 
 
+@jax.named_scope("decode_v1")
 def decode_updates_v1(
     buf: jax.Array,
     lens: jax.Array,
@@ -1562,16 +1563,16 @@ def decode_updates_v1(
 
     tick()
     if phases.enabled:
-        # wire bytes shipped to HBM this step (buf may already be a device
-        # array — either way these bytes crossed or will cross the link).
-        # size*itemsize, not .nbytes: callers sometimes wrap this entry in
-        # an outer jax.jit (bench probes), and tracers carry shape/dtype
-        # but not nbytes
-        phases.transfer(
-            "decode.v1",
-            buf.size * buf.dtype.itemsize + lens.size * lens.dtype.itemsize,
-            "h2d",
-        )
+        if not isinstance(buf, jax.Array):
+            # a host buffer crosses the link at this call; a device array
+            # was uploaded, and its bytes counted, by whoever made it
+            # (`ingest.merge.h2d`). jit tracers are jax.Arrays too.
+            phases.transfer(
+                "decode.v1",
+                buf.size * buf.dtype.itemsize
+                + lens.size * lens.dtype.itemsize,
+                "h2d",
+            )
         span = phases.span(
             "decode.v1",
             (buf.shape, max_rows, max_dels, n_steps, max_sections,

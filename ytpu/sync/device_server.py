@@ -26,6 +26,7 @@ Two serving modes:
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -41,6 +42,8 @@ from ytpu.sync.protocol import (
     message_reader,
 )
 from ytpu.sync.server import DeviceBatchFull, Session, SyncServer
+from ytpu.utils.phases import phases
+from ytpu.utils.trace import current_trace_id, tracer
 
 __all__ = ["DeviceBatchFull", "DeviceSyncServer"]
 
@@ -114,10 +117,12 @@ class DeviceSyncServer(SyncServer):
         self._queues: List[List[bytes]] = [
             [] for _ in range(ingestor.n_docs)
         ]
-        # per-queued-update request trace ids, in lockstep with _queues
-        # (ISSUE-11): the device-dispatch span names the requests whose
-        # updates it ships, closing the net → admission → dispatch chain
-        self._queue_traces: List[List[Optional[str]]] = [
+        # per-queued-update (request trace id, enqueue instant), in
+        # lockstep with _queues (ISSUE-11): the device-dispatch span names
+        # the requests whose updates it ships, closing the net → admission
+        # → dispatch chain, and `sync.queue_wait` is the time from that
+        # instant to the start of the step that carries the update
+        self._queue_traces: List[List[tuple]] = [
             [] for _ in range(ingestor.n_docs)
         ]
         self._last_dispatch = metrics.gauge("sync.last_dispatch_unix")
@@ -191,11 +196,12 @@ class DeviceSyncServer(SyncServer):
 
     def _enqueue(self, slot: int, payload: bytes) -> None:
         """Queue one update for a slot, recording the ambient request
-        trace id (None outside a traced request) in lockstep."""
-        from ytpu.utils.trace import current_trace_id
-
+        trace id (None outside a traced request) and the instant in
+        lockstep."""
         self._queues[slot].append(payload)
-        self._queue_traces[slot].append(current_trace_id())
+        self._queue_traces[slot].append(
+            (current_trace_id(), time.perf_counter())
+        )
 
     # --- slot management -------------------------------------------------------
 
@@ -275,7 +281,7 @@ class DeviceSyncServer(SyncServer):
         try:
             return self._receive_frames_unsafe(session, data)
         except Exception as e:
-            from ytpu.utils import metrics, tracer
+            from ytpu.utils import metrics
 
             metrics.counter("net.bad_frames").inc()
             # the flight-recorder ring keeps WHAT threw (bounded,
@@ -298,11 +304,18 @@ class DeviceSyncServer(SyncServer):
     ) -> List[bytes]:
         if not self.device_authoritative or session.tenant in self._host_tenants:
             return super().receive_frames(session, data)
+        with phases.span("sync.receive"):
+            return self._receive_device_frames(session, data)
+
+    def _receive_device_frames(
+        self, session: Session, data: bytes
+    ) -> List[bytes]:
         t = self.tenant(session.tenant)
         slot = self.slot_of(session.tenant)
         replies: List[bytes] = []
-        msgs = list(message_reader(data))
-        for i, msg in enumerate(msgs):
+        with phases.span("sync.receive.parse"):
+            msgs = list(message_reader(data))
+        for msg in msgs:
             if msg.kind == MSG_SYNC:
                 sub: SyncMessage = msg.body
                 if sub.tag == MSG_SYNC_STEP_1:
@@ -323,7 +336,8 @@ class DeviceSyncServer(SyncServer):
                     # via the ingestor's BLOCK_ROOT_ANCHOR rows — multi-root
                     # tenants are served from the batch like any other
                     # (doc.rs:156-228 is the reference's normal doc shape)
-                    self._note_roots(session.tenant, sub.payload)
+                    with phases.span("sync.receive.roots"):
+                        self._note_roots(session.tenant, sub.payload)
                     self._enqueue(slot, sub.payload)
                     self._applied.inc()
                     t.applied.inc()
@@ -331,15 +345,16 @@ class DeviceSyncServer(SyncServer):
                     # broadcast at-least-once (idempotent CRDT updates;
                     # the host path dedups via observer events, the device
                     # path trades that for never touching a host doc)
-                    frame = Message.sync(
-                        SyncMessage.update(sub.payload)
-                    ).encode_v1()
-                    tframe = self._trace_frame()
-                    for other in t.sessions:
-                        if other is not session:
-                            if tframe is not None:
-                                other.push(tframe)
-                            other.push(frame)
+                    with phases.span("sync.receive.fanout"):
+                        frame = Message.sync(
+                            SyncMessage.update(sub.payload)
+                        ).encode_v1()
+                        tframe = self._trace_frame()
+                        for other in t.sessions:
+                            if other is not session:
+                                if tframe is not None:
+                                    other.push(tframe)
+                                other.push(frame)
                 continue
             reply = self.protocol.handle_message(t.awareness, msg)
             if reply is not None:
@@ -653,56 +668,58 @@ class DeviceSyncServer(SyncServer):
         failure dumps the tracer's flight-recorder ring (`YTPU_TRACE`)
         before re-raising — a kernel abort leaves a replayable trace.
         """
-        import time as _time
-
-        from ytpu.utils import tracer
-
         depth_gauge = self._queue_depth
         depth_gauge.set(sum(len(q) for q in self._queues))
         steps = 0
         while any(self._queues) and (max_steps is None or steps < max_steps):
-            # peek, apply, THEN pop — a failing step must not drop the other
-            # slots' already-dequeued updates. The apply histogram times the
-            # real device step here (the SLO metric), not the enqueue.
-            payloads = [q[0] if q else None for q in self._queues]
             # dispatch span (ISSUE-11): names the request trace ids whose
             # updates this batch step ships, so the Chrome trace links a
             # frame's net/admission spans to the device dispatch that
-            # integrated it (plus the ambient ctx of whoever flushed)
-            span = (
-                tracer.span(
+            # integrated it (plus the ambient ctx of whoever flushed).
+            # Without the ring the span is still live while `phases` is on.
+            if tracer.enabled:
+                span = tracer.span(
                     "sync.dispatch",
                     step=steps,
                     traces=[
-                        t[0] for t in self._queue_traces if t and t[0]
+                        t[0][0] for t in self._queue_traces if t and t[0][0]
                     ],
                 )
-                if tracer.enabled
-                else None
-            )
-            try:
-                with self._apply_hist.time():
-                    if span is not None:
-                        with span:
-                            self.ingestor.apply_bytes(payloads)
-                    else:
+            else:
+                span = tracer.span("sync.dispatch")
+            with span:
+                # peek, apply, THEN pop — a failing step must not drop the
+                # other slots' already-dequeued updates. The apply histogram
+                # times the real device step here (the SLO metric), not the
+                # enqueue.
+                with phases.span("sync.dispatch.peek"):
+                    payloads = [q[0] if q else None for q in self._queues]
+                if phases.enabled:
+                    start = time.perf_counter()
+                    carried = [t[0][1] for t in self._queue_traces if t]
+                    phases.add_value("sync.dispatch_updates", len(carried))
+                    for enqueued in carried:
+                        phases.add_time("sync.queue_wait", start - enqueued)
+                try:
+                    with self._apply_hist.time():
                         self.ingestor.apply_bytes(payloads)
-            except Exception as e:
-                tracer.dump_on_error(error=e)
-                raise
-            for q in self._queues:
-                if q:
-                    q.pop(0)
-            for t in self._queue_traces:
-                if t:
-                    t.pop(0)
+                except Exception as e:
+                    tracer.dump_on_error(error=e)
+                    raise
+                with phases.span("sync.dispatch.pop"):
+                    for q in self._queues:
+                        if q:
+                            q.pop(0)
+                    for t in self._queue_traces:
+                        if t:
+                            t.pop(0)
             steps += 1
         if steps:
             # only a REAL dispatch refreshes the freshness gauge: the
             # serve loop flushes on every frame/idle tick, and an
             # empty-queue flush must not make /healthz report a device
             # that never dispatched as fresh
-            self._last_dispatch.set(_time.time())
+            self._last_dispatch.set(time.time())
         depth_gauge.set(sum(len(q) for q in self._queues))
         return steps
 

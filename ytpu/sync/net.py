@@ -52,7 +52,7 @@ _BYTES_OUT = metrics.counter("net.bytes_out")
 _CONNECTIONS = metrics.gauge("net.connections")
 # resilience series (ISSUE-6, docs/robustness.md)
 _FRAME_TIMEOUTS = metrics.counter("net.frame_timeouts")
-_BAD_FRAMES = metrics.counter("net.bad_frames")
+metrics.counter("net.bad_frames")  # counted through _session_dropped()
 _CONNECT_RETRIES = metrics.counter("net.connect_retries")
 _RECONNECTS = metrics.counter("net.reconnects")
 # per-session serving series (ISSUE-9): how many sessions are live right
@@ -65,9 +65,21 @@ _RECONNECTS = metrics.counter("net.reconnects")
 # "failover" for sessions a killed replica dropped wholesale — they
 # reconnect to a mesh survivor, ISSUE-13)
 _SESSIONS_ACTIVE = metrics.gauge("net.sessions_active")
-_SESSIONS_DROPPED = metrics.counter(
-    "net.sessions_dropped", labelnames=("reason",)
-)
+metrics.counter("net.sessions_dropped", labelnames=("reason",))
+
+
+def _session_dropped(reason: str) -> None:
+    """Count one dropped session by reason (and the bad frame behind a
+    ``bad_frame`` drop). A drop is rare, so the families are looked up
+    here, not cached at import like the per-frame series above: a
+    test-time ``metrics.reset()`` orphans a cached family, and the
+    exposition then misses the very series an operator reads after an
+    incident."""
+    if reason == "bad_frame":
+        metrics.counter("net.bad_frames").inc()
+    metrics.counter("net.sessions_dropped", labelnames=("reason",)).labels(
+        reason
+    ).inc()
 
 
 class FrameTimeout(ConnectionError):
@@ -296,8 +308,7 @@ async def serve(
                                     write_frame(writer, f)
                         except _PEER_ERRORS:
                             # malformed frame: this session's problem only
-                            _BAD_FRAMES.inc()
-                            _SESSIONS_DROPPED.labels("bad_frame").inc()
+                            _session_dropped("bad_frame")
                             break
                         except Exception as e:
                             # a server-side bug triggered by one frame
@@ -306,8 +317,7 @@ async def serve(
                             # session drops, the accept loop lives — and
                             # the flight recorder keeps what threw
                             # (bounded ring)
-                            _BAD_FRAMES.inc()
-                            _SESSIONS_DROPPED.labels("bad_frame").inc()
+                            _session_dropped("bad_frame")
                             tracer.instant(
                                 "net.bad_frame",
                                 error=repr(e),
@@ -331,14 +341,14 @@ async def serve(
             # from peer garbage (FrameTimeout IS a ConnectionError, so it
             # must be caught before the generic peer-error band)
             if session is not None:
-                _SESSIONS_DROPPED.labels("timeout").inc()
+                _session_dropped("timeout")
         except _PEER_ERRORS:
             # this band is mostly abortive transport closes (RST, EOF
             # inside a header) — a real malformed FRAME is counted
             # bad_frame at the receive loop above; conflating the two
             # would mis-attribute plain peer deaths in a churny soak
             if session is not None:
-                _SESSIONS_DROPPED.labels("disconnect").inc()
+                _session_dropped("disconnect")
         finally:
             _CONNECTIONS.dec()
             if session is not None:

@@ -49,13 +49,22 @@ INTO the compile event (``event["memory"]``), surfaced as
 ``memory.program_peak_bytes{program=}`` per-program ratchet, and
 rolled up by ``memory_report()`` (per-program peaks + the peak
 program — the resident-bytes axis the PR-4 roofline lacked).
-Snapshots are taken EAGERLY at thunk-build time because donated
-arguments (`donate_argnums`) are deleted by the time the span exits.
+The thunk reads only shapes and dtypes, which a donated
+(`donate_argnums`) and by then deleted argument still answers for, so
+building it costs a closure and a warm call builds no spec tree.
 
-Disabled-path contract (the default): one attribute check, zero
+Disabled-path contract (the default): attribute checks alone (this
+recorder's flag and, for the process-wide pair, the tracer's), zero
 allocation — call sites guard with ``if phases.enabled:`` before
 building keys, and ``span()`` hands back a shared no-op context
 manager. Enable via ``YTPU_PHASES=1`` or ``phases.enable()``.
+
+Host spans (keyless stages) are on the same seam as the tracer's
+(`_Span` below, utils/trace.py): a span made here also lands in the
+tracer's ring when that is on, one made through ``tracer.span`` is also
+summed into the stage of its name here, and either way it is written
+into the profiler's trace as ``ytpu.<name>``. Stages nest by
+containment; ``self_s`` is a stage's time less its nested spans'.
 
 Stage namespaces: ``replay.*`` is the async apply pipeline (stage /
 stall / overlap_ratio / inflight_depth / stage_bytes...), ``encode.*``
@@ -67,6 +76,7 @@ never from real runs.
 
 from __future__ import annotations
 
+import contextvars
 import os
 import threading
 import time
@@ -104,6 +114,7 @@ class _Stage:
         "compile_calls",
         "compile_s",
         "execute_s",
+        "self_s",
         "h2d_bytes",
         "d2h_bytes",
         "value",
@@ -114,6 +125,7 @@ class _Stage:
         self.compile_calls = 0
         self.compile_s = 0.0
         self.execute_s = 0.0
+        self.self_s = 0.0  # compile_s + execute_s less the time in nested spans
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.value = None  # scalar gauge (overlap_ratio, in-flight depth)
@@ -144,22 +156,26 @@ def _sig_delta(prev, new, axes) -> List[Dict[str, str]]:
 def program_memory(fn, *args, **kwargs):
     """Build a zero-arg memory-capture thunk for ``span(memory=...)``.
 
-    Snapshots every array-like argument (has ``.shape`` and ``.dtype``)
-    into a ``jax.ShapeDtypeStruct`` EAGERLY — the instrumented programs
-    donate their state operands (`donate_argnums`), so by span exit the
-    real buffers are deleted; specs survive. Non-array arguments pass
-    through verbatim (they are the program's static args). ``fn`` is
-    the jitted callable, or a zero-arg resolver returning one (for
-    lazily-built module globals the span body itself constructs).
+    Building it costs one closure: the thunk runs on the first-sighting
+    path only, and it is there that every array-like argument (has
+    ``.shape`` and ``.dtype``) becomes a ``jax.ShapeDtypeStruct``. The
+    instrumented programs donate their state operands
+    (`donate_argnums`), so by then the real buffers are deleted — a
+    deleted array still answers for its shape and dtype, which is all
+    that is read. Non-array arguments pass through verbatim (they are
+    the program's static args). ``fn`` is the jitted callable, or a
+    zero-arg resolver returning one (for lazily-built module globals the
+    span body itself constructs).
 
     The thunk AOT-lowers and compiles against the specs — a
     compile-cache hit when invoked on the first-sighting path, since
     the traced call that just ran compiled the identical program — and
     returns the ``memory_analysis()`` kind split in bytes, or raises
     (the recorder treats any raise as "no capture")."""
-    import jax
 
     def _spec(a):
+        import jax
+
         if hasattr(a, "shape") and hasattr(a, "dtype"):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
         if isinstance(a, tuple) and hasattr(a, "_fields"):  # NamedTuple
@@ -168,10 +184,9 @@ def program_memory(fn, *args, **kwargs):
             return type(a)(_spec(x) for x in a)
         return a
 
-    specs = tuple(_spec(a) for a in args)
-    kwspecs = {k: _spec(v) for k, v in kwargs.items()}
-
     def thunk():
+        specs = tuple(_spec(a) for a in args)
+        kwspecs = {k: _spec(v) for k, v in kwargs.items()}
         f = fn if hasattr(fn, "lower") else fn()
         stats = f.lower(*specs, **kwspecs).compile().memory_analysis()
         return {
@@ -189,52 +204,95 @@ def program_memory(fn, *args, **kwargs):
     return thunk
 
 
-class _PhaseSpan:
-    __slots__ = ("_rec", "_stage", "_key", "_axes", "_start", "_memory")
+# --- the span seam -----------------------------------------------------------
+# One host span, whoever makes it: `tracer.span(name)` (utils/trace.py)
+# and `phases.span(stage)` both build a `_Span`, and its one enter/exit
+# feeds every recorder that is on — the tracer's Chrome-event ring, this
+# module's per-stage sums — and opens `jax.profiler.TraceAnnotation(
+# "ytpu." + name)`, so that while a profiler trace is being taken the
+# span sits on the profiler's host plane, on the clock the device ops
+# are on. The `ytpu.` prefix exists only there: a reducer picks the
+# program's spans out of the host plane by it.
+
+#: the innermost open span of this thread/task: a span's exit gives its
+#: time to the span around it, which is how a stage's `self_s` (time in
+#: no nested span) is known without naming the nesting anywhere
+_OPEN: "contextvars.ContextVar[Optional[_Span]]" = contextvars.ContextVar(
+    "ytpu_open_span", default=None
+)
+
+#: jax.profiler.TraceAnnotation; resolved at the first live span (False:
+#: no jax here), so importing this module never imports jax
+_ANNOTATION = None
+
+
+def _annotate(name: str):
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:  # host-only install: the recorders still work
+            cls = False
+        _ANNOTATION = cls
+    if cls is False:
+        return None
+    ann = cls("ytpu." + name)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    __slots__ = (
+        "_name", "_rec", "_key", "_axes", "_memory", "_ring", "_args",
+        "_ann", "_token", "_outer", "_nested_s", "_start",
+    )
 
     def __init__(
-        self, rec: "PhaseRecorder", stage: str, key, axes=None, memory=None
+        self, name: str, rec=None, key=None, axes=None, memory=None,
+        ring=None, args=None,
     ):
-        self._rec = rec
-        self._stage = stage
+        self._name = name
+        self._rec = rec  # PhaseRecorder to sum into, or None
         self._key = key
         self._axes = axes
         self._memory = memory
+        self._ring = ring  # Tracer to append a Chrome event to, or None
+        self._args = args
 
     def __enter__(self):
+        self._outer = _OPEN.get()
+        self._token = _OPEN.set(self)
+        self._nested_s = 0.0
+        self._ann = _annotate(self._name)
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._start
-        rec = self._rec
-        event = None
-        with rec._lock:
-            st = rec._stages.get(self._stage)
-            if st is None:
-                st = rec._stages[self._stage] = _Stage()
-            st.calls += 1
-            if self._key is not None and (
-                (self._stage, self._key) not in rec._seen
-            ):
-                rec._seen.add((self._stage, self._key))
-                st.compile_calls += 1
-                st.compile_s += dt
-                event = rec._record_compile_locked(
-                    self._stage, self._key, self._axes, dt
-                )
-            else:
-                st.execute_s += dt
-        if event is not None:
-            rec._emit_compile_metrics(event)
-            if self._memory is not None:
-                rec._record_memory(self._stage, event, self._memory)
+        end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _OPEN.reset(self._token)
+        dt = end - self._start
+        if self._outer is not None:
+            self._outer._nested_s += dt
+        if self._ring is not None:
+            self._ring._complete(self._name, self._start, dt, self._args)
+        if self._rec is not None:
+            self._rec._record(
+                self._name, self._key, self._axes, self._memory,
+                dt, dt - self._nested_s,
+            )
         return False
 
 
 class PhaseRecorder:
     def __init__(self, enabled: bool = False):
         self.enabled = enabled
+        #: the Tracer whose ring this recorder's spans also feed, and
+        #: whose being on makes them live: set for the process-wide pair
+        #: alone (utils/trace.py links `phases` and `tracer`)
+        self._peer = None
         self._stages: Dict[str, _Stage] = {}
         self._seen: set = set()
         self._lock = threading.Lock()
@@ -454,12 +512,50 @@ class PhaseRecorder:
         attribution (e.g. ``("state", "rows", "scan_plan")``).
         ``memory`` optionally passes a ``program_memory(...)`` thunk,
         invoked ONLY on the first-sighting path (compile-cache hit) to
-        journal the program's device-memory kind split."""
-        if not self.enabled:
-            return NULL_SPAN
-        if key is not None:
+        journal the program's device-memory kind split.
+
+        The span is the one `_Span` (the seam above): it also lands in
+        the process-wide tracer's ring when that is on, and in the
+        profiler's trace as ``ytpu.<stage>``."""
+        rec = self if self.enabled else None
+        ring = self._peer
+        if ring is not None and not ring.enabled:
+            ring = None
+        if rec is None:
+            if ring is None:
+                return NULL_SPAN
+            key = axes = memory = None
+        elif key is not None:
             key = self._fault_key(stage, key)
-        return _PhaseSpan(self, stage, key, axes, memory)
+        return _Span(
+            stage, rec, key, axes, memory, ring,
+            None if ring is None else ring._context_args(None),
+        )
+
+    def _record(
+        self, stage: str, key, axes, memory, dt: float, self_dt: float
+    ) -> None:
+        """A `_Span`'s exit: sum `dt` into `stage` (compile_s on the
+        first sighting of a key, else execute_s) and `self_dt`, the part
+        of it spent in no nested span, into its self_s."""
+        event = None
+        with self._lock:
+            st = self._stages.get(stage)
+            if st is None:
+                st = self._stages[stage] = _Stage()
+            st.calls += 1
+            st.self_s += self_dt
+            if key is not None and (stage, key) not in self._seen:
+                self._seen.add((stage, key))
+                st.compile_calls += 1
+                st.compile_s += dt
+                event = self._record_compile_locked(stage, key, axes, dt)
+            else:
+                st.execute_s += dt
+        if event is not None:
+            self._emit_compile_metrics(event)
+            if memory is not None:
+                self._record_memory(stage, event, memory)
 
     def transfer(
         self, stage: str, nbytes: int, direction: str = "h2d"
@@ -491,6 +587,7 @@ class PhaseRecorder:
                 st = self._stages[stage] = _Stage()
             st.calls += int(calls)
             st.execute_s += float(seconds)
+            st.self_s += float(seconds)
 
     def set_value(self, stage: str, value: float) -> None:
         """Record a scalar gauge under `stage` (snapshot key "value") —
@@ -531,7 +628,9 @@ class PhaseRecorder:
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         """Per-stage breakdown: calls / compile_calls / compile_s /
-        execute_s / h2d_bytes / d2h_bytes / transfer_bytes (sum)."""
+        execute_s / self_s (compile_s + execute_s less the time spent in
+        spans nested in this stage's) / h2d_bytes / d2h_bytes /
+        transfer_bytes (sum)."""
         out: Dict[str, Dict[str, float]] = {}
         with self._lock:
             for name, st in self._stages.items():
@@ -540,6 +639,7 @@ class PhaseRecorder:
                     "compile_calls": st.compile_calls,
                     "compile_s": round(st.compile_s, 6),
                     "execute_s": round(st.execute_s, 6),
+                    "self_s": round(st.self_s, 6),
                     "h2d_bytes": st.h2d_bytes,
                     "d2h_bytes": st.d2h_bytes,
                     "transfer_bytes": st.h2d_bytes + st.d2h_bytes,

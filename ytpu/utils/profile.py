@@ -20,7 +20,12 @@ report time, attributes the elapsed wall into seven exclusive buckets:
 - ``net``       — serving-loop residual: `sync.apply_update` histogram
   wall not explained by the instrumented stages nested inside the apply
   path (framing, socket writes, queue hops);
-- ``host``      — every other instrumented host stage.
+- ``host``      — every other instrumented host stage (the ingest
+  merge's packing, uploads and eager glue, the frame path's stages).
+
+Stages nest (`sync.dispatch` ⊃ `ingest.apply` ⊃ `ingest.merge` ⊃
+`decode.v1`), so a bucket takes each stage's ``self_s`` — its time in no
+nested span — never a container's whole time on top of its children's.
 
 ``idle`` is what remains of the measured wall, and ``stall`` (the
 overlap engines' consumer-blocked time) is reported informationally —
@@ -79,7 +84,7 @@ _PREFIX_RULES = (
     ("finisher", ("encode.finish",)),
     ("device", ("replay.chunk", "integrate.", "decode.", "compact.",
                 "encode.select", "encode.pack", "encode.diff",
-                "pipeline.decode", "ingest.")),
+                "pipeline.decode")),
 )
 
 
@@ -89,14 +94,18 @@ _PREFIX_RULES = (
 #: and the `encode.pack` span runs nested inside that same timing
 _DOUBLE_COUNTED = frozenset({"encode.stage", "encode.pack"})
 
+#: time an update spent queued, summed over updates: nobody's work, and
+#: many updates wait at once, so it belongs to no bucket of the wall
+_WAITS = frozenset({"sync.queue_wait"})
+
 
 def classify_stage(name: str) -> Optional[str]:
     """Bucket for one phases stage name; None = excluded (bench
-    rehearsal wrappers, double-counted encode gauges), ``"stall"`` =
-    informational only."""
+    rehearsal wrappers, double-counted encode gauges, queue waits),
+    ``"stall"`` = informational only."""
     if name.startswith("rehearsal") or name.startswith("host."):
         return None
-    if name in _DOUBLE_COUNTED:
+    if name in _DOUBLE_COUNTED or name in _WAITS:
         return None
     if name.endswith(".stall"):
         return "stall"
@@ -131,7 +140,7 @@ class ProfileWindow:
     def _capture(self):
         snap = self._rec.snapshot()
         per_stage = {
-            name: (d["compile_s"], d["execute_s"])
+            name: (d["compile_s"], d["self_s"])
             for name, d in snap.items()
         }
         return per_stage, _apply_wall_s(), time.perf_counter()
@@ -148,10 +157,12 @@ class ProfileWindow:
         wall = max(wall, 0.0)
         seconds = {b: 0.0 for b in _BUCKETS}
         stall_s = 0.0
-        for name, (comp, execu) in cur.items():
-            base_comp, base_exec = self._base.get(name, (0.0, 0.0))
+        for name, (comp, own) in cur.items():
+            base_comp, base_own = self._base.get(name, (0.0, 0.0))
             d_comp = max(0.0, comp - base_comp)
-            d_exec = max(0.0, execu - base_exec)
+            # a first sighting's whole time is compile_s; the rest of the
+            # stage's own time is steady-state
+            d_exec = max(0.0, own - base_own - d_comp)
             bucket = classify_stage(name)
             if bucket is None:
                 continue

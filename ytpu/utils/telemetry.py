@@ -75,10 +75,20 @@ from .phases import phases
 
 __all__ = ["TelemetryServer"]
 
-#: metrics the plane records about itself (scrape visibility is also an
-#: observability surface — a dashboard that stops updating should be
-#: distinguishable from a process that stopped serving)
-_SCRAPES = metrics.counter("telemetry.scrapes", labelnames=("endpoint",))
+def _count_scrape(endpoint: str) -> None:
+    """The plane's record of itself (scrape visibility is also an
+    observability surface — a dashboard that stops updating should be
+    distinguishable from a process that stopped serving). The family is
+    looked up per scrape, which is rare: a module-level one would be
+    orphaned from the exposition by a test-time ``metrics.reset()``."""
+    metrics.counter("telemetry.scrapes", labelnames=("endpoint",)).labels(
+        endpoint
+    ).inc()
+
+
+# registered at import so the series is scraped (at nothing) before the
+# first scrape is counted
+metrics.counter("telemetry.scrapes", labelnames=("endpoint",))
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -101,42 +111,42 @@ class _Handler(BaseHTTPRequestHandler):
         path = self.path.split("?", 1)[0]
         try:
             if path == "/metrics":
-                _SCRAPES.labels("metrics").inc()
+                _count_scrape("metrics")
                 self._reply(
                     200,
                     "text/plain; version=0.0.4; charset=utf-8",
                     self.telemetry.metrics_text().encode("utf-8"),
                 )
             elif path == "/fleet":
-                _SCRAPES.labels("fleet").inc()
+                _count_scrape("fleet")
                 self._reply(
                     200,
                     "text/plain; version=0.0.4; charset=utf-8",
                     self.telemetry.fleet_text().encode("utf-8"),
                 )
             elif path == "/snapshot":
-                _SCRAPES.labels("snapshot").inc()
+                _count_scrape("snapshot")
                 self._reply(
                     200,
                     "application/json",
                     json.dumps(self.telemetry.snapshot()).encode("utf-8"),
                 )
             elif path == "/profile":
-                _SCRAPES.labels("profile").inc()
+                _count_scrape("profile")
                 self._reply(
                     200,
                     "application/json",
                     json.dumps(self.telemetry.profile()).encode("utf-8"),
                 )
             elif path == "/capacity":
-                _SCRAPES.labels("capacity").inc()
+                _count_scrape("capacity")
                 self._reply(
                     200,
                     "application/json",
                     json.dumps(self.telemetry.capacity()).encode("utf-8"),
                 )
             elif path in ("/healthz", "/health"):
-                _SCRAPES.labels("healthz").inc()
+                _count_scrape("healthz")
                 self._reply(
                     200,
                     "application/json",

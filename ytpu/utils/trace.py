@@ -3,8 +3,19 @@
 The reference's only introspection is Debug/Display dumps; here spans wrap
 the host stages (decode, dispatch, encode, commit) and export to the
 chrome://tracing / Perfetto JSON format. Device-side profiling remains
-jax.profiler's job — `trace_span` nests correctly under its host annotations
-because both use wall-clock.
+jax.profiler's job, and the two meet in the profiler's own trace: every
+live span is also opened as ``jax.profiler.TraceAnnotation("ytpu." +
+name)``, so while a profiler trace is being taken the span sits on the
+profiler's host plane, on the clock the device ops are on. (The ring's
+own ``ts`` is this process's `perf_counter` since the tracer's origin: a
+different clock, good for a Chrome trace of the host alone.)
+
+One seam with the phase recorder (`ytpu.utils.phases._Span`): a span
+made here and one made through ``phases.span(stage)`` are the same
+object with the same enter/exit. It feeds whichever of the two
+process-wide recorders is on — this ring, the stage sums of the same
+name — so ``tracer.span("sync.dispatch")`` is live, and summed into the
+``sync.dispatch`` stage, when only ``phases`` is enabled.
 
 Flight-recorder semantics: the event store is a BOUNDED ring (drop-oldest,
 `max_events`), so a long-lived server can leave tracing on and always
@@ -23,8 +34,9 @@ paths write it out:
   when a process is SIGKILLed by a timeout), so a lost-device or
   kernel-abort round leaves a replayable trace instead of a stderr tail.
 
-Disabled-path cost: `span()` returns a shared no-op context manager —
-no allocation, no string formatting (SURVEY §5.5 hot-path rule).
+Disabled-path cost (neither recorder on): `span()` returns a shared
+no-op context manager — no allocation, no string formatting (SURVEY
+§5.5 hot-path rule).
 """
 
 from __future__ import annotations
@@ -40,6 +52,7 @@ from collections import deque
 from typing import Optional
 
 from .phases import NULL_SPAN as _NULL_SPAN  # shared no-op span singleton
+from .phases import _Span, phases
 
 __all__ = [
     "Tracer",
@@ -132,36 +145,6 @@ def resume_trace(trace: str, origin: str = "", **fields):
     return trace_context(trace=trace, **fields)
 
 
-class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_start")
-
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
-        self._tracer = tracer
-        self._name = name
-        self._args = args
-
-    def __enter__(self):
-        self._start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        end = time.perf_counter()
-        tr = self._tracer
-        ev = {
-            "name": self._name,
-            "ph": "X",  # complete event
-            "ts": (self._start - tr._t0) * 1e6,
-            "dur": (end - self._start) * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() % 1_000_000,
-        }
-        if self._args:
-            ev["args"] = self._args
-        with tr._lock:
-            tr._events.append(ev)  # deque(maxlen=...): drop-oldest
-        return False
-
-
 class Tracer:
     """Bounded-ring span recorder (drop-oldest at `max_events`)."""
 
@@ -173,6 +156,10 @@ class Tracer:
         self._events: deque = deque(maxlen=max_events)
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
+        #: the PhaseRecorder this tracer's spans are also summed into,
+        #: and whose being on makes them live: set for the process-wide
+        #: pair alone (the bottom of this module)
+        self._peer = None
 
     def enable(self) -> None:
         self.enabled = True
@@ -192,22 +179,48 @@ class Tracer:
         """Context manager recording one complete event; the disabled
         path returns a shared no-op (zero per-call allocation). An
         active `trace_context()` merges its fields (trace id, tenant,
-        session) into the span args — explicit args win on collision."""
+        session) into the span args — explicit args win on collision.
+        With the phase recorder on the span is live whether or not the
+        ring is, and its time is summed into the stage of its name."""
+        rec = self._peer
+        if rec is not None and not rec.enabled:
+            rec = None
         if not self.enabled:
-            return _NULL_SPAN
+            if rec is None:
+                return _NULL_SPAN
+            return _Span(name, rec)
+        return _Span(name, rec, ring=self, args=self._context_args(args))
+
+    @staticmethod
+    def _context_args(args: Optional[dict]) -> Optional[dict]:
         ctx = _TRACE_CTX.get()
         if ctx is not None:
-            args = {**ctx, **args}
-        return _Span(self, name, args or None)
+            args = {**ctx, **args} if args else ctx
+        return args or None
+
+    def _complete(
+        self, name: str, start: float, dur: float, args: Optional[dict]
+    ) -> None:
+        """A `_Span`'s exit: one complete ("X") event into the ring."""
+        ev = {
+            "name": name,
+            "ph": "X",
+            "ts": (start - self._t0) * 1e6,
+            "dur": dur * 1e6,
+            "pid": os.getpid(),
+            "tid": threading.get_ident() % 1_000_000,
+        }
+        if args:
+            ev["args"] = args
+        with self._lock:
+            self._events.append(ev)  # deque(maxlen=...): drop-oldest
 
     def instant(self, name: str, **args) -> None:
         """One point-in-time marker event (phase transitions, errors).
         Merges the active `trace_context()` fields like `span`."""
         if not self.enabled:
             return
-        ctx = _TRACE_CTX.get()
-        if ctx is not None:
-            args = {**ctx, **args}
+        args = self._context_args(args)
         ev = {
             "name": name,
             "ph": "i",
@@ -266,6 +279,9 @@ class Tracer:
 
 
 tracer = Tracer()
+# the process-wide pair share the span seam (module docstring)
+tracer._peer = phases
+phases._peer = tracer
 
 
 def trace_span(name: str, **args):
