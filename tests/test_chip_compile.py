@@ -154,6 +154,56 @@ def test_served_decode_compiles(one_chip, lanes, max_sections):
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
 
 
+# (lanes, rows = deletes bucket, lane width): the decode cases' lane counts
+# at the 4-row bucket, and the benchmark's prefill step — every slot a lane,
+# the 512-row bucket, 6.6 KB of wire a lane
+MERGE_SHAPES = [(1, 4, 64), (8, 4, 64), (N_DOCS, 512, 8192)]
+
+
+@pytest.mark.parametrize("lanes,rows,width", MERGE_SHAPES)
+def test_served_merge_compiles(one_chip, lanes, rows, width):
+    """`merge_stream` (rebase + every plane's scatter, one program) as
+    `_merge_fast_lane` calls it: the host lane's batch over all slots, the
+    decoded stream over `lanes` of them."""
+    from ytpu.models.batch_doc import BatchEncoder
+    from ytpu.models.ingest import _merge_stream_jit
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, rows, rows)
+    stream = jax.tree.map(lambda a: a[:lanes], batch)
+    compiled = _merge_stream_jit.lower(
+        _shapes(batch, one_chip),
+        _shapes(stream, one_chip),
+        i32(lanes),
+        i32(lanes),
+        i32(),
+        width=width,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
+    # one output per plane of the batch, each over all slots
+    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [
+        a.shape for a in jax.tree.leaves(batch)
+    ]
+
+
+@pytest.mark.parametrize("lanes,width", [(s, w) for s, _, w in MERGE_SHAPES])
+def test_served_gather_compiles(one_chip, lanes, width):
+    """`gather_raw_lanes` through the merge's jit: a bucketed wire arena
+    and its offsets table in, the padded [S, L] lane matrix out."""
+    from ytpu.models.ingest import _bucket, _gather_raw_lanes_jit
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    wire = _bucket(lanes * (width - 16) * 13 // 16, 256)  # lanes about 13/16 full
+    compiled = _gather_raw_lanes_jit.lower(
+        jax.ShapeDtypeStruct((wire,), jnp.uint8, sharding=one_chip),
+        i32(lanes),
+        i32(lanes),
+        width=width,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
+    assert compiled.out_info.shape == (lanes, width)
+
+
 def test_served_diff_selection_compiles(one_chip):
     from ytpu.models.batch_doc import _encode_diff_batch_jit
 

@@ -561,3 +561,159 @@ def test_fast_lane_multi_root_doc():
         assert d.get_text("body").get_string() == body.get_string()
         assert d.get_text("title").get_string() == "A Title?"
         assert d.get_map("meta").to_json() == {"lang": "en"}
+
+
+# --- the merge as jitted programs (ISSUE-27) ---------------------------------
+
+MERGE_DOCS = 16
+# per room: an insert, a delete-only update, a second insert (multi-byte
+# text); the last two are each applicable right after the first
+_MERGE_OPS = lambda d: [("i", 0, f"hello{d:02d}"), ("d", 1, 2), ("i", 5, f"wörld🙂{d:02d}")]
+INS, DEL, INS2 = 0, 1, 2
+# case -> {slot: which update of its room's log rides the measured step}
+MERGE_CASES = {
+    "one_lane": {5: INS2},
+    "eight_scattered_lanes": {d: INS2 for d in (0, 2, 5, 7, 9, 11, 14, 15)},
+    "all_delete": {d: DEL for d in (1, 4, 6)},
+    "mixed_with_host_lane": {0: INS2, 8: INS2, 12: DEL},  # + slot 3, below
+    "every_slot": {d: INS2 for d in range(MERGE_DOCS)},
+    "delete_lane_among_strings": {2: INS2, 6: DEL, 9: INS2},
+}
+HOST_SLOT = 3  # "mixed": a stash drains through the host lane in this slot
+
+
+def _spy_on_merge(monkeypatch):
+    """Record every `merge_stream` call: (args, kwargs, merged batch)."""
+    from ytpu.models import ingest
+
+    calls, real = [], ingest._merge_stream_jit
+
+    def spy(*args, **kw):
+        out = real(*args, **kw)
+        calls.append((args, kw, out))
+        return out
+
+    monkeypatch.setattr(ingest, "_merge_stream_jit", spy)
+    return calls
+
+
+def _reference_merge(batch, stream, idx, prefix, base, width):
+    """The merge in numpy: rebase the string refs, then `full[idx] = fast`."""
+    full = {k: np.array(v) for k, v in batch._asdict().items()}
+    fast = {k: np.asarray(v) for k, v in stream._asdict().items()}
+    ref = fast["content_ref"]
+    lane = np.arange(len(idx), dtype=np.int32)[:, None]
+    compact = prefix[:, None].astype(np.int32) + (ref - lane * np.int32(width))
+    fast["content_ref"] = np.where(
+        fast["valid"] & (ref >= 0), np.int32(-2 - base) - compact, ref
+    )
+    for k in full:
+        full[k][idx] = fast[k]
+    return full
+
+
+@needs_native
+@pytest.mark.parametrize("ingest_mode", ["raw", "packed"])
+@pytest.mark.parametrize("case", sorted(MERGE_CASES))
+def test_merge_stream_equals_numpy_reference(monkeypatch, case, ingest_mode):
+    """One jitted program in place of the eager tree-map: same leaves, same
+    dtypes, and `idx` / `prefix` / `base` as the step's payloads give them."""
+    from ytpu.models.ingest import _bucket
+
+    logs = [_edit_log(_MERGE_OPS(d), client_id=d + 1)[0] for d in range(MERGE_DOCS)]
+    oracles = [Doc(client_id=99) for _ in range(MERGE_DOCS)]
+    ing = BatchIngestor(n_docs=MERGE_DOCS, capacity=64, ingest=ingest_mode)
+
+    def step(payloads):
+        for d, p in enumerate(payloads):
+            if p is not None:
+                oracles[d].apply_update_v1(p)
+        ing.apply_bytes(payloads)
+        assert _flags_clean(ing)
+
+    step([log[INS] for log in logs])
+    plan = {d: logs[d][which] for d, which in MERGE_CASES[case].items()}
+    if case == "mixed_with_host_lane":
+        # slot 3 skips an update (stash), then receives it: the host lane
+        # plans the stashed rows into the same step the fast lanes ride
+        extra, _ = _edit_log(
+            [("i", 0, "hello03"), ("i", 7, "abc"), ("i", 10, "def")], client_id=HOST_SLOT + 1
+        )
+        step([extra[2] if d == HOST_SLOT else None for d in range(MERGE_DOCS)])
+        assert ing.pending_update(HOST_SLOT) is not None
+        plan[HOST_SLOT] = extra[1]
+    calls = _spy_on_merge(monkeypatch)
+    base_before = ing.payloads.total_bytes
+    fast_before = ing.fast_docs
+    step([plan.get(d) for d in range(MERGE_DOCS)])
+
+    lanes = sorted(MERGE_CASES[case])  # the fast lanes' slots, in slot order
+    assert ing.fast_docs - fast_before == len(lanes)
+    ((batch, stream, idx, prefix, base), kw, merged), = calls
+    # what the step's payloads say the operands are
+    keep = [MERGE_CASES[case][d] != DEL for d in lanes]
+    kept_lens = [len(plan[d]) if k else 0 for d, k in zip(lanes, keep)]
+    want_prefix = np.concatenate([[0], np.cumsum(kept_lens[:-1])]).astype(np.int32)
+    assert idx.dtype == np.int32 and idx.tolist() == lanes
+    assert prefix.dtype == np.int32 and prefix.tolist() == want_prefix.tolist()
+    assert np.asarray(base).dtype == np.int32
+    assert int(base) == (base_before if any(keep) else 0)
+    assert kw == {"width": _bucket(max(len(plan[d]) for d in lanes) + 16, 64)}
+    assert np.asarray(stream.valid).shape[0] == len(lanes)
+
+    want = _reference_merge(batch, stream, idx, prefix, int(base), kw["width"])
+    assert type(merged) is type(batch)
+    for name, leaf in merged._asdict().items():
+        got = np.asarray(leaf)
+        assert got.dtype == want[name].dtype == np.asarray(getattr(batch, name)).dtype, name
+        np.testing.assert_array_equal(got, want[name], err_msg=name)
+    if case == "mixed_with_host_lane":
+        # the host lane's rows sit in their slot, untouched by the scatter
+        host_rows = np.asarray(batch.valid)[HOST_SLOT]
+        assert host_rows.sum() == 2
+        np.testing.assert_array_equal(np.asarray(merged.valid)[HOST_SLOT], host_rows)
+        np.testing.assert_array_equal(
+            np.asarray(merged.content_ref)[HOST_SLOT], np.asarray(batch.content_ref)[HOST_SLOT]
+        )
+    if any(keep):  # string rows point into the chunk this step retained
+        refs = np.asarray(merged.content_ref)[lanes]
+        assert (refs[np.asarray(stream.valid) & (np.asarray(stream.content_ref) >= 0)] <= -2 - base_before).all()
+    # and the refs resolve: every room reads back as the host oracle does
+    assert int(np.asarray(ing.state.error).max()) == 0
+    for d in range(MERGE_DOCS):
+        assert get_string(ing.state, d, ing.payloads) == oracles[d].get_text("text").get_string(), d
+
+
+@needs_native
+def test_merge_programs_do_not_retrace_on_values(monkeypatch):
+    """`idx`, `prefix`, `base` and the payload bytes are operands: steps of
+    one shape share one entry of each program's cache, a new lane count adds
+    exactly one. (The guard against `base` becoming a static argument.)"""
+    from ytpu.models import ingest
+    from ytpu.utils import progbudget
+
+    monkeypatch.setattr(progbudget, "_MAX", 10**9)  # no eviction under our feet
+    logs = [_edit_log(_MERGE_OPS(d), client_id=d + 1)[0] for d in range(8)]
+    ing = BatchIngestor(n_docs=8, capacity=64)
+    programs = (ingest._gather_raw_lanes_jit, ingest._merge_stream_jit)
+    for jit in programs:  # earlier tests of this process may hold the same keys
+        jit.clear_cache()
+    sizes = lambda: tuple(jit._cache_size() for jit in programs)
+    calls = _spy_on_merge(monkeypatch)
+
+    def step(plan):
+        ing.apply_bytes([logs[d][w] if (w := plan.get(d)) is not None else None for d in range(8)])
+        assert _flags_clean(ing)
+
+    step({d: INS for d in range(8)})
+    step({0: INS2, 1: INS2, 2: INS2})
+    first = sizes()
+    step({4: DEL, 6: INS2, 7: INS2})  # other slots, prefix, base and bytes; same shapes
+    (_, _, idx_a, prefix_a, base_a), _, _ = calls[1]
+    (_, _, idx_b, prefix_b, base_b), _, _ = calls[2]
+    assert idx_a.tolist() != idx_b.tolist() and prefix_a.tolist() != prefix_b.tolist()
+    assert int(base_a) != int(base_b)
+    assert sizes() == first
+    step({3: INS2, 5: INS2})  # a new lane count
+    assert sizes() == (first[0] + 1, first[1] + 1)
+    assert int(np.asarray(ing.state.error).max()) == 0

@@ -27,7 +27,7 @@ NESTING = {
     "ingest.plan": ("ingest.plan.prescan", "ingest.plan.host_rows"),
     "ingest.merge": (
         "ingest.merge.pack", "ingest.merge.h2d", "ingest.merge.gather", "ingest.merge.retain",
-        "ingest.merge.tables", "decode.v1", "ingest.merge.rebase", "ingest.merge.scatter",
+        "ingest.merge.tables", "decode.v1", "ingest.merge.scatter",
     ),
 }
 COUNTERS = ("sync.dispatch_updates", "sync.queue_wait")
@@ -94,6 +94,7 @@ def test_served_step_yields_every_stage(served):
     assert snap["sync.dispatch_updates"]["value"] == steps  # one update a step
     assert snap["sync.queue_wait"]["execute_s"] > 0.0
     assert "ingest.recover" not in snap  # the rare path stayed rare
+    assert "ingest.merge.rebase" not in snap  # folded into `.scatter`'s one program
 
 
 @pytest.mark.parametrize("parent", sorted(NESTING))

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ytpu.core import Update
@@ -33,7 +35,11 @@ from ytpu.models.batch_doc import (
     apply_update_batch,
     init_state,
 )
-from ytpu.ops.decode_kernel import ChunkedWirePayloads, steps_for_columns
+from ytpu.ops.decode_kernel import (
+    ChunkedWirePayloads,
+    gather_raw_lanes,
+    steps_for_columns,
+)
 
 __all__ = ["BatchIngestor"]
 
@@ -41,8 +47,6 @@ __all__ = ["BatchIngestor"]
 def _sorted_table(mapping: Dict[int, int]):
     """(sorted keys, value perm) as device i32 arrays — the shape every
     device lookup table (clients, key hashes, client hashes) shares."""
-    import jax.numpy as jnp
-
     ks = sorted(mapping)
     return (
         jnp.asarray(np.asarray(ks, dtype=np.int32)),
@@ -55,6 +59,47 @@ _FAST_KINDS = frozenset((0, 1, 2, 3, 4, 5, 6, 7, 8, 10, 11))
 # kinds whose rows keep content refs into the retained wire bytes
 _WIRE_REF_KINDS = frozenset((2, 3, 4, 5, 6, 7, 8))
 _I32_MAX = 2**31 - 1
+
+
+@jax.named_scope("merge_stream")
+def merge_stream(batch, stream, idx, prefix, base, width: int):
+    """The fast lanes' decoded `stream` ([S, ...]) laid over the host
+    lane's `batch` ([n_docs, ...]) at the slots `idx` ([S] i32).
+
+    String rows leave the decoder with refs into the padded lane matrix
+    (``s * width + start``); the step retained only the string-bearing
+    lanes' bytes, trimmed and concatenated, so each ref is rebased onto
+    that chunk: lane s's bytes start at ``prefix[s]`` ([S] i32) of the
+    chunk at `base` (0-d i32), and a wire ref is stored as ``-2 - ref``.
+    `prefix` and `base` differ every step: operands, never statics."""
+    lane = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None]
+    compact_ref = prefix[:, None] + (stream.content_ref - lane * width)
+    is_str_ref = stream.valid & (stream.content_ref >= 0)
+    stream = stream._replace(
+        content_ref=jnp.where(
+            is_str_ref, -2 - base - compact_ref, stream.content_ref
+        )
+    )
+    return jax.tree.map(lambda full, fast: full.at[idx].set(fast), batch, stream)
+
+
+# The merge's device-side glue is two programs of its own, not eager ops:
+# eagerly one plane's `at[idx].set` is about a dozen tiny programs, each
+# ~180 us of host time on the chip's host (PERF.md §6, PR 27).
+# `gather_raw_lanes` is jitted here, not at its definition: decode_v2,
+# integrate_kernel and replay call it inside programs of their own.
+_merge_stream_jit = jax.jit(merge_stream, static_argnames=("width",))
+_gather_raw_lanes_jit = jax.jit(gather_raw_lanes, static_argnames=("width",))
+
+
+def _register_programs():
+    from ytpu.utils import progbudget
+
+    progbudget.register("merge_stream", _merge_stream_jit)
+    progbudget.register("gather_raw_lanes", _gather_raw_lanes_jit)
+
+
+_register_programs()
 
 
 def _bucket(n: int, lo: int = 4) -> int:
@@ -100,8 +145,6 @@ class BatchIngestor:
         self.ingest = ingest
         self.state: DocStateBatch = init_state(n_docs, capacity)
         if self.shard_docs:
-            import jax
-
             from ytpu.parallel.mesh import batch_mesh, shard_docs_put
 
             mesh = batch_mesh()
@@ -516,8 +559,6 @@ class BatchIngestor:
         Ids above int32 (random 53-bit Yjs clients) are excluded here —
         they resolve through the varint-byte hash table instead
         (`_client_hash_table`)."""
-        import jax.numpy as jnp
-
         ids = sorted(
             c for c in self.enc.interner.to_idx if 0 <= c <= _I32_MAX
         )
@@ -535,8 +576,10 @@ class BatchIngestor:
 
         Host stages (docs/observability.md, "Inside a dispatch"):
         `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan`, `.host_rows`),
-        `ingest.merge` (`_merge_fast_lane`), `ingest.rank_table`,
-        `integrate.xla_batch`, `ingest.flags`, `ingest.recover`.
+        `ingest.merge` (`_merge_fast_lane`: the uploads, then one enqueue
+        each under `.gather`, `decode.v1` and `.scatter`),
+        `ingest.rank_table`, `integrate.xla_batch`, `ingest.flags`,
+        `ingest.recover`.
         """
         if len(payloads) != self.n_docs:
             raise ValueError(f"expected {self.n_docs} payload slots")
@@ -712,12 +755,14 @@ class BatchIngestor:
         n_steps=None,
         max_sections=None,
     ):
-        import jax
-        import jax.numpy as jnp
+        """Decode the fast lanes on the device and lay them over `batch`.
 
+        After the uploads, at most three device programs: the raw lanes'
+        gather, `decode_updates_v1`, `merge_stream`. Each is keyed by what
+        keys the decode family (S, the wire bucket, L, `n_rows`, `n_dels`)
+        and by nothing else."""
         from ytpu.ops.decode_kernel import (
             decode_updates_v1,
-            gather_raw_lanes,
             key_hash_host,
             pack_updates,
         )
@@ -725,8 +770,8 @@ class BatchIngestor:
 
         # the host stages of the merge, in order (docs/observability.md,
         # "Inside a dispatch"): pack, h2d, gather, retain, tables,
-        # decode.v1, rebase, scatter — between them they are the host's
-        # share of a served step that is neither planning nor integrate
+        # decode.v1, scatter — between them they are the host's share of
+        # a served step that is neither planning nor integrate
         with phases.span("ingest.merge"):
             maxlen = max(len(p) for p in fast_payloads)
             S = len(fast_payloads)
@@ -771,23 +816,26 @@ class BatchIngestor:
                         "h2d",
                     )
             with phases.span("ingest.merge.gather"):
+                # one enqueue (`jit_gather_raw_lanes`); the packed mode
+                # uploaded the matrix itself
                 dev_buf = (
-                    gather_raw_lanes(*dev_arrays, L) if raw else dev_arrays[0]
+                    _gather_raw_lanes_jit(*dev_arrays, width=L)
+                    if raw
+                    else dev_arrays[0]
                 )
             # Retain only the wire bytes of lanes that emitted string rows
-            # (lens-trimmed, concatenated) — refs are rebased from the
-            # padded s*L layout onto the compact one. Lanes without string
-            # rows have no device-referenced spans, so their bytes are
-            # never kept.
+            # (lens-trimmed, concatenated) — `merge_stream` rebases refs
+            # from the padded s*L layout onto the compact one. Lanes
+            # without string rows have no device-referenced spans, so
+            # their bytes are never kept.
             with phases.span("ingest.merge.retain"):
                 keep = (
                     np.ones(S, dtype=bool)
                     if retain_lanes is None
                     else np.asarray(retain_lanes, dtype=bool)
                 )
-                kept_lens = np.where(keep, lens, 0).astype(np.int64)
-                prefix = np.zeros(S, dtype=np.int64)
-                prefix[1:] = np.cumsum(kept_lens[:-1])
+                prefix = np.zeros(S, dtype=np.int32)
+                prefix[1:] = np.cumsum(np.where(keep, lens, 0)[:-1])
                 base = 0
                 if keep.any():
                     compact = b"".join(
@@ -817,21 +865,15 @@ class BatchIngestor:
                 max_sections=max_sections,
                 **tables,
             )
-            with phases.span("ingest.merge.rebase"):
-                is_str_ref = stream.valid & (stream.content_ref >= 0)
-                lane = jnp.arange(S, dtype=jnp.int32)[:, None]
-                local = stream.content_ref - lane * L
-                compact_ref = (
-                    jnp.asarray(prefix.astype(np.int32))[:, None] + local
-                )
-                stream = stream._replace(
-                    content_ref=jnp.where(
-                        is_str_ref, -2 - base - compact_ref, stream.content_ref
-                    )
-                )
             with phases.span("ingest.merge.scatter"):
-                idx = jnp.asarray(np.asarray(fast_idx, dtype=np.int32))
-                merged = jax.tree.map(
-                    lambda full, fast: full.at[idx].set(fast), batch, stream
+                # one enqueue (`jit_merge_stream`: rebase + the scatter
+                # of every plane); idx, prefix and base ride up with it
+                merged = _merge_stream_jit(
+                    batch,
+                    stream,
+                    np.asarray(fast_idx, dtype=np.int32),
+                    prefix,
+                    np.int32(base),
+                    width=L,
                 )
         return merged, flags, (base if keep.any() else None)
