@@ -12,6 +12,13 @@ path fired. Nothing it prints is a performance claim.
 
     python chip_smoke.py            # one chip; what the driver runs
     python chip_smoke.py --chips 4  # only the doc-sharded served phase
+    python chip_smoke.py --chips 4 --rooms 4096  # ... at 1,024 rooms a chip,
+                                    # the benchmark's `yws-rooms-4k-x4`
+
+`--rooms` sets the resident room slots (default 1,024); capacity is never
+an argument. The sharded phase also checks that the server says it is
+sharded (`ingest.state_shards` = the chips) and that every state plane
+still spans them after the last flush.
 
 Exit 0 and a last line `{"ok": true, "device": {...}}` only on a TPU with
 every check passed; any other outcome exits non-zero with `"ok": false`.
@@ -296,12 +303,17 @@ def served_phase(
             for i, a in enumerate(jax.tree.leaves(ing.state))
             if len(a.sharding.device_set) != n_dev
         ]
+        shards = metrics.gauge("ingest.state_shards").value
         say(
             f"smoke: after the last flush {n_planes - len(local)} of "
-            f"{n_planes} state planes span {n_dev} devices"
+            f"{n_planes} state planes span {n_dev} devices; the gauge "
+            f"ingest.state_shards reads {shards}"
         )
-        if local:
-            failures.append(f"state planes {local} no longer span {n_dev} devices")
+        if local or shards != n_dev:
+            failures.append(
+                f"state planes {local} no longer span {n_dev} devices "
+                f"(ingest.state_shards {shards})"
+            )
     return failures
 
 
@@ -309,6 +321,12 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument(
+        "--rooms",
+        type=int,
+        default=N_DOCS,
+        help="resident room slots; with --chips 4 they are laid over the chips",
+    )
     ap.add_argument(
         "--events-per-session",
         type=int,
@@ -377,6 +395,7 @@ def main(argv=None) -> int:
         f"{native.load()._name.rsplit('/', 1)[-1]}; compile cache {cache_dir}"
     )
     failures = served_phase(
+        n_docs=args.rooms,
         events_per_session=args.events_per_session,
         seed=args.seed,
         shard_docs=args.chips == 4,

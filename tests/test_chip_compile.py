@@ -16,6 +16,7 @@ touches libtpu while a module is imported; no child process; ONE file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,6 +131,63 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
         assert out.spec[0] == AXIS_BATCH, out
     # per chip: a quarter of the state in and out, plus temporaries
     assert _hbm_bytes(compiled) < V5E_HBM // 4, compiled.memory_analysis()
+
+
+def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
+    """`yws-rooms-4k-x4`: 4,096 rooms over four chips, 1,024 a chip. A
+    doc-sharded ingestor uploads a step's inputs onto the mesh
+    (`BatchIngestor._upload`): the host lane's planes by room, the decoded
+    stream and the rank table whole on every chip. `merge_stream` must
+    then scatter into the planes where they lie (no collective, output by
+    room), and the step must take that output as it is, gather no state
+    plane and leave every plane of the state where it was."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ytpu.models.batch_doc import (
+        BatchEncoder,
+        _apply_update_batch_jit,
+        init_state,
+        scan_tier_plan,
+    )
+    from ytpu.models.ingest import _merge_stream_jit
+    from ytpu.parallel.mesh import AXIS_BATCH
+
+    rooms, lanes, rows = 4 * N_DOCS, 8, 4
+    mesh = Mesh(np.array(topo.devices), (AXIS_BATCH,))
+    on = lambda spec: lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
+    )
+    by_room, whole = on(P(AXIS_BATCH)), on(P())
+    host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
+    batch = BatchEncoder().batch_from_rows([[]] * rooms, [[]] * rooms, rows, rows)
+    merge = _merge_stream_jit.lower(
+        jax.tree.map(by_room, batch),
+        jax.tree.map(whole, jax.tree.map(lambda a: a[:lanes], batch)),
+        host(lanes),
+        host(lanes),
+        host(),
+        width=64,
+    ).compile()
+    assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
+    assert {s.spec for s in jax.tree.leaves(merge.output_shardings)} == {P(AXIS_BATCH)}
+
+    merged = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        merge.out_info,
+        merge.output_shardings,
+    )
+    state = jax.tree.map(by_room, jax.eval_shape(lambda: init_state(rooms, CAPACITY)))
+    step = _apply_update_batch_jit.lower(
+        state,
+        merged,
+        whole(jnp.zeros((2 * N_CLIENTS,), jnp.int32)),
+        scan_tier_plan(),
+    ).compile()
+    assert "all-gather" not in step.as_text()
+    for out in jax.tree.leaves(step.output_shardings):
+        assert out.spec[0] == AXIS_BATCH, out
+    # per chip: 1,024 rooms of state in and out, plus temporaries
+    assert _hbm_bytes(step) < V5E_HBM // 4, step.memory_analysis()
 
 
 @pytest.mark.parametrize("lanes,max_sections", [(1, 2), (8, 2), (8, None)])

@@ -14,7 +14,8 @@ import chip_smoke  # noqa: E402
 
 
 @pytest.mark.usefixtures("native_lib")
-def test_served_phase_passes_every_check_at_8_rooms():
+@pytest.mark.parametrize("shard_docs", [False, True], ids=["one_device", "doc_sharded"])
+def test_served_phase_passes_every_check_at_8_rooms(shard_docs):
     said = []
     failures = chip_smoke.served_phase(
         n_docs=8,
@@ -22,6 +23,7 @@ def test_served_phase_passes_every_check_at_8_rooms():
         n_sessions=16,
         events_per_session=8,
         seed=0,
+        shard_docs=shard_docs,
         exact_rooms=4,
         say=said.append,
     )
@@ -31,6 +33,9 @@ def test_served_phase_passes_every_check_at_8_rooms():
     assert "of 4 touched rooms 4 render the oracle's text and 4 answer" in text
     assert "byte-equal to the Python one in 4/4" in text
     assert "fast_recoveries 0" in text and "encode.demotions 0" in text
+    # `--chips 4`: a room a device here, and the server says so itself
+    sharded = "29 of 29 state planes span 8 devices; the gauge ingest.state_shards reads 8"
+    assert (sharded in text) == shard_docs
 
 
 def test_served_phase_reports_a_room_that_left_the_oracle(monkeypatch):

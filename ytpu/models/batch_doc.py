@@ -2633,7 +2633,7 @@ class ClientInterner:
             self.from_idx.append(client)
         return idx
 
-    def rank_table(self, pad_to: Optional[int] = None) -> jax.Array:
+    def rank_table_host(self, pad_to: Optional[int] = None) -> np.ndarray:
         """[C] i32: rank of each interned client in real-id order.
 
         Padded to a power of two so the jitted kernel's shape stays stable
@@ -2645,7 +2645,11 @@ class ClientInterner:
         order = sorted(range(n), key=lambda i: self.from_idx[i])
         for rank, idx in enumerate(order):
             ranks[idx] = rank
-        return jnp.asarray(ranks)
+        return ranks
+
+    def rank_table(self, pad_to: Optional[int] = None) -> jax.Array:
+        """`rank_table_host` on the default device."""
+        return jnp.asarray(self.rank_table_host(pad_to))
 
     def __len__(self) -> int:
         return len(self.from_idx)
@@ -2959,6 +2963,46 @@ class BatchEncoder:
                 all_dels.append(d)
         return self.batch_from_rows(all_rows, all_dels, n_rows, n_dels)
 
+    def batch_planes(
+        self,
+        all_rows: List[list],
+        all_dels: List[list],
+        n_rows: Optional[int] = None,
+        n_dels: Optional[int] = None,
+    ) -> List[np.ndarray]:
+        """Per-doc row/del tuple lists padded to [D, U] / [D, R]: the 27
+        host planes of an `UpdateBatch`, in its field order."""
+        U = n_rows or max(1, max(len(r) for r in all_rows))
+        R = n_dels or max(1, max(len(d) for d in all_dels))
+        D = len(all_rows)
+
+        rows = np.zeros((D, U, 22), dtype=np.int32)
+        rows[:, :, 10] = -1  # key padding must read as "sequence row"
+        rows[:, :, 12] = -1  # p_client padding
+        rows[:, :, 14] = -1  # p_root padding (primary root)
+        rows[:, :, 15] = -1  # mv_sc padding
+        rows[:, :, 18] = -1  # mv_ec padding
+        rows[:, :, 21] = -1  # mv_prio padding
+        rows_valid = np.zeros((D, U), dtype=bool)
+        for d, doc_rows in enumerate(all_rows):
+            for i, row in enumerate(doc_rows):
+                rows[d, i] = row
+                rows_valid[d, i] = True
+
+        dels = np.zeros((D, R, 3), dtype=np.int32)
+        dels_valid = np.zeros((D, R), dtype=bool)
+        for d, doc_dels in enumerate(all_dels):
+            for i, de in enumerate(doc_dels):
+                dels[d, i] = de
+                dels_valid[d, i] = True
+
+        return (
+            [rows[:, :, i] for i in range(22)]  # client .. mv_prio
+            + [rows_valid]
+            + [dels[:, :, i] for i in range(3)]  # del_client, _start, _end
+            + [dels_valid]
+        )
+
     def batch_from_rows(
         self,
         all_rows: List[list],
@@ -2966,66 +3010,10 @@ class BatchEncoder:
         n_rows: Optional[int] = None,
         n_dels: Optional[int] = None,
     ) -> UpdateBatch:
-        """Pad per-doc row/del tuple lists into one [D, U] / [D, R] batch."""
-        U = n_rows or max(1, max(len(r) for r in all_rows))
-        R = n_dels or max(1, max(len(d) for d in all_dels))
-        D = len(all_rows)
-
-        def pad_rows():
-            out = np.zeros((D, U, 22), dtype=np.int32)
-            out[:, :, 10] = -1  # key padding must read as "sequence row"
-            out[:, :, 12] = -1  # p_client padding
-            out[:, :, 14] = -1  # p_root padding (primary root)
-            out[:, :, 15] = -1  # mv_sc padding
-            out[:, :, 18] = -1  # mv_ec padding
-            out[:, :, 21] = -1  # mv_prio padding
-            valid = np.zeros((D, U), dtype=bool)
-            for d, rows in enumerate(all_rows):
-                for i, row in enumerate(rows):
-                    out[d, i] = row
-                    valid[d, i] = True
-            return out, valid
-
-        def pad_dels():
-            out = np.zeros((D, R, 3), dtype=np.int32)
-            valid = np.zeros((D, R), dtype=bool)
-            for d, dels in enumerate(all_dels):
-                for i, de in enumerate(dels):
-                    out[d, i] = de
-                    valid[d, i] = True
-            return out, valid
-
-        rows, rows_valid = pad_rows()
-        dels, dels_valid = pad_dels()
-        return UpdateBatch(
-            client=jnp.asarray(rows[:, :, 0]),
-            clock=jnp.asarray(rows[:, :, 1]),
-            length=jnp.asarray(rows[:, :, 2]),
-            origin_client=jnp.asarray(rows[:, :, 3]),
-            origin_clock=jnp.asarray(rows[:, :, 4]),
-            ror_client=jnp.asarray(rows[:, :, 5]),
-            ror_clock=jnp.asarray(rows[:, :, 6]),
-            kind=jnp.asarray(rows[:, :, 7]),
-            content_ref=jnp.asarray(rows[:, :, 8]),
-            content_off=jnp.asarray(rows[:, :, 9]),
-            key=jnp.asarray(rows[:, :, 10]),
-            p_tag=jnp.asarray(rows[:, :, 11]),
-            p_client=jnp.asarray(rows[:, :, 12]),
-            p_clock=jnp.asarray(rows[:, :, 13]),
-            p_root=jnp.asarray(rows[:, :, 14]),
-            mv_sc=jnp.asarray(rows[:, :, 15]),
-            mv_sk=jnp.asarray(rows[:, :, 16]),
-            mv_sa=jnp.asarray(rows[:, :, 17]),
-            mv_ec=jnp.asarray(rows[:, :, 18]),
-            mv_ek=jnp.asarray(rows[:, :, 19]),
-            mv_ea=jnp.asarray(rows[:, :, 20]),
-            mv_prio=jnp.asarray(rows[:, :, 21]),
-            valid=jnp.asarray(rows_valid),
-            del_client=jnp.asarray(dels[:, :, 0]),
-            del_start=jnp.asarray(dels[:, :, 1]),
-            del_end=jnp.asarray(dels[:, :, 2]),
-            del_valid=jnp.asarray(dels_valid),
-        )
+        """`batch_planes` as one [D, U] / [D, R] batch on the default
+        device, a plane an upload."""
+        planes = self.batch_planes(all_rows, all_dels, n_rows, n_dels)
+        return UpdateBatch(*[jnp.asarray(p) for p in planes])
 
     def build_step(
         self, update: Update, n_rows: int, n_dels: int, primary_root=None

@@ -165,6 +165,11 @@ def load_ingestor_with_extra(path: str) -> Tuple[BatchIngestor, dict]:
     # they restore onto the current default
     ing.ingest = side.get("ingest", "raw")
     ing.state = state
+    # a checkpoint restores onto the default device: not doc-sharded, and
+    # the ingestor says so (`DeviceSyncServer.shard_docs`, the gauge
+    # `ingest.state_shards` of a freshly built one)
+    ing.shard_docs = False
+    ing._by_doc = ing._on_every_chip = None
     ing.svs = [StateVector(dict(c)) for c in side["svs"]]
     ing._pending = [dict(p) for p in side["pending"]]
     ing._pending_ds = [DeleteSet(dict(d)) for d in side["pending_ds"]]

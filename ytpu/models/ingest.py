@@ -20,6 +20,7 @@ update goes pending must not stall its batch":
 
 from __future__ import annotations
 
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -32,6 +33,7 @@ from ytpu.core.state_vector import StateVector
 from ytpu.models.batch_doc import (
     BatchEncoder,
     DocStateBatch,
+    UpdateBatch,
     apply_update_batch,
     init_state,
 )
@@ -40,17 +42,25 @@ from ytpu.ops.decode_kernel import (
     gather_raw_lanes,
     steps_for_columns,
 )
+from ytpu.parallel.mesh import (
+    batch_sharding,
+    replicated,
+    require_doc_mesh,
+    shard_docs_put,
+    state_shards,
+)
 
 __all__ = ["BatchIngestor"]
 
 
 def _sorted_table(mapping: Dict[int, int]):
-    """(sorted keys, value perm) as device i32 arrays — the shape every
-    device lookup table (clients, key hashes, client hashes) shares."""
+    """(sorted keys, value perm) as host i32 arrays — the shape every
+    device lookup table (clients, key hashes, client hashes) shares;
+    `_merge_fast_lane` uploads them."""
     ks = sorted(mapping)
     return (
-        jnp.asarray(np.asarray(ks, dtype=np.int32)),
-        jnp.asarray(np.asarray([mapping[k] for k in ks], dtype=np.int32)),
+        np.asarray(ks, dtype=np.int32),
+        np.asarray([mapping[k] for k in ks], dtype=np.int32),
     )
 
 # content kinds the device decoder handles: GC, Deleted, Json, Binary,
@@ -129,8 +139,12 @@ class BatchIngestor:
         self.enc = enc or BatchEncoder()
         self.n_docs = n_docs
         #: doc-axis sharding (ISSUE-20): place the batched state so its
-        #: doc axis spans the batch mesh (`ytpu.parallel.mesh`); a no-op
-        #: on single-device hosts, so CPU behavior is byte-identical
+        #: doc axis spans the batch mesh (`ytpu.parallel.mesh`). A no-op
+        #: off the chip with one device visible, so CPU behavior is
+        #: byte-identical; refused (`require_doc_mesh`) where it would be
+        #: a silent no-op on the chip or on a room count the mesh does
+        #: not divide. What the state does span is the gauge
+        #: `ingest.state_shards`, worked out when it is read
         self.shard_docs = bool(shard_docs)
         #: fast-lane wire shipping (ISSUE-9 satellite, ROADMAP item 2):
         #: ``"raw"`` (default) ships the eligible docs' updates as ONE
@@ -144,14 +158,16 @@ class BatchIngestor:
         #: (tests/test_serving_soak.py asserts it end to end).
         self.ingest = ingest
         self.state: DocStateBatch = init_state(n_docs, capacity)
+        # where a step's uploads go (`_upload`): None = the default device
+        self._by_doc = self._on_every_chip = None
         if self.shard_docs:
-            from ytpu.parallel.mesh import batch_mesh, shard_docs_put
-
-            mesh = batch_mesh()
+            mesh = require_doc_mesh(n_docs)
             if mesh is not None:
                 self.state = jax.tree.map(
                     lambda a: shard_docs_put(a, mesh), self.state
                 )
+                self._by_doc = batch_sharding(mesh)
+                self._on_every_chip = replicated(mesh)
         self.svs: List[StateVector] = [StateVector() for _ in range(n_docs)]
         # per-doc stash: carriers waiting for dependencies + deferred deletes
         self._pending: List[Dict[int, list]] = [{} for _ in range(n_docs)]
@@ -170,6 +186,15 @@ class BatchIngestor:
         self._m_fast = metrics.counter("ingest.fast_docs")
         self._m_slow = metrics.counter("ingest.slow_docs")
         self._m_recoveries = metrics.counter("ingest.fast_recoveries")
+        # of the newest ingestor; a weak reference, so the registry keeps
+        # no state alive
+        me = weakref.ref(self)
+
+        def shards() -> int:
+            ing = me()
+            return 0 if ing is None else state_shards(ing.state)
+
+        metrics.gauge("ingest.state_shards").set_function(shards)
         self._last_fast_flags: Optional[np.ndarray] = None
         # device key hashing (map rows on the fast lane): hash -> key idx;
         # keys whose hash collides with a different key take the host lane
@@ -184,6 +209,40 @@ class BatchIngestor:
         # BLOCK_ROOT_ANCHOR rows created before the apply
         self.primary_roots: Dict[int, str] = {}
         self._anchored_roots: List[set] = [set() for _ in range(n_docs)]
+
+    def _upload(self, host, by_doc: bool = False):
+        """A tree of host arrays onto the device(s) the state lives on.
+
+        One chip: `jnp.asarray`, leaf by leaf. A doc-sharded state: one
+        `device_put` straight onto the mesh — by room where the leading
+        axis is the room axis (`by_doc`), else whole on every chip — so
+        the step's four programs all run where the state is and nothing
+        of a step sits on the first chip alone. Left there, jax carries
+        each plane across inside the integrate call, a slicing program
+        and a copy a chip: 32 ms of a 127 ms step on four v5e chips
+        (PERF.md §6, PR 28)."""
+        if self._on_every_chip is None:
+            return jax.tree.map(jnp.asarray, host)
+        return jax.device_put(
+            host, self._by_doc if by_doc else self._on_every_chip
+        )
+
+    def _uploaded_bytes(self, host, by_doc: bool = False) -> int:
+        """Bytes `_upload(host, by_doc)` sends: a whole copy a chip unless
+        the tree is laid out by room."""
+        copies = (
+            1 if by_doc or self._on_every_chip is None
+            else len(self._on_every_chip.device_set)
+        )
+        return copies * sum(a.nbytes for a in jax.tree.leaves(host))
+
+    def _batch(self, all_rows, all_dels, n_rows=None, n_dels=None):
+        """`BatchEncoder.batch_from_rows`, uploaded to where the state is."""
+        planes = self.enc.batch_planes(all_rows, all_dels, n_rows, n_dels)
+        return UpdateBatch(*self._upload(planes, by_doc=True))
+
+    def _client_rank(self):
+        return self._upload(self.enc.interner.rank_table_host())
 
     def reset_slot(self, doc: int) -> None:
         """Return a doc slot to its empty state (start/-1, zero blocks,
@@ -319,9 +378,8 @@ class BatchIngestor:
             rows, dels = self._plan_doc(d, u)
             all_rows.append(rows)
             all_dels.append(dels)
-        batch = self.enc.batch_from_rows(all_rows, all_dels)
         self.state = apply_update_batch(
-            self.state, batch, self.enc.interner.rank_table()
+            self.state, self._batch(all_rows, all_dels), self._client_rank()
         )
         return self.state
 
@@ -575,9 +633,9 @@ class BatchIngestor:
         `apply_update_batch` dispatch, so mixed batches cost one step.
 
         Host stages (docs/observability.md, "Inside a dispatch"):
-        `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan`, `.host_rows`),
-        `ingest.merge` (`_merge_fast_lane`: the uploads, then one enqueue
-        each under `.gather`, `decode.v1` and `.scatter`),
+        `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan`, `.host_rows`,
+        `.h2d`), `ingest.merge` (`_merge_fast_lane`: the uploads, then one
+        enqueue each under `.gather`, `decode.v1` and `.scatter`),
         `ingest.rank_table`, `integrate.xla_batch`, `ingest.flags`,
         `ingest.recover`.
         """
@@ -665,9 +723,18 @@ class BatchIngestor:
                     n_dels = _bucket(
                         max(max_fast_dels, 1, max(len(d_) for d_ in all_dels))
                     )
-                    batch = self.enc.batch_from_rows(
+                    planes = self.enc.batch_planes(
                         all_rows, all_dels, n_rows, n_dels
                     )
+                with phases.span("ingest.plan.h2d"):
+                    # the host lane's 27 planes, over every slot
+                    batch = UpdateBatch(*self._upload(planes, by_doc=True))
+                    if phases.enabled:
+                        phases.transfer(
+                            "ingest.plan.h2d",
+                            self._uploaded_bytes(planes, by_doc=True),
+                            "h2d",
+                        )
             self._m_fast.inc(len(fast_idx))
             self._m_slow.inc(sum(1 for u in slow_updates if u is not None))
 
@@ -683,7 +750,12 @@ class BatchIngestor:
                     max_sections=_bucket(max_sections, 2) if max_sections else None,
                 )
             with phases.span("ingest.rank_table"):
-                client_rank = self.enc.interner.rank_table()
+                ranks = self.enc.interner.rank_table_host()
+                client_rank = self._upload(ranks)
+                if phases.enabled:
+                    phases.transfer(
+                        "ingest.rank_table", self._uploaded_bytes(ranks), "h2d"
+                    )
             self.state = apply_update_batch(self.state, batch, client_rank)
             if flags is not None:
                 # `_fast_eligible` proved these lanes decode clean, and flagged
@@ -739,9 +811,8 @@ class BatchIngestor:
             rows, dels = self._plan_doc(d, u)
             r_rows.append(rows)
             r_dels.append(dels)
-        rbatch = self.enc.batch_from_rows(r_rows, r_dels)
         self.state = apply_update_batch(
-            self.state, rbatch, self.enc.interner.rank_table()
+            self.state, self._batch(r_rows, r_dels), self._client_rank()
         )
 
     def _merge_fast_lane(
@@ -807,12 +878,12 @@ class BatchIngestor:
             with phases.span("ingest.merge.h2d"):
                 # the wire bytes' one trip to HBM, counted here and
                 # nowhere else (decode.v1 is handed device arrays)
-                dev_arrays = [jnp.asarray(a) for a in host_arrays]
+                dev_arrays = self._upload(list(host_arrays))
                 dev_lens = dev_arrays[-1]
                 if phases.enabled:
                     phases.transfer(
                         "ingest.merge.h2d",
-                        sum(a.nbytes for a in host_arrays),
+                        self._uploaded_bytes(host_arrays),
                         "h2d",
                     )
             with phases.span("ingest.merge.gather"):
@@ -850,11 +921,13 @@ class BatchIngestor:
                     name = self.primary_roots.get(d)
                     if name is not None:
                         prim_hash[s_i] = key_hash_host(name.encode("utf-8"))
-                tables = dict(
-                    client_table=self._client_table(),
-                    key_table=self._key_table(),
-                    client_hash_table=self._client_hash_table(),
-                    primary_root_hash=jnp.asarray(prim_hash),
+                tables = self._upload(
+                    dict(
+                        client_table=self._client_table(),
+                        key_table=self._key_table(),
+                        client_hash_table=self._client_hash_table(),
+                        primary_root_hash=prim_hash,
+                    )
                 )
             stream, flags = decode_updates_v1(
                 dev_buf,

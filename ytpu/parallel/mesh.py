@@ -31,6 +31,8 @@ __all__ = [
     "batch_sharding",
     "subbatch_devices",
     "shard_docs_put",
+    "require_doc_mesh",
+    "state_shards",
     "AXIS_DP",
     "AXIS_TP",
     "AXIS_BATCH",
@@ -136,3 +138,34 @@ def shard_docs_put(arr, mesh: Optional[Mesh] = None, doc_axis: int = 0):
     if arr.ndim <= doc_axis or arr.shape[doc_axis] % n != 0:
         return arr
     return jax.device_put(arr, batch_sharding(mesh, doc_axis, arr.ndim))
+
+
+def require_doc_mesh(n_docs: int) -> Optional[Mesh]:
+    """The batch mesh a served state of `n_docs` rooms is laid over when
+    its owner asked for `shard_docs=True`, or a refusal: `shard_docs_put`
+    is the identity on one device and on a doc axis the mesh does not
+    divide, so a server asked to shard would run unsharded and say
+    nothing. None only off the chip with one device visible, the
+    documented no-op the CPU tests run."""
+    mesh = batch_mesh()
+    if mesh is None:
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "shard_docs=True needs more than one chip, and jax sees "
+                f"{len(jax.devices())}: refusing to serve unsharded"
+            )
+        return None
+    n = int(mesh.devices.size)
+    if n_docs % n:
+        raise ValueError(
+            f"shard_docs=True cannot lay {n_docs} rooms over {n} devices "
+            "in equal blocks: refusing to serve unsharded"
+        )
+    return mesh
+
+
+def state_shards(state) -> int:
+    """Devices every plane of `state` spans, read off the arrays' own
+    shardings: the fewest over the planes, so one plane gathered onto one
+    chip reads 1. For a scrape or a test, never inside a step."""
+    return min(len(a.sharding.device_set) for a in jax.tree.leaves(state))

@@ -34,6 +34,7 @@ import numpy as np
 from ytpu.core.state_vector import StateVector
 from ytpu.encoding.lib0 import Writer
 from ytpu.models.ingest import BatchIngestor
+from ytpu.parallel.mesh import state_shards
 from ytpu.sync.protocol import (
     MSG_SYNC,
     MSG_SYNC_STEP_1,
@@ -148,7 +149,9 @@ class DeviceSyncServer(SyncServer):
             "n_docs": self.ingestor.n_docs,
             "queued_updates": self.pending_device_updates(),
             "device_authoritative": self.device_authoritative,
+            # the flag as asked for, and what the planes do span
             "shard_docs": self.shard_docs,
+            "state_shards": state_shards(self.ingestor.state),
         }
         try:
             out["capacity"] = self.capacity_snapshot()

@@ -183,8 +183,19 @@ class Gauge(_Family):
 
     def __init__(self, name: str, labelnames: Tuple[str, ...] = ()):
         super().__init__(name, labelnames)
-        self._value = 0.0
+        self._fn = None
+        self._stored = 0.0
         self._vlock = threading.Lock()
+
+    @property
+    def _value(self) -> float:
+        fn = self._fn
+        return self._stored if fn is None else fn()
+
+    @_value.setter
+    def _value(self, v: float) -> None:
+        self._fn = None
+        self._stored = v
 
     def _make_child(self, key):
         return Gauge(self.name)
@@ -193,6 +204,14 @@ class Gauge(_Family):
         self._require_unlabeled()
         with self._vlock:
             self._value = v
+
+    def set_function(self, fn) -> None:
+        """Work the value out when it is read (a scrape, `value`), by
+        calling `fn()`: for what costs too much to keep current on a hot
+        path. A later `set`/`inc` puts a stored value back."""
+        self._require_unlabeled()
+        with self._vlock:
+            self._fn = fn
 
     def inc(self, n: float = 1) -> None:
         self._require_unlabeled()
