@@ -190,6 +190,80 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     assert _hbm_bytes(step) < V5E_HBM // 4, step.memory_analysis()
 
 
+COMPACT_WIDTH = 16  # `BatchIngestor._active_slots`: a tick of at most 16 rooms
+
+
+def test_compact_integrate_step_needs_a_fraction_of_the_dense_steps_memory(one_chip):
+    """The step `apply_bytes` dispatches for a tick of at most 16 rooms:
+    16 rooms gathered, integrated, scattered back. Not donated, so the
+    state is there twice as in the dense step; the temporaries are those
+    of 16 rooms, not of 1,024."""
+    from ytpu.models.batch_doc import (
+        BatchEncoder,
+        _apply_update_batch_jit,
+        scan_tier_plan,
+    )
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, 4, 4)
+    args = (_state(one_chip), _shapes(batch, one_chip), i32(N_CLIENTS), scan_tier_plan())
+    dense = _apply_update_batch_jit.lower(*args).compile().memory_analysis()
+    compact = _apply_update_batch_jit.lower(*args, i32(COMPACT_WIDTH)).compile()
+    m = compact.memory_analysis()
+    print(f"temp bytes: dense step {dense.temp_size_in_bytes}, compact step {m.temp_size_in_bytes}")
+    assert m.alias_size_in_bytes == 0, m  # the roofline counts a state read once, written once
+    assert m.output_size_in_bytes == dense.output_size_in_bytes
+    assert m.temp_size_in_bytes < dense.temp_size_in_bytes // 16, (m, dense)
+    assert _hbm_bytes(compact) < V5E_HBM // 4, m
+
+
+def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
+    """`yws-rooms-4k-x4`: 4,096 rooms by room over four chips, the batch as
+    `merge_stream` leaves it (by room), the rank table whole on every chip,
+    `active` a numpy array the call takes up. The partitioner must answer the gather with each chip's own
+    rooms and a sum of the `[16, ...]` pieces, never with a gathered plane,
+    and scatter into the planes where they lie."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ytpu.models.batch_doc import (
+        BatchEncoder,
+        _apply_update_batch_jit,
+        init_state,
+        scan_tier_plan,
+    )
+    from ytpu.parallel.mesh import AXIS_BATCH
+
+    rooms = 4 * N_DOCS
+    mesh = Mesh(np.array(topo.devices), (AXIS_BATCH,))
+    on = lambda spec: lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
+    )
+    by_room, whole = on(P(AXIS_BATCH)), on(P())
+    batch = BatchEncoder().batch_from_rows([[]] * rooms, [[]] * rooms, 4, 4)
+    step = _apply_update_batch_jit.lower(
+        jax.tree.map(by_room, jax.eval_shape(lambda: init_state(rooms, CAPACITY))),
+        jax.tree.map(by_room, batch),
+        whole(jnp.zeros((2 * N_CLIENTS,), jnp.int32)),
+        scan_tier_plan(),
+        jax.ShapeDtypeStruct((COMPACT_WIDTH,), jnp.int32),  # numpy: jit places it
+    ).compile()
+    text = step.as_text()
+    assert not re.search(r"all-(gather|to-all)|collective-permute|reduce-scatter", text)
+    # what crosses: the gathered rooms' pieces, nothing as large as a plane
+    # (a chip's share of one is [1024, 4096])
+    crossing = [ln for ln in text.splitlines() if re.search(r"\ball-reduce(-start)?\(", ln)]
+    assert crossing
+    for ln in crossing:
+        result = ln.split(" all-reduce", 1)[0]
+        for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+            assert np.prod([int(d) for d in dims.split(",")]) <= COMPACT_WIDTH * CAPACITY, ln[:200]
+    for out in jax.tree.leaves(step.output_shardings):
+        assert out.spec[0] == AXIS_BATCH, out
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes == 0, m
+    assert _hbm_bytes(step) < V5E_HBM // 8, m
+
+
 @pytest.mark.parametrize("lanes,max_sections", [(1, 2), (8, 2), (8, None)])
 def test_served_decode_compiles(one_chip, lanes, max_sections):
     """`decode_updates_v1` over [S, 64] wire lanes with all four lookup

@@ -7,7 +7,9 @@ chips, here on the suite's 8 forced host devices at 16 rooms x capacity 512
 through the served path, sessions synced with their room's document that
 type inserts and deletes, one wire update an edit, several updates to one
 room in a tick, `flush_device(max_steps=1)` until the queues are empty. The
-checks are `benchmark/oracle.py`'s, each with the limit 0.
+checks are `benchmark/oracle.py`'s, each with the limit 0. At 16 rooms every
+step is the dense one; two cases at 128 rooms x capacity 256 take the compact
+step (`BatchIngestor._active_slots`) on the sharded state.
 """
 
 import random
@@ -25,8 +27,11 @@ from ytpu.utils import metrics
 N_ROOMS, CAPACITY = 16, 512
 ROOT = "text"
 TICK = 6  # frames a tick
-CLIENTS = [900_000 + k for k in range(N_ROOMS)] + [7000 + i for i in range(16)]  # loaders, typists
+# where the compact integrate step engages (16 rooms stay dense: 16 > 16 // 4):
+# 16 rooms a device, and a tick's rooms are under a quarter of them
+MANY_ROOMS, SMALL_CAPACITY = 128, 256
 WATCHED = ("ingest.fast_recoveries", "encode.demotions", "lane.demotions", "net.bad_frames")
+STEPS = ("ingest.compact_steps", "ingest.dense_steps")
 
 
 def _frame(update: bytes) -> bytes:
@@ -90,13 +95,14 @@ def _traffic(seed: int, rooms, n_sessions: int = 12, edits: int = 4):
     return stages, ticks
 
 
-def _serve(shard_docs: bool, connect, stages, ticks):
+def _serve(shard_docs: bool, connect, stages, ticks, n_rooms=N_ROOMS, capacity=CAPACITY):
     server = DeviceSyncServer(
-        n_docs=N_ROOMS, capacity=CAPACITY, device_authoritative=True, shard_docs=shard_docs
+        n_docs=n_rooms, capacity=capacity, device_authoritative=True, shard_docs=shard_docs
     )
     # client ids preregistered, as the benchmark does: a first-seen client
     # grows the lookup tables, and every size is a program family of its own
-    for client in CLIENTS:
+    loaders, typists = range(900_000, 900_000 + n_rooms), range(7000, 7016)
+    for client in [*loaders, *typists]:
         server.ingestor.enc.interner.intern(client)
     sess = {k: server.connect_frames(f"room{k}")[0] for k in connect}
     steps_mixed = 0
@@ -157,10 +163,10 @@ def _check_against_oracle(server, connect, stages, ticks):
     ing = server.ingestor
     written = {server.slot_of(f"room{k}") for k in rooms}
     n_blocks, start = np.asarray(ing.state.n_blocks), np.asarray(ing.state.start)
-    for slot in set(range(N_ROOMS)) - written:  # never assigned, or connected and silent
+    for slot in set(range(ing.n_docs)) - written:  # never assigned, or connected and silent
         assert n_blocks[slot] == 0 and start[slot] == -1, slot
     assert not np.asarray(ing.state.error).any()
-    assert not [d for d in range(N_ROOMS) if ing.pending_update(d) or ing.pending_ds(d)]
+    assert not [d for d in range(ing.n_docs) if ing.pending_update(d) or ing.pending_ds(d)]
     assert ing.fast_recoveries == 0 and not server._host_tenants
     assert server._diff_pipeline.stats.fallback_docs == 0
     return dict(zip(rooms, diffs))
@@ -203,6 +209,36 @@ def test_sharded_server_equals_the_oracle_and_the_unsharded_server(rooms):
     assert sharded.ingestor.slow_docs == plain.ingestor.slow_docs
     assert {n: metrics.counter(n).value for n in WATCHED} == before
     assert any(len({k for k, _ in frames}) < len(frames) for frames in ticks)  # a room twice in a tick
+
+
+# at 128 rooms, 16 a device: a room on every device and the last slot, or
+# three rooms of the first device
+MANY_EVERY_SHARD = [0, 17, 34, 51, 68, 85, 102, 119, 127]
+MANY_FIRST_SHARD = [0, 1, 5]
+
+
+@pytest.mark.parametrize(
+    "rooms", [MANY_EVERY_SHARD, MANY_FIRST_SHARD], ids=["every_shard", "first_shard"]
+)
+def test_compact_steps_on_a_sharded_state(rooms):
+    """Every step carries under a quarter of the 128 rooms, so every step is
+    the compact one: the rooms gathered off the devices that hold them,
+    integrated, scattered back, and the planes left laid by room."""
+    stages, ticks = _traffic(29_000_001 + len(rooms), rooms, n_sessions=8, edits=3)
+    connect = rooms + [100]  # a room that connects and never sends
+    before = {n: metrics.counter(n).value for n in WATCHED + STEPS}
+    sharded, _ = _serve(True, connect, stages, ticks, MANY_ROOMS, SMALL_CAPACITY)
+    steps = {n: metrics.counter(n).value - before[n] for n in STEPS}
+    assert steps["ingest.compact_steps"] >= len(stages) + len(ticks)
+    assert steps["ingest.dense_steps"] == 0
+    _spans_every_device(sharded)
+    got = _check_against_oracle(sharded, connect, stages, ticks)
+    _spans_every_device(sharded)
+    assert sharded.ingestor.fast_docs > 0
+    plain, _ = _serve(False, connect, stages, ticks, MANY_ROOMS, SMALL_CAPACITY)
+    assert got == _check_against_oracle(plain, connect, stages, ticks)  # the same bytes
+    assert sharded.ingestor.fast_docs == plain.ingestor.fast_docs
+    assert {n: metrics.counter(n).value for n in WATCHED} == {n: before[n] for n in WATCHED}
 
 
 def test_a_mixed_step_one_room_on_each_lane():

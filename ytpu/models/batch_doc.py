@@ -1488,6 +1488,7 @@ def apply_update_batch(
     batch: UpdateBatch,
     client_rank: jax.Array,
     scan_plan: Optional[tuple] = None,
+    active: Optional[jax.Array] = None,
 ) -> DocStateBatch:
     """Integrate one decoded update per doc — the north-star entry point.
 
@@ -1495,12 +1496,36 @@ def apply_update_batch(
     `scan_plan` is the two-tier static (None = `scan_tier_plan()` read at
     trace time — the public wrapper re-reads per call and threads it, so
     a changed knob retraces instead of silently reusing the old plan).
+
+    `active` ([K] i32 of DISTINCT in-range slots, or None) makes the step
+    compact: under `vmap` the per-row `lax.cond` runs both branches for
+    every slot, so a step costs its width in rooms whether or not a room
+    carries a row. With `active` the step gathers those K rooms' planes
+    and batch rows, integrates `[K, ...]`, and scatters the rooms back;
+    every other room's planes are carried over untouched, which is what
+    the dense step's identity on an all-invalid slot gives. The caller
+    vouches that every slot outside `active` has no valid row. One
+    program either way, and the state is not donated: every plane is
+    still read once and written once (PERF.md section 6, PR 29).
     """
-    state, _hist = jax.vmap(
+    step = jax.vmap(
         lambda s, b, cr: _apply_update_one_doc(s, b, cr, scan_plan),
         in_axes=(0, 0, None),
-    )(state, batch, client_rank)
-    return state
+    )
+    if active is None:
+        state, _hist = step(state, batch, client_rank)
+        return state
+    with jax.named_scope("compact_gather"):
+        sub_state, sub_batch = jax.tree.map(
+            lambda a: a[active], (state, batch)
+        )
+    sub_state, _hist = step(sub_state, sub_batch, client_rank)
+    # a plain scatter on the room axis, outside any vmap: the form
+    # `ingest.merge_stream` proved right on the chip
+    with jax.named_scope("compact_scatter"):
+        return jax.tree.map(
+            lambda full, sub: full.at[active].set(sub), state, sub_state
+        )
 
 
 def _apply_update_stream_hist_body(
@@ -3440,7 +3465,10 @@ _apply_update_stream_state_jit = partial(
 
 
 def apply_update_batch(
-    state: DocStateBatch, batch: UpdateBatch, client_rank: jax.Array
+    state: DocStateBatch,
+    batch: UpdateBatch,
+    client_rank: jax.Array,
+    active: Optional[jax.Array] = None,
 ) -> DocStateBatch:
     from ytpu.utils.phases import NULL_SPAN, phases
     from ytpu.utils.progbudget import tick
@@ -3461,18 +3489,25 @@ def apply_update_batch(
     span = (
         phases.span(
             "integrate.xla_batch",
-            (state.blocks.client.shape, batch.client.shape, scan_plan),
-            axes=("state", "batch", "scan_plan"),
+            (
+                state.blocks.client.shape,
+                batch.client.shape,
+                scan_plan,
+                None if active is None else active.shape[0],
+            ),
+            axes=("state", "batch", "scan_plan", "active"),
             memory=program_memory(
                 _apply_update_batch_jit, state, batch, client_rank,
-                scan_plan,
+                scan_plan, active,
             ),
         )
         if phases.enabled
         else NULL_SPAN
     )
     with span:
-        return _apply_update_batch_jit(state, batch, client_rank, scan_plan)
+        return _apply_update_batch_jit(
+            state, batch, client_rank, scan_plan, active
+        )
 
 
 def apply_update_stream(

@@ -183,11 +183,7 @@ def load_ingestor_with_extra(path: str) -> Tuple[BatchIngestor, dict]:
     ing.slow_docs = 0
     ing.fast_recoveries = 0
     ing._last_fast_flags = None
-    from ytpu.utils import metrics
-
-    ing._m_fast = metrics.counter("ingest.fast_docs")
-    ing._m_slow = metrics.counter("ingest.slow_docs")
-    ing._m_recoveries = metrics.counter("ingest.fast_recoveries")
+    ing._bind_counters()
     # rebuild the device hash tables from the restored interners
     ing._key_hashes = {}
     ing._key_collisions = set()

@@ -21,6 +21,7 @@ update goes pending must not stall its batch":
 from __future__ import annotations
 
 import weakref
+from itertools import islice
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -179,13 +180,9 @@ class BatchIngestor:
         self.fast_docs = 0
         self.slow_docs = 0
         self.fast_recoveries = 0  # flagged fast lanes replayed via host lane
-        # process-wide mirrors of the lane stats (cached metric objects:
-        # O(1) increments, no per-step lookups — SURVEY §5.5)
+        self._bind_counters()
         from ytpu.utils import metrics
 
-        self._m_fast = metrics.counter("ingest.fast_docs")
-        self._m_slow = metrics.counter("ingest.slow_docs")
-        self._m_recoveries = metrics.counter("ingest.fast_recoveries")
         # of the newest ingestor; a weak reference, so the registry keeps
         # no state alive
         me = weakref.ref(self)
@@ -209,6 +206,19 @@ class BatchIngestor:
         # BLOCK_ROOT_ANCHOR rows created before the apply
         self.primary_roots: Dict[int, str] = {}
         self._anchored_roots: List[set] = [set() for _ in range(n_docs)]
+
+    def _bind_counters(self) -> None:
+        """Process-wide mirrors of the lane stats (cached metric objects:
+        O(1) increments, no per-step lookups — SURVEY §5.5); a restored
+        ingestor (`checkpoint.load_ingestor`) binds the same."""
+        from ytpu.utils import metrics
+
+        self._m_fast = metrics.counter("ingest.fast_docs")
+        self._m_slow = metrics.counter("ingest.slow_docs")
+        self._m_recoveries = metrics.counter("ingest.fast_recoveries")
+        # which integrate step an `apply_bytes` call took (`_active_slots`)
+        self._m_compact = metrics.counter("ingest.compact_steps")
+        self._m_dense = metrics.counter("ingest.dense_steps")
 
     def _upload(self, host, by_doc: bool = False):
         """A tree of host arrays onto the device(s) the state lives on.
@@ -243,6 +253,23 @@ class BatchIngestor:
 
     def _client_rank(self):
         return self._upload(self.enc.interner.rank_table_host())
+
+    def _active_slots(self, live: List[int]) -> Optional[np.ndarray]:
+        """`apply_update_batch`'s `active` for a step in which only the
+        slots `live` carry rows: `live` padded with idle slots (distinct,
+        in range: their batch rows are all invalid, so the step is the
+        identity on them) up to a power of two, floor 16, and sorted; or
+        None, the dense step, once that is over a quarter of the slots
+        (the prefill's all-room dispatches, bulk loads, small batches).
+        Decided by the count of rooms that carry an update, by nothing
+        else; every tick of at most 16 rooms is one program family."""
+        width = _bucket(len(live), 16)
+        if width > self.n_docs // 4:
+            return None
+        taken = set(live)
+        idle = (d for d in range(self.n_docs) if d not in taken)
+        pad = list(islice(idle, width - len(live)))
+        return np.sort(np.asarray(live + pad, dtype=np.int32))
 
     def reset_slot(self, doc: int) -> None:
         """Return a doc slot to its empty state (start/-1, zero blocks,
@@ -654,6 +681,7 @@ class BatchIngestor:
 
             with phases.span("ingest.plan"):
                 native = available()
+                live: List[int] = []  # the slots that carry a payload
                 fast_idx: List[int] = []
                 fast_payloads: List[bytes] = []
                 # recovery support: per fast doc, first-touch (client ->
@@ -668,6 +696,7 @@ class BatchIngestor:
                     for d, p in enumerate(payloads):
                         if p is None:
                             continue
+                        live.append(d)
                         cols = decode_update_columns(p) if native else None
                         if cols is None or not self._fast_eligible(d, cols):
                             slow_updates[d] = Update.decode_v1(p)
@@ -737,6 +766,12 @@ class BatchIngestor:
                         )
             self._m_fast.inc(len(fast_idx))
             self._m_slow.inc(sum(1 for u in slow_updates if u is not None))
+            # a slot without a payload plans no row (`_plan_doc`): the step
+            # need be no wider than the slots that carry one
+            active = self._active_slots(live)
+            took = self._m_dense if active is None else self._m_compact
+            took.inc()
+            phases.add_value(took.name, 1)  # the recorder's: a window's delta
 
             flags = None
             chunk_base = None
@@ -756,7 +791,10 @@ class BatchIngestor:
                     phases.transfer(
                         "ingest.rank_table", self._uploaded_bytes(ranks), "h2d"
                     )
-            self.state = apply_update_batch(self.state, batch, client_rank)
+            # `active` rides up with the call, as `merge_stream`'s `idx` does
+            self.state = apply_update_batch(
+                self.state, batch, client_rank, active
+            )
             if flags is not None:
                 # `_fast_eligible` proved these lanes decode clean, and flagged
                 # lanes integrate nothing (their rows are marked invalid), so a
@@ -812,7 +850,10 @@ class BatchIngestor:
             r_rows.append(rows)
             r_dels.append(dels)
         self.state = apply_update_batch(
-            self.state, self._batch(r_rows, r_dels), self._client_rank()
+            self.state,
+            self._batch(r_rows, r_dels),
+            self._client_rank(),
+            self._active_slots(bad),
         )
 
     def _merge_fast_lane(
