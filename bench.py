@@ -2036,7 +2036,7 @@ def _device_phase_child(in_path: str, out_path: str) -> None:
     # windows): the FLAGSHIP full-B4 replay goes absolutely first — in
     # round 4/5 the micro+config phases burned the 2400s child budget
     # before the flagship phase ever started. Then latency (cheap,
-    # serving-SLO evidence), configs, sp, micro; the Pallas fused lane
+    # serving-SLO evidence), configs, micro; the Pallas fused lane
     # stays LAST because a Mosaic miscompile can crash the TPU worker —
     # everything flushed before it survives.
     try:
@@ -2066,27 +2066,6 @@ def _device_phase_child(in_path: str, out_path: str) -> None:
     flush()
     phase_gc()
     _device_configs(result, flush)
-    phase_gc()
-    try:
-        # sequence-parallel axis (SURVEY §5.7; VERDICT r3 #6): B4-prefix
-        # replay on a 1- vs 8-shard ShardedDoc
-        import importlib.util as _ilu2
-
-        _sp_path = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "benches", "sp_axis.py"
-        )
-        _sp_spec = _ilu2.spec_from_file_location("ytpu_bench_sp", _sp_path)
-        _sp = _ilu2.module_from_spec(_sp_spec)
-        _sp_spec.loader.exec_module(_sp)
-        sp_log, sp_expect = _sp.b4_prefix_updates(1200)
-        sp = {}
-        for n in (1, 8):
-            sp[f"shards_{n}"] = _sp.run_shards(sp_log, sp_expect, n)
-            result["sp"] = sp
-            flush()
-    except Exception as e:
-        result["sp_error"] = f"{type(e).__name__}: {e}"[:300]
-    flush()
     phase_gc()
     if devs[0].platform == "cpu":
         # the 512-doc decode-machine programs take tens of minutes in the
@@ -3144,10 +3123,6 @@ def main(dry_run: bool = False, compare_baseline: bool = False):
                 out[k] = res[k]
         if "soak_error" in res:
             out["soak_error"] = res["soak_error"]
-        if "sp" in res:
-            out["sp"] = res["sp"]
-        if "sp_error" in res:
-            out["sp_error"] = res["sp_error"]
     if res and "quick_dt" in res:
         quick_rate = len(quick_log) * N_DOCS / res["quick_dt"]
         out["quick_updates_per_sec"] = round(quick_rate, 1)

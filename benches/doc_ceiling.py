@@ -282,7 +282,7 @@ def sub_batch_scaling(
     admits exactly it. On a single CPU device narrower widths pay the
     re-dispatch overhead (ratio ≤ 1); on a batch mesh the slices spread
     across devices — this leg is the trendable axis for that speedup
-    (VERDICT Weak #5's sp-axis promise)."""
+    (VERDICT Weak #5)."""
     import jax
 
     from ytpu.models.replay import FusedReplay, plan_replay

@@ -267,31 +267,61 @@ def test_bare_mesh_sync_rounds_quiesce():
     assert rep["frames"] == 0 and rep["passes"] == 1, rep
 
 
-def test_silently_dropped_update_is_not_blacklisted():
-    """An update the receiving server REFUSED without any reply
-    (admission policy="drop") must not enter the dedup set: the
-    mark-on-success gate reads the applied counter, so the SV-resync
-    retransmission — byte-identical payload — still lands (review-caught
-    correctness pin)."""
+def _round_under_drop_policy(client_id: int, text: str, over_the_link: bool):
+    """a and b meshed on "room", b under admission policy="drop": one write
+    on a, `admission.reject` armed once, one sync round. With
+    `over_the_link` False the session on b that a's frames arrive on is an
+    ordinary client's for that round. Returns (mesh, b, the armed fault)."""
     from ytpu.serving import AdmissionController
 
     a, b = SyncServer(), SyncServer()
     mesh = ReplicaMesh([("a", a), ("b", b)], tenants=["room"])
     mesh.sync_round()
     b.admission = AdmissionController(policy="drop")
-    _write(a, "room", Doc(client_id=801), "must-arrive ")
+    _write(a, "room", Doc(client_id=client_id), text)
+    (link,) = mesh._tenant_links("room")
+    recv = link.sess_b if link.b.server is b else link.sess_a
+    assert recv.mesh_link
+    recv.mesh_link = over_the_link
     faults.clear()
     spec = faults.arm("admission.reject", n=1)
     try:
-        mesh.sync_round()  # the update crosses the link and is refused
+        mesh.sync_round()
     finally:
         faults.clear()
-    assert spec.fired == 1
+        recv.mesh_link = True
+    assert not recv.dead
+    return mesh, b, spec
+
+
+def test_silently_dropped_update_is_not_blacklisted():
+    """An update the receiving server REFUSED without any reply
+    (admission policy="drop") must not enter the dedup set: the
+    mark-on-success gate reads the applied counter, so the SV-resync
+    retransmission — byte-identical payload — still lands (review-caught
+    correctness pin).
+
+    A mesh link is never refused by admission (the next test), so for the
+    refused round the receiving session is made an ordinary client's: the
+    armed `admission.reject` is consulted, and the gate in
+    `replica._PeerLink._deliver` sees an update that did not apply."""
+    mesh, b, spec = _round_under_drop_policy(801, "must-arrive ", over_the_link=False)
+    assert spec.fired == 1  # the update crossed the link and was refused
     assert b.doc("room").get_text("text").get_string() == ""
     b.admission = None
     rep = mesh.anti_entropy_round()
     assert rep["mismatches"] >= 1 and rep["pulled"] >= 1, rep
     assert b.doc("room").get_text("text").get_string() == "must-arrive "
+
+
+def test_admission_never_refuses_an_update_over_a_mesh_link():
+    """Peer replication bypasses the client valve (`SyncServer._admit_update`:
+    a refused peer update is not load shedding, it is data loss in flight):
+    under policy="drop" with `admission.reject` armed, an update over an
+    untouched link is applied and the fault is never consulted."""
+    _, b, spec = _round_under_drop_policy(802, "over-the-link ", over_the_link=True)
+    assert spec.fired == 0
+    assert b.doc("room").get_text("text").get_string() == "over-the-link "
 
 
 # ------------------------------------------- divergence + health surface

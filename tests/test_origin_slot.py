@@ -276,27 +276,3 @@ def test_fused_lane_default_defers_and_marks_stale():
     )
     assert not origin_slot_is_stale(eager)
     assert _invariant_violations(eager) == []
-
-
-def test_sharded_cache_is_minus_one_only_for_nonlocal_origins():
-    from ytpu.parallel.sharded_doc import ShardedDoc
-
-    doc = Doc(client_id=5)
-    log = []
-    doc.observe_update_v1(lambda p, o, t: log.append(p))
-    t = doc.get_text("text")
-    words = [f"w{i} " for i in range(60)]
-    for i, w in enumerate(words):
-        with doc.transact() as txn:
-            t.insert(txn, (i * 3) % max(1, len(t.get_string())), w)
-    sd = ShardedDoc(n_shards=4, capacity=1024)
-    for p in log:
-        sd.apply_update_v1(p)
-    sd.flush()
-    state = sd.state
-    viols = _invariant_violations(state)
-    assert viols == [], viols
-
-    sd.rebalance()
-    assert _invariant_violations(sd.state) == [], "rebalance broke the cache"
-    assert sd.get_string() == doc.get_text("text").get_string()

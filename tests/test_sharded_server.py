@@ -10,9 +10,21 @@ room in a tick, `flush_device(max_steps=1)` until the queues are empty. The
 checks are `benchmark/oracle.py`'s, each with the limit 0. At 16 rooms every
 step is the dense one; two cases at 128 rooms x capacity 256 take the compact
 step (`BatchIngestor._active_slots`) on the sharded state.
+
+Below them, the wire logs the sequence-parallel engine's tests were worth
+keeping for (that engine left in PR 31), each served to one room of the same
+16 x 512 family on one device and doc-sharded: maps, XML, several roots, GC
+carriers and the stash on a sharded state, which text rooms never reach.
+Then `ytpu/parallel/mesh.py` itself on the 8 devices, and what the server's
+import loads.
 """
 
+import functools
+import os
 import random
+import subprocess
+import sys
+from collections import deque
 
 import jax
 import numpy as np
@@ -20,6 +32,8 @@ import pytest
 
 from ytpu.core import Doc
 from ytpu.core.state_vector import StateVector
+from ytpu.core.update import Update
+from ytpu.parallel import mesh as doc_mesh
 from ytpu.sync.device_server import DeviceSyncServer
 from ytpu.sync.protocol import Message, SyncMessage
 from ytpu.utils import metrics
@@ -292,3 +306,608 @@ def test_one_chip_asked_to_shard_is_refused_and_one_cpu_device_is_the_no_op(monk
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(RuntimeError, match="refusing to serve unsharded"):
         DeviceSyncServer(n_docs=4, capacity=64, device_authoritative=True, shard_docs=True)
+
+
+# --------------------------------------------------------------------------
+# Wire logs of real `Doc`s, a room a log. Each builder returns the updates in
+# the order the server receives them.
+
+
+def _capture(doc):
+    log = []
+    doc.observe_update_v1(lambda p, o, t: log.append(p))
+    return log
+
+
+def _random_edit(txn, txt, r, length):
+    if length > 10 and r.random() < 0.3:
+        n = r.randint(1, 3)
+        txt.remove_range(txn, r.randint(0, length - 3), n)
+        return length - n
+    w = "".join(r.choice("abcdefgh ") for _ in range(r.randint(1, 5)))
+    txt.insert(txn, r.randint(0, length), w)
+    return length + len(w)
+
+
+def _sequential_log(n_ops, seed):
+    src = Doc(client_id=1)
+    log = _capture(src)
+    t = src.get_text(ROOT)
+    r = random.Random(seed)
+    length = 0
+    for _ in range(n_ops):
+        with src.transact() as txn:
+            length = _random_edit(txn, t, r, length)
+    return log
+
+
+def _gcify(payload: bytes) -> bytes:
+    """A full-state update as a gc-enabled yrs peer encodes it: deleted
+    items become position-free GC carriers (BlockCell::GC)."""
+    from ytpu.core.block import GCRange
+    from ytpu.core.content import CONTENT_DELETED
+
+    u = Update.decode_v1(payload)
+    blocks = {}
+    for client, carriers in u.blocks.items():
+        blocks[client] = deque(
+            GCRange(c.id, c.len)
+            if getattr(c, "is_item", False) and c.content.kind == CONTENT_DELETED
+            else c
+            for c in carriers
+        )
+    return Update(blocks=blocks, delete_set=u.delete_set).encode_v1()
+
+
+def sequential_replay():
+    return _sequential_log(60, seed=3)
+
+
+def _concurrent_at_one_position():
+    base = Doc(client_id=1)
+    with base.transact() as txn:
+        base.get_text(ROOT).insert(txn, 0, "abcdefghijklmnop")
+    state0 = base.encode_state_as_update_v1()
+    peer_a, peer_b = Doc(client_id=2), Doc(client_id=3)
+    peer_a.apply_update_v1(state0)
+    peer_b.apply_update_v1(state0)
+    ta, tb = peer_a.get_text(ROOT), peer_b.get_text(ROOT)
+    with peer_a.transact() as txn:
+        ta.insert(txn, 4, "AAA")  # the same spot as peer_b: the conflict scan
+        ta.insert(txn, 19, "XX")  # an append at the tail
+    with peer_b.transact() as txn:
+        tb.insert(txn, 4, "BBB")
+        tb.remove_range(txn, 8, 4)
+    sv = base.state_vector()
+    return state0, peer_a.encode_state_as_update_v1(sv), peer_b.encode_state_as_update_v1(sv)
+
+
+def concurrent_edits_a_then_b():
+    state0, upd_a, upd_b = _concurrent_at_one_position()
+    return [state0, upd_a, upd_b]
+
+
+def concurrent_edits_b_then_a():
+    state0, upd_a, upd_b = _concurrent_at_one_position()
+    return [state0, upd_b, upd_a]
+
+
+def multi_peer_fuzz():
+    """4 peers editing concurrently in rounds, a full exchange after each."""
+    r = random.Random(7)
+    peers = [Doc(client_id=i + 1) for i in range(4)]
+    texts = [p.get_text(ROOT) for p in peers]
+    made = []
+    for _ in range(6):
+        for p, t in zip(peers, texts):
+            edit = _capture(p)
+            with p.transact() as txn:
+                _random_edit(txn, t, r, len(t.get_string()))
+            made.extend(edit[:1])
+        for _ in range(2):
+            for a in peers:
+                for b in peers:
+                    if a is not b:
+                        b.apply_update_v1(a.encode_state_as_update_v1(b.state_vector()))
+    assert len({t.get_string() for t in texts}) == 1
+    return made
+
+
+def stash_of_text_and_map():
+    """Updates delivered out of order: the later one waits in the stash
+    (transaction.rs:675-727) and its room plans on the host until the gap
+    closes. Text and map of one root, so the host lane's planes carry map
+    rows onto the sharded state too."""
+    src = Doc(client_id=1)
+    log = _capture(src)
+    t, m = src.get_text(ROOT), src.get_map(ROOT)
+    for i, ch in enumerate("abcdef"):
+        with src.transact() as txn:
+            t.insert(txn, len(t.get_string()), ch)
+            m.insert(txn, f"k{i % 2}", i)
+    return [log[0], log[2], log[1], log[4], log[5], log[3]]
+
+
+def delete_spanning_many_blocks():
+    log = _sequential_log(8, seed=23)
+    peer = Doc(client_id=50)
+    for u in log:
+        peer.apply_update_v1(u)
+    tp = peer.get_text(ROOT)
+    plog = _capture(peer)
+    with peer.transact() as txn:
+        tp.remove_range(txn, 2, len(tp.get_string()) - 4)
+    return log + plog
+
+
+def origin_in_the_middle_of_a_block():
+    """A peer that synced only a prefix appends with an origin in the middle
+    of what is by now one block."""
+    src = Doc(client_id=1)
+    log = _capture(src)
+    t = src.get_text(ROOT)
+    with src.transact() as txn:
+        t.insert(txn, 0, "abcde")
+    with src.transact() as txn:
+        t.insert(txn, 5, "fghijklmnop")
+    peer = Doc(client_id=2)
+    peer.apply_update_v1(log[0])
+    plog = _capture(peer)
+    with peer.transact() as txn:
+        peer.get_text(ROOT).insert(txn, 5, "ZZ")
+    return log + plog
+
+
+def text_plus_map():
+    """The text and the map component of ONE root."""
+    src = Doc(client_id=1)
+    log = _capture(src)
+    t, m = src.get_text(ROOT), src.get_map(ROOT)
+    r = random.Random(7)
+    length = 0
+    for i in range(90):
+        with src.transact() as txn:
+            if i % 3 == 0:
+                m.insert(txn, f"k{r.randint(0, 9)}", r.randint(0, 999))
+            else:
+                length = _random_edit(txn, t, r, length)
+    return log
+
+
+def concurrent_map_writers():
+    """Two writers on the same keys: last writer wins, as the oracle picks."""
+    a, b = Doc(client_id=5), Doc(client_id=9)
+    log_a, log_b = _capture(a), _capture(b)
+    ma, mb = a.get_map("m"), b.get_map("m")
+    with a.transact() as txn:
+        ma.insert(txn, "color", "red")
+        a.get_text("m").insert(txn, 0, "alpha")
+    with b.transact() as txn:
+        mb.insert(txn, "color", "blue")
+        mb.insert(txn, "size", 4)
+        b.get_text("m").insert(txn, 0, "beta")
+    for p in list(log_b):  # a sees b's writes; b stays behind
+        a.apply_update_v1(p)
+    with a.transact() as txn:
+        ma.insert(txn, "color", "green")  # a new winner over the merged chain
+        ma.remove(txn, "size")
+    return log_a + log_b
+
+
+def map_chain_fuzz():
+    """3 writers on one root's map and text, syncing at random points; the
+    log is their edits in the order they were made (what a peer re-emits
+    when it takes a diff in only repeats them)."""
+    r = random.Random(23)
+    peers = [Doc(client_id=10 + i) for i in range(3)]
+    made = []
+    length = [0, 0, 0]
+    for _ in range(60):
+        i = r.randrange(3)
+        d = peers[i]
+        edit = _capture(d)
+        with d.transact() as txn:
+            x = r.random()
+            if x < 0.4:
+                d.get_map("doc").insert(txn, f"k{r.randint(0, 4)}", r.randint(0, 99))
+            elif x < 0.5 and len(list(d.get_map("doc").keys())):
+                d.get_map("doc").remove(txn, next(iter(d.get_map("doc").keys())))
+            else:
+                length[i] = _random_edit(txn, d.get_text("doc"), r, length[i])
+        made.extend(edit[:1])
+        if r.random() < 0.3:
+            j = r.randrange(3)
+            if j != i:
+                peers[j].apply_update_v1(d.encode_state_as_update_v1(peers[j].state_vector()))
+                length[j] = len(peers[j].get_text("doc").get_string())
+    return made
+
+
+def nested_xml_tree():
+    """Elements, attributes (nested LWW chains), nested text edits, two
+    concurrent clients, as a relay hands their updates on."""
+    from ytpu.types import XmlElementPrelim, XmlTextPrelim
+
+    r = random.Random(11)
+    a, b = Doc(client_id=1, skip_gc=True), Doc(client_id=2, skip_gc=True)
+    relay = Doc(client_id=0xFFFF, skip_gc=True)
+    log = _capture(relay)
+    fa, fb = a.get_xml_fragment("x"), b.get_xml_fragment("x")
+    with a.transact() as txn:
+        fa.insert(txn, 0, XmlElementPrelim("doc"))
+        fa.insert(txn, 1, XmlTextPrelim("seed"))
+    relay.apply_update_v1(a.encode_state_as_update_v1(relay.state_vector()))
+    b.apply_update_v1(a.encode_state_as_update_v1(b.state_vector()))
+    for step in range(50):
+        doc, frag = (a, fa) if r.random() < 0.5 else (b, fb)
+        with doc.transact() as txn:
+            x = r.random()
+            kids = list(frag.children())
+            if x < 0.3:
+                frag.insert(
+                    txn,
+                    r.randrange(len(kids) + 1),
+                    XmlElementPrelim(f"e{step}", attributes={"n": str(step)}),
+                )
+            elif x < 0.6 and kids:
+                el = kids[r.randrange(len(kids))]
+                if hasattr(el, "insert_attribute"):
+                    el.insert_attribute(txn, f"k{step % 5}", str(step))
+            else:
+                tx = [k for k in kids if type(k).__name__ == "XmlText"]
+                if tx:
+                    t = tx[r.randrange(len(tx))]
+                    n = len(t)
+                    if n > 3 and r.random() < 0.3:
+                        t.remove_range(txn, r.randrange(n - 2), 2)
+                    else:
+                        t.insert(txn, r.randrange(n + 1), f"w{step} ")
+        relay.apply_update_v1(doc.encode_state_as_update_v1(relay.state_vector()))
+        other = b if doc is a else a
+        other.apply_update_v1(doc.encode_state_as_update_v1(other.state_vector()))
+    return log
+
+
+def multi_root():
+    """A text root and a map root beside the primary XML fragment."""
+    from ytpu.types import XmlElementPrelim
+
+    d = Doc(client_id=1, skip_gc=True)
+    log = _capture(d)
+    frag, m, t = d.get_xml_fragment("x"), d.get_map("meta"), d.get_text("title")
+    with d.transact() as txn:
+        frag.insert(txn, 0, XmlElementPrelim("div", attributes={"id": "a"}))
+    with d.transact() as txn:
+        m.insert(txn, "version", 3)
+        t.insert(txn, 0, "hello")
+    with d.transact() as txn:
+        t.insert(txn, 5, " world")
+        m.insert(txn, "version", 4)
+    with d.transact() as txn:
+        t.remove_range(txn, 0, 3)
+    return log
+
+
+def gc_carriers():
+    """The full state of a gc-enabled peer: deleted items arrive as GC
+    carriers, and " world", whose only anchor is GC'd, degrades to one
+    (update.rs, the unresolvable-parent rule)."""
+    a = Doc(client_id=1)
+    t = a.get_text("t")
+    with a.transact() as txn:
+        t.insert(txn, 0, "hello cruel world")
+    with a.transact() as txn:
+        t.remove_range(txn, 5, 6)
+    return [_gcify(a.encode_state_as_update_v1())]
+
+
+def _anchored_into_a_gcd_region(insert_at):
+    """A stale peer's insert whose anchors were GC'd since."""
+    a, b = Doc(client_id=1), Doc(client_id=2)
+    ta = a.get_text("t")
+    with a.transact() as txn:
+        ta.insert(txn, 0, "abcdef")
+    b.apply_update_v1(a.encode_state_as_update_v1())
+    with b.transact() as txn:
+        b.get_text("t").insert(txn, insert_at, "XY")
+    b_update = b.encode_state_as_update_v1(a.state_vector())
+    with a.transact() as txn:
+        ta.remove_range(txn, 1, 3)  # "bcd"
+    return [_gcify(a.encode_state_as_update_v1()), b_update]
+
+
+def anchored_both_sides_gcd():
+    return _anchored_into_a_gcd_region(3)  # origin 'c' and right origin 'd' GC'd: degrades
+
+
+def anchored_left_gcd():
+    return _anchored_into_a_gcd_region(4)  # origin 'd' GC'd, right origin 'e' live
+
+
+def anchored_right_gcd():
+    return _anchored_into_a_gcd_region(1)  # origin 'a' live, right origin 'b' GC'd
+
+
+# a room each: 16 logs on the module's 16 rooms. Array moves are not among
+# them: the rooms read right and `device_encode_diff` cannot write a move row
+# the device decoded, on one device as on eight (ROADMAP, Reach A)
+SCENARIOS = [
+    sequential_replay,
+    text_plus_map,
+    concurrent_map_writers,
+    map_chain_fuzz,
+    nested_xml_tree,
+    multi_root,
+    gc_carriers,
+    anchored_both_sides_gcd,
+    anchored_left_gcd,
+    anchored_right_gcd,
+    stash_of_text_and_map,
+    multi_peer_fuzz,
+    delete_spanning_many_blocks,
+    origin_in_the_middle_of_a_block,
+    concurrent_edits_a_then_b,
+    concurrent_edits_b_then_a,
+]
+assert len(SCENARIOS) == N_ROOMS
+# what the oracle is read by beside state vector and full-state diff: root -> kinds
+READS = {
+    text_plus_map: {ROOT: ("text", "map")},
+    stash_of_text_and_map: {ROOT: ("text", "map")},
+    concurrent_map_writers: {"m": ("text", "map")},
+    map_chain_fuzz: {"doc": ("text", "map")},
+    nested_xml_tree: {},
+    multi_root: {"meta": ("map",), "title": ("text",)},  # beside the primary, "x"
+    gc_carriers: {"t": ("text",)},
+    anchored_both_sides_gcd: {"t": ("text",)},
+    anchored_left_gcd: {"t": ("text",)},
+    anchored_right_gcd: {"t": ("text",)},
+}
+# updates the served path plans on the host (`BatchIngestor.slow_docs`): the
+# stashed ones and those that close their gaps. Every other update of every
+# log rides the fast lane, maps and XML too, and no room leaves the device.
+HOST_LANE = {stash_of_text_and_map: 5}
+# a room's first mention of a secondary root builds the anchor row's mask on
+# the first device (`batch_doc.ensure_root_anchor`) and jax carries it onto
+# the mesh: the one step of these logs the guard would refuse (ROADMAP, Reach A)
+UNGUARDED = {multi_root}
+
+@functools.lru_cache(maxsize=None)
+def _log(scenario):
+    return scenario()
+
+
+@functools.lru_cache(maxsize=None)
+def _scenario_server(shard_docs: bool) -> DeviceSyncServer:
+    """The layout's one server. Clients, map keys and root names of every
+    log are registered before the first frame, as the benchmark registers
+    its clients: a first-seen one grows a lookup table, and every table
+    size is a program family of its own."""
+    server = DeviceSyncServer(
+        n_docs=N_ROOMS, capacity=CAPACITY, device_authoritative=True, shard_docs=shard_docs
+    )
+    clients, names = set(), set()
+    for scenario in SCENARIOS:
+        for payload in _log(scenario):
+            for client, carriers in Update.decode_v1(payload).blocks.items():
+                clients.add(client)
+                for c in carriers:
+                    named = (getattr(c, "parent", None), getattr(c, "parent_sub", None))
+                    names.update(n for n in named if isinstance(n, str))
+    for client in sorted(clients):
+        server.ingestor.enc.interner.intern(client)
+    for name in sorted(names):
+        assert server.ingestor._register_key(name)
+    return server
+
+
+def _device_reads(server, room, reads, primary) -> dict:
+    tree = server.device_tree(room)
+    out = {}
+    for root, kinds in reads.items():
+        branch = tree if root == primary else tree["roots"][root]
+        for kind in kinds:
+            if kind == "text":
+                out[root, kind] = "".join(v for v in branch["seq"] if isinstance(v, str))
+            else:
+                out[root, kind] = branch["map"]
+    return out
+
+
+def _oracle_reads(oracle: Doc, reads) -> dict:
+    get = {
+        "text": lambda root: oracle.get_text(root).get_string(),
+        "map": lambda root: oracle.get_map(root).to_json(),
+    }
+    return {(root, kind): get[kind](root) for root, kinds in reads.items() for kind in kinds}
+
+
+@functools.lru_cache(maxsize=None)
+def _serve_log(shard_docs: bool, scenario) -> dict:
+    """One log to one room, an update a step, and what the room then reads.
+    Kept, so the doc-sharded case compares with the one-device case's bytes
+    without serving the log again."""
+    server = _scenario_server(shard_docs)
+    ing, room, log = server.ingestor, scenario.__name__, _log(scenario)
+    session, _ = server.connect_frames(room)
+    slot = server.slot_of(room)
+    fast, slow, recovered = ing.fast_docs, ing.slow_docs, ing.fast_recoveries
+    stashed = 0
+    for update in log:
+        assert server.receive_frames(session, _frame(update)) == []
+        if scenario in UNGUARDED:
+            assert server.flush_device(max_steps=1) == 1
+        else:
+            with jax.transfer_guard_device_to_device("disallow"):
+                assert server.flush_device(max_steps=1) == 1
+        stashed += ing.pending_update(slot) is not None
+    jax.block_until_ready(ing.state)
+    server.disconnect(session)
+    assert not server.pending_device_updates()
+    assert int(ing.state.error[slot]) == 0
+    assert ing.pending_update(slot) is None and ing.pending_ds(slot) is None
+    assert room not in server._host_tenants and ing.fast_recoveries == recovered
+    primary = ing.primary_roots[slot]
+    reads = READS.get(scenario, {ROOT: ("text",)})
+    got = {
+        "lanes": (ing.fast_docs - fast, ing.slow_docs - slow),
+        "stashed": stashed,
+        "sv": dict(server.device_state_vector(room).clocks),
+        "reads": _device_reads(server, room, reads, primary),
+        # `device_text` reads the primary root as a text, whatever it is
+        "text": (primary, server.device_text(room)) if "text" in reads.get(primary, ()) else None,
+        "diff": server.device_encode_diff(room, StateVector()),
+    }
+    assert server._diff_pipeline.stats.fallback_docs == 0
+    return got
+
+
+def _canonical_bytes(update: bytes) -> bytes:
+    """What a fresh replica holds after `update`, adjacent GC ranges as one
+    (the device cuts a GC range where a later insert anchored into it)."""
+    from ytpu.core.block import GCRange
+
+    u = Update.decode_v1(_canonical(update)[2])
+    blocks = {}
+    for client, carriers in u.blocks.items():
+        out = deque()
+        for c in carriers:
+            last = out[-1] if out else None
+            if (
+                isinstance(c, GCRange)
+                and isinstance(last, GCRange)
+                and last.id.clock + last.len == c.id.clock
+            ):
+                out[-1] = GCRange(last.id, last.len + c.len)
+            else:
+                out.append(c)
+        blocks[client] = out
+    return Update(blocks=blocks, delete_set=u.delete_set).encode_v1()
+
+
+SERVED_CASES = [(s, sharded) for sharded in (False, True) for s in SCENARIOS]
+
+
+@pytest.mark.parametrize(
+    "scenario,shard_docs",
+    SERVED_CASES,
+    ids=[f"{s.__name__}-{'doc_sharded' if sh else 'one_device'}" for s, sh in SERVED_CASES],
+)
+def test_a_served_wire_log_equals_the_oracle(scenario, shard_docs):
+    got = _serve_log(shard_docs, scenario)
+    log = _log(scenario)
+    oracle = Doc(client_id=99)
+    for update in log:
+        oracle.apply_update_v1(update)
+    host_lane = HOST_LANE.get(scenario, 0)
+    assert got["lanes"] == (len(log) - host_lane, host_lane)
+    assert (got["stashed"] > 0) == (scenario is stash_of_text_and_map)
+    assert got["sv"] == dict(oracle.state_vector().clocks)
+    reads = READS.get(scenario, {ROOT: ("text",)})
+    assert got["reads"] == _oracle_reads(oracle, reads)
+    if got["text"] is not None:
+        primary, text = got["text"]
+        assert text == oracle.get_text(primary).get_string()
+    assert _canonical_bytes(got["diff"]) == _canonical_bytes(oracle.encode_state_as_update_v1())
+    if shard_docs:
+        assert got["diff"] == _serve_log(False, scenario)["diff"]  # the same bytes
+        server = _scenario_server(True)
+        n_dev = len(jax.devices())
+        assert n_dev == 8  # tests/conftest.py
+        assert all(
+            len(a.sharding.device_set) == n_dev for a in jax.tree.leaves(server.ingestor.state)
+        )
+        assert server._telemetry_provider()["state_shards"] == n_dev
+
+
+# --------------------------------------------------------------------------
+# `ytpu/parallel/mesh.py` on the suite's 8 devices (`test_subbatch.py` holds
+# the one-device no-ops).
+
+
+def test_shard_docs_put_splits_an_even_doc_axis_in_contiguous_blocks():
+    mesh = doc_mesh.batch_mesh()
+    assert mesh is not None and dict(mesh.shape) == {doc_mesh.AXIS_BATCH: 8}
+    arr = np.arange(16 * 3, dtype=np.int32).reshape(16, 3)
+    put = doc_mesh.shard_docs_put(arr, mesh)
+    assert len(put.sharding.device_set) == 8
+    by_device = {s.device: np.asarray(s.data) for s in put.addressable_shards}
+    for i, dev in enumerate(mesh.devices.flat):  # rooms 2i, 2i+1 on device i
+        assert np.array_equal(by_device[dev], arr[2 * i : 2 * i + 2])
+
+
+@pytest.mark.parametrize("shape", [(12, 4), (7,), ()], ids=["uneven", "odd_vector", "scalar"])
+def test_shard_docs_put_hands_back_what_the_mesh_does_not_divide(shape):
+    arr = np.zeros(shape, np.int32)
+    assert doc_mesh.shard_docs_put(arr) is arr
+
+
+def test_shard_docs_put_on_axis_one_for_packed_columns():
+    arr = np.arange(5 * 16 * 4, dtype=np.int32).reshape(5, 16, 4)  # [NC, D, C]
+    put = doc_mesh.shard_docs_put(arr, doc_axis=1)
+    assert put.sharding.spec == jax.sharding.PartitionSpec(None, doc_mesh.AXIS_BATCH, None)
+    assert {s.data.shape for s in put.addressable_shards} == {(5, 2, 4)}
+    assert np.array_equal(np.asarray(put), arr)
+    uneven = np.zeros((16, 12), np.int32)  # axis 0 would divide; axis 1 does not
+    assert doc_mesh.shard_docs_put(uneven, doc_axis=1) is uneven
+
+
+@pytest.mark.parametrize(
+    "doc_axis,ndim,spec",
+    [(0, 1, ("batch",)), (0, 3, ("batch", None, None)), (1, 3, (None, "batch", None)),
+     (1, 1, (None, "batch"))],
+    ids=["vector", "leading", "packed", "rank_grows_to_hold_the_axis"],
+)
+def test_batch_sharding_spec(doc_axis, ndim, spec):
+    mesh = doc_mesh.batch_mesh()
+    sharding = doc_mesh.batch_sharding(mesh, doc_axis, ndim)
+    assert sharding.mesh == mesh
+    assert sharding.spec == jax.sharding.PartitionSpec(*spec)
+
+
+def test_batch_mesh_takes_the_first_n_devices_and_none_for_one():
+    assert list(doc_mesh.batch_mesh(4).devices.flat) == jax.devices()[:4]
+    assert doc_mesh.batch_mesh(1) is None
+
+
+def test_subbatch_devices_go_round_the_mesh():
+    devices = jax.devices()
+    assert doc_mesh.subbatch_devices(11) == [devices[i % 8] for i in range(11)]
+    assert doc_mesh.subbatch_devices(3, doc_mesh.batch_mesh(2)) == [
+        devices[0], devices[1], devices[0]
+    ]
+
+
+def test_state_shards_reads_the_fewest_over_the_planes():
+    mesh = doc_mesh.batch_mesh()
+    by_room = doc_mesh.shard_docs_put(np.zeros((16, 4), np.int32), mesh)
+    everywhere = jax.device_put(np.zeros(3, np.int32), doc_mesh.replicated(mesh))
+    assert len(everywhere.sharding.device_set) == 8 and everywhere.sharding.is_fully_replicated
+    assert doc_mesh.state_shards({"a": by_room, "b": everywhere}) == 8
+    on_one = jax.device_put(np.zeros((16, 4), np.int32), jax.devices()[3])
+    assert doc_mesh.state_shards({"a": by_room, "b": on_one}) == 1
+    half = doc_mesh.shard_docs_put(np.zeros((16, 4), np.int32), doc_mesh.batch_mesh(4))
+    assert doc_mesh.state_shards([by_room, half, everywhere]) == 4
+
+
+def test_require_doc_mesh_is_the_mesh_of_every_device():
+    mesh = doc_mesh.require_doc_mesh(16)
+    assert list(mesh.devices.flat) == jax.devices() and mesh.axis_names == (doc_mesh.AXIS_BATCH,)
+
+
+def test_the_server_imports_the_doc_mesh_and_no_other_parallel_module():
+    """`ytpu.parallel` is `mesh.py`: what the served server loads of it is
+    what the four-chip cell runs."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys, ytpu.sync.device_server\n"
+        "print(sorted(m for m in sys.modules if m.startswith('ytpu.parallel')))"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, env=env, capture_output=True, text=True, timeout=300
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "['ytpu.parallel', 'ytpu.parallel.mesh']"
+    files = set(os.listdir(os.path.join(root, "ytpu", "parallel"))) - {"__pycache__"}
+    assert files == {"__init__.py", "mesh.py"}

@@ -9,7 +9,7 @@ snapshots and the y-sync/Awareness protocol — executed as a batched engine:
 - `ytpu.models.batch_doc` — N docs as one struct-of-arrays pytree; the
   flagship `apply_update_batch` / `encode_diff_batch` JAX programs.
 - `ytpu.ops` — device kernels (state-vector math, integration waves, codecs).
-- `ytpu.parallel` — mesh construction + shardings (dp/sp axes over ICI).
+- `ytpu.parallel` — the doc mesh: a server's rooms over the chips of a host.
 - `ytpu.sync` — y-sync protocol + Awareness host frontends.
 """
 

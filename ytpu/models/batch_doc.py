@@ -279,7 +279,7 @@ def stream_worst_case_adds(stream: UpdateBatch) -> np.ndarray:
 
     Each valid row can cost 3 slots (itself + two anchor splits), each
     valid delete range 2 (edge splits) — the same accounting as
-    `replay.ReplayPlan.adds` and `sharded_doc.flush`'s pre-grow. Drives
+    `replay.ReplayPlan.adds` (the one other place it is written). Drives
     the chunk planner's occupancy projection; host-side (numpy) so the
     projection never touches the device."""
     rows = np.asarray(stream.valid).sum(axis=-1).astype(np.int64)
@@ -420,7 +420,7 @@ def recompute_origin_slot(state: DocStateBatch) -> DocStateBatch:
     Used at boundaries where the cache cannot ride along: fused-kernel
     unpack (the packed domain CARRIES an OS plane, but the kernel itself
     never maintains it — see integrate_kernel.OS), pre-origin_slot
-    checkpoint restore, and ShardedDoc.rebalance. Docs are processed
+    checkpoint restore: those two and no other. Docs are processed
     sequentially (`lax.map`) so the [B, B] containment compare never
     materializes across the whole batch."""
 
@@ -597,8 +597,8 @@ SCAN_WIDTH_UPPER = (1, 3, 7, 15, 31, 63, 127)
 # argument (like YTPU_FUSED_VMEM_MB), so the driver re-reads the env
 # per chunk and a changed value forces a retrace of the dispatch
 # programs; the bare `apply_update_batch`/`apply_update_stream` wrappers
-# AND the sequence-parallel lane (`sharded_doc`'s inline `_conflict_scan`
-# caller) read it once at first trace and keep the baked value for
+# (a direct caller of `apply_update_batch`, which the served path is)
+# read it once at first trace and keep the baked value for
 # already-compiled shapes (set the env before first dispatch, or go
 # through the replay drivers). Width SEMANTICS are tier-independent:
 # `width` counts visited candidates exactly as the single-tier loop did,
@@ -761,8 +761,8 @@ def _conflict_scan(
     left_idx,
     scan_plan: Optional[tuple] = None,
 ):
-    """The YATA conflict scan (parity: block.rs:537-602), shared by the
-    batched engine and the sequence-parallel engine (`sharded_doc`).
+    """The YATA conflict scan (parity: block.rs:537-602) of the batched
+    engine: `_integrate_row` below is its one caller.
 
     Walks candidates from `o0` toward `right_idx` (or the sequence tail),
     resolving the final left neighbor: same-origin candidates tie-break on

@@ -1900,7 +1900,7 @@ class PackedReplayDriver:
     The occupancy protocol (no per-chunk sync): the host maintains an
     optimistic UPPER BOUND on the max per-doc block count — each chunk
     adds its worst-case growth (3 slots/row + 2/delete range, the same
-    accounting as `ReplayPlan.adds` and `sharded_doc.flush`) — and each
+    accounting as `ReplayPlan.adds` and `stream_worst_case_adds`) — and each
     chunk dispatches a tiny `[2]` (occupancy, sticky-error) readout that
     stays an un-materialized device future. Only when the BOUND says the
     next chunk might not fit (or the high-watermark tripped) does the

@@ -1,24 +1,4 @@
-"""Mesh + sharding layer (dp/tp/sp axes over ICI)."""
+"""The doc mesh: rooms over the chips of one host (`mesh.py`)."""
 
-from .mesh import (
-    AXIS_DP,
-    AXIS_TP,
-    doc_sharding,
-    make_mesh,
-    shard_batch,
-    shard_state,
-    sv_sharding,
-)
-from .sharded_doc import AXIS_SP, ShardedDoc
-
-__all__ = [
-    "AXIS_DP",
-    "AXIS_TP",
-    "AXIS_SP",
-    "make_mesh",
-    "doc_sharding",
-    "sv_sharding",
-    "shard_state",
-    "shard_batch",
-    "ShardedDoc",
-]
+from .mesh import *  # noqa: F401,F403
+from .mesh import __all__  # noqa: F401

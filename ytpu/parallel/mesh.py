@@ -1,94 +1,46 @@
-"""Mesh construction and shardings for the batched engine.
+"""The doc mesh: a served state's rooms laid over the chips of one host.
 
-Parallelism mapping (SURVEY.md §2 table):
-- dp — the doc-batch axis: `DocStateBatch` shards its leading doc axis here
-  (the reference analogue: N independent Docs; north-star 10k-doc batch).
-- tp — the client axis of dense state-vector tensors ([D, C]) for
-  encode_diff_batch's per-client clock compares.
-- sp — the sequence axis inside one hot doc (sequence/context parallelism):
-  `ytpu.parallel.seq_shard` — contiguous chunk partitioning, prefix-sum
-  index routing, ppermute halo exchange.
+One axis, `batch`: the leading (room) axis of every plane of a
+`DocStateBatch` is split in equal contiguous blocks over the visible
+devices, and a room's block columns stay whole on its chip. This is what
+`DeviceSyncServer(shard_docs=True)` builds and what the benchmark's
+four-chip cell (`yws-rooms-4k-x4.edit-flood`) measures. A step's small
+inputs (wire bytes, lookup tables, the rank table) go whole onto every chip
+(`replicated`); XLA's partitioner places the collectives, and no
+hand-written one exists.
 
-All collectives ride ICI via XLA's sharding propagation — no hand-written
-NCCL-style calls (reference has none either; its y-sync protocol is the
-host-side analogue, see ytpu.sync).
+`subbatch_devices` belongs to the packed replay stack
+(`ops/integrate_kernel.py`): round-robin placement of its sub-batches.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
-    "make_mesh",
-    "doc_sharding",
-    "sv_sharding",
-    "shard_state",
+    "AXIS_BATCH",
     "batch_mesh",
     "batch_sharding",
+    "replicated",
     "subbatch_devices",
     "shard_docs_put",
     "require_doc_mesh",
     "state_shards",
-    "AXIS_DP",
-    "AXIS_TP",
-    "AXIS_BATCH",
 ]
 
-AXIS_DP = "dp"
-AXIS_TP = "tp"
-#: doc-batch axis for sub-batched integrate dispatch (ISSUE-20): the
-#: packed [NC, D, C] state splits into pow2 doc-width sub-batches and
-#: each sub-batch lands on one mesh slot
+#: the one mesh axis: rooms (docs) over chips
 AXIS_BATCH = "batch"
 
 
-def make_mesh(
-    n_devices: Optional[int] = None,
-    axes: Tuple[str, str] = (AXIS_DP, AXIS_TP),
-    tp: int = 1,
-) -> Mesh:
-    """Mesh with a doc-parallel axis and a (usually small) tp axis."""
-    devices = jax.devices()
-    if n_devices is not None:
-        devices = devices[:n_devices]
-    n = len(devices)
-    if n % tp != 0:
-        raise ValueError(f"{n} devices not divisible by tp={tp}")
-    arr = np.array(devices).reshape(n // tp, tp)
-    return Mesh(arr, axes)
-
-
-def doc_sharding(mesh: Mesh) -> NamedSharding:
-    """Shard the leading doc axis over dp; block columns stay local."""
-    return NamedSharding(mesh, P(AXIS_DP))
-
-
-def sv_sharding(mesh: Mesh) -> NamedSharding:
-    """[D, C] state-vector tensors: docs over dp, clients over tp."""
-    return NamedSharding(mesh, P(AXIS_DP, AXIS_TP))
-
-
+# whole on every chip of the mesh: a step's tables and wire bytes
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def shard_state(state, mesh: Mesh):
-    """Place a DocStateBatch so its doc axis spans the dp mesh axis."""
-    sh = doc_sharding(mesh)
-    return jax.tree.map(lambda a: jax.device_put(a, sh), state)
-
-
-def shard_batch(batch, mesh: Mesh):
-    sh = doc_sharding(mesh)
-    return jax.tree.map(lambda a: jax.device_put(a, sh), batch)
-
-
-# --------------------------------------------------------------------------
-# Doc-axis (batch) sharding for sub-batched integrate dispatch (ISSUE-20).
 # All helpers degrade to a single-device no-op: `batch_mesh()` returns
 # None when one device is visible, and every consumer treats None as
 # "skip placement entirely", so the CPU tier-1 path stays byte-identical
