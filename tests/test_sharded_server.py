@@ -255,6 +255,30 @@ def test_compact_steps_on_a_sharded_state(rooms):
     assert {n: metrics.counter(n).value for n in WATCHED} == {n: before[n] for n in WATCHED}
 
 
+def test_the_lookup_tables_stay_on_every_device():
+    """The client, key, client-hash and rank tables go up at the first step
+    (the clients are preregistered) and every later step is handed those
+    arrays again: whole on every device, where `_upload` put them, so a
+    reuse crosses no device inside the jitted calls (`_serve` runs every
+    step under the device-to-device guard)."""
+    tables = ("ingest.table_builds", "ingest.table_reuses")
+    stages, ticks = _traffic(28_000_001 + len(FIRST_SHARD), FIRST_SHARD)
+    before = {n: metrics.counter(n).value for n in tables + STEPS}
+    server, _ = _serve(True, FIRST_SHARD + [15], stages, ticks)
+    took = {n: metrics.counter(n).value - before[n] for n in tables + STEPS}
+    steps = sum(took[n] for n in STEPS)
+    assert steps >= len(stages) + len(ticks)
+    assert took["ingest.table_builds"] == 4
+    assert took["ingest.table_reuses"] == 4 * (steps - 1)
+    cache = server.ingestor._table_cache
+    assert sorted(cache) == ["client_hash_table", "client_rank", "client_table", "key_table"]
+    for name, (_, arrays) in cache.items():
+        for a in jax.tree.leaves(arrays):
+            assert len(a.sharding.device_set) == len(jax.devices()) == 8, name
+            assert a.sharding.is_fully_replicated, name
+    _check_against_oracle(server, FIRST_SHARD + [15], stages, ticks)
+
+
 def test_a_mixed_step_one_room_on_each_lane():
     """One dispatch carries a fast-lane room and a host-lane room: the
     second room's updates arrive out of order, so the first to come waits

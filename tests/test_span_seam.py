@@ -111,9 +111,11 @@ def test_wire_bytes_are_counted_once(served):
     snap, _ = served
     counted = {stage: st["h2d_bytes"] for stage, st in snap.items() if st["h2d_bytes"]}
     # the wire bytes in `ingest.merge.h2d` and nowhere else (`decode.v1` is
-    # handed device arrays); the host lane's planes and the rank table are
-    # uploads of their own stages
-    assert sorted(counted) == ["ingest.merge.h2d", "ingest.plan.h2d", "ingest.rank_table"], counted
+    # handed device arrays); the host lane's planes are an upload of their
+    # own stage, and the lookup tables of theirs at the step that built them
+    assert sorted(counted) == [
+        "ingest.merge.h2d", "ingest.merge.tables", "ingest.plan.h2d", "ingest.rank_table",
+    ], counted
     assert "ingest.fast_lane" not in snap
 
 

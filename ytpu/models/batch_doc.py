@@ -2667,9 +2667,11 @@ class ClientInterner:
         n = len(self.from_idx)
         size = pad_to or max(8, 1 << (max(1, n - 1)).bit_length())
         ranks = np.zeros(size, dtype=np.int32)
-        order = sorted(range(n), key=lambda i: self.from_idx[i])
-        for rank, idx in enumerate(order):
-            ranks[idx] = rank
+        try:
+            ids = np.asarray(self.from_idx, dtype=np.int64)
+        except OverflowError:  # an id past 63 bits: compare as Python ints
+            ids = np.asarray(self.from_idx, dtype=object)
+        ranks[np.argsort(ids)] = np.arange(n, dtype=np.int32)
         return ranks
 
     def rank_table(self, pad_to: Optional[int] = None) -> jax.Array:

@@ -185,12 +185,9 @@ def load_ingestor_with_extra(path: str) -> Tuple[BatchIngestor, dict]:
     ing._last_fast_flags = None
     ing._bind_counters()
     # rebuild the device hash tables from the restored interners
-    ing._key_hashes = {}
-    ing._key_collisions = set()
+    ing._reset_tables()
     for key in ing.enc.keys.ids:
         ing._register_key(key)
-    ing._client_hashes = {}
-    ing._client_id_collisions = set()
     for cid in ing.enc.interner.from_idx:
         if cid > 2**31 - 1:
             ing._register_big_client(cid)

@@ -57,12 +57,11 @@ __all__ = ["BatchIngestor"]
 def _sorted_table(mapping: Dict[int, int]):
     """(sorted keys, value perm) as host i32 arrays — the shape every
     device lookup table (clients, key hashes, client hashes) shares;
-    `_merge_fast_lane` uploads them."""
-    ks = sorted(mapping)
-    return (
-        np.asarray(ks, dtype=np.int32),
-        np.asarray([mapping[k] for k in ks], dtype=np.int32),
-    )
+    `BatchIngestor._cached_table` uploads them when they change."""
+    ks = np.fromiter(mapping, np.int32, len(mapping))
+    vs = np.fromiter(mapping.values(), np.int32, len(mapping))
+    order = np.argsort(ks)  # a dict's keys are distinct: one order
+    return ks[order], vs[order]
 
 # content kinds the device decoder handles: GC, Deleted, Json, Binary,
 # String, Embed, Format, Type (non-weak), Any(scalar), Skip, Move
@@ -193,14 +192,7 @@ class BatchIngestor:
 
         metrics.gauge("ingest.state_shards").set_function(shards)
         self._last_fast_flags: Optional[np.ndarray] = None
-        # device key hashing (map rows on the fast lane): hash -> key idx;
-        # keys whose hash collides with a different key take the host lane
-        self._key_hashes: Dict[int, int] = {}
-        self._key_collisions: set = set()
-        # device big-client hashing (ids beyond i32): varint-byte hash ->
-        # interned idx; colliding ids take the host lane
-        self._client_hashes: Dict[int, int] = {}
-        self._client_id_collisions: set = set()
+        self._reset_tables()
         # multi-root docs (doc.rs:156-228): the first named root seen per
         # doc maps onto the implicit device branch; others anchor through
         # BLOCK_ROOT_ANCHOR rows created before the apply
@@ -219,6 +211,63 @@ class BatchIngestor:
         # which integrate step an `apply_bytes` call took (`_active_slots`)
         self._m_compact = metrics.counter("ingest.compact_steps")
         self._m_dense = metrics.counter("ingest.dense_steps")
+        # what a step's lookup tables cost it (`_cached_table`)
+        self._m_table_reuses = metrics.counter("ingest.table_reuses")
+        self._m_table_builds = metrics.counter("ingest.table_builds")
+
+    def _reset_tables(self) -> None:
+        """The device lookup tables' sources, empty, and nothing built of
+        them yet; a restored ingestor (`checkpoint.load_ingestor`) starts
+        from the same and registers its interners' keys and clients."""
+        # device key hashing (map rows on the fast lane): hash -> key idx;
+        # keys whose hash collides with a different key take the host lane
+        self._key_hashes: Dict[int, int] = {}
+        self._key_collisions: set = set()
+        # device big-client hashing (ids beyond i32): varint-byte hash ->
+        # interned idx; colliding ids take the host lane
+        self._client_hashes: Dict[int, int] = {}
+        self._client_id_collisions: set = set()
+        # one count a dict, bumped where an entry comes or goes
+        self._key_gen = self._client_hash_gen = 0
+        # table name -> (its source's stamp at the build, device arrays)
+        self._table_cache: Dict[str, tuple] = {}
+        # a key's or root name's device hash, worked out once
+        self._name_hashes: Dict[str, int] = {}
+
+    def _key_hash(self, key: str) -> int:
+        h = self._name_hashes.get(key)
+        if h is None:
+            from ytpu.ops.decode_kernel import key_hash_host
+
+            h = self._name_hashes[key] = key_hash_host(key.encode("utf-8"))
+        return h
+
+    def _cached_table(self, name: str, stage: str, stamp, build):
+        """Lookup table `name` on the device(s) the state lives on: the
+        arrays of its last build while `stamp`, which says what it was
+        built from, is that build's; else `build()` (host arrays),
+        uploaded whole on every chip and counted against `stage`.
+
+        The tables hold every interned client or key, not a step's, and a
+        server interns when a client or a key is first seen, not per
+        keystroke. The programs that take them donate no operand, so one
+        upload serves every step until its source changes."""
+        from ytpu.utils.phases import phases
+
+        hit = self._table_cache.get(name)
+        if hit is not None and hit[0] == stamp:
+            took = self._m_table_reuses
+            dev = hit[1]
+        else:
+            took = self._m_table_builds
+            host = build()
+            dev = self._upload(host)
+            self._table_cache[name] = (stamp, dev)
+            if phases.enabled:
+                phases.transfer(stage, self._uploaded_bytes(host), "h2d")
+        took.inc()
+        phases.add_value(took.name, 1)  # the recorder's: a window's delta
+        return dev
 
     def _upload(self, host, by_doc: bool = False):
         """A tree of host arrays onto the device(s) the state lives on.
@@ -251,8 +300,36 @@ class BatchIngestor:
         planes = self.enc.batch_planes(all_rows, all_dels, n_rows, n_dels)
         return UpdateBatch(*self._upload(planes, by_doc=True))
 
+    def _decode_tables(self) -> dict:
+        """`decode_updates_v1`'s tables of every interned client, key and
+        big client, each on the device since the step that last changed
+        its source. The two hash dicts lose an entry on a collision, so
+        their stamp is a count of changes, not a length."""
+        stage = "ingest.merge.tables"
+        return dict(
+            client_table=self._cached_table(
+                "client_table", stage, len(self.enc.interner),
+                self._client_table,
+            ),
+            key_table=self._cached_table(
+                "key_table", stage, self._key_gen, self._key_table
+            ),
+            client_hash_table=self._cached_table(
+                "client_hash_table", stage, self._client_hash_gen,
+                self._client_hash_table,
+            ),
+        )
+
     def _client_rank(self):
-        return self._upload(self.enc.interner.rank_table_host())
+        """The rank table of every interned client. The interner only
+        appends, and others intern into it too (the encoder's host lane,
+        a server preregistering its sessions): its length, read here, at
+        the point of use, says whether the table still holds."""
+        interner = self.enc.interner
+        return self._cached_table(
+            "client_rank", "ingest.rank_table", len(interner),
+            interner.rank_table_host,
+        )
 
     def _active_slots(self, live: List[int]) -> Optional[np.ndarray]:
         """`apply_update_batch`'s `active` for a step in which only the
@@ -537,8 +614,11 @@ class BatchIngestor:
             self._client_id_collisions.add(client)
             self._client_id_collisions.add(self.enc.interner.from_idx[prev])
             del self._client_hashes[h]
+            self._client_hash_gen += 1
             return False
-        self._client_hashes[h] = idx
+        if prev is None:
+            self._client_hashes[h] = idx
+            self._client_hash_gen += 1
         return True
 
     def _client_hash_table(self):
@@ -548,12 +628,10 @@ class BatchIngestor:
 
     def _register_key(self, key: str) -> bool:
         """Intern `key` and record its device hash; False on collision."""
-        from ytpu.ops.decode_kernel import key_hash_host
-
         if key in self._key_collisions:
             return False
         kid = self.enc.keys.intern(key)
-        h = key_hash_host(key.encode("utf-8"))
+        h = self._key_hash(key)
         prev = self._key_hashes.get(h)
         if prev is not None and prev != kid:
             # two distinct keys share a hash: neither may use the device
@@ -561,8 +639,11 @@ class BatchIngestor:
             self._key_collisions.add(key)
             self._key_collisions.add(self.enc.keys.names[prev])
             del self._key_hashes[h]
+            self._key_gen += 1
             return False
-        self._key_hashes[h] = kid
+        if prev is None:
+            self._key_hashes[h] = kid
+            self._key_gen += 1
         return True
 
     def _key_table(self):
@@ -644,11 +725,12 @@ class BatchIngestor:
         Ids above int32 (random 53-bit Yjs clients) are excluded here —
         they resolve through the varint-byte hash table instead
         (`_client_hash_table`)."""
-        ids = sorted(
-            c for c in self.enc.interner.to_idx if 0 <= c <= _I32_MAX
-        )
         return _sorted_table(
-            {c: self.enc.interner.to_idx[c] for c in ids}
+            {
+                c: i
+                for c, i in self.enc.interner.to_idx.items()
+                if 0 <= c <= _I32_MAX
+            }
         )
 
     def apply_bytes(self, payloads: List[Optional[bytes]]) -> DocStateBatch:
@@ -785,12 +867,8 @@ class BatchIngestor:
                     max_sections=_bucket(max_sections, 2) if max_sections else None,
                 )
             with phases.span("ingest.rank_table"):
-                ranks = self.enc.interner.rank_table_host()
-                client_rank = self._upload(ranks)
-                if phases.enabled:
-                    phases.transfer(
-                        "ingest.rank_table", self._uploaded_bytes(ranks), "h2d"
-                    )
+                # after the prescan has interned what this step brought
+                client_rank = self._client_rank()
             # `active` rides up with the call, as `merge_stream`'s `idx` does
             self.state = apply_update_batch(
                 self.state, batch, client_rank, active
@@ -873,11 +951,7 @@ class BatchIngestor:
         gather, `decode_updates_v1`, `merge_stream`. Each is keyed by what
         keys the decode family (S, the wire bucket, L, `n_rows`, `n_dels`)
         and by nothing else."""
-        from ytpu.ops.decode_kernel import (
-            decode_updates_v1,
-            key_hash_host,
-            pack_updates,
-        )
+        from ytpu.ops.decode_kernel import decode_updates_v1, pack_updates
         from ytpu.utils.phases import phases
 
         # the host stages of the merge, in order (docs/observability.md,
@@ -916,10 +990,17 @@ class BatchIngestor:
                     )
                     S, L = buf.shape
                     host_arrays = (buf, lens)
+                # the lanes' primary roots: the one table a step makes
+                prim_hash = np.full(S, -1, dtype=np.int32)
+                for s_i, d in enumerate(fast_idx):
+                    name = self.primary_roots.get(d)
+                    if name is not None:
+                        prim_hash[s_i] = self._key_hash(name)
+                host_arrays += (prim_hash,)
             with phases.span("ingest.merge.h2d"):
                 # the wire bytes' one trip to HBM, counted here and
                 # nowhere else (decode.v1 is handed device arrays)
-                dev_arrays = self._upload(list(host_arrays))
+                *dev_arrays, dev_prim_hash = self._upload(list(host_arrays))
                 dev_lens = dev_arrays[-1]
                 if phases.enabled:
                     phases.transfer(
@@ -957,19 +1038,7 @@ class BatchIngestor:
                         np.frombuffer(compact, dtype=np.uint8)
                     )
             with phases.span("ingest.merge.tables"):
-                prim_hash = np.full(S, -1, dtype=np.int32)
-                for s_i, d in enumerate(fast_idx):
-                    name = self.primary_roots.get(d)
-                    if name is not None:
-                        prim_hash[s_i] = key_hash_host(name.encode("utf-8"))
-                tables = self._upload(
-                    dict(
-                        client_table=self._client_table(),
-                        key_table=self._key_table(),
-                        client_hash_table=self._client_hash_table(),
-                        primary_root_hash=prim_hash,
-                    )
-                )
+                tables = self._decode_tables()
             stream, flags = decode_updates_v1(
                 dev_buf,
                 dev_lens,
@@ -977,6 +1046,7 @@ class BatchIngestor:
                 n_dels,
                 n_steps=n_steps,
                 max_sections=max_sections,
+                primary_root_hash=dev_prim_hash,
                 **tables,
             )
             with phases.span("ingest.merge.scatter"):
