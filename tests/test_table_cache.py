@@ -7,6 +7,10 @@ never what it holds: every step's tables are compared, bit for bit, with
 what the step used to build (the `_parent_*` functions below are that
 code), over a served sequence in which clients, map keys, a key-hash
 collision and a big-client collision arrive at chosen steps.
+
+Since ISSUE-35 a table has the ingestor's `_table_floor` entries whoever
+has written: what the parent built sits at its end, behind padding whose
+key is -1 (the rank table's padding follows its ranks, as it did).
 """
 
 import numpy as np
@@ -41,11 +45,15 @@ def test_the_twins_collide():
 # --- what the parent built, every step -----------------------------------------
 
 
+FLOOR = 1024  # `BatchIngestor._table_floor` of a server this small
+
+
 def _parent_sorted_table(mapping):
     ks = sorted(mapping)
+    pad = FLOOR - len(ks)
     return (
-        np.asarray(ks, dtype=np.int32),
-        np.asarray([mapping[k] for k in ks], dtype=np.int32),
+        np.asarray([-1] * pad + ks, dtype=np.int32),
+        np.asarray([0] * pad + [mapping[k] for k in ks], dtype=np.int32),
     )
 
 
@@ -53,7 +61,7 @@ def _parent_tables(ing) -> dict:
     to_idx, from_idx = ing.enc.interner.to_idx, ing.enc.interner.from_idx
     ids = sorted(c for c in to_idx if 0 <= c <= 2**31 - 1)
     n = len(from_idx)
-    ranks = np.zeros(max(8, 1 << (max(1, n - 1)).bit_length()), dtype=np.int32)
+    ranks = np.zeros(FLOOR, dtype=np.int32)
     for rank, idx in enumerate(sorted(range(n), key=lambda i: from_idx[i])):
         ranks[idx] = rank
     return dict(
@@ -205,10 +213,10 @@ BUILDS = {
     3: {"client_table", "client_rank"},
     6: {"key_table"},
     7: {"key_table"},
-    9: {"client_table", "client_rank", "client_hash_table"},
+    9: {"client_rank", "client_hash_table"},  # an id past i32 leaves the raw table alone
     12: {"key_table"},
     13: {"client_table", "client_rank"},
-    15: {"client_table", "client_rank", "client_hash_table"},
+    15: {"client_rank", "client_hash_table"},
 }
 
 

@@ -54,14 +54,24 @@ from ytpu.parallel.mesh import (
 __all__ = ["BatchIngestor"]
 
 
-def _sorted_table(mapping: Dict[int, int]):
-    """(sorted keys, value perm) as host i32 arrays — the shape every
-    device lookup table (clients, key hashes, client hashes) shares;
-    `BatchIngestor._cached_table` uploads them when they change."""
-    ks = np.fromiter(mapping, np.int32, len(mapping))
-    vs = np.fromiter(mapping.values(), np.int32, len(mapping))
-    order = np.argsort(ks)  # a dict's keys are distinct: one order
-    return ks[order], vs[order]
+def _sorted_table(mapping: Dict[int, int], width: int):
+    """(sorted keys, value perm) as host i32 arrays of `width` entries —
+    the shape every device lookup table (clients, key hashes, client
+    hashes) shares; `BatchIngestor._cached_table` uploads them when they
+    change. The mapping's entries sit at the end, behind padding whose
+    key is -1: no client id, key hash or client hash is negative, and the
+    decoder's lookups (`decode_kernel._resolve_and_pack`) take a hit only
+    for a value that is not, so the padding answers to nothing and the
+    programs that take the table compile once a `width`, not once a
+    writer."""
+    n = len(mapping)
+    ks = np.full(width, -1, np.int32)
+    vs = np.zeros(width, np.int32)
+    keys = np.fromiter(mapping, np.int32, n)
+    order = np.argsort(keys)  # a dict's keys are distinct: one order
+    ks[width - n :] = keys[order]
+    vs[width - n :] = np.fromiter(mapping.values(), np.int32, n)[order]
+    return ks, vs
 
 # content kinds the device decoder handles: GC, Deleted, Json, Binary,
 # String, Embed, Format, Type (non-weak), Any(scalar), Skip, Move
@@ -214,11 +224,25 @@ class BatchIngestor:
         # what a step's lookup tables cost it (`_cached_table`)
         self._m_table_reuses = metrics.counter("ingest.table_reuses")
         self._m_table_builds = metrics.counter("ingest.table_builds")
+        self._m_table_grows = metrics.counter("ingest.table_grows")
+        # writers the tables had not held (`_note_clients`)
+        self._m_first_seen = metrics.counter("ingest.clients_first_seen")
+        self._m_first_seen_big = metrics.counter(
+            "ingest.clients_first_seen_big"
+        )
 
     def _reset_tables(self) -> None:
         """The device lookup tables' sources, empty, and nothing built of
         them yet; a restored ingestor (`checkpoint.load_ingestor`) starts
         from the same and registers its interners' keys and clients."""
+        #: entries every device lookup table starts with (a power of two).
+        #: A server is not told who will write: a Yjs client draws its id
+        #: when its document is made, and the first the server hears of it
+        #: is its first update. So the tables' shape is the server's own
+        #: choice, four writers a room and 1,024 at least, and a table
+        #: that outgrows it doubles (`_cached_table`): the decode and
+        #: integrate programs specialize on the shape, not on the writers
+        self._table_floor = _bucket(4 * self.n_docs, 1024)
         # device key hashing (map rows on the fast lane): hash -> key idx;
         # keys whose hash collides with a different key take the host lane
         self._key_hashes: Dict[int, int] = {}
@@ -231,6 +255,14 @@ class BatchIngestor:
         self._key_gen = self._client_hash_gen = 0
         # table name -> (its source's stamp at the build, device arrays)
         self._table_cache: Dict[str, tuple] = {}
+        # table name -> its entries, padding included, once past the floor
+        self._table_width: Dict[str, int] = {}
+        # the interner as `_note_clients` last saw it: how many clients,
+        # and how many of them the raw table holds. What it holds already
+        # (a restored checkpoint's writers) is known, not first seen
+        known = self.enc.interner.from_idx
+        self._clients_seen = len(known)
+        self._raw_clients = sum(1 for c in known if c <= _I32_MAX)
         # a key's or root name's device hash, worked out once
         self._name_hashes: Dict[str, int] = {}
 
@@ -242,17 +274,22 @@ class BatchIngestor:
             h = self._name_hashes[key] = key_hash_host(key.encode("utf-8"))
         return h
 
-    def _cached_table(self, name: str, stage: str, stamp, build):
+    def _cached_table(self, name: str, stage: str, stamp, size: int, build):
         """Lookup table `name` on the device(s) the state lives on: the
         arrays of its last build while `stamp`, which says what it was
-        built from, is that build's; else `build()` (host arrays),
-        uploaded whole on every chip and counted against `stage`.
+        built from, is that build's; else `build(width)` (host arrays of
+        `width` entries, `size` of them real), uploaded whole on every
+        chip and counted against `stage`.
 
         The tables hold every interned client or key, not a step's, and a
         server interns when a client or a key is first seen, not per
         keystroke. The programs that take them donate no operand, so one
-        upload serves every step until its source changes."""
-        from ytpu.utils.phases import phases
+        upload serves every step until its source changes. A first-seen
+        writer changes what a table holds and not its shape: `width` is
+        `_table_floor` until `size` passes it and doubles then, a counted
+        and spanned event (`ingest.table_grows`, `ingest.table_grow`)
+        after which the programs that take the table compile anew."""
+        from ytpu.utils.phases import NULL_SPAN, phases
 
         hit = self._table_cache.get(name)
         if hit is not None and hit[0] == stamp:
@@ -260,14 +297,45 @@ class BatchIngestor:
             dev = hit[1]
         else:
             took = self._m_table_builds
-            host = build()
-            dev = self._upload(host)
+            width = self._table_width.get(name, self._table_floor)
+            span = NULL_SPAN
+            if size > width:
+                width = self._table_width[name] = _bucket(size, width)
+                self._m_table_grows.inc()
+                phases.add_value(self._m_table_grows.name, 1)
+                span = phases.span("ingest.table_grow")
+            with span:
+                host = build(width)
+                dev = self._upload(host)
             self._table_cache[name] = (stamp, dev)
             if phases.enabled:
                 phases.transfer(stage, self._uploaded_bytes(host), "h2d")
         took.inc()
         phases.add_value(took.name, 1)  # the recorder's: a window's delta
         return dev
+
+    def _note_clients(self) -> None:
+        """Count the writers interned since the last look: one count a
+        writer, in the step that brought it, before the step's tables are
+        looked up (`ingest.clients_first_seen`, and `_big` for an id past
+        int32, which resolves through the hash table). The interner only
+        appends and others intern into it too (the encoder's host lane, a
+        driver that preregisters its sessions): what it has grown by,
+        read here, is every path's."""
+        from ytpu.utils.phases import phases
+
+        from_idx = self.enc.interner.from_idx
+        new = from_idx[self._clients_seen :]
+        if not new:
+            return
+        self._clients_seen = len(from_idx)
+        big = sum(1 for c in new if c > _I32_MAX)
+        self._raw_clients += len(new) - big
+        self._m_first_seen.inc(len(new))
+        phases.add_value(self._m_first_seen.name, len(new))
+        if big:
+            self._m_first_seen_big.inc(big)
+            phases.add_value(self._m_first_seen_big.name, big)
 
     def _upload(self, host, by_doc: bool = False):
         """A tree of host arrays onto the device(s) the state lives on.
@@ -303,20 +371,23 @@ class BatchIngestor:
     def _decode_tables(self) -> dict:
         """`decode_updates_v1`'s tables of every interned client, key and
         big client, each on the device since the step that last changed
-        its source. The two hash dicts lose an entry on a collision, so
-        their stamp is a count of changes, not a length."""
+        its source: a writer past int32 leaves the raw table as it is, a
+        small one the hash table. The two hash dicts lose an entry on a
+        collision, so their stamp is a count of changes, not a length."""
         stage = "ingest.merge.tables"
+        self._note_clients()
         return dict(
             client_table=self._cached_table(
-                "client_table", stage, len(self.enc.interner),
+                "client_table", stage, self._raw_clients, self._raw_clients,
                 self._client_table,
             ),
             key_table=self._cached_table(
-                "key_table", stage, self._key_gen, self._key_table
+                "key_table", stage, self._key_gen, len(self._key_hashes),
+                self._key_table,
             ),
             client_hash_table=self._cached_table(
                 "client_hash_table", stage, self._client_hash_gen,
-                self._client_hash_table,
+                len(self._client_hashes), self._client_hash_table,
             ),
         )
 
@@ -326,8 +397,9 @@ class BatchIngestor:
         a server preregistering its sessions): its length, read here, at
         the point of use, says whether the table still holds."""
         interner = self.enc.interner
+        self._note_clients()
         return self._cached_table(
-            "client_rank", "ingest.rank_table", len(interner),
+            "client_rank", "ingest.rank_table", len(interner), len(interner),
             interner.rank_table_host,
         )
 
@@ -621,10 +693,10 @@ class BatchIngestor:
             self._client_hash_gen += 1
         return True
 
-    def _client_hash_table(self):
+    def _client_hash_table(self, width: int):
         """Device big-client table: (sorted varint-byte hashes, interned
         idx perm)."""
-        return _sorted_table(self._client_hashes)
+        return _sorted_table(self._client_hashes, width)
 
     def _register_key(self, key: str) -> bool:
         """Intern `key` and record its device hash; False on collision."""
@@ -646,9 +718,9 @@ class BatchIngestor:
             self._key_gen += 1
         return True
 
-    def _key_table(self):
+    def _key_table(self, width: int):
         """Device key table: (sorted hashes, interned key idx perm)."""
-        return _sorted_table(self._key_hashes)
+        return _sorted_table(self._key_hashes, width)
 
     def _ensure_anchor(self, doc: int, name: str) -> None:
         """Create doc's BLOCK_ROOT_ANCHOR row for a non-primary named root
@@ -719,18 +791,19 @@ class BatchIngestor:
                     else:
                         self._ensure_anchor(doc, p)
 
-    def _client_table(self):
+    def _client_table(self, width: int):
         """Device intern table: (sorted raw ids, perm to interned idx).
 
-        Ids above int32 (random 53-bit Yjs clients) are excluded here —
-        they resolve through the varint-byte hash table instead
-        (`_client_hash_table`)."""
+        Ids above int32 (half of the uint32 ids Yjs draws, and the 53-bit
+        ones of older clients) are excluded here — they resolve through
+        the varint-byte hash table instead (`_client_hash_table`)."""
         return _sorted_table(
             {
                 c: i
                 for c, i in self.enc.interner.to_idx.items()
                 if 0 <= c <= _I32_MAX
-            }
+            },
+            width,
         )
 
     def apply_bytes(self, payloads: List[Optional[bytes]]) -> DocStateBatch:

@@ -248,10 +248,15 @@ class SoakDriver:
 
     def _preregister_clients(self, scenario: Scenario) -> None:
         """Intern the round's known client ids up front (device-backed
-        servers only).  The decode/integrate programs specialize on the
-        client-table SIZE; without this, every first-seen client mid-run
-        retraces them — a real serving pod registers expected writers at
-        session admission for exactly this reason."""
+        servers only).  A choice, not a need: the lookup tables keep one
+        padded shape whoever writes (`BatchIngestor._table_floor`), so a
+        first-seen client mid-run retraces nothing and stays on the fast
+        lane.  What this still saves is the rebuild and upload of the
+        rank table and the client (or client-hash) table in the step that
+        meets a new writer — 1-2 ms of host time on a v5e host, once a
+        writer — and, for a scenario with more writers than the tables'
+        floor, the doublings (one compile of the decode or integrate
+        program each) happen here, before the measured rounds."""
         ing = getattr(self.server, "ingestor", None)
         if ing is None:
             return

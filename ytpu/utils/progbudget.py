@@ -38,7 +38,7 @@ _REGISTRY: Dict[str, Callable] = {}
 # Budget on RESIDENT EXECUTABLES across the registered (large) programs.
 # ~64 large CPU programs sit well under the observed exhaustion point
 # (the r4 repro needed hundreds of large compiles to die); TPU
-# executables don't ride the LLVM arena, so the ceiling there is moot.
+# executables don't ride the LLVM arena, so `enforce` leaves them alone.
 _MAX = int(os.environ.get("YTPU_MAX_RESIDENT_PROGRAMS", "64"))
 _EVERY = int(os.environ.get("YTPU_PROGBUDGET_EVERY", "16"))
 _calls = 0
@@ -65,7 +65,16 @@ def resident_programs() -> Dict[str, int]:
 def enforce() -> int:
     """Evict largest holders until the resident total is under budget.
 
-    Returns the number of functions whose caches were cleared."""
+    Returns the number of functions whose caches were cleared. On the CPU
+    backend only: it is XLA:CPU's LLVM arena the budget guards. On a TPU a
+    served tick of at most 16 rooms is 16 lane counts x 2 wire buckets x 3
+    programs (gather, decode, merge), over the budget before the first
+    handshake: an eviction there is a rebuild in the middle of traffic,
+    11 of them in a 30 s window (PERF.md §6, PR 35), for nothing."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return 0
     sizes = [(name, fn, _entries(fn)) for name, fn in _REGISTRY.items()]
     total = sum(s for _, _, s in sizes)
     if total <= _MAX:
