@@ -1,4 +1,4 @@
-"""Host time of the program's `ingest.merge` stage (`_merge_fast_lane`: pack, uploads, the eager programs, `decode.v1`'s dispatch) per step (phases recorder; a host stage)."""
+"""Host time of the program's `ingest.merge` stage (`_merge_fast_lane`: pack, uploads, the table look-ups and the three enqueues: gather, `decode.v1`, `merge_stream`) per step (phases recorder; a host stage)."""
 
 
 def read(w):

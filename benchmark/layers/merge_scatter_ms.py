@@ -1,4 +1,4 @@
-"""Host time of the program's `ingest.merge.scatter` stage (the eager `full.at[idx].set(fast)` over every plane of the batch) per step (phases recorder; a host stage)."""
+"""Host time of the program's `ingest.merge.scatter` stage (the one enqueue of `merge_stream`: rebase + `full.at[idx].set(fast)` over every plane of the batch, with its three small uploads) per step (phases recorder; a host stage)."""
 
 
 def read(w):

@@ -75,14 +75,15 @@ def sample_rooms(taken: List[List[bytes]], window_counts: List[int], seed: int) 
 
 
 def check(server, loop, plan, prefill, room_updates: List[List[bytes]], window_counts: List[int],
-          counters_before: Dict[str, float], seed: int, say) -> bool:
-    """Print every number compared beside its limit; True if all hold."""
+          counters_before: Dict[str, float], seed: int, say, compared: Dict[str, list]) -> bool:
+    """Print every number compared beside its limit, and put it into
+    `compared` under a short name as `[number, limit]`; True if all hold."""
     import numpy as np
 
     from ytpu.core.state_vector import StateVector
 
     n_rooms = len(room_updates)
-    rows: List[tuple] = []  # (what, value, limit)
+    rows: List[tuple] = []  # (short name, what, value, limit)
 
     # --- sampled rooms: text, state vector, canonical full-state diff -------
     groups = sample_rooms(room_updates, window_counts, seed)
@@ -100,9 +101,9 @@ def check(server, loop, plan, prefill, room_updates: List[List[bytes]], window_c
         if _canonical(diff) != (want_text, want_sv, _canonical(want.encode_state_as_update_v1())[2]):
             bad_diff.append(k)
     rows += [
-        (f"rooms of {len(rooms)} sampled whose device text differs from the oracle", len(bad_text), 0),
-        (f"rooms of {len(rooms)} sampled whose state vector differs from the oracle", len(bad_sv), 0),
-        (f"rooms of {len(rooms)} sampled whose full-state diff, re-encoded, differs", len(bad_diff), 0),
+        ("text_rooms_wrong", f"rooms of {len(rooms)} sampled whose device text differs from the oracle", len(bad_text), 0),
+        ("sv_rooms_wrong", f"rooms of {len(rooms)} sampled whose state vector differs from the oracle", len(bad_sv), 0),
+        ("diff_rooms_wrong", f"rooms of {len(rooms)} sampled whose full-state diff, re-encoded, differs", len(bad_diff), 0),
     ]
 
     # --- every room: state vector against the grammar's own prediction ------
@@ -117,7 +118,7 @@ def check(server, loop, plan, prefill, room_updates: List[List[bytes]], window_c
             if c >= g.WARM_CLIENT_BASE and c < g.TEMPLATE_CLIENT_BASE:
                 del have[c]  # warm-up typists: checked through the oracle above
         wrong += have != want
-    rows.append((f"rooms of {n_rooms} whose state vector differs from the grammar's count", wrong, 0))
+    rows.append(("sv_vs_grammar_wrong", f"rooms of {n_rooms} whose state vector differs from the grammar's count", wrong, 0))
 
     # --- SyncStep2 replies of the window ------------------------------------
     replies = list(loop.replies)
@@ -143,7 +144,7 @@ def check(server, loop, plan, prefill, room_updates: List[List[bytes]], window_c
                 and dict(client.state_vector().clocks) == dict(want.state_vector().clocks)
             )
             bad += not ok
-        rows.append((f"SyncStep2 replies of {len(pick)} sampled (longest {len(longest[1])} B) that leave a client short of the oracle", bad, 0))
+        rows.append(("replies_wrong", f"SyncStep2 replies of {len(pick)} sampled (longest {len(longest[1])} B) that leave a client short of the oracle", bad, 0))
 
     # --- flags, stashes, recovery paths --------------------------------------
     ing = server.ingestor
@@ -153,18 +154,19 @@ def check(server, loop, plan, prefill, room_updates: List[List[bytes]], window_c
     fired = sum(after[n] - counters_before[n] for n in WATCHED)
     punted = finisher_punts(server)
     rows += [
-        ("room slots with an error flag (1 = capacity, 2 = missing dependency)", flagged, 0),
-        ("room slots with updates left pending", stuck, 0),
-        ("recovery paths fired (" + ", ".join(WATCHED) + ")", fired, 0),
-        ("ingestor fast-lane recoveries", int(ing.fast_recoveries), 0),
-        ("rooms the native finisher punted to the Python finisher in the check's fan-out", punted, 0),
+        ("flagged_slots", "room slots with an error flag (1 = capacity, 2 = missing dependency)", flagged, 0),
+        ("pending_slots", "room slots with updates left pending", stuck, 0),
+        ("recoveries_fired", "recovery paths fired (" + ", ".join(WATCHED) + ")", fired, 0),
+        ("fast_recoveries", "ingestor fast-lane recoveries", int(ing.fast_recoveries), 0),
+        ("check_punts", "rooms the native finisher punted to the Python finisher in the check's fan-out", punted, 0),
         # a private read (no public counter; listed in benchmark/README.md): a rename fails here, loudly
-        ("tenants demoted to the host path", len(server._host_tenants), 0),
+        ("host_tenants", "tenants demoted to the host path", len(server._host_tenants), 0),
     ]
     ok = True
-    for what, value, limit in rows:
+    for name, what, value, limit in rows:
         verdict = "ok" if value <= limit else "FAILED"
         ok &= value <= limit
+        compared[name] = [value.item() if hasattr(value, "item") else value, limit]
         say(f"check: {what}: {value} (limit {limit}) {verdict}")
     if bad_text or bad_sv or bad_diff:
         say(f"check: first wrong rooms: text {bad_text[:6]}, state vector {bad_sv[:6]}, diff {bad_diff[:6]}")

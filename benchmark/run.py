@@ -6,10 +6,14 @@
 One process: refuses anything but a TPU with the chips the cell asks for,
 builds the server, prefills every room through the served path, warms up
 every shape the window will use (all of that is `setup_s`), measures for
-`--seconds`, checks the server against the host oracle, and prints one JSON
-object as the last line. `--trace 0` reports the cell's end-to-end metrics,
+`--seconds`, checks the server against the host oracle (every number compared
+is printed beside its limit, last on stderr and as the line's last key,
+`compared`), and prints one JSON object as the last line. `--trace 0` reports the cell's end-to-end metrics,
 `--trace 1` its per-layer metrics (profiler and the program's phase recorder
-on). See `benchmark/README.md`.
+on). The window is `min(--seconds, the pool)` where a saturated pool does not
+repeat; the traced slice is its last 4 s wherever it ends, and a window the
+pool closed in under 8 s ends the run with exit code 5 and no result
+(`benchmark/window.py`). See `benchmark/README.md`.
 
 `--rehearse` (the benchmark's own tests) runs the same command path on the
 CPU at the tiny sizes the data files give under "rehearsal", and prints no
@@ -38,8 +42,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-
-TRACE_SLICE_S = 4.0  # the traced slice: the window's last seconds
 
 
 def say(msg: str) -> None:
@@ -189,10 +191,9 @@ def main(argv=None) -> int:
     import numpy as np
 
     from benchmark import grammar as g
-    from benchmark import oracle, peaks, trace_reduce, warmup
+    from benchmark import oracle, peaks, trace_reduce, warmup, window
     from benchmark.ops import Op
     from benchmark.serve import ServerLoop, now
-    from benchmark.window import Window
     from ytpu.sync.device_server import DeviceSyncServer
 
     # --- inputs from the seed -------------------------------------------------
@@ -276,14 +277,17 @@ def main(argv=None) -> int:
     trace_dir = os.path.join(ROOT, ".bench_trace")
     if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    tracing = {"on": False}
+    tracing = {"on": False, "at": None, "end": None}  # the tick the profiler started at, the end it projected
+    handed = {"share": 0.0}  # of a pool that does not repeat, after the last tick
     counter_names = ("ingest.fast_docs", "ingest.slow_docs")
     prog_before = {n: prog_metrics.counter(n).value for n in counter_names}
     phases_before = phases.snapshot() if args.trace else {}
-    slice_s = min(TRACE_SLICE_S, args.seconds / 2.0)
+    slice_s = min(window.TRACE_SLICE_S, args.seconds / 2.0)
 
-    def on_tick(elapsed: float) -> None:
-        if args.trace and not tracing["on"] and elapsed >= args.seconds - slice_s:
+    def on_tick(elapsed: float, handed_share: float) -> None:
+        handed["share"] = handed_share
+        if args.trace and not tracing["on"] and window.slice_opens(elapsed, handed_share, args.seconds, slice_s):
+            tracing.update(at=elapsed, end=window.projected_end(elapsed, handed_share, args.seconds))
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1  # the bench.* annotations, not every runtime call
@@ -316,6 +320,18 @@ def main(argv=None) -> int:
         third = (t_close - t_open) / 3.0
         by_third = [sum(1 for d in done if t_open + k * third <= d < t_open + (k + 1) * third) / third for k in range(3)]
         say("window: updates/s in its first, second and last third: " + ", ".join(f"{x:.2f}" for x in by_third))
+    window_s = t_close - t_open
+    by_pool = window.closed_by_pool(handed["share"], window_s, args.seconds)
+    say(f"window: closed by {'the pool' if by_pool else '--seconds'} after {window_s:.3f} s, "
+        + (f"{100.0 * handed['share']:.1f}% of the pool of {len(plan.ops)} ops handed over" if handed["share"]
+           else "the plan repeats or is open loop")
+        + ("; no traced slice (--trace 0)" if not args.trace
+           else "; the traced slice never opened" if not tracing["on"]
+           else f"; the traced slice opened at {tracing['at']:.3f} s (projected end {tracing['end']:.3f} s - {slice_s:g})"))
+    if window.too_short_to_read(by_pool, window_s) and not args.rehearse:
+        print(f"bench: pool of {len(plan.ops)} drained in {window_s:.3f} s, under {window.MIN_POOL_WINDOW_S:g} s: "
+              f"too short a window to read a rate or a slice from; no result", file=sys.stderr)
+        return window.EXIT_TOO_SHORT
 
     # --- correct --------------------------------------------------------------
     t = now()
@@ -325,9 +341,11 @@ def main(argv=None) -> int:
         for r in rec.room:
             window_counts[r] += 1
     room_updates = [[u for _, u, _ in q] for q in loop.taken]
-    correct = oracle.check(server, loop, plan, prefill, room_updates, window_counts, counters_before, seed, say)
+    compared = {}  # short name -> [number, limit] of everything `correct` rests on
+    correct = oracle.check(server, loop, plan, prefill, room_updates, window_counts, counters_before, seed, say, compared)
     flagged_rooms = set(np.nonzero(np.asarray(server.ingestor.state.error))[0].tolist())
     failed = sum(1 for i in range(n_ops) if rec.failed[i] or rec.room[i] in flagged_rooms)
+    compared["window_punts"], compared["ops_failed"] = [loop.punted, 0], [failed, 0]
     if loop.punted:
         say(f"check: rooms the native finisher punted during the window: {loop.punted} (limit 0) FAILED")
         correct = False
@@ -351,7 +369,7 @@ def main(argv=None) -> int:
     for stage, vals in phases_after.items():
         b = phases_before.get(stage, {})
         phase_delta[stage] = {k: v - b.get(k, 0) for k, v in vals.items() if isinstance(v, (int, float))}
-    w = Window(
+    w = window.Window(
         rec=rec, t_open=t_open, t_close=t_close, setup_s=setup_s,
         dispatch_spans=loop.dispatch_spans,
         counters={n: prog_metrics.counter(n).value - prog_before[n] for n in counter_names},
@@ -372,9 +390,13 @@ def main(argv=None) -> int:
         value = load_reader(directory, m["name"]).read(w)
         if value is not None and value == value:
             out_metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    # every number compared beside its limit: the last lines on stderr, the last key of the result line
+    for name, (value, limit) in compared.items():
+        print(f"bench: compared {name} {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     if args.rehearse:
         print(json.dumps({"rehearsal": True, "correct": bool(correct), "attempted": n_ops, "failed": failed,
-                          "would_report": sorted(out_metrics)}))
+                          "would_report": sorted(out_metrics), "compared": compared}))
         return 0
     device["memory_peak_bytes"] = int(peak_bytes)
     line = {"correct": bool(correct), "attempted": n_ops, "failed": failed, "metrics": out_metrics, "device": device}
@@ -382,6 +404,7 @@ def main(argv=None) -> int:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
         line["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
+    line["compared"] = compared
     print(json.dumps(line), flush=True)
     return 0
 

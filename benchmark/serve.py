@@ -232,7 +232,10 @@ class ServerLoop:
     # --- the window ----------------------------------------------------------
 
     def run_window(self, seconds: float, on_tick=None) -> tuple:
-        """Drive the plan's ops for `seconds`; returns (t_open, t_close)."""
+        """Drive the plan's ops for `seconds`; returns (t_open, t_close). After
+        every tick `on_tick(elapsed, handed_share)`: the share of the plan's ops
+        handed to the server so far where draining them closes the window (a
+        saturated plan that does not repeat), else 0."""
         plan = self.plan
         tick_n = plan.tick_max_frames
         ops = plan.ops
@@ -248,7 +251,7 @@ class ServerLoop:
                 pos += len(batch)
                 self.tick(batch, self.sessions, [t_open] * len(batch), [t_open] * len(batch))
                 if on_tick:
-                    on_tick(now() - t_open)
+                    on_tick(now() - t_open, 0.0 if plan.repeat else pos / len(ops))
             return t_open, now()
 
         inbox: collections.deque = collections.deque()
@@ -280,7 +283,7 @@ class ServerLoop:
                 self.tick([b[0] for b in batch], self.sessions,
                           [b[1] for b in batch], [b[2] for b in batch])
                 if on_tick:
-                    on_tick(now() - t_open)
+                    on_tick(now() - t_open, 0.0)
         finally:
             feeder.join(timeout=seconds + 60)
         if feeder.is_alive():

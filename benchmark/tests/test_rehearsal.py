@@ -46,7 +46,11 @@ def test_rehearsal_runs_the_command_path(cell, trace):
     e2e = {m["name"] for m in BENCH["end_to_end"] if cell in m.get("workloads", [cell])}
     if not trace:
         assert set(last["would_report"]) == e2e
-    assert "limit 0" in p.stdout  # every number compared is printed beside its limit
+    assert "limit 0" in p.stdout  # every number compared is printed beside its limit,
+    # and again as the last key of the result line and the last lines on stderr
+    assert list(last)[-1] == "compared" and len(last["compared"]) >= 12
+    assert all(value <= limit for value, limit in last["compared"].values())
+    assert p.stderr.strip().splitlines()[-len(last["compared"])].startswith("bench: compared text_rooms_wrong 0 (limit 0)")
     assert " 0 programs built inside the window" in p.stdout
 
 
@@ -77,3 +81,4 @@ def test_lost_update_is_not_correct(mix):
     last = json.loads(p.stdout.strip().splitlines()[-1])
     assert last["correct"] is False
     assert "FAILED" in p.stdout
+    assert any(value > limit for value, limit in last["compared"].values())

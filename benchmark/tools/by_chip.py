@@ -22,7 +22,8 @@ from benchmark import chip_trace as ct  # noqa: E402
 from benchmark import program_trace as pt  # noqa: E402
 from benchmark import trace_reduce  # noqa: E402
 
-SCOPES = ("integrate_rows", "delete_pass", "split", "conflict_scan", "move_recompute", "decode_v1", "merge_stream")
+SCOPES = ("integrate_rows", "delete_pass", "split", "conflict_scan", "move_recompute", "decode_v1", "merge_stream",
+          "compact_gather", "compact_scatter")
 
 
 def main(argv) -> int:
