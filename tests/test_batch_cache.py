@@ -1,0 +1,440 @@
+"""The host lane's empty batch stays on the device (ISSUE-37).
+
+A step none of whose payloads takes the host lane plans no row: its 27
+host planes are `batch_planes`' padding, a constant of `(n_docs, n_rows,
+n_dels)`, so `apply_bytes` hands `merge_stream` the device arrays an earlier
+step of the bucket uploaded. What changes is when the planes are built and
+uploaded, never what a program receives: every step's batch is compared,
+leaf for leaf and bit for bit, with what the step used to build
+(`_parent_planes` below is that code), over a served sequence in which a
+stashed out-of-order update, changes of bucket, an eviction by the byte
+bound, a bucket too large to keep and a flagged lane arrive at chosen steps.
+"""
+
+import jax
+import numpy as np
+import pytest
+
+from test_table_cache import _Room, _cut, _replayed, _type
+from ytpu.core import Doc
+from ytpu.models import ingest as ingest_mod
+from ytpu.models.batch_doc import UpdateBatch, get_string
+from ytpu.models.ingest import BatchIngestor
+from ytpu.native import decode_update_columns
+from ytpu.ops import decode_kernel as dk
+from ytpu.utils import metrics
+
+pytestmark = pytest.mark.usefixtures("native_lib")
+
+COUNTERS = ("ingest.batch_builds", "ingest.batch_reuses")
+N_DOCS, CAPACITY = 4, 256
+N_PLANES = len(UpdateBatch._fields)
+
+
+# --- what the parent built, every step -----------------------------------------
+
+
+def _bucket(n, lo=4):
+    b = lo
+    while b < n:
+        b *= 2
+    return b
+
+
+def _parent_planes(all_rows, all_dels, n_rows, n_dels):
+    """`BatchEncoder.batch_planes` as the parent had it: the planes' one
+    source of truth then, and the padding's now."""
+    D = len(all_rows)
+    rows = np.zeros((D, n_rows, 22), dtype=np.int32)
+    for col in (10, 12, 14, 15, 18, 21):
+        rows[:, :, col] = -1
+    rows_valid = np.zeros((D, n_rows), dtype=bool)
+    for d, doc_rows in enumerate(all_rows):
+        for i, row in enumerate(doc_rows):
+            rows[d, i] = row
+            rows_valid[d, i] = True
+    dels = np.zeros((D, n_dels, 3), dtype=np.int32)
+    dels_valid = np.zeros((D, n_dels), dtype=bool)
+    for d, doc_dels in enumerate(all_dels):
+        for i, de in enumerate(doc_dels):
+            dels[d, i] = de
+            dels_valid[d, i] = True
+    return (
+        [rows[:, :, i] for i in range(22)] + [rows_valid]
+        + [dels[:, :, i] for i in range(3)] + [dels_valid]
+    )
+
+
+def _entry_bytes(n_rows, n_dels, n_docs=N_DOCS):
+    return sum(a.nbytes for a in _parent_planes([[]] * n_docs, [[]] * n_docs, n_rows, n_dels))
+
+
+def _bound(ing):
+    return sum(a.nbytes for a in jax.tree.leaves(ing.state)) // 16
+
+
+def _wrong_leaves(handed, want):
+    """Indices of the leaves of `handed` (device) that are not `want`'s (host), bit for bit."""
+    assert len(handed) == len(want) == N_PLANES
+    return [
+        i for i, (h, w) in enumerate(zip(handed, want))
+        if not (h.dtype == w.dtype and h.shape == w.shape and np.asarray(h).tobytes() == w.tobytes())
+    ]
+
+
+class _Spy:
+    """The batch every program was handed, call by call, beside what the
+    parent's `apply_bytes` would have built from the same step: its walk
+    called `_plan_doc` for every slot (a slot without a host-lane update
+    plans nothing), sized the planes by the widest of both lanes, padded
+    and uploaded."""
+
+    def __init__(self, monkeypatch, ing, flag_decode=None):
+        self.ing = ing
+        self.merged = []  # per `merge_stream` call: the batch it was handed
+        self.applied = []  # per `apply_update_batch` call: the batch it was handed
+        self.planned = {}  # this step: slot -> (rows, dels) its host lane planned
+        self.decodes = 0
+        self.recovering = False
+        real_merge, real_apply = ingest_mod._merge_stream_jit, ingest_mod.apply_update_batch
+        real_decode, real_plan, real_recover = dk.decode_updates_v1, ing._plan_doc, ing._recover_flagged
+
+        def merge(batch, *a, **kw):
+            self.merged.append(batch)
+            return real_merge(batch, *a, **kw)
+
+        def apply(state, batch, *rest):
+            self.applied.append(batch)
+            return real_apply(state, batch, *rest)
+
+        def plan(doc, incoming):
+            got = real_plan(doc, incoming)
+            if incoming is not None and not self.recovering:
+                self.planned[doc] = got
+            return got
+
+        def recover(*a):  # the follow-up step plans its own rows: not the step's
+            self.recovering = True
+            try:
+                return real_recover(*a)
+            finally:
+                self.recovering = False
+
+        def decode(*a, **kw):
+            stream, flags = real_decode(*a, **kw)
+            self.decodes += 1
+            if self.decodes == flag_decode:  # the device flags every lane of this call
+                import jax.numpy as jnp
+
+                flags = flags | jnp.full_like(flags, dk.FLAG_MALFORMED)
+                stream = stream._replace(
+                    valid=jnp.zeros_like(stream.valid), del_valid=jnp.zeros_like(stream.del_valid)
+                )
+            return stream, flags
+
+        monkeypatch.setattr(ingest_mod, "_merge_stream_jit", merge)
+        monkeypatch.setattr(ingest_mod, "apply_update_batch", apply)
+        monkeypatch.setattr(dk, "decode_updates_v1", decode)
+        monkeypatch.setattr(ing, "_plan_doc", plan)
+        monkeypatch.setattr(ing, "_recover_flagged", recover)
+
+    def parent_planes(self, payloads):
+        """What the parent built for the step that has just ended."""
+        planned, self.planned = self.planned, {}
+        fast_rows = fast_dels = 0
+        for d, p in enumerate(payloads):
+            if p is None or d in planned:
+                continue
+            cols = decode_update_columns(p)
+            fast_rows = max(fast_rows, sum(
+                1 for i in range(cols.n_blocks) if int(cols.kind[i]) != 10 and int(cols.length[i]) > 0
+            ))
+            fast_dels = max(fast_dels, cols.n_dels)
+        all_rows = [planned.get(d, ([], []))[0] for d in range(len(payloads))]
+        all_dels = [planned.get(d, ([], []))[1] for d in range(len(payloads))]
+        n_rows = _bucket(max(fast_rows, 1, max(len(r) for r in all_rows)))
+        n_dels = _bucket(max(fast_dels, 1, max(len(d) for d in all_dels)))
+        return (n_rows, n_dels), _parent_planes(all_rows, all_dels, n_rows, n_dels), sorted(planned)
+
+
+def _counts() -> dict:
+    return {n: metrics.counter(n).value for n in COUNTERS}
+
+
+def _counted(before: dict) -> dict:
+    return {n: v - before[n] for n, v in _counts().items()}
+
+
+def _many(n):
+    """`n` inserts at the head of the text in one transaction: `n` blocks."""
+    def fn(doc, txn):
+        for i in range(n):
+            doc.get_text("text").insert(txn, 0, "abcdefghijklmnopq"[i])
+
+    return fn
+
+
+def _cuts(n):
+    """`n` deletes of one character, none next to another: `n` ranges."""
+    def fn(doc, txn):
+        for i in range(n):
+            doc.get_text("text").remove_range(txn, 2 * i, 1)
+
+    return fn
+
+
+# step -> what room 0 receives; every other step client 1 types a word there.
+# Room 1 takes one plain insert a step from client 50 (so every step has a
+# fast lane, whichever lane room 0's update takes); rooms 2 and 3 are idle.
+LATE, EARLY = "the second edit of client 2", "its first"
+ROOM0 = {
+    3: LATE,  # comes before the edit it follows: stashed, the host lane plans nothing yet
+    4: EARLY,  # the gap closes: the host lane plans both
+    6: (1, _many(5)),  # 5 rows: the (8, 4) bucket
+    7: (1, _many(6)),
+    8: (1, _type("abcdefghijklmnop ")),  # one block, for step 9 to cut into
+    9: (1, _cuts(5)),  # 5 delete ranges: the (4, 8) bucket; (8, 4), least recently used, goes
+    11: (1, _many(7)),  # (8, 4) again, built again; (4, 8) goes
+    12: (1, _many(17)),  # the (32, 4) bucket: passes the bound alone
+    13: (1, _many(17)),
+    # step 15: the device flags both lanes (`FLAGGED_DECODE`); one follow-up step on the host lane
+}
+N_STEPS = 18
+FLAGGED_STEP = 15
+# step -> (the bucket, built or reused, the buckets kept after it, least recently used first)
+A, B, C, BIG = (4, 4), (8, 4), (4, 8), (32, 4)
+WANT = {
+    0: (A, "build", [A]),
+    1: (A, "reuse", [A]),
+    2: (A, "reuse", [A]),
+    3: (A, "build", [A]),  # a host-lane room: today's path, and keeps nothing
+    4: (A, "build", [A]),
+    5: (A, "reuse", [A]),
+    6: (B, "build", [A, B]),
+    7: (B, "reuse", [A, B]),
+    8: (A, "reuse", [B, A]),
+    9: (C, "build", [A, C]),
+    10: (A, "reuse", [C, A]),
+    11: (B, "build", [A, B]),
+    12: (BIG, "build", [A, B]),
+    13: (BIG, "build", [A, B]),
+    14: (A, "reuse", [B, A]),
+    15: (A, "reuse", [B, A]),
+    16: (A, "reuse", [B, A]),
+    17: (A, "reuse", [B, A]),
+}
+
+
+def test_the_sequence_meets_the_bound_where_it_says():
+    bound = _bound(BatchIngestor(n_docs=N_DOCS, capacity=CAPACITY))
+    a, b, c, big = (_entry_bytes(*k) for k in (A, B, C, BIG))
+    assert a + b <= bound < a + b + c  # step 9 evicts
+    assert a + c <= bound < a + c + b  # step 11 evicts
+    assert a + b <= bound < big  # steps 12 and 13 keep nothing
+
+
+@pytest.fixture(scope="module")
+def served():
+    """The sequence served once; what every step handed its programs."""
+    monkeypatch = pytest.MonkeyPatch()
+    rooms = [_Room() for _ in range(N_DOCS)]
+    ing = BatchIngestor(n_docs=N_DOCS, capacity=CAPACITY)
+    steps = []
+    try:
+        # step 0's decode is call 1; the recovery's follow-up step decodes nothing
+        spy = _Spy(monkeypatch, ing, flag_decode=FLAGGED_STEP + 1)
+        late = early = None
+        for step in range(N_STEPS):
+            got = ROOM0.get(step, (1, _type(f"w{step} ")))
+            if got is LATE:
+                early = rooms[0].edit(2, _type("two "))
+                late = rooms[0].edit(2, _type("more ", 2))
+            to_room0 = late if got is LATE else early if got is EARLY else rooms[0].edit(*got)
+            payloads = [to_room0, rooms[1].edit(50, _type(f"x{step}")), None, None]
+            merged, applied, before = len(spy.merged), len(spy.applied), _counts()
+            recoveries = ing.fast_recoveries
+            ing.apply_bytes(payloads)
+            bucket, want, host_lane = spy.parent_planes(payloads)
+            steps.append(
+                dict(
+                    merged=spy.merged[merged:],
+                    applied=spy.applied[applied:],
+                    counted=_counted(before),
+                    bucket=bucket,
+                    want=want,
+                    host_lane=host_lane,
+                    recovered=ing.fast_recoveries - recoveries,
+                    kept=list(ing._batch_cache),
+                    kept_arrays=dict(ing._batch_cache),
+                )
+            )
+    finally:
+        monkeypatch.undo()
+    return ing, rooms, steps
+
+
+@pytest.mark.parametrize("step", range(N_STEPS))
+def test_every_step_hands_merge_stream_the_parents_batch(served, step):
+    _, _, steps = served
+    s = steps[step]
+    assert s["bucket"] == WANT[step][0]
+    assert len(s["merged"]) == 1  # one fast lane a step, at least room 1's
+    assert _wrong_leaves(s["merged"][0], s["want"]) == []
+    # the host lane planned where the sequence says, and had rows to carry once
+    assert s["host_lane"] == ([0] if step in (3, 4) else [])
+    assert bool(np.asarray(s["merged"][0].valid).any()) == (step == 4)
+
+
+def test_a_reuse_hands_over_the_arrays_of_the_last_build(served):
+    _, _, steps = served
+    built = {}  # bucket -> the batch of its last all-fast-lane build
+    for step, s in enumerate(steps):
+        bucket, what, _ = WANT[step]
+        handed = s["merged"][0]
+        if what == "reuse":
+            assert all(h is b for h, b in zip(handed, built[bucket])), step
+        else:
+            earlier = {id(a) for batch in built.values() for a in batch}
+            assert not earlier & {id(h) for h in handed}, step
+            if not s["host_lane"]:
+                built[bucket] = handed
+    # step 5 was handed step 0's arrays: the two host-lane steps between kept nothing
+    assert all(h is b for h, b in zip(steps[5]["merged"][0], steps[0]["merged"][0]))
+
+
+def test_the_counters_count_what_happened(served):
+    _, _, steps = served
+    for step, s in enumerate(steps):
+        built = WANT[step][1] == "build"
+        assert s["counted"] == {
+            "ingest.batch_builds": int(built), "ingest.batch_reuses": int(not built),
+        }, step
+
+
+def test_the_kept_bytes_never_pass_the_bound(served):
+    ing, _, steps = served
+    bound = _bound(ing)
+    for step, s in enumerate(steps):
+        assert s["kept"] == WANT[step][2], step
+        kept_bytes = sum(a.nbytes for batch in s["kept_arrays"].values() for a in batch)
+        assert kept_bytes == sum(_entry_bytes(*k) for k in s["kept"]) <= bound, step
+        for bucket, batch in s["kept_arrays"].items():
+            empty = [[]] * N_DOCS
+            assert _wrong_leaves(batch, _parent_planes(empty, empty, *bucket)) == [], (step, bucket)
+    assert _entry_bytes(*BIG) > bound  # and the one that was never kept could not be
+
+
+def test_the_flagged_lanes_recover_through_a_batch_of_their_own(served):
+    """The follow-up step of a flagged lane carries host-lane rows by
+    definition: built, uploaded and handed to the integrate program, it
+    neither reads the kept batches nor is counted as a step's."""
+    ing, _, steps = served
+    s = steps[FLAGGED_STEP]
+    assert s["recovered"] == 2 and ing.fast_recoveries == 2
+    assert [st["recovered"] for st in steps].count(0) == N_STEPS - 1
+    assert len(s["applied"]) == 2  # the step's own integrate call, then the recovery's
+    recovery = s["applied"][1]
+    assert np.asarray(recovery.valid).any(axis=1).tolist() == [True, True, False, False]
+    kept = [a for batch in s["kept_arrays"].values() for a in batch]
+    assert all(leaf is not k for leaf in recovery for k in kept)
+    assert sum(s["counted"].values()) == 1
+    for other in steps[:FLAGGED_STEP] + steps[FLAGGED_STEP + 1 :]:
+        assert len(other["applied"]) == 1
+
+
+def test_the_served_rooms_equal_the_oracle(served):
+    import jax.numpy as jnp
+
+    from ytpu.models.batch_doc import encode_diff_batch, finish_encode_diff_batch
+
+    ing, rooms, _ = served
+    assert not np.asarray(ing.state.error).any()
+    assert (ing.slow_docs, ing.fast_docs) == (2, 2 * N_STEPS - 2)
+    want = [room.oracle() for room in rooms]
+    for d, doc in enumerate(want):
+        assert get_string(ing.state, d, ing.payloads) == doc.get_text("text").get_string(), d
+        assert dict(ing.svs[d].clocks) == dict(doc.state_vector().clocks), d
+    assert len(want[0].get_text("text").get_string()) > 60
+    # the full diff, off the device: a fresh replica ends where the oracle is
+    C_ = max(8, len(ing.enc.interner))
+    ship, offsets, _sv, deleted = encode_diff_batch(
+        ing.state, jnp.zeros((N_DOCS, C_), dtype=jnp.int32), C_
+    )
+    diffs = finish_encode_diff_batch(
+        ing.state, list(range(N_DOCS)), ship, offsets, deleted, ing.enc,
+        payloads=ing.payloads, root_name="text",
+    )
+    for diff, doc in zip(diffs, want):
+        fresh = Doc(client_id=77)
+        fresh.apply_update_v1(diff)
+        assert fresh.get_text("text").get_string() == doc.get_text("text").get_string()
+        assert dict(fresh.state_vector().clocks) == dict(doc.state_vector().clocks)
+        assert fresh.encode_state_as_update_v1() == _replayed(doc).encode_state_as_update_v1()
+
+
+def test_apply_builds_its_batch_and_keeps_nothing(monkeypatch):
+    """`apply()` plans every slot on the host: it goes through `_batch` as
+    before, counts no step of `apply_bytes` and leaves the kept batches alone."""
+    room = _Room()
+    ing = BatchIngestor(n_docs=1, capacity=CAPACITY)
+    spy = _Spy(monkeypatch, ing)
+    ing.apply_bytes([room.edit(1, _type("one "))])
+    kept = dict(ing._batch_cache)
+    before = _counts()
+    ing.apply([room.edit(1, _type("two "))])
+    assert _counted(before) == {"ingest.batch_builds": 0, "ingest.batch_reuses": 0}
+    assert list(ing._batch_cache) == [A] and ing._batch_cache[A] is kept[A]
+    assert all(leaf is not k for leaf in spy.applied[-1] for k in kept[A])
+    assert np.asarray(spy.applied[-1].valid).any()
+    ing.apply_bytes([room.edit(1, _type("three "))])
+    assert all(h is k for h, k in zip(spy.merged[-1], kept[A]))
+    assert get_string(ing.state, 0, ing.payloads) == room.oracle().get_text("text").get_string()
+
+
+def test_a_step_without_a_payload_takes_the_kept_batch(monkeypatch):
+    """No payload at all is no host-lane room either: the integrate program
+    is handed the bucket's kept batch, and the state stays as it was."""
+    room = _Room()
+    ing = BatchIngestor(n_docs=2, capacity=CAPACITY)
+    spy = _Spy(monkeypatch, ing)
+    ing.apply_bytes([room.edit(1, _type("one ")), None])
+    before = _counts()
+    ing.apply_bytes([None, None])
+    assert _counted(before) == {"ingest.batch_builds": 0, "ingest.batch_reuses": 1}
+    assert all(h is k for h, k in zip(spy.applied[-1], spy.merged[-1]))
+    assert get_string(ing.state, 0, ing.payloads) == "one "
+
+
+def test_a_restored_ingestor_builds_at_its_first_step(monkeypatch, tmp_path):
+    from ytpu.models.checkpoint import load_ingestor, save_ingestor
+
+    room = _Room()
+    ing = BatchIngestor(n_docs=1, capacity=CAPACITY)
+    for fn in (_type("saved "), _many(5), _cut(1, 2)):
+        ing.apply_bytes([room.edit(1, fn)])
+    assert list(ing._batch_cache) == [B, A]
+    path = str(tmp_path / "ckpt")
+    save_ingestor(path, ing)
+    restored = load_ingestor(path)
+    assert restored._batch_cache == {}
+    assert _bound(restored) == _bound(ing)
+    nxt = room.edit(1, _type("on "))
+    handed = []
+    for which in (restored, ing):
+        spy = _Spy(monkeypatch, which)
+        before = _counts()
+        which.apply_bytes([nxt])
+        built = which is restored
+        assert _counted(before) == {
+            "ingest.batch_builds": int(built), "ingest.batch_reuses": int(not built),
+        }
+        bucket, want, _ = spy.parent_planes([nxt])
+        assert bucket == A and _wrong_leaves(spy.merged[-1], want) == []
+        handed.append(spy.merged[-1])
+        monkeypatch.undo()
+    # what the restored one built is what the one that never stopped holds
+    assert _wrong_leaves(handed[0], [np.asarray(a) for a in handed[1]]) == []
+    assert list(restored._batch_cache) == [A]
+    want = room.oracle().get_text("text").get_string()
+    assert get_string(restored.state, 0, restored.payloads) == want
+    assert get_string(ing.state, 0, ing.payloads) == want

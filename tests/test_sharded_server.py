@@ -279,6 +279,42 @@ def test_the_lookup_tables_stay_on_every_device():
     _check_against_oracle(server, FIRST_SHARD + [15], stages, ticks)
 
 
+def test_the_empty_host_lane_batch_stays_laid_out_by_room():
+    """A step without a host-lane room is handed the 27 all-invalid planes
+    an earlier step of its `(n_rows, n_dels)` bucket put on the mesh: every
+    kept leaf split by room as the state's planes are, so a reuse crosses no
+    device inside the jitted calls (`_serve` runs every step under the
+    device-to-device guard)."""
+    batches = ("ingest.batch_builds", "ingest.batch_reuses")
+    stages, ticks = _traffic(28_000_001 + len(EVERY_SHARD), EVERY_SHARD)
+    before = {n: metrics.counter(n).value for n in batches + STEPS}
+    server, mixed = _serve(True, EVERY_SHARD + [15], stages, ticks)
+    took = {n: metrics.counter(n).value - before[n] for n in batches + STEPS}
+    steps = sum(took[n] for n in STEPS)
+    assert steps >= len(stages) + len(ticks) and mixed == 0
+    ing = server.ingestor
+    assert ing.slow_docs == 0
+    # one build a bucket, every other step a reuse
+    assert took["ingest.batch_builds"] == len(ing._batch_cache) >= 1
+    assert took["ingest.batch_reuses"] == steps - took["ingest.batch_builds"] > 0
+    by_room = ing.state.blocks.client.sharding
+    kept_bytes = 0
+    for bucket, batch in ing._batch_cache.items():
+        for i, a in enumerate(batch):  # 23 row planes, then 4 delete planes
+            assert a.shape == (N_ROOMS, bucket[0] if i < 23 else bucket[1])
+            assert len(a.sharding.device_set) == len(jax.devices()) == 8, bucket
+            assert a.sharding.is_equivalent_to(by_room, a.ndim), bucket
+            assert not a.sharding.is_fully_replicated, bucket
+            # 2 rooms a device, in the state's order
+            assert [s.index[0] for s in a.addressable_shards] == [
+                s.index[0] for s in ing.state.blocks.client.addressable_shards
+            ], bucket
+            kept_bytes += a.nbytes
+    state_bytes = sum(a.nbytes for a in jax.tree.leaves(ing.state))
+    assert 0 < kept_bytes <= state_bytes // 16
+    _check_against_oracle(server, EVERY_SHARD + [15], stages, ticks)
+
+
 def test_a_mixed_step_one_room_on_each_lane():
     """One dispatch carries a fast-lane room and a host-lane room: the
     second room's updates arrive out of order, so the first to come waits

@@ -21,6 +21,7 @@ update goes pending must not stall its batch":
 from __future__ import annotations
 
 import weakref
+from collections import OrderedDict
 from itertools import islice
 from typing import Dict, List, Optional, Tuple
 
@@ -225,6 +226,9 @@ class BatchIngestor:
         self._m_table_reuses = metrics.counter("ingest.table_reuses")
         self._m_table_builds = metrics.counter("ingest.table_builds")
         self._m_table_grows = metrics.counter("ingest.table_grows")
+        # whether a step built the host lane's planes (`_kept_batch`)
+        self._m_batch_reuses = metrics.counter("ingest.batch_reuses")
+        self._m_batch_builds = metrics.counter("ingest.batch_builds")
         # writers the tables had not held (`_note_clients`)
         self._m_first_seen = metrics.counter("ingest.clients_first_seen")
         self._m_first_seen_big = metrics.counter(
@@ -257,6 +261,11 @@ class BatchIngestor:
         self._table_cache: Dict[str, tuple] = {}
         # table name -> its entries, padding included, once past the floor
         self._table_width: Dict[str, int] = {}
+        # (n_rows, n_dels) -> the host lane's empty batch on the device(s),
+        # least recently used first (`_kept_batch`)
+        self._batch_cache: "OrderedDict[Tuple[int, int], UpdateBatch]" = (
+            OrderedDict()
+        )
         # the interner as `_note_clients` last saw it: how many clients,
         # and how many of them the raw table holds. What it holds already
         # (a restored checkpoint's writers) is known, not first seen
@@ -313,6 +322,33 @@ class BatchIngestor:
         took.inc()
         phases.add_value(took.name, 1)  # the recorder's: a window's delta
         return dev
+
+    def _kept_batch(self, n_rows: int, n_dels: int) -> Optional[UpdateBatch]:
+        """The host lane's empty batch of this bucket, if a step left it
+        on the device(s): what `batch_planes` pads to when no slot plans a
+        row, a constant of `(n_docs, n_rows, n_dels)`. `merge_stream` and
+        `apply_update_batch` donate no operand, so one upload serves every
+        step without a host-lane room until it is evicted (`_keep_batch`)."""
+        kept = self._batch_cache.get((n_rows, n_dels))
+        if kept is not None:
+            self._batch_cache.move_to_end((n_rows, n_dels))
+        return kept
+
+    def _keep_batch(self, n_rows: int, n_dels: int, batch: UpdateBatch) -> None:
+        """Keep an empty batch a step has just uploaded. The entries hold
+        at most 1/16 of the state's resident bytes between them, least
+        recently used out; one that alone passes that (the bulk loads'
+        wide buckets) is not kept, and its step is today's."""
+        def nbytes(tree) -> int:
+            return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+        room = nbytes(self.state) // 16 - nbytes(batch)
+        if room < 0:
+            return
+        kept = self._batch_cache
+        while nbytes(list(kept.values())) > room:
+            kept.popitem(last=False)
+        kept[(n_rows, n_dels)] = batch
 
     def _note_clients(self) -> None:
         """Count the writers interned since the last look: one count a
@@ -895,30 +931,52 @@ class BatchIngestor:
                 self.fast_docs += len(fast_idx)
                 self.slow_docs += sum(1 for u in slow_updates if u is not None)
 
+                # a step none of whose payloads took the host lane plans no
+                # row (`_plan_doc(d, None)` touches nothing): its batch is
+                # `batch_planes`' padding, kept on the device by bucket
+                host_lane = len(fast_idx) != len(live)
                 with phases.span("ingest.plan.host_rows"):
-                    all_rows, all_dels = [], []
-                    for d, u in enumerate(slow_updates):
-                        rows, dels = self._plan_doc(d, u)
-                        all_rows.append(rows)
-                        all_dels.append(dels)
-                    n_rows = _bucket(
-                        max(max_fast_rows, 1, max(len(r) for r in all_rows))
-                    )
-                    n_dels = _bucket(
-                        max(max_fast_dels, 1, max(len(d_) for d_ in all_dels))
-                    )
-                    planes = self.enc.batch_planes(
-                        all_rows, all_dels, n_rows, n_dels
-                    )
-                with phases.span("ingest.plan.h2d"):
-                    # the host lane's 27 planes, over every slot
-                    batch = UpdateBatch(*self._upload(planes, by_doc=True))
-                    if phases.enabled:
-                        phases.transfer(
-                            "ingest.plan.h2d",
-                            self._uploaded_bytes(planes, by_doc=True),
-                            "h2d",
+                    if host_lane:
+                        batch = None
+                        all_rows, all_dels = [], []
+                        for d, u in enumerate(slow_updates):
+                            rows, dels = self._plan_doc(d, u)
+                            all_rows.append(rows)
+                            all_dels.append(dels)
+                        n_rows = _bucket(
+                            max(max_fast_rows, 1, max(len(r) for r in all_rows))
                         )
+                        n_dels = _bucket(
+                            max(max_fast_dels, 1, max(len(d_) for d_ in all_dels))
+                        )
+                    else:
+                        all_rows = all_dels = [[]] * self.n_docs
+                        n_rows = _bucket(max(max_fast_rows, 1))
+                        n_dels = _bucket(max(max_fast_dels, 1))
+                        batch = self._kept_batch(n_rows, n_dels)
+                    planes = None
+                    if batch is None:
+                        planes = self.enc.batch_planes(
+                            all_rows, all_dels, n_rows, n_dels
+                        )
+                with phases.span("ingest.plan.h2d"):
+                    if batch is None:
+                        # the host lane's 27 planes, over every slot
+                        batch = UpdateBatch(*self._upload(planes, by_doc=True))
+                        if phases.enabled:
+                            phases.transfer(
+                                "ingest.plan.h2d",
+                                self._uploaded_bytes(planes, by_doc=True),
+                                "h2d",
+                            )
+                        if not host_lane:
+                            self._keep_batch(n_rows, n_dels, batch)
+                took = (
+                    self._m_batch_reuses if planes is None
+                    else self._m_batch_builds
+                )
+                took.inc()
+                phases.add_value(took.name, 1)  # the recorder's: a window's delta
             self._m_fast.inc(len(fast_idx))
             self._m_slow.inc(sum(1 for u in slow_updates if u is not None))
             # a slot without a payload plans no row (`_plan_doc`): the step
