@@ -13,8 +13,9 @@ step (`BatchIngestor._active_slots`) on the sharded state.
 
 Below them, the wire logs the sequence-parallel engine's tests were worth
 keeping for (that engine left in PR 31), each served to one room of the same
-16 x 512 family on one device and doc-sharded: maps, XML, several roots, GC
-carriers and the stash on a sharded state, which text rooms never reach.
+family (24 x 512: three rooms a device) on one device and doc-sharded: maps,
+XML, several roots, GC carriers, the stash and a store of nested JSON records
+on a sharded state, which text rooms never reach.
 Then `ytpu/parallel/mesh.py` itself on the 8 devices, and what the server's
 import loads.
 """
@@ -661,6 +662,54 @@ def gc_carriers():
     return [_gcify(a.encode_state_as_update_v1())]
 
 
+def nested_json_records():
+    """A room that is a store of JSON records, the tldraw-over-Yjs shape
+    (`benchmark/generators/record_mix.py`): a `Y.Array` of `{key, val}`
+    entries under `YKeyValue`, `val` an object with objects in it. A loader's
+    records arrive in two stages of one-record blocks (what a relay's
+    `mergeUpdates` makes of a room's history); three writers synced with the
+    loaded store, one with an id past int32 and none seeing another, then set
+    (`remove` + `push_back` in one transaction), add (`push_back`) and delete
+    (`remove`) records. Every update that carries a record is a nested Any
+    and plans on the host; a delete carries none and rides the fast lane."""
+    from ytpu.core.update import merge_updates_v1
+
+    def record(i, **props):
+        key = f"shape:{i:04d}"
+        return {"key": key, "val": {
+            "id": key, "typeName": "shape", "type": "geo", "x": i + 0.13, "y": -i - 0.37, "rotation": 0,
+            "index": f"a{i}", "parentId": "page:page", "isLocked": False, "opacity": 1,
+            "props": {"geo": "rectangle", "w": 100 + i, "h": 80, "color": "black", "text": "", **props},
+            "meta": {},
+        }}
+
+    root = "tl_nested_json_records"
+    loader = Doc(client_id=900_032)
+    loaded = _capture(loader)
+    for i in range(12):
+        with loader.transact() as txn:
+            loader.get_array(root).push_back(txn, record(i))
+    state = loader.encode_state_as_update_v1()
+    logs = []
+    for w, client in enumerate((7, 9, 2**31 + 5)):
+        d = Doc(client_id=client)
+        d.apply_update_v1(state)
+        log, arr = _capture(d), d.get_array(root)
+        with d.transact() as txn:  # a restyle of a record all three set: the rightmost entry wins
+            arr.remove(txn, 3)
+            arr.push_back(txn, record(3, color=("red", "blue", "green")[w]))
+        with d.transact() as txn:  # a new record
+            arr.push_back(txn, record(100 + w))
+        with d.transact() as txn:  # a drag of the writer's own first push, now second to last
+            arr.remove(txn, 11)
+            arr.push_back(txn, record(3, color="grey", text=f"moved by {w}"))
+        with d.transact() as txn:  # a delete of one of the loader's
+            arr.remove(txn, 5 + w)
+        logs.append(log)
+    assert all(len(log) == 4 for log in logs)
+    return [merge_updates_v1(loaded[:7]), merge_updates_v1(loaded[7:])] + [u for step in zip(*logs) for u in step]
+
+
 def _anchored_into_a_gcd_region(insert_at):
     """A stale peer's insert whose anchors were GC'd since."""
     a, b = Doc(client_id=1), Doc(client_id=2)
@@ -688,7 +737,7 @@ def anchored_right_gcd():
     return _anchored_into_a_gcd_region(1)  # origin 'a' live, right origin 'b' GC'd
 
 
-# a room each: 16 logs on the module's 16 rooms. Array moves are not among
+# a room each: 17 logs on a server of 24 rooms. Array moves are not among
 # them: the rooms read right and `device_encode_diff` cannot write a move row
 # the device decoded, on one device as on eight (ROADMAP, Reach A)
 SCENARIOS = [
@@ -708,8 +757,10 @@ SCENARIOS = [
     origin_in_the_middle_of_a_block,
     concurrent_edits_a_then_b,
     concurrent_edits_b_then_a,
+    nested_json_records,
 ]
-assert len(SCENARIOS) == N_ROOMS
+SCENARIO_ROOMS = 24  # a room a log, and a count the 8 devices divide
+assert len(SCENARIOS) <= SCENARIO_ROOMS
 # what the oracle is read by beside state vector and full-state diff: root -> kinds
 READS = {
     text_plus_map: {ROOT: ("text", "map")},
@@ -722,11 +773,14 @@ READS = {
     anchored_both_sides_gcd: {"t": ("text",)},
     anchored_left_gcd: {"t": ("text",)},
     anchored_right_gcd: {"t": ("text",)},
+    nested_json_records: {"tl_nested_json_records": ("array",)},
 }
 # updates the served path plans on the host (`BatchIngestor.slow_docs`): the
 # stashed ones and those that close their gaps. Every other update of every
 # log rides the fast lane, maps and XML too, and no room leaves the device.
-HOST_LANE = {stash_of_text_and_map: 5}
+# Of the record store's 14 updates the three deletes carry no record: the two
+# stages and the nine sets and adds are nested Any values.
+HOST_LANE = {stash_of_text_and_map: 5, nested_json_records: 11}
 # a room's first mention of a secondary root builds the anchor row's mask on
 # the first device (`batch_doc.ensure_root_anchor`) and jax carries it onto
 # the mesh: the one step of these logs the guard would refuse (ROADMAP, Reach A)
@@ -744,7 +798,7 @@ def _scenario_server(shard_docs: bool) -> DeviceSyncServer:
     its clients: a first-seen one grows a lookup table, and every table
     size is a program family of its own."""
     server = DeviceSyncServer(
-        n_docs=N_ROOMS, capacity=CAPACITY, device_authoritative=True, shard_docs=shard_docs
+        n_docs=SCENARIO_ROOMS, capacity=CAPACITY, device_authoritative=True, shard_docs=shard_docs
     )
     clients, names = set(), set()
     for scenario in SCENARIOS:
@@ -769,6 +823,8 @@ def _device_reads(server, room, reads, primary) -> dict:
         for kind in kinds:
             if kind == "text":
                 out[root, kind] = "".join(v for v in branch["seq"] if isinstance(v, str))
+            elif kind == "array":
+                out[root, kind] = branch["seq"]
             else:
                 out[root, kind] = branch["map"]
     return out
@@ -778,6 +834,7 @@ def _oracle_reads(oracle: Doc, reads) -> dict:
     get = {
         "text": lambda root: oracle.get_text(root).get_string(),
         "map": lambda root: oracle.get_map(root).to_json(),
+        "array": lambda root: oracle.get_array(root).to_json(),
     }
     return {(root, kind): get[kind](root) for root, kinds in reads.items() for kind in kinds}
 

@@ -80,6 +80,12 @@ _FAST_KINDS = frozenset((0, 1, 2, 3, 4, 5, 6, 7, 8, 10, 11))
 # kinds whose rows keep content refs into the retained wire bytes
 _WIRE_REF_KINDS = frozenset((2, 3, 4, 5, 6, 7, 8))
 _I32_MAX = 2**31 - 1
+# why the prescan sent a payload to the host lane (`_slow_reason`), one
+# counter each: `ingest.slow.<reason>`
+_SLOW_REASONS = (
+    "pending", "root", "sections", "complex_any", "kind", "key",
+    "dependency", "client",
+)
 
 
 @jax.named_scope("merge_stream")
@@ -234,6 +240,12 @@ class BatchIngestor:
         self._m_first_seen_big = metrics.counter(
             "ingest.clients_first_seen_big"
         )
+        # why a payload took the host lane (`_fast_eligible`), and the
+        # rows the host lane planned for the step (`_plan_doc`)
+        self._m_slow_reason = {
+            r: metrics.counter("ingest.slow." + r) for r in _SLOW_REASONS
+        }
+        self._m_host_rows = metrics.counter("ingest.host_rows")
 
     def _reset_tables(self) -> None:
         """The device lookup tables' sources, empty, and nothing built of
@@ -598,30 +610,54 @@ class BatchIngestor:
     # --- raw-bytes fast lane ---------------------------------------------------
 
     def _fast_eligible(self, doc: int, cols) -> bool:
-        """Can this update's wire bytes go straight to the device?
+        """Can this update's wire bytes go straight to the device? Where
+        they cannot, the first reason found is counted
+        (`ingest.slow.<reason>`, `_slow_reason`): a payload that can
+        counts nothing."""
+        reason = self._slow_reason(doc, cols)
+        if reason is None:
+            return True
+        from ytpu.utils.phases import phases
+
+        took = self._m_slow_reason[reason]
+        took.inc()
+        phases.add_value(took.name, 1)  # the recorder's: a window's delta
+        return False
+
+    def _slow_reason(self, doc: int, cols) -> Optional[str]:
+        """Why this update's wire bytes cannot go straight to the device
+        (one of `_SLOW_REASONS`), or None where they can.
 
         The native columns (C++ `lib0_codec`) are the control plane: they
         prove, before anything ships, that integrating the blocks in wire
         order needs no stash/retry and no host-only feature — so the device
         decode cannot flag and the device integrate cannot miss a
         dependency (the exactness the slow lane gets from
-        `partition_carriers`)."""
+        `partition_carriers`). The reasons: `pending` (the prescan could
+        not read the payload, or the room has a stash), `root` (a root
+        name past the hash window or colliding), `sections` (more
+        sections than the decode budget bounds), `complex_any` (an Any
+        value with an object or array inside), `kind` (ContentDoc, a
+        WeakRef branch, an unknown type, an unreadable move), `key` (a map
+        key past the hash window or colliding), `client` (an id past int32
+        whose hash collides, or a clock past int32), `dependency` (a clock
+        gap, or an origin, parent, move bound or delete not yet covered)."""
         if cols.error or self._pending[doc] or not self._pending_ds[doc].is_empty():
-            return False
+            return "pending"
         # named roots: record primaries, create anchors for the rest; any
         # un-hashable/colliding root name routes the doc to the host lane
         # (anchors created here are needed either way — both lanes
         # integrate on device)
         if not self._register_roots_from_cols(doc, cols):
-            return False
+            return "root"
         # Degenerate-but-legal wire shapes (many client sections holding only
         # covered Skip runs, many empty ds-client sections) are correct on
         # the fast lane only if the decode budget covers them; bound the
         # blow-up so one doc can't balloon the whole step's T.
         if cols.n_client_sections > cols.n_blocks + 16:
-            return False
+            return "sections"
         if cols.n_ds_sections > cols.n_dels + 16:
-            return False
+            return "sections"
         n = cols.n_blocks
         sv = self.svs[doc]
         covered: Dict[int, int] = {}
@@ -629,20 +665,26 @@ class BatchIngestor:
         def cov(c: int) -> int:
             return covered.get(c, sv.get(c))
 
+        def uncovered(client: int, clock: int) -> Optional[str]:
+            """Why the id cannot be named on the device yet, if it cannot."""
+            if not self._client_ok(client):
+                return "client"
+            return "dependency" if clock >= cov(client) else None
+
         if cols.n_complex_any > 0:
-            return False  # recursive Any values: host lane
+            return "complex_any"  # recursive Any values: host lane
         from ytpu.ops.decode_kernel import KEY_HASH_BYTES
 
         for i in range(n):
             kind = int(cols.kind[i])
             if kind not in _FAST_KINDS:
-                return False
+                return "kind"
             if kind == 7:
                 # ContentType rides the wire lane except WeakRef branches
                 # (host-resolved link sources) and unknown TypeRef tags
                 span = cols.content_bytes(i)
                 if not span or span[0] >= 7:
-                    return False
+                    return "kind"
             if kind == 11:
                 # ContentMove: the range-bound ids must already be covered
                 # (the claim walk resolves them by id; an unresolved bound
@@ -658,49 +700,52 @@ class BatchIngestor:
                             (cur.read_var_uint(), cur.read_var_uint())
                         )
                 except EncodingError:
-                    return False  # truncated span: host lane decides
+                    return "kind"  # truncated span: host lane decides
                 for bc, bk in bounds:
-                    if not self._client_ok(bc) or bk >= cov(bc):
-                        return False
+                    why = uncovered(bc, bk)
+                    if why:
+                        return why
             psl = int(cols.parent_sub_len[i])
             if psl > KEY_HASH_BYTES:
-                return False  # key exceeds the device hash window
+                return "key"  # key exceeds the device hash window
             if psl >= 0:
                 key = cols.parent_sub(i)
                 if not self._register_key(key):
-                    return False  # hash collision: host lane
+                    return "key"  # hash collision: host lane
             if int(cols.parent_kind[i]) == 2:
                 # nested-branch parent: the ContentType item must already
                 # be covered (the device resolves it by id)
-                pic, pik = int(cols.parent_id_client[i]), int(
-                    cols.parent_id_clock[i]
+                why = uncovered(
+                    int(cols.parent_id_client[i]), int(cols.parent_id_clock[i])
                 )
-                if not self._client_ok(pic) or pik >= cov(pic):
-                    return False
+                if why:
+                    return why
             c = int(cols.client[i])
             ck = int(cols.clock[i])
             ln = int(cols.length[i])
             if not self._client_ok(c) or ck + ln > _I32_MAX:
-                return False
+                return "client"
             if ck > cov(c):
-                return False  # clock gap → pending semantics needed
+                return "dependency"  # clock gap → pending semantics needed
             if kind != 10:  # Skip advances no state
                 ok = int(cols.origin_clock[i])
                 if ok >= 0:
-                    oc = int(cols.origin_client[i])
-                    if not self._client_ok(oc) or ok >= cov(oc):
-                        return False
+                    why = uncovered(int(cols.origin_client[i]), ok)
+                    if why:
+                        return why
                 rk = int(cols.ror_clock[i])
                 if rk >= 0:
-                    rc = int(cols.ror_client[i])
-                    if not self._client_ok(rc) or rk >= cov(rc):
-                        return False
+                    why = uncovered(int(cols.ror_client[i]), rk)
+                    if why:
+                        return why
                 covered[c] = max(cov(c), ck + ln)
         for i in range(cols.n_dels):
             c = int(cols.del_client[i])
-            if not self._client_ok(c) or int(cols.del_end[i]) > cov(c):
-                return False
-        return True
+            if not self._client_ok(c):
+                return "client"
+            if int(cols.del_end[i]) > cov(c):
+                return "dependency"
+        return None
 
     def _client_ok(self, client: int) -> bool:
         """Small ids ride raw; ids beyond i32 (real Yjs clients) must
@@ -851,9 +896,9 @@ class BatchIngestor:
         `apply_update_batch` dispatch, so mixed batches cost one step.
 
         Host stages (docs/observability.md, "Inside a dispatch"):
-        `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan`, `.host_rows`,
-        `.h2d`), `ingest.merge` (`_merge_fast_lane`: the uploads, then one
-        enqueue each under `.gather`, `decode.v1` and `.scatter`),
+        `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan` (⊃ `.decode_host`, one
+        a host-lane payload), `.host_rows`, `.h2d`), `ingest.merge` (the
+        uploads, then one enqueue each under `.gather`, `decode.v1`, `.scatter`),
         `ingest.rank_table`, `integrate.xla_batch`, `ingest.flags`,
         `ingest.recover`.
         """
@@ -890,7 +935,8 @@ class BatchIngestor:
                         live.append(d)
                         cols = decode_update_columns(p) if native else None
                         if cols is None or not self._fast_eligible(d, cols):
-                            slow_updates[d] = Update.decode_v1(p)
+                            with phases.span("ingest.plan.decode_host"):
+                                slow_updates[d] = Update.decode_v1(p)
                             continue
                         fast_idx.append(d)
                         fast_payloads.append(p)
@@ -949,6 +995,9 @@ class BatchIngestor:
                         n_dels = _bucket(
                             max(max_fast_dels, 1, max(len(d_) for d_ in all_dels))
                         )
+                        host_rows = sum(len(all_rows[d]) for d in live)
+                        self._m_host_rows.inc(host_rows)
+                        phases.add_value(self._m_host_rows.name, host_rows)
                     else:
                         all_rows = all_dels = [[]] * self.n_docs
                         n_rows = _bucket(max(max_fast_rows, 1))
