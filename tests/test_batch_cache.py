@@ -1,9 +1,12 @@
 """The host lane's empty batch stays on the device (ISSUE-37).
 
 A step none of whose payloads takes the host lane plans no row: its 27
-host planes are `batch_planes`' padding, a constant of `(n_docs, n_rows,
-n_dels)`, so `apply_bytes` hands `merge_stream` the device arrays an earlier
-step of the bucket uploaded. What changes is when the planes are built and
+planes are `batch_packed`'s padding, a constant of `(width, n_rows, n_dels)`
+(the width is `n_docs` here: four rooms make every step dense; the compact
+width is `tests/test_step_width_batch.py`'s), so `apply_bytes` hands
+`merge_stream` the device arrays an earlier step of the bucket uploaded:
+since PR 40 the two arrays of a `PackedBatch`, which `merge_stream` takes
+apart (`unpack_batch`; `_wrong_leaves` does the same to compare). What changes is when the planes are built and
 uploaded, never what a program receives: every step's batch is compared,
 leaf for leaf and bit for bit, with what the step used to build
 (`_parent_planes` below is that code), over a served sequence in which a
@@ -18,7 +21,7 @@ import pytest
 from test_table_cache import _Room, _cut, _replayed, _type
 from ytpu.core import Doc
 from ytpu.models import ingest as ingest_mod
-from ytpu.models.batch_doc import UpdateBatch, get_string
+from ytpu.models.batch_doc import UpdateBatch, unpack_batch_jit, get_string
 from ytpu.models.ingest import BatchIngestor
 from ytpu.native import decode_update_columns
 from ytpu.ops import decode_kernel as dk
@@ -42,8 +45,9 @@ def _bucket(n, lo=4):
 
 
 def _parent_planes(all_rows, all_dels, n_rows, n_dels):
-    """`BatchEncoder.batch_planes` as the parent had it: the planes' one
-    source of truth then, and the padding's now."""
+    """`BatchEncoder.batch_planes` as the parent (of PR 40) had it: 27 host
+    planes over every slot, the planes' one source of truth then; what
+    `batch_packed` and `unpack_batch` must still hand the programs."""
     D = len(all_rows)
     rows = np.zeros((D, n_rows, 22), dtype=np.int32)
     for col in (10, 12, 14, 15, 18, 21):
@@ -65,8 +69,9 @@ def _parent_planes(all_rows, all_dels, n_rows, n_dels):
     )
 
 
-def _entry_bytes(n_rows, n_dels, n_docs=N_DOCS):
-    return sum(a.nbytes for a in _parent_planes([[]] * n_docs, [[]] * n_docs, n_rows, n_dels))
+def _entry_bytes(n_docs, n_rows, n_dels):
+    """A kept batch: `[n_docs, n_rows, 23]` and `[n_docs, n_dels, 4]`, int32."""
+    return 4 * n_docs * (23 * n_rows + 4 * n_dels)
 
 
 def _bound(ing):
@@ -74,7 +79,9 @@ def _bound(ing):
 
 
 def _wrong_leaves(handed, want):
-    """Indices of the leaves of `handed` (device) that are not `want`'s (host), bit for bit."""
+    """Indices of the planes of `handed` (device; a `PackedBatch` taken apart
+    as the programs take it apart) that are not `want`'s (host), bit for bit."""
+    handed = unpack_batch_jit(handed)
     assert len(handed) == len(want) == N_PLANES
     return [
         i for i, (h, w) in enumerate(zip(handed, want))
@@ -92,7 +99,10 @@ class _Spy:
     def __init__(self, monkeypatch, ing, flag_decode=None):
         self.ing = ing
         self.merged = []  # per `merge_stream` call: the batch it was handed
+        self.merge_args = []  # and the rest: (stream, idx, prefix, base), its keywords
         self.applied = []  # per `apply_update_batch` call: the batch it was handed
+        self.active = []  # and its `active` (None: the dense step)
+        self.plan_calls = []  # this step: the slots `_plan_doc` was called for
         self.planned = {}  # this step: slot -> (rows, dels) its host lane planned
         self.decodes = 0
         self.recovering = False
@@ -101,14 +111,18 @@ class _Spy:
 
         def merge(batch, *a, **kw):
             self.merged.append(batch)
+            self.merge_args.append((a, kw))
             return real_merge(batch, *a, **kw)
 
         def apply(state, batch, *rest):
             self.applied.append(batch)
+            self.active.append(rest[1] if len(rest) > 1 else None)
             return real_apply(state, batch, *rest)
 
         def plan(doc, incoming):
             got = real_plan(doc, incoming)
+            if not self.recovering:
+                self.plan_calls.append(doc)
             if incoming is not None and not self.recovering:
                 self.planned[doc] = got
             return got
@@ -154,7 +168,8 @@ class _Spy:
         all_dels = [planned.get(d, ([], []))[1] for d in range(len(payloads))]
         n_rows = _bucket(max(fast_rows, 1, max(len(r) for r in all_rows)))
         n_dels = _bucket(max(fast_dels, 1, max(len(d) for d in all_dels)))
-        return (n_rows, n_dels), _parent_planes(all_rows, all_dels, n_rows, n_dels), sorted(planned)
+        bucket = (len(payloads), n_rows, n_dels)
+        return bucket, _parent_planes(all_rows, all_dels, n_rows, n_dels), sorted(planned)
 
 
 def _counts() -> dict:
@@ -202,7 +217,7 @@ ROOM0 = {
 N_STEPS = 18
 FLAGGED_STEP = 15
 # step -> (the bucket, built or reused, the buckets kept after it, least recently used first)
-A, B, C, BIG = (4, 4), (8, 4), (4, 8), (32, 4)
+A, B, C, BIG = ((N_DOCS,) + k for k in ((4, 4), (8, 4), (4, 8), (32, 4)))
 WANT = {
     0: (A, "build", [A]),
     1: (A, "reuse", [A]),
@@ -282,7 +297,7 @@ def test_every_step_hands_merge_stream_the_parents_batch(served, step):
     assert _wrong_leaves(s["merged"][0], s["want"]) == []
     # the host lane planned where the sequence says, and had rows to carry once
     assert s["host_lane"] == ([0] if step in (3, 4) else [])
-    assert bool(np.asarray(s["merged"][0].valid).any()) == (step == 4)
+    assert bool(np.asarray(unpack_batch_jit(s["merged"][0]).valid).any()) == (step == 4)
 
 
 def test_a_reuse_hands_over_the_arrays_of_the_last_build(served):
@@ -320,7 +335,7 @@ def test_the_kept_bytes_never_pass_the_bound(served):
         assert kept_bytes == sum(_entry_bytes(*k) for k in s["kept"]) <= bound, step
         for bucket, batch in s["kept_arrays"].items():
             empty = [[]] * N_DOCS
-            assert _wrong_leaves(batch, _parent_planes(empty, empty, *bucket)) == [], (step, bucket)
+            assert _wrong_leaves(batch, _parent_planes(empty, empty, *bucket[1:])) == [], (step, bucket)
     assert _entry_bytes(*BIG) > bound  # and the one that was never kept could not be
 
 
@@ -334,7 +349,7 @@ def test_the_flagged_lanes_recover_through_a_batch_of_their_own(served):
     assert [st["recovered"] for st in steps].count(0) == N_STEPS - 1
     assert len(s["applied"]) == 2  # the step's own integrate call, then the recovery's
     recovery = s["applied"][1]
-    assert np.asarray(recovery.valid).any(axis=1).tolist() == [True, True, False, False]
+    assert np.asarray(unpack_batch_jit(recovery).valid).any(axis=1).tolist() == [True, True, False, False]
     kept = [a for batch in s["kept_arrays"].values() for a in batch]
     assert all(leaf is not k for leaf in recovery for k in kept)
     assert sum(s["counted"].values()) == 1
@@ -377,6 +392,7 @@ def test_apply_builds_its_batch_and_keeps_nothing(monkeypatch):
     before, counts no step of `apply_bytes` and leaves the kept batches alone."""
     room = _Room()
     ing = BatchIngestor(n_docs=1, capacity=CAPACITY)
+    A = (1, 4, 4)
     spy = _Spy(monkeypatch, ing)
     ing.apply_bytes([room.edit(1, _type("one "))])
     kept = dict(ing._batch_cache)
@@ -385,7 +401,7 @@ def test_apply_builds_its_batch_and_keeps_nothing(monkeypatch):
     assert _counted(before) == {"ingest.batch_builds": 0, "ingest.batch_reuses": 0}
     assert list(ing._batch_cache) == [A] and ing._batch_cache[A] is kept[A]
     assert all(leaf is not k for leaf in spy.applied[-1] for k in kept[A])
-    assert np.asarray(spy.applied[-1].valid).any()
+    assert np.asarray(unpack_batch_jit(spy.applied[-1]).valid).any()
     ing.apply_bytes([room.edit(1, _type("three "))])
     assert all(h is k for h, k in zip(spy.merged[-1], kept[A]))
     assert get_string(ing.state, 0, ing.payloads) == room.oracle().get_text("text").get_string()
@@ -401,7 +417,8 @@ def test_a_step_without_a_payload_takes_the_kept_batch(monkeypatch):
     before = _counts()
     ing.apply_bytes([None, None])
     assert _counted(before) == {"ingest.batch_builds": 0, "ingest.batch_reuses": 1}
-    assert all(h is k for h, k in zip(spy.applied[-1], spy.merged[-1]))
+    # the kept pair, taken apart by the one small program: no merge ran to do it
+    assert _wrong_leaves(spy.applied[-1], [np.asarray(a) for a in unpack_batch_jit(spy.merged[-1])]) == []
     assert get_string(ing.state, 0, ing.payloads) == "one "
 
 
@@ -412,6 +429,7 @@ def test_a_restored_ingestor_builds_at_its_first_step(monkeypatch, tmp_path):
     ing = BatchIngestor(n_docs=1, capacity=CAPACITY)
     for fn in (_type("saved "), _many(5), _cut(1, 2)):
         ing.apply_bytes([room.edit(1, fn)])
+    A, B = (1, 4, 4), (1, 8, 4)
     assert list(ing._batch_cache) == [B, A]
     path = str(tmp_path / "ckpt")
     save_ingestor(path, ing)
@@ -433,7 +451,7 @@ def test_a_restored_ingestor_builds_at_its_first_step(monkeypatch, tmp_path):
         handed.append(spy.merged[-1])
         monkeypatch.undo()
     # what the restored one built is what the one that never stopped holds
-    assert _wrong_leaves(handed[0], [np.asarray(a) for a in handed[1]]) == []
+    assert _wrong_leaves(handed[0], [np.asarray(a) for a in unpack_batch_jit(handed[1])]) == []
     assert list(restored._batch_cache) == [A]
     want = room.oracle().get_text("text").get_string()
     assert get_string(restored.state, 0, restored.payloads) == want

@@ -134,13 +134,15 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
 
 
 def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
-    """`yws-rooms-4k-x4`: 4,096 rooms over four chips, 1,024 a chip. A
-    doc-sharded ingestor uploads a step's inputs onto the mesh
-    (`BatchIngestor._upload`): the host lane's planes by room, the decoded
-    stream and the rank table whole on every chip. `merge_stream` must
-    then scatter into the planes where they lie (no collective, output by
-    room), and the step must take that output as it is, gather no state
-    plane and leave every plane of the state where it was."""
+    """`yws-rooms-4k-x4`, the dense step (the prefill's all-room
+    dispatches): 4,096 rooms over four chips, 1,024 a chip. A doc-sharded
+    ingestor uploads a step's inputs onto the mesh (`BatchIngestor._upload`):
+    the host lane's `PackedBatch` by room, the decoded stream and the
+    rank table whole on every chip. `merge_stream` must take the planes
+    apart where they lie and scatter into them there (no collective, 27
+    planes out, by room), and the step must take that output as it is,
+    gather no state plane and leave every plane of the state where it
+    was."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
@@ -160,8 +162,9 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     by_room, whole = on(P(AXIS_BATCH)), on(P())
     host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
     batch = BatchEncoder().batch_from_rows([[]] * rooms, [[]] * rooms, rows, rows)
+    packed = BatchEncoder().batch_packed([[]] * rooms, [[]] * rooms, rows, rows)
     merge = _merge_stream_jit.lower(
-        jax.tree.map(by_room, batch),
+        jax.tree.map(by_room, packed),
         jax.tree.map(whole, jax.tree.map(lambda a: a[:lanes], batch)),
         host(lanes),
         host(lanes),
@@ -170,6 +173,7 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     ).compile()
     assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
     assert {s.spec for s in jax.tree.leaves(merge.output_shardings)} == {P(AXIS_BATCH)}
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(merge.out_info)] == [(a.shape, a.dtype) for a in batch]
 
     merged = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
@@ -205,10 +209,11 @@ def test_compact_integrate_step_needs_a_fraction_of_the_dense_steps_memory(one_c
     )
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, 4, 4)
-    args = (_state(one_chip), _shapes(batch, one_chip), i32(N_CLIENTS), scan_tier_plan())
-    dense = _apply_update_batch_jit.lower(*args).compile().memory_analysis()
-    compact = _apply_update_batch_jit.lower(*args, i32(COMPACT_WIDTH)).compile()
+    # the batch is as wide as the step: every slot, or the tick's 16
+    batch = {w: _shapes(BatchEncoder().batch_from_rows([[]] * w, [[]] * w, 4, 4), one_chip) for w in (N_DOCS, COMPACT_WIDTH)}
+    rest = (i32(N_CLIENTS), scan_tier_plan())
+    dense = _apply_update_batch_jit.lower(_state(one_chip), batch[N_DOCS], *rest).compile().memory_analysis()
+    compact = _apply_update_batch_jit.lower(_state(one_chip), batch[COMPACT_WIDTH], *rest, i32(COMPACT_WIDTH)).compile()
     m = compact.memory_analysis()
     print(f"temp bytes: dense step {dense.temp_size_in_bytes}, compact step {m.temp_size_in_bytes}")
     assert m.alias_size_in_bytes == 0, m  # the roofline counts a state read once, written once
@@ -218,9 +223,12 @@ def test_compact_integrate_step_needs_a_fraction_of_the_dense_steps_memory(one_c
 
 
 def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
-    """`yws-rooms-4k-x4`: 4,096 rooms by room over four chips, the batch as
-    `merge_stream` leaves it (by room), the rank table whole on every chip,
-    `active` a numpy array the call takes up. The partitioner must answer the gather with each chip's own
+    """`yws-rooms-4k-x4`, a tick: 4,096 rooms by room over four chips, the
+    host lane's `[16, ...]` `PackedBatch` whole on every chip (`_upload`),
+    `merge_stream` over it and the decoded lanes (nothing of it is laid by
+    room, so the program may hold no collective, and its 27 planes come
+    out whole on every chip), the rank table whole on every chip, `active` a numpy array the call takes
+    up. The partitioner must answer the state's gather with each chip's own
     rooms and a sum of the `[16, ...]` pieces, never with a gathered plane,
     and scatter into the planes where they lie."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -231,18 +239,32 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
         init_state,
         scan_tier_plan,
     )
+    from ytpu.models.ingest import _merge_stream_jit
     from ytpu.parallel.mesh import AXIS_BATCH
 
-    rooms = 4 * N_DOCS
+    rooms, lanes = 4 * N_DOCS, 8
     mesh = Mesh(np.array(topo.devices), (AXIS_BATCH,))
     on = lambda spec: lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
     )
     by_room, whole = on(P(AXIS_BATCH)), on(P())
-    batch = BatchEncoder().batch_from_rows([[]] * rooms, [[]] * rooms, 4, 4)
+    host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
+    empty = [[]] * COMPACT_WIDTH
+    batch = BatchEncoder().batch_from_rows(empty, empty, 4, 4)
+    merge = _merge_stream_jit.lower(
+        jax.tree.map(whole, BatchEncoder().batch_packed(empty, empty, 4, 4)),
+        jax.tree.map(whole, jax.tree.map(lambda a: a[:lanes], batch)),
+        host(lanes),
+        host(lanes),
+        host(),
+        width=64,
+    ).compile()
+    assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
+    assert {s.spec for s in jax.tree.leaves(merge.output_shardings)} == {P()}
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(merge.out_info)] == [(a.shape, a.dtype) for a in batch]
     step = _apply_update_batch_jit.lower(
         jax.tree.map(by_room, jax.eval_shape(lambda: init_state(rooms, CAPACITY))),
-        jax.tree.map(by_room, batch),
+        jax.tree.map(whole, batch),
         whole(jnp.zeros((2 * N_CLIENTS,), jnp.int32)),
         scan_tier_plan(),
         jax.ShapeDtypeStruct((COMPACT_WIDTH,), jnp.int32),  # numpy: jit places it
@@ -286,25 +308,27 @@ def test_served_decode_compiles(one_chip, lanes, max_sections):
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
 
 
-# (lanes, rows = deletes bucket, lane width): the decode cases' lane counts
-# at the 4-row bucket, and the benchmark's prefill step — every slot a lane,
-# the 512-row bucket, 6.6 KB of wire a lane
-MERGE_SHAPES = [(1, 4, 64), (8, 4, 64), (N_DOCS, 512, 8192)]
+# (lanes, rows = deletes bucket, lane width, the step's width): the decode
+# cases' lane counts at the 4-row bucket, in a tick's 16-wide step, and the
+# benchmark's prefill step — every slot a lane, the 512-row bucket, 6.6 KB
+# of wire a lane, the dense step
+MERGE_SHAPES = [(1, 4, 64, 16), (8, 4, 64, 16), (N_DOCS, 512, 8192, N_DOCS)]
 
 
-@pytest.mark.parametrize("lanes,rows,width", MERGE_SHAPES)
-def test_served_merge_compiles(one_chip, lanes, rows, width):
-    """`merge_stream` (rebase + every plane's scatter, one program) as
-    `_merge_fast_lane` calls it: the host lane's batch over all slots, the
-    decoded stream over `lanes` of them."""
+@pytest.mark.parametrize("lanes,rows,width,slots", MERGE_SHAPES)
+def test_served_merge_compiles(one_chip, lanes, rows, width, slots):
+    """`merge_stream` (unpack + rebase + every plane's scatter, one
+    program) as `_merge_fast_lane` calls it: the host lane's `PackedBatch`
+    over the step's `slots`, the decoded stream over `lanes` of them."""
     from ytpu.models.batch_doc import BatchEncoder
     from ytpu.models.ingest import _merge_stream_jit
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, rows, rows)
+    batch = BatchEncoder().batch_from_rows([[]] * slots, [[]] * slots, rows, rows)
     stream = jax.tree.map(lambda a: a[:lanes], batch)
+    packed = BatchEncoder().batch_packed([[]] * slots, [[]] * slots, rows, rows)
     compiled = _merge_stream_jit.lower(
-        _shapes(batch, one_chip),
+        _shapes(packed, one_chip),
         _shapes(stream, one_chip),
         i32(lanes),
         i32(lanes),
@@ -312,13 +336,33 @@ def test_served_merge_compiles(one_chip, lanes, rows, width):
         width=width,
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
-    # one output per plane of the batch, each over all slots
+    # one output per plane of the batch, each over the step's slots
     assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [
         a.shape for a in jax.tree.leaves(batch)
     ]
 
 
-@pytest.mark.parametrize("lanes,width", [(s, w) for s, _, w in MERGE_SHAPES])
+@pytest.mark.parametrize("slots,rows", [(16, 4), (16, 512), (N_DOCS, 4)], ids=["tick", "load", "every_slot"])
+def test_served_unpack_compiles(one_chip, slots, rows):
+    """`unpack_batch` as a program of its own, for a step none of whose
+    rooms rode the fast lane: a tick's 16-wide batch at the 4-row bucket,
+    the record cell's load (16 rooms, the 512-row bucket), and every slot
+    (`apply()`, a dense recovery). 27 planes out, and no device memory
+    but the arrays in and out."""
+    from ytpu.models.batch_doc import BatchEncoder, UpdateBatch, unpack_batch_jit
+
+    packed = BatchEncoder().batch_packed([[]] * slots, [[]] * slots, rows, rows)
+    compiled = unpack_batch_jit.lower(_shapes(packed, one_chip)).compile()
+    out = jax.tree.leaves(compiled.out_info)
+    assert len(out) == len(UpdateBatch._fields) == 27
+    assert {o.shape for o in out} == {(slots, rows)}
+    assert [o.dtype == jnp.bool_ for o in out] == [i in (22, 26) for i in range(27)]
+    m = compiled.memory_analysis()
+    # what goes in, what comes out, nothing between; the chip pads the tick's small planes
+    assert m.temp_size_in_bytes == 0 and _hbm_bytes(compiled) < max(3 * sum(a.nbytes for a in packed), 1 << 17), m
+
+
+@pytest.mark.parametrize("lanes,width", [(s, w) for s, _, w, _ in MERGE_SHAPES])
 def test_served_gather_compiles(one_chip, lanes, width):
     """`gather_raw_lanes` through the merge's jit: a bucketed wire arena
     and its offsets table in, the padded [S, L] lane matrix out."""

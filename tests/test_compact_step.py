@@ -6,9 +6,10 @@ all-room dispatch: dense, 128 > 128 // 4). Then two steps in which only the
 rooms of one active set carry an update: inserts, deletes and, in the same
 step, one room on the host lane (its updates arrive out of order, so the
 first waits in the stash and the second plans on the host). Every integrate
-call of those steps runs twice, with `active` and without: every plane must
-come out the same bit for bit, and a room outside `active` keeps the planes
-it came in with.
+call of those steps runs twice: with `active` and the `[K, ...]` batch the
+step was handed, and dense, over that batch laid out over every slot (row i
+at slot `active[i]`, padding elsewhere): every plane must come out the same
+bit for bit, and a room outside `active` keeps the planes it came in with.
 """
 
 import random
@@ -20,6 +21,8 @@ import pytest
 from ytpu.core import Doc
 from ytpu.models import ingest
 from ytpu.models.batch_doc import (
+    BatchEncoder,
+    unpack_batch_jit,
     apply_update_batch,
     encode_diff_batch,
     finish_encode_diff,
@@ -78,17 +81,23 @@ def both_steps(monkeypatch):
     widths = []
 
     def checked(state, batch, client_rank, active=None):
-        dense = apply_update_batch(state, batch, client_rank)
         widths.append(None if active is None else int(active.shape[0]))
         if active is None:
-            return dense
+            return apply_update_batch(state, batch, client_rank)
         slots = np.asarray(active)
         assert len(set(slots.tolist())) == len(slots), slots
         assert slots.min() >= 0 and slots.max() < N_DOCS, slots
         idle = np.setdiff1d(np.arange(N_DOCS), slots)
-        # the caller's promise: a slot outside `active` has no valid row
-        assert not np.asarray(batch.valid)[idle].any()
-        assert not np.asarray(batch.del_valid)[idle].any()
+        # the batch is as wide as the step (the host lane's packed pair, or
+        # the merge's planes); over every slot it is padding outside `active`
+        batch = unpack_batch_jit(batch)
+        assert {a.shape[0] for a in batch} == {len(slots)}
+        empty = [[]] * N_DOCS
+        padding = BatchEncoder().batch_from_rows(
+            empty, empty, batch.client.shape[1], batch.del_client.shape[1]
+        )
+        over_all = jax.tree.map(lambda pad, sub: pad.at[slots].set(sub), padding, batch)
+        dense = apply_update_batch(state, over_all, client_rank)
         compact = apply_update_batch(state, batch, client_rank, active)
         for was, got, want in zip(_planes(state), _planes(compact), _planes(dense)):
             assert got.dtype == want.dtype and np.array_equal(got, want)

@@ -650,6 +650,12 @@ def test_merge_stream_equals_numpy_reference(monkeypatch, case, ingest_mode):
     lanes = sorted(MERGE_CASES[case])  # the fast lanes' slots, in slot order
     assert ing.fast_docs - fast_before == len(lanes)
     ((batch, stream, idx, prefix, base), kw, merged), = calls
+    # the host lane's batch is handed over as the two arrays the host
+    # shipped; the program takes its planes apart, as here
+    from ytpu.models.batch_doc import PackedBatch, unpack_batch_jit
+
+    assert isinstance(batch, PackedBatch)
+    batch = unpack_batch_jit(batch)
     # what the step's payloads say the operands are
     keep = [MERGE_CASES[case][d] != DEL for d in lanes]
     kept_lens = [len(plan[d]) if k else 0 for d, k in zip(lanes, keep)]

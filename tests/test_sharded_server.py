@@ -281,9 +281,10 @@ def test_the_lookup_tables_stay_on_every_device():
 
 
 def test_the_empty_host_lane_batch_stays_laid_out_by_room():
-    """A step without a host-lane room is handed the 27 all-invalid planes
-    an earlier step of its `(n_rows, n_dels)` bucket put on the mesh: every
-    kept leaf split by room as the state's planes are, so a reuse crosses no
+    """A step without a host-lane room is handed the all-padding batch
+    an earlier step of its `(width, n_rows, n_dels)` bucket put on the mesh
+    (the two arrays of a `PackedBatch`, laid out by room): every kept
+    leaf split by room as the state's planes are, so a reuse crosses no
     device inside the jitted calls (`_serve` runs every step under the
     device-to-device guard)."""
     batches = ("ingest.batch_builds", "ingest.batch_reuses")
@@ -301,8 +302,9 @@ def test_the_empty_host_lane_batch_stays_laid_out_by_room():
     by_room = ing.state.blocks.client.sharding
     kept_bytes = 0
     for bucket, batch in ing._batch_cache.items():
-        for i, a in enumerate(batch):  # 23 row planes, then 4 delete planes
-            assert a.shape == (N_ROOMS, bucket[0] if i < 23 else bucket[1])
+        for a, (entries, columns) in zip(batch, ((bucket[1], 23), (bucket[2], 4))):  # rows, then deletes
+            assert bucket[0] == N_ROOMS  # 16 rooms: every step is dense, as wide as the slots
+            assert a.shape == (N_ROOMS, entries, columns)
             assert len(a.sharding.device_set) == len(jax.devices()) == 8, bucket
             assert a.sharding.is_equivalent_to(by_room, a.ndim), bucket
             assert not a.sharding.is_fully_replicated, bucket

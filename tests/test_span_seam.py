@@ -120,13 +120,14 @@ def test_wire_bytes_are_counted_once(served):
 
 
 def test_a_reuse_step_counts_no_bytes_under_plan_h2d(served):
-    """The host lane's planes go up in the step that builds them: the two
-    steps after it are handed those arrays, span `ingest.plan.h2d` around a
-    look-up and count no byte there."""
+    """The host lane's batch goes up in the step that builds it, as the two
+    arrays of a `PackedBatch` (the programs take the planes apart): the two
+    steps after it are handed those arrays, span `ingest.plan.h2d` around
+    nothing and count no byte there."""
     snap, steps = served
     assert (snap["ingest.batch_builds"]["value"], snap["ingest.batch_reuses"]["value"]) == (1, steps - 1)
-    # one build of 2 rooms x the (4, 4) bucket: 22 i32 + 1 bool row planes, 3 i32 + 1 bool delete planes
-    assert snap["ingest.plan.h2d"]["h2d_bytes"] == 2 * (4 * (22 * 4 + 1) + 4 * (3 * 4 + 1))
+    # one build of 2 rooms x the (4, 4) bucket: [2, 4, 23] and [2, 4, 4], both i32
+    assert snap["ingest.plan.h2d"]["h2d_bytes"] == 2 * 4 * (4 * 23 + 4 * 4)
     assert snap["ingest.plan.h2d"]["calls"] == snap["ingest.plan.host_rows"]["calls"] == steps
 
 

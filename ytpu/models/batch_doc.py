@@ -59,6 +59,9 @@ __all__ = [
     "BlockCols",
     "DocStateBatch",
     "UpdateBatch",
+    "PackedBatch",
+    "unpack_batch",
+    "unpack_batch_jit",
     "init_state",
     "CompactionPolicy",
     "DEFAULT_COMPACTION_POLICY",
@@ -171,6 +174,45 @@ class UpdateBatch(NamedTuple):
     del_start: jax.Array  # [*, R] i32
     del_end: jax.Array  # [*, R] i32
     del_valid: jax.Array  # [*, R] bool
+
+
+# one padding entry of `BatchEncoder.batch_packed`'s `rows`: the 22 row
+# fields in `UpdateBatch`'s order, then `valid`. A padded key must read as
+# "sequence row", a padded p_root as the primary root
+_PAD_ROW = np.zeros(23, dtype=np.int32)
+_PAD_ROW[[10, 12, 14, 15, 18, 21]] = -1  # key, p_client, p_root, mv_sc, mv_ec, mv_prio
+
+
+class PackedBatch(NamedTuple):
+    """An `UpdateBatch` as the host builds and ships it
+    (`BatchEncoder.batch_packed`): two contiguous int32 arrays, so two
+    uploads, each field a column and each array's last column its
+    `valid` plane (1 a real entry, 0 padding). `unpack_batch` takes it
+    apart where it lands."""
+
+    rows: jax.Array  # [*, U, 23] client .. mv_prio, valid
+    dels: jax.Array  # [*, R, 4] del_client, del_start, del_end, del_valid
+
+
+def unpack_batch(batch) -> UpdateBatch:
+    """The 27 planes of a `PackedBatch`, sliced out on the device(s) its
+    arrays are on and laid out as they are: inside the program that is
+    handed it (`ingest.merge_stream`: no enqueue of its own), or as the
+    small program `unpack_batch_jit` where no such program runs. An
+    `UpdateBatch` passes through."""
+    if isinstance(batch, UpdateBatch):
+        return batch
+    rows, dels = batch
+    return UpdateBatch(
+        *(rows[..., i] for i in range(22)),  # client .. mv_prio
+        rows[..., 22] != 0,
+        *(dels[..., i] for i in range(3)),  # del_client, _start, _end
+        dels[..., 3] != 0,
+    )
+
+
+# for a caller that has no program to take the planes apart in
+unpack_batch_jit = jax.jit(unpack_batch)
 
 
 ERR_CAPACITY = 1
@@ -1501,11 +1543,12 @@ def apply_update_batch(
     compact: under `vmap` the per-row `lax.cond` runs both branches for
     every slot, so a step costs its width in rooms whether or not a room
     carries a row. With `active` the step gathers those K rooms' planes
-    and batch rows, integrates `[K, ...]`, and scatters the rooms back;
+    of the state, integrates `[K, ...]`, and scatters the rooms back;
     every other room's planes are carried over untouched, which is what
-    the dense step's identity on an all-invalid slot gives. The caller
-    vouches that every slot outside `active` has no valid row. One
-    program either way, and the state is not donated: every plane is
+    the dense step's identity on an all-invalid slot gives. `batch` is
+    then `[K, ...]` already, row i the update of slot `active[i]`: the
+    caller builds it no wider than the step (`BatchIngestor.apply_bytes`).
+    One program either way, and the state is not donated: every plane is
     still read once and written once (PERF.md section 6, PR 29).
     """
     step = jax.vmap(
@@ -1515,11 +1558,14 @@ def apply_update_batch(
     if active is None:
         state, _hist = step(state, batch, client_rank)
         return state
-    with jax.named_scope("compact_gather"):
-        sub_state, sub_batch = jax.tree.map(
-            lambda a: a[active], (state, batch)
+    if batch.client.shape[0] != active.shape[0]:
+        raise ValueError(
+            f"a compact step over {active.shape[0]} slots takes a batch of "
+            f"as many rows, not {batch.client.shape[0]}"
         )
-    sub_state, _hist = step(sub_state, sub_batch, client_rank)
+    with jax.named_scope("compact_gather"):
+        sub_state = jax.tree.map(lambda a: a[active], state)
+    sub_state, _hist = step(sub_state, batch, client_rank)
     # a plain scatter on the room axis, outside any vmap: the form
     # `ingest.merge_stream` proved right on the chip
     with jax.named_scope("compact_scatter"):
@@ -2990,45 +3036,29 @@ class BatchEncoder:
                 all_dels.append(d)
         return self.batch_from_rows(all_rows, all_dels, n_rows, n_dels)
 
-    def batch_planes(
+    def batch_packed(
         self,
         all_rows: List[list],
         all_dels: List[list],
         n_rows: Optional[int] = None,
         n_dels: Optional[int] = None,
-    ) -> List[np.ndarray]:
-        """Per-doc row/del tuple lists padded to [D, U] / [D, R]: the 27
-        host planes of an `UpdateBatch`, in its field order."""
+    ) -> PackedBatch:
+        """Per-doc row/del tuple lists padded to a host `PackedBatch`:
+        `rows` [D, U, 23] and `dels` [D, R, 4], both int32 and
+        contiguous."""
         U = n_rows or max(1, max(len(r) for r in all_rows))
         R = n_dels or max(1, max(len(d) for d in all_dels))
         D = len(all_rows)
 
-        rows = np.zeros((D, U, 22), dtype=np.int32)
-        rows[:, :, 10] = -1  # key padding must read as "sequence row"
-        rows[:, :, 12] = -1  # p_client padding
-        rows[:, :, 14] = -1  # p_root padding (primary root)
-        rows[:, :, 15] = -1  # mv_sc padding
-        rows[:, :, 18] = -1  # mv_ec padding
-        rows[:, :, 21] = -1  # mv_prio padding
-        rows_valid = np.zeros((D, U), dtype=bool)
-        for d, doc_rows in enumerate(all_rows):
-            for i, row in enumerate(doc_rows):
-                rows[d, i] = row
-                rows_valid[d, i] = True
-
-        dels = np.zeros((D, R, 3), dtype=np.int32)
-        dels_valid = np.zeros((D, R), dtype=bool)
-        for d, doc_dels in enumerate(all_dels):
-            for i, de in enumerate(doc_dels):
-                dels[d, i] = de
-                dels_valid[d, i] = True
-
-        return (
-            [rows[:, :, i] for i in range(22)]  # client .. mv_prio
-            + [rows_valid]
-            + [dels[:, :, i] for i in range(3)]  # del_client, _start, _end
-            + [dels_valid]
-        )
+        rows = np.empty((D, U, len(_PAD_ROW)), dtype=np.int32)
+        rows[:] = _PAD_ROW
+        dels = np.zeros((D, R, 4), dtype=np.int32)
+        for packed, per_doc in ((rows, all_rows), (dels, all_dels)):
+            for d, entries in enumerate(per_doc):
+                if entries:
+                    packed[d, : len(entries), :-1] = entries
+                    packed[d, : len(entries), -1] = 1
+        return PackedBatch(rows, dels)
 
     def batch_from_rows(
         self,
@@ -3037,10 +3067,11 @@ class BatchEncoder:
         n_rows: Optional[int] = None,
         n_dels: Optional[int] = None,
     ) -> UpdateBatch:
-        """`batch_planes` as one [D, U] / [D, R] batch on the default
-        device, a plane an upload."""
-        planes = self.batch_planes(all_rows, all_dels, n_rows, n_dels)
-        return UpdateBatch(*[jnp.asarray(p) for p in planes])
+        """`batch_packed` as the planes of one [D, U] / [D, R] batch on
+        the default device."""
+        return unpack_batch_jit(
+            self.batch_packed(all_rows, all_dels, n_rows, n_dels)
+        )
 
     def build_step(
         self, update: Update, n_rows: int, n_dels: int, primary_root=None
@@ -3053,51 +3084,8 @@ class BatchEncoder:
                 f"update needs {len(rows)} rows/{len(dels)} dels, "
                 f"buckets are {n_rows}/{n_dels}"
             )
-        row_arr = np.zeros((n_rows, 22), dtype=np.int32)
-        row_arr[:, 10] = -1
-        row_arr[:, 12] = -1
-        row_arr[:, 14] = -1
-        row_arr[:, 15] = -1
-        row_arr[:, 18] = -1
-        row_arr[:, 21] = -1
-        row_valid = np.zeros(n_rows, dtype=bool)
-        for i, row in enumerate(rows):
-            row_arr[i] = row
-            row_valid[i] = True
-        del_arr = np.zeros((n_dels, 3), dtype=np.int32)
-        del_valid = np.zeros(n_dels, dtype=bool)
-        for i, de in enumerate(dels):
-            del_arr[i] = de
-            del_valid[i] = True
-        return UpdateBatch(
-            client=jnp.asarray(row_arr[:, 0]),
-            clock=jnp.asarray(row_arr[:, 1]),
-            length=jnp.asarray(row_arr[:, 2]),
-            origin_client=jnp.asarray(row_arr[:, 3]),
-            origin_clock=jnp.asarray(row_arr[:, 4]),
-            ror_client=jnp.asarray(row_arr[:, 5]),
-            ror_clock=jnp.asarray(row_arr[:, 6]),
-            kind=jnp.asarray(row_arr[:, 7]),
-            content_ref=jnp.asarray(row_arr[:, 8]),
-            content_off=jnp.asarray(row_arr[:, 9]),
-            key=jnp.asarray(row_arr[:, 10]),
-            p_tag=jnp.asarray(row_arr[:, 11]),
-            p_client=jnp.asarray(row_arr[:, 12]),
-            p_clock=jnp.asarray(row_arr[:, 13]),
-            p_root=jnp.asarray(row_arr[:, 14]),
-            mv_sc=jnp.asarray(row_arr[:, 15]),
-            mv_sk=jnp.asarray(row_arr[:, 16]),
-            mv_sa=jnp.asarray(row_arr[:, 17]),
-            mv_ec=jnp.asarray(row_arr[:, 18]),
-            mv_ek=jnp.asarray(row_arr[:, 19]),
-            mv_ea=jnp.asarray(row_arr[:, 20]),
-            mv_prio=jnp.asarray(row_arr[:, 21]),
-            valid=jnp.asarray(row_valid),
-            del_client=jnp.asarray(del_arr[:, 0]),
-            del_start=jnp.asarray(del_arr[:, 1]),
-            del_end=jnp.asarray(del_arr[:, 2]),
-            del_valid=jnp.asarray(del_valid),
-        )
+        packed = self.batch_packed([rows], [dels], n_rows, n_dels)
+        return unpack_batch_jit(PackedBatch(packed.rows[0], packed.dels[0]))
 
     @staticmethod
     def stack_steps(steps: List[UpdateBatch]) -> UpdateBatch:
@@ -3575,6 +3563,7 @@ def _register_programs():
     progbudget.register("finish_pack", _finish_pack)
     progbudget.register("finish_counts", _finish_counts)
     progbudget.register("state_vectors", state_vectors)
+    progbudget.register("unpack_batch", unpack_batch_jit)
 
 
 _register_programs()

@@ -35,9 +35,12 @@ from ytpu.core.state_vector import StateVector
 from ytpu.models.batch_doc import (
     BatchEncoder,
     DocStateBatch,
+    PackedBatch,
     UpdateBatch,
     apply_update_batch,
     init_state,
+    unpack_batch,
+    unpack_batch_jit,
 )
 from ytpu.ops.decode_kernel import (
     ChunkedWirePayloads,
@@ -91,7 +94,9 @@ _SLOW_REASONS = (
 @jax.named_scope("merge_stream")
 def merge_stream(batch, stream, idx, prefix, base, width: int):
     """The fast lanes' decoded `stream` ([S, ...]) laid over the host
-    lane's `batch` ([n_docs, ...]) at the slots `idx` ([S] i32).
+    lane's `batch` ([W, ...], the step's width; a `PackedBatch` as the
+    host shipped it, taken apart here) at its rows `idx` ([S] i32: where
+    each lane's slot sits in the step, `_step_rows`).
 
     String rows leave the decoder with refs into the padded lane matrix
     (``s * width + start``); the step retained only the string-bearing
@@ -99,6 +104,7 @@ def merge_stream(batch, stream, idx, prefix, base, width: int):
     that chunk: lane s's bytes start at ``prefix[s]`` ([S] i32) of the
     chunk at `base` (0-d i32), and a wire ref is stored as ``-2 - ref``.
     `prefix` and `base` differ every step: operands, never statics."""
+    batch = unpack_batch(batch)
     lane = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None]
     compact_ref = prefix[:, None] + (stream.content_ref - lane * width)
     is_str_ref = stream.valid & (stream.content_ref >= 0)
@@ -273,9 +279,9 @@ class BatchIngestor:
         self._table_cache: Dict[str, tuple] = {}
         # table name -> its entries, padding included, once past the floor
         self._table_width: Dict[str, int] = {}
-        # (n_rows, n_dels) -> the host lane's empty batch on the device(s),
-        # least recently used first (`_kept_batch`)
-        self._batch_cache: "OrderedDict[Tuple[int, int], UpdateBatch]" = (
+        # (width, n_rows, n_dels) -> the host lane's empty batch on the
+        # device(s), least recently used first (`_kept_batch`)
+        self._batch_cache: "OrderedDict[Tuple[int, int, int], PackedBatch]" = (
             OrderedDict()
         )
         # the interner as `_note_clients` last saw it: how many clients,
@@ -335,22 +341,23 @@ class BatchIngestor:
         phases.add_value(took.name, 1)  # the recorder's: a window's delta
         return dev
 
-    def _kept_batch(self, n_rows: int, n_dels: int) -> Optional[UpdateBatch]:
+    def _kept_batch(self, bucket: Tuple[int, int, int]) -> Optional[PackedBatch]:
         """The host lane's empty batch of this bucket, if a step left it
-        on the device(s): what `batch_planes` pads to when no slot plans a
-        row, a constant of `(n_docs, n_rows, n_dels)`. `merge_stream` and
+        on the device(s): what `batch_packed` pads to when no slot plans a
+        row, a constant of `(width, n_rows, n_dels)`, the step's width
+        among them (`_active_slots`). `merge_stream` and
         `apply_update_batch` donate no operand, so one upload serves every
         step without a host-lane room until it is evicted (`_keep_batch`)."""
-        kept = self._batch_cache.get((n_rows, n_dels))
+        kept = self._batch_cache.get(bucket)
         if kept is not None:
-            self._batch_cache.move_to_end((n_rows, n_dels))
+            self._batch_cache.move_to_end(bucket)
         return kept
 
-    def _keep_batch(self, n_rows: int, n_dels: int, batch: UpdateBatch) -> None:
+    def _keep_batch(self, bucket: Tuple[int, int, int], batch: PackedBatch) -> None:
         """Keep an empty batch a step has just uploaded. The entries hold
         at most 1/16 of the state's resident bytes between them, least
-        recently used out; one that alone passes that (the bulk loads'
-        wide buckets) is not kept, and its step is today's."""
+        recently used out; one that alone passes that (a dense step's
+        wide buckets) is not kept, and its step builds it again."""
         def nbytes(tree) -> int:
             return sum(a.nbytes for a in jax.tree.leaves(tree))
 
@@ -360,7 +367,7 @@ class BatchIngestor:
         kept = self._batch_cache
         while nbytes(list(kept.values())) > room:
             kept.popitem(last=False)
-        kept[(n_rows, n_dels)] = batch
+        kept[bucket] = batch
 
     def _note_clients(self) -> None:
         """Count the writers interned since the last look: one count a
@@ -411,10 +418,11 @@ class BatchIngestor:
         )
         return copies * sum(a.nbytes for a in jax.tree.leaves(host))
 
-    def _batch(self, all_rows, all_dels, n_rows=None, n_dels=None):
-        """`BatchEncoder.batch_from_rows`, uploaded to where the state is."""
-        planes = self.enc.batch_planes(all_rows, all_dels, n_rows, n_dels)
-        return UpdateBatch(*self._upload(planes, by_doc=True))
+    def _batch(self, all_rows, all_dels, by_doc: bool = True) -> UpdateBatch:
+        """`BatchEncoder.batch_from_rows` where the state is: the two
+        packed arrays uploaded (`_upload`), then `unpack_batch_jit`."""
+        packed = self.enc.batch_packed(all_rows, all_dels)
+        return unpack_batch_jit(self._upload(packed, by_doc))
 
     def _decode_tables(self) -> dict:
         """`decode_updates_v1`'s tables of every interned client, key and
@@ -467,6 +475,28 @@ class BatchIngestor:
         idle = (d for d in range(self.n_docs) if d not in taken)
         pad = list(islice(idle, width - len(live)))
         return np.sort(np.asarray(live + pad, dtype=np.int32))
+
+    def _step_rows(self, active: Optional[np.ndarray], slots) -> np.ndarray:
+        """The row of the step's batch each of `slots` has: the slot
+        itself in a dense step, its place in `active` (sorted, and it
+        holds every slot with a payload) in a compact one. The batch is
+        as wide as the step in both, so one rule lays out both lanes."""
+        slots = np.asarray(slots, dtype=np.int32)
+        if active is None:
+            return slots
+        return np.searchsorted(active, slots).astype(np.int32)
+
+    def _planned_batch(self, active: Optional[np.ndarray], planned: dict):
+        """(all_rows, all_dels) of a batch as wide as the step, from
+        `planned`: slot -> `_plan_doc`'s (rows, dels). Every other row of
+        the batch is padding."""
+        width = self.n_docs if active is None else len(active)
+        all_rows, all_dels = [[]] * width, [[]] * width
+        for at, (rows, dels) in zip(
+            self._step_rows(active, list(planned)), planned.values()
+        ):
+            all_rows[at], all_dels[at] = rows, dels
+        return all_rows, all_dels
 
     def reset_slot(self, doc: int) -> None:
         """Return a doc slot to its empty state (start/-1, zero blocks,
@@ -895,12 +925,16 @@ class BatchIngestor:
         host lane (`_plan_doc`). Both lanes merge into one
         `apply_update_batch` dispatch, so mixed batches cost one step.
 
+        The step is as wide as the rooms that carry a payload
+        (`_active_slots`), and so is the batch both lanes meet in: a row
+        a slot of `active`, or a row a slot in a dense step.
+
         Host stages (docs/observability.md, "Inside a dispatch"):
         `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan` (⊃ `.decode_host`, one
-        a host-lane payload), `.host_rows`, `.h2d`), `ingest.merge` (the
-        uploads, then one enqueue each under `.gather`, `decode.v1`, `.scatter`),
-        `ingest.rank_table`, `integrate.xla_batch`, `ingest.flags`,
-        `ingest.recover`.
+        a host-lane payload), `.host_rows`, `.h2d`, `.unpack` (a step with
+        no fast lane)), `ingest.merge` (the uploads, then one enqueue each
+        under `.gather`, `decode.v1`, `.scatter`), `ingest.rank_table`,
+        `integrate.xla_batch`, `ingest.flags`, `ingest.recover`.
         """
         if len(payloads) != self.n_docs:
             raise ValueError(f"expected {self.n_docs} payload slots")
@@ -925,7 +959,7 @@ class BatchIngestor:
                 # the hot path
                 fast_sv_deltas: Dict[int, Dict[int, int]] = {}
                 fast_has_str: List[bool] = []
-                slow_updates: List[Optional[Update]] = [None] * self.n_docs
+                slow_updates: Dict[int, Update] = {}  # slot -> its update
                 max_fast_rows, max_fast_dels = 0, 0
                 max_sections, max_steps = 0, 0
                 with phases.span("ingest.plan.prescan"):
@@ -975,62 +1009,64 @@ class BatchIngestor:
                         max_sections = max(max_sections, cols.n_client_sections)
                         max_steps = max(max_steps, steps_for_columns(cols))
                 self.fast_docs += len(fast_idx)
-                self.slow_docs += sum(1 for u in slow_updates if u is not None)
+                self.slow_docs += len(slow_updates)
 
+                # a slot without a payload plans no row (`_plan_doc`): the
+                # step, and the batch both lanes meet in, need be no wider
+                # than the slots that carry one
+                active = self._active_slots(live)
+                width = self.n_docs if active is None else len(active)
                 # a step none of whose payloads took the host lane plans no
-                # row (`_plan_doc(d, None)` touches nothing): its batch is
-                # `batch_planes`' padding, kept on the device by bucket
-                host_lane = len(fast_idx) != len(live)
+                # row at all: its batch is `batch_packed`'s padding, kept
+                # on the device by bucket
                 with phases.span("ingest.plan.host_rows"):
-                    if host_lane:
-                        batch = None
-                        all_rows, all_dels = [], []
-                        for d, u in enumerate(slow_updates):
-                            rows, dels = self._plan_doc(d, u)
-                            all_rows.append(rows)
-                            all_dels.append(dels)
-                        n_rows = _bucket(
-                            max(max_fast_rows, 1, max(len(r) for r in all_rows))
-                        )
-                        n_dels = _bucket(
-                            max(max_fast_dels, 1, max(len(d_) for d_ in all_dels))
-                        )
-                        host_rows = sum(len(all_rows[d]) for d in live)
+                    planned = {
+                        d: self._plan_doc(d, u) for d, u in slow_updates.items()
+                    }
+                    n_rows = _bucket(max(
+                        [max_fast_rows, 1] + [len(r) for r, _ in planned.values()]
+                    ))
+                    n_dels = _bucket(max(
+                        [max_fast_dels, 1] + [len(d_) for _, d_ in planned.values()]
+                    ))
+                    bucket = (width, n_rows, n_dels)
+                    if planned:
+                        host_rows = sum(len(r) for r, _ in planned.values())
                         self._m_host_rows.inc(host_rows)
                         phases.add_value(self._m_host_rows.name, host_rows)
-                    else:
-                        all_rows = all_dels = [[]] * self.n_docs
-                        n_rows = _bucket(max(max_fast_rows, 1))
-                        n_dels = _bucket(max(max_fast_dels, 1))
-                        batch = self._kept_batch(n_rows, n_dels)
-                    planes = None
-                    if batch is None:
-                        planes = self.enc.batch_planes(
-                            all_rows, all_dels, n_rows, n_dels
+                    batch = None if planned else self._kept_batch(bucket)
+                    built = batch is None
+                    if built:
+                        packed = self.enc.batch_packed(
+                            *self._planned_batch(active, planned), n_rows, n_dels
                         )
                 with phases.span("ingest.plan.h2d"):
-                    if batch is None:
-                        # the host lane's 27 planes, over every slot
-                        batch = UpdateBatch(*self._upload(planes, by_doc=True))
+                    if built:
+                        # the host lane's two packed arrays: by room over
+                        # the chips in a dense step, whole on every chip
+                        # in a compact one
+                        by_doc = active is None
+                        batch = self._upload(packed, by_doc)
                         if phases.enabled:
                             phases.transfer(
                                 "ingest.plan.h2d",
-                                self._uploaded_bytes(planes, by_doc=True),
+                                self._uploaded_bytes(packed, by_doc),
                                 "h2d",
                             )
-                        if not host_lane:
-                            self._keep_batch(n_rows, n_dels, batch)
-                took = (
-                    self._m_batch_reuses if planes is None
-                    else self._m_batch_builds
-                )
+                        if not planned:
+                            self._keep_batch(bucket, batch)
+                took = self._m_batch_builds if built else self._m_batch_reuses
                 took.inc()
                 phases.add_value(took.name, 1)  # the recorder's: a window's delta
+                if not fast_idx:
+                    # `merge_stream` takes the planes apart where a room
+                    # rides the fast lane; where none does, one small
+                    # program (`jit_unpack_batch`) does, so that the
+                    # integrate program has one form
+                    with phases.span("ingest.plan.unpack"):
+                        batch = unpack_batch_jit(batch)
             self._m_fast.inc(len(fast_idx))
-            self._m_slow.inc(sum(1 for u in slow_updates if u is not None))
-            # a slot without a payload plans no row (`_plan_doc`): the step
-            # need be no wider than the slots that carry one
-            active = self._active_slots(live)
+            self._m_slow.inc(len(slow_updates))
             took = self._m_dense if active is None else self._m_compact
             took.inc()
             phases.add_value(took.name, 1)  # the recorder's: a window's delta
@@ -1041,7 +1077,8 @@ class BatchIngestor:
                 # retain wire bytes only for lanes that actually emitted string
                 # rows (delete/GC-only payloads hold no device-referenced spans)
                 batch, flags, chunk_base = self._merge_fast_lane(
-                    batch, fast_idx, fast_payloads, n_rows, n_dels,
+                    batch, fast_idx, self._step_rows(active, fast_idx),
+                    fast_payloads, n_rows, n_dels,
                     retain_lanes=fast_has_str,
                     n_steps=16 * ((max_steps + 15) // 16) or None,
                     max_sections=_bucket(max_sections, 2) if max_sections else None,
@@ -1049,7 +1086,8 @@ class BatchIngestor:
             with phases.span("ingest.rank_table"):
                 # after the prescan has interned what this step brought
                 client_rank = self._client_rank()
-            # `active` rides up with the call, as `merge_stream`'s `idx` does
+            # `active` rides up with the call, as `merge_stream`'s `idx`
+            # does; the batch is `[len(active), ...]` already
             self.state = apply_update_batch(
                 self.state, batch, client_rank, active
             )
@@ -1081,7 +1119,7 @@ class BatchIngestor:
         from ytpu.ops.decode_kernel import FLAG_ERRORS
 
         bad_lanes = set(np.nonzero(f & FLAG_ERRORS)[0].tolist())
-        bad = [fast_idx[i] for i in bad_lanes]
+        bad = sorted(fast_idx[i] for i in bad_lanes)
         self.fast_recoveries += len(bad)
         self._m_recoveries.inc(len(bad))
         # release the retained wire chunk if every string-bearing
@@ -1093,7 +1131,7 @@ class BatchIngestor:
             i in bad_lanes for i, has in enumerate(fast_has_str) if has
         ):
             self.payloads.drop_if_unreferenced(chunk_base)
-        recovery: List[Optional[Update]] = [None] * self.n_docs
+        planned = {}
         for d in bad:
             clocks = self.svs[d].clocks
             for c, old in fast_sv_deltas[d].items():
@@ -1101,23 +1139,23 @@ class BatchIngestor:
                     clocks.pop(c, None)
                 else:
                     clocks[c] = old
-            recovery[d] = Update.decode_v1(payloads[d])
-        r_rows, r_dels = [], []
-        for d, u in enumerate(recovery):
-            rows, dels = self._plan_doc(d, u)
-            r_rows.append(rows)
-            r_dels.append(dels)
+            planned[d] = self._plan_doc(d, Update.decode_v1(payloads[d]))
+        # as wide as the flagged rooms, by the step's own rule
+        active = self._active_slots(bad)
         self.state = apply_update_batch(
             self.state,
-            self._batch(r_rows, r_dels),
+            self._batch(
+                *self._planned_batch(active, planned), by_doc=active is None
+            ),
             self._client_rank(),
-            self._active_slots(bad),
+            active,
         )
 
     def _merge_fast_lane(
         self,
         batch,
         fast_idx,
+        fast_at,
         fast_payloads,
         n_rows,
         n_dels,
@@ -1125,7 +1163,8 @@ class BatchIngestor:
         n_steps=None,
         max_sections=None,
     ):
-        """Decode the fast lanes on the device and lay them over `batch`.
+        """Decode the fast lanes (the slots `fast_idx`) on the device and
+        lay them over `batch` at its rows `fast_at` (`_step_rows`).
 
         After the uploads, at most three device programs: the raw lanes'
         gather, `decode_updates_v1`, `merge_stream`. Each is keyed by what
@@ -1235,7 +1274,7 @@ class BatchIngestor:
                 merged = _merge_stream_jit(
                     batch,
                     stream,
-                    np.asarray(fast_idx, dtype=np.int32),
+                    fast_at,
                     prefix,
                     np.int32(base),
                     width=L,
