@@ -1,0 +1,7 @@
+"""Seconds spent reading and deserialising executables the persistent cache held, before the window (`cache_retrieval_time_sec`): the process's build totals (`ytpu/utils/compile_cache.py`, `jax.monitoring`'s own timings) as they stood at the window's opening (`benchmark/setup_parts.py`). A program without the totals has nothing to read."""
+
+from benchmark import setup_parts
+
+
+def read(w):
+    return setup_parts.part(w, "cache_load_s")

@@ -361,28 +361,13 @@ def main(argv=None) -> int:
         return finish(False, device, "--chips 4 needs four chips")
 
     from ytpu import native
-    from ytpu.utils.compile_cache import enable_compile_cache
+    from ytpu.utils.compile_cache import build_totals, enable_compile_cache
     from ytpu.utils.phases import phases
 
     if not native.available():
         return finish(False, device, "native library did not build from the sources")
     cache_dir = enable_compile_cache()
     phases.enable()
-    seen = {"programs": 0, "build_s": 0.0, "cache_hits": 0, "cache_misses": 0}
-
-    def on_event(name, **_):
-        if name.endswith("/cache_hits"):
-            seen["cache_hits"] += 1
-        elif name.endswith("/cache_misses"):
-            seen["cache_misses"] += 1
-
-    def on_duration(name, secs, **_):
-        if name.endswith("/backend_compile_duration"):
-            seen["programs"] += 1  # compiled, or loaded from the cache
-            seen["build_s"] += secs
-
-    jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
         from importlib.metadata import version
 
@@ -401,13 +386,17 @@ def main(argv=None) -> int:
         shard_docs=args.chips == 4,
     )
     stats = devices[0].memory_stats() or {}
+    built = build_totals()
     print(
         f"smoke: peak_bytes_in_use {stats.get('peak_bytes_in_use', 'not reported')} "
         f"on device 0; {len(phases.compile_events())} program signatures "
-        f"(compile_events), {seen['programs']} XLA programs built in "
-        f"{seen['build_s']:.1f} s of which "
-        f"{seen['cache_hits']} came from the persistent cache "
-        f"({seen['cache_misses']} misses written to it); "
+        f"(compile_events), {built['builds']} XLA programs built: traced "
+        f"{built['trace_s']:.1f} s, lowered {built['lower_s']:.1f} s, "
+        f"{built['backend_s']:.1f} s in the backend of which "
+        f"{built['cache_load_s']:.1f} s reading the {built['cache_hits']} that "
+        f"came from the persistent cache "
+        f"({built['cache_requests'] - built['cache_hits']} misses written to it; "
+        f"a cold cache would have cost +{built['saved_s']:.1f} s of compiling); "
         f"{time.perf_counter() - t_start:.1f} s wall in all. Observations of "
         "a smoke, not metrics."
     )

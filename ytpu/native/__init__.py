@@ -16,11 +16,13 @@ import hashlib
 import os
 import subprocess
 import threading
+import time
 from typing import Optional
 
 __all__ = [
     "load",
     "available",
+    "startup",
     "NativeColumns",
     "decode_update_columns",
     "build_capi",
@@ -41,6 +43,12 @@ _BUILD_LOCK = os.path.join(_HERE, ".build.lock")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+
+#: what the process's one `load()` cost: whether g++ ran, its seconds (the
+#: wait on another process's build among them) and the `dlopen`'s. Written
+#: once, by the load that succeeded: it happens before any recorder can be
+#: on, and is part of a server's start all the same.
+startup = {"built": False, "build_s": 0.0, "load_s": 0.0}
 
 _COLUMNS = [
     "client",
@@ -228,13 +236,21 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         path = _lib_path()
-        if not os.path.exists(path) and not _build(path):
+        t0 = time.perf_counter()
+        built = not os.path.exists(path)
+        if built and not _build(path):
             _tried = True
             return None
+        t1 = time.perf_counter()
         try:
             lib = ctypes.CDLL(path)
         except OSError:
             return None
+        startup.update(
+            built=built,
+            build_s=t1 - t0 if built else 0.0,
+            load_s=time.perf_counter() - t1,
+        )
         lib.ytpu_decode_update_v1.restype = ctypes.c_void_p
         lib.ytpu_decode_update_v1.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
         lib.ytpu_columns_error.restype = ctypes.c_int

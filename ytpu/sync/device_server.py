@@ -27,6 +27,7 @@ Two serving modes:
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -47,6 +48,14 @@ from ytpu.utils.phases import phases
 from ytpu.utils.trace import current_trace_id, tracer
 
 __all__ = ["DeviceBatchFull", "DeviceSyncServer"]
+
+#: the process's live servers, for `sync.device_queue_depth`: weak, so the
+#: registry keeps no server alive
+_SERVERS: "weakref.WeakSet[DeviceSyncServer]" = weakref.WeakSet()
+
+
+def _queued_everywhere() -> int:
+    return sum(srv.pending_device_updates() for srv in tuple(_SERVERS))
 
 
 class DeviceSyncServer(SyncServer):
@@ -91,7 +100,6 @@ class DeviceSyncServer(SyncServer):
             "sync.diffs_encoded", labelnames=("tenant",)
         )
         self._slots_gauge = metrics.gauge("sync.device_slots_assigned")
-        self._queue_depth = metrics.gauge("sync.device_queue_depth")
         self._slot_of: Dict[str, int] = {}
         # pipelined encode/diff driver (ISSUE-10): every SyncStep1 answer
         # and batched fan-out routes through it — single-tenant calls take
@@ -126,6 +134,11 @@ class DeviceSyncServer(SyncServer):
         self._queue_traces: List[List[tuple]] = [
             [] for _ in range(ingestor.n_docs)
         ]
+        # queued updates over all slots of the process's live servers,
+        # worked out when `/metrics` or `/healthz` reads it: a walk over
+        # every slot is no work for a flush
+        _SERVERS.add(self)
+        metrics.gauge("sync.device_queue_depth").set_function(_queued_everywhere)
         self._last_dispatch = metrics.gauge("sync.last_dispatch_unix")
         # live telemetry plane (ISSUE-11): `telemetry_port` starts the
         # scrapeable HTTP endpoint on its own daemon thread (0 = any
@@ -666,13 +679,12 @@ class DeviceSyncServer(SyncServer):
         queues keep shipping while others ride as no-ops (the engine's
         padding rows), so a chatty tenant never blocks a quiet one.
 
-        Observability: the `sync.device_queue_depth` gauge tracks the
-        total queued updates before/after each flush, and a device-step
+        Observability: the `sync.device_queue_depth` gauge reads the
+        total queued updates (of every live server in the process) when
+        it is scraped, and a device-step
         failure dumps the tracer's flight-recorder ring (`YTPU_TRACE`)
         before re-raising — a kernel abort leaves a replayable trace.
         """
-        depth_gauge = self._queue_depth
-        depth_gauge.set(sum(len(q) for q in self._queues))
         steps = 0
         while any(self._queues) and (max_steps is None or steps < max_steps):
             # dispatch span (ISSUE-11): names the request trace ids whose
@@ -701,8 +713,11 @@ class DeviceSyncServer(SyncServer):
                     start = time.perf_counter()
                     carried = [t[0][1] for t in self._queue_traces if t]
                     phases.add_value("sync.dispatch_updates", len(carried))
-                    for enqueued in carried:
-                        phases.add_time("sync.queue_wait", start - enqueued)
+                    phases.add_time(
+                        "sync.queue_wait",
+                        sum(start - enqueued for enqueued in carried),
+                        calls=len(carried),
+                    )
                 try:
                     with self._apply_hist.time():
                         self.ingestor.apply_bytes(payloads)
@@ -723,7 +738,6 @@ class DeviceSyncServer(SyncServer):
             # empty-queue flush must not make /healthz report a device
             # that never dispatched as fresh
             self._last_dispatch.set(time.time())
-        depth_gauge.set(sum(len(q) for q in self._queues))
         return steps
 
     def device_text(self, tenant_name: str) -> str:

@@ -12,7 +12,12 @@ Attribution model: every instrumented call passes a hashable ``key``
 describing the compiled-program identity (static args + operand shapes).
 The FIRST call with an unseen (stage, key) is charged to ``compile_s``
 (that wall time includes trace + compile + the first execute); later
-calls with the same key charge ``execute_s``. ``key=None`` marks a
+calls with the same key charge ``execute_s``. What of a stage's time
+went into building programs is beside it, by part, as jax itself times
+it (``trace_s``, ``lower_s``, ``backend_s``, ``cache_load_s``, ``builds``,
+``cache_hits``: "Build parts" below): ``compile_s`` less those is the
+first execution and the Python around the call, and a stage with no key
+(or a warm key) that still built something shows it there. ``key=None`` marks a
 host-only stage with no compile phase. Because JAX dispatch is async,
 ``execute_s`` measures dispatch (plus any blocking the callee already
 does) — the recorder itself NEVER adds a device sync, so it is safe on
@@ -41,7 +46,10 @@ analysis. A call site passes ``memory=`` a zero-arg thunk (built with
 ``program_memory(fn, *args, **kwargs)``) that AOT-lowers the jitted
 program against ShapeDtypeStruct snapshots and reads
 ``compiled.memory_analysis()`` — a compile-cache HIT on the
-first-sighting path (the traced call just compiled the same program),
+first-sighting path (the traced call just compiled the same program:
+the snapshots keep the sharding of an array laid over several devices,
+without which a doc-sharded state lowered to another program and every
+first sighting was traced, lowered and compiled twice, PR 41),
 so the capture costs ~1ms, never a second compile. The kind split
 (temp / argument / output / generated_code / alias bytes) is journaled
 INTO the compile event (``event["memory"]``), surfaced as
@@ -52,6 +60,24 @@ program — the resident-bytes axis the PR-4 roofline lacked).
 The thunk reads only shapes and dtypes, which a donated
 (`donate_argnums`) and by then deleted argument still answers for, so
 building it costs a closure and a warm call builds no spec tree.
+
+Build parts (ISSUE-41): `utils/compile_cache.py::listen_to_builds`
+(registered by ``enable()``; this module still imports no jax) hears
+``jax.monitoring`` time every program's trace, lowering and backend
+build, on the calling thread and inside the call that needed it, and
+keeps one row a program with the innermost open span and its recorder
+(``build_log()``). That log is the one record: ``snapshot()`` sums a
+stage's parts from the rows built under its spans since ``reset()`` (no
+roll-up into the spans around it, as ``self_s``), and a first
+sighting's compile event takes the rows of its own span.
+``backend_s`` is a read of the persistent cache where that hit
+(``cache_load_s`` of it), a compile where it missed. Rows built with no
+span open while the process's recorder is on show as the stage
+``build.unspanned``. A compile event carries the ``parts`` and the
+``fun_names`` built under its span, and ``compile_report()`` their
+totals, so a retrace says what it cost and of what. The process's
+totals and the log are kept whether or not a recorder is on
+(``compile_cache.build_totals()``, ``build_log()``).
 
 Disabled-path contract (the default): attribute checks alone (this
 recorder's flag and, for the process-wide pair, the tracer's), zero
@@ -89,6 +115,17 @@ __all__ = [
     "compile_storm_provider",
     "program_memory",
 ]
+
+#: what a stage, a compile event and `compile_report()` hold of the
+#: programs built: seconds as `jax.monitoring` timed them, and counts
+BUILD_PARTS = (
+    "trace_s", "lower_s", "backend_s", "cache_load_s", "builds", "cache_hits",
+)
+
+_NO_PARTS = dict.fromkeys(BUILD_PARTS, 0)
+#: the stage `snapshot()` shows the programs under that were built with no
+#: span open while the process's recorder was on
+UNSPANNED = "build.unspanned"
 
 #: journal ring bound — a run that compiles more programs than this is
 #: itself a compile storm; the TAIL is what the sentinel reports on
@@ -131,6 +168,22 @@ class _Stage:
         self.value = None  # scalar gauge (overlap_ratio, in-flight depth)
 
 
+def _built_under(rec, since: float, stage=None) -> List[Dict]:
+    """The build log's rows of `rec` (`compile_cache.built_under`; that
+    module imports no jax until something listens)."""
+    from ytpu.utils.compile_cache import built_under
+
+    return built_under(rec, since, stage)
+
+
+def _sum_parts(into: Dict, row: Dict) -> None:
+    """One build-log row into a dict of BUILD_PARTS."""
+    for k in ("trace_s", "lower_s", "backend_s", "cache_load_s"):
+        into[k] += row[k]
+    into["builds"] += 1
+    into["cache_hits"] += row["cache"] == "hit"
+
+
 def _sig_delta(prev, new, axes) -> List[Dict[str, str]]:
     """Element-wise diff of two signatures with axis-name attribution.
     Non-tuple keys compare as one-element tuples; a length change shows
@@ -151,6 +204,16 @@ def _sig_delta(prev, new, axes) -> List[Dict[str, str]]:
                 }
             )
     return delta
+
+
+def _sharded_over(a):
+    """`a`'s sharding where it spans more than one device, else None (a
+    single-device array lowers as the call did with none stated). A
+    deleted donated array still answers."""
+    sharding = getattr(a, "sharding", None)
+    if sharding is None or len(sharding.device_set) < 2:
+        return None
+    return sharding
 
 
 def program_memory(fn, *args, **kwargs):
@@ -177,7 +240,12 @@ def program_memory(fn, *args, **kwargs):
         import jax
 
         if hasattr(a, "shape") and hasattr(a, "dtype"):
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+            # an array laid over several devices keeps its sharding: left
+            # out, the lowering is of another program than the call built,
+            # and a second trace, lowering and compile (`_sharded_over`)
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=_sharded_over(a)
+            )
         if isinstance(a, tuple) and hasattr(a, "_fields"):  # NamedTuple
             return type(a)(*(_spec(x) for x in a))
         if isinstance(a, (tuple, list)):
@@ -226,6 +294,15 @@ _OPEN: "contextvars.ContextVar[Optional[_Span]]" = contextvars.ContextVar(
 _ANNOTATION = None
 
 
+def _listen_to_builds() -> None:
+    try:
+        from ytpu.utils.compile_cache import listen_to_builds
+
+        listen_to_builds()
+    except ImportError:  # host-only install: no jax, no program to build
+        pass
+
+
 def _annotate(name: str):
     global _ANNOTATION
     cls = _ANNOTATION
@@ -235,6 +312,7 @@ def _annotate(name: str):
         except ImportError:  # host-only install: the recorders still work
             cls = False
         _ANNOTATION = cls
+        _listen_to_builds()  # a recorder turned on by `YTPU_PHASES` or `YTPU_TRACE`
     if cls is False:
         return None
     ann = cls("ytpu." + name)
@@ -304,12 +382,15 @@ class PhaseRecorder:
         #: compile-event journal (bounded ring; see compile_events)
         self._events: List[Dict] = []
         self._event_seq = 0
+        #: `reset()`'s moment: the build log's rows from here are this recorder's
+        self._since = time.perf_counter()
         # ---- device-memory attribution (ISSUE-18) ----
         #: per program: peak resident bytes + the signature that set it
         self._memory_peaks: Dict[str, Dict] = {}
 
     def enable(self) -> None:
         self.enabled = True
+        _listen_to_builds()
 
     def disable(self) -> None:
         self.enabled = False
@@ -323,13 +404,21 @@ class PhaseRecorder:
             self._events.clear()
             self._event_seq = 0
             self._memory_peaks.clear()
+            self._since = time.perf_counter()
 
     # --- compile/retrace sentinel (ISSUE-17) ---------------------------------
 
     def _record_compile_locked(self, stage: str, key, axes, dt: float):
-        """Journal one first-sighting (caller holds the lock). The
-        SECOND-or-later signature for a program is a retrace; its delta
-        names the axis that changed vs the previous signature."""
+        """Journal one first-sighting (caller holds the lock, at the
+        span's exit). The SECOND-or-later signature for a program is a
+        retrace; its delta names the axis that changed vs the previous
+        signature. Its ``parts`` are what jax timed of the programs built
+        under the span: the build log's rows of this stage from the last
+        ``dt`` seconds."""
+        built = _built_under(self, time.perf_counter() - dt, stage)
+        parts = dict.fromkeys(BUILD_PARTS, 0)
+        for row in built:
+            _sum_parts(parts, row)
         sigs = self._signatures.setdefault(stage, [])
         if axes:
             self._axes[stage] = tuple(axes)
@@ -348,6 +437,8 @@ class PhaseRecorder:
             "signature": repr(key),
             "retrace": retrace,
             "delta": delta,
+            "parts": {k: round(v, 6) for k, v in parts.items()},
+            "fun_names": [row["fun_name"] for row in built],
         }
         self._events.append(event)
         if len(self._events) > _MAX_COMPILE_EVENTS:
@@ -484,21 +575,28 @@ class PhaseRecorder:
 
     def compile_report(self, since: int = 0) -> Dict:
         """Sentinel rollup since a marker: total events, retrace count,
-        compile seconds, per-program event counts, and the retrace
-        journal (each entry's ``delta`` names the changed axes)."""
+        compile seconds and, of them, the seconds by build part
+        (``parts``: trace, lower, backend, cache load, with the counts of
+        programs built and of cache hits), per-program event counts, and
+        the retrace journal (each entry's ``delta`` names the changed
+        axes, its ``parts`` what the retrace cost and of what)."""
         evs = self.compile_events(since)
         programs: Dict[str, int] = {}
         retraces = 0
         s_total = 0.0
+        parts = dict.fromkeys(BUILD_PARTS, 0)
         for e in evs:
             programs[e["program"]] = programs.get(e["program"], 0) + 1
             s_total += e["compile_s"]
+            for k in BUILD_PARTS:
+                parts[k] += e["parts"][k]
             if e["retrace"]:
                 retraces += 1
         return {
             "events": len(evs),
             "retraces": retraces,
             "s_total": round(s_total, 6),
+            "parts": {k: round(v, 6) for k, v in parts.items()},
             "programs": programs,
             "journal": [e for e in evs if e["retrace"]],
         }
@@ -630,10 +728,23 @@ class PhaseRecorder:
         """Per-stage breakdown: calls / compile_calls / compile_s /
         execute_s / self_s (compile_s + execute_s less the time spent in
         spans nested in this stage's) / h2d_bytes / d2h_bytes /
-        transfer_bytes (sum)."""
+        transfer_bytes (sum) / the build parts (trace_s, lower_s,
+        backend_s, cache_load_s, builds, cache_hits: what jax timed of
+        the programs built under the stage's spans)."""
+        built: Dict[str, Dict[str, float]] = {}
+        for row in _built_under(self, self._since):
+            _sum_parts(
+                built.setdefault(
+                    row["stage"] or UNSPANNED, dict.fromkeys(BUILD_PARTS, 0)
+                ),
+                row,
+            )
         out: Dict[str, Dict[str, float]] = {}
         with self._lock:
-            for name, st in self._stages.items():
+            stages = dict(self._stages)
+            for name in built:  # built with no span open, or under one still open
+                stages.setdefault(name, _Stage())
+            for name, st in stages.items():
                 out[name] = {
                     "calls": st.calls,
                     "compile_calls": st.compile_calls,
@@ -644,8 +755,12 @@ class PhaseRecorder:
                     "d2h_bytes": st.d2h_bytes,
                     "transfer_bytes": st.h2d_bytes + st.d2h_bytes,
                 }
+                for k, v in built.get(name, _NO_PARTS).items():
+                    out[name][k] = round(v, 6)
                 if st.value is not None:
                     out[name]["value"] = round(st.value, 6)
+        if UNSPANNED in built:  # no span to count: a build there is its call
+            out[UNSPANNED]["calls"] = built[UNSPANNED]["builds"]
         return out
 
 
@@ -659,7 +774,9 @@ def compile_storm_provider(
     ``marker`` and flips ``degraded``/``storm`` once they exceed
     ``budget`` (None = report-only, never degrades). The section also
     carries the LAST retrace's signature delta so a probe sees *which
-    axis changed* without walking the journal."""
+    axis changed* without walking the journal, with the ``parts`` of its
+    build (what it cost, and whether that was tracing, lowering or the
+    backend) and the seconds by part of everything since the marker."""
 
     def provider() -> Dict:
         rec = recorder if recorder is not None else phases
@@ -670,12 +787,15 @@ def compile_storm_provider(
             "retraces": rep["retraces"],
             "budget": budget,
             "compile_s": rep["s_total"],
+            "parts": rep["parts"],
             "storm": storm,
             "degraded": storm,
             "last_retrace": (
                 {
                     "program": last["program"],
                     "delta": last["delta"],
+                    "parts": last["parts"],
+                    "fun_names": last["fun_names"],
                 }
                 if last
                 else None
