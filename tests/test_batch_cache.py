@@ -5,23 +5,27 @@ planes are `batch_packed`'s padding, a constant of `(width, n_rows, n_dels)`
 (the width is `n_docs` here: four rooms make every step dense; the compact
 width is `tests/test_step_width_batch.py`'s), so `apply_bytes` hands
 `merge_stream` the device arrays an earlier step of the bucket uploaded:
-since PR 40 the two arrays of a `PackedBatch`, which `merge_stream` takes
-apart (`unpack_batch`; `_wrong_leaves` does the same to compare). What changes is when the planes are built and
-uploaded, never what a program receives: every step's batch is compared,
-leaf for leaf and bit for bit, with what the step used to build
-(`_parent_planes` below is that code), over a served sequence in which a
-stashed out-of-order update, changes of bucket, an eviction by the byte
-bound, a bucket too large to keep and a flagged lane arrive at chosen steps.
+since PR 40 the two arrays of a `PackedBatch`, and since PR 42 a
+`PackedBatch` is what crosses every program boundary of the step: the served
+decoder's output, the merge's, the integrate program's operand, which takes
+the planes apart inside itself (`unpack_batch`; `_wrong_leaves` does the
+same to compare). What changes is when the planes are built and uploaded
+and how many buffers carry them, never what a program works on: every
+step's batch is compared, leaf for leaf and bit for bit, with what the step
+used to build (`_parent_planes` and `_parent_merged` below are that code),
+over a served sequence in which a stashed out-of-order update, changes of
+bucket, an eviction by the byte bound, a bucket too large to keep and a
+flagged lane arrive at chosen steps.
 """
 
 import jax
 import numpy as np
 import pytest
 
-from test_table_cache import _Room, _cut, _replayed, _type
+from test_table_cache import _Room, _cut, _flag_lanes, _replayed, _type
 from ytpu.core import Doc
 from ytpu.models import ingest as ingest_mod
-from ytpu.models.batch_doc import UpdateBatch, unpack_batch_jit, get_string
+from ytpu.models.batch_doc import PackedBatch, UpdateBatch, unpack_batch_jit, get_string
 from ytpu.models.ingest import BatchIngestor
 from ytpu.native import decode_update_columns
 from ytpu.ops import decode_kernel as dk
@@ -69,6 +73,21 @@ def _parent_planes(all_rows, all_dels, n_rows, n_dels):
     )
 
 
+def _parent_merged(want, stream, idx, prefix, base, width):
+    """`merge_stream` as the parent had it, in numpy over the 27 planes:
+    the string refs rebased onto the retained chunk, then `full[idx] = fast`
+    plane by plane. `want` is the host lane's planes (`_parent_planes`),
+    `stream` the decoder's, as planes."""
+    ref, valid = UpdateBatch._fields.index("content_ref"), UpdateBatch._fields.index("valid")
+    full, fast = [np.array(p) for p in want], [np.asarray(p) for p in stream]
+    lane = np.arange(len(idx), dtype=np.int32)[:, None]
+    compact = np.asarray(prefix, np.int32)[:, None] + (fast[ref] - lane * np.int32(width))
+    fast[ref] = np.where(fast[valid] & (fast[ref] >= 0), np.int32(-2 - int(base)) - compact, fast[ref])
+    for f, s in zip(full, fast):
+        f[np.asarray(idx)] = s
+    return full
+
+
 def _entry_bytes(n_docs, n_rows, n_dels):
     """A kept batch: `[n_docs, n_rows, 23]` and `[n_docs, n_dels, 4]`, int32."""
     return 4 * n_docs * (23 * n_rows + 4 * n_dels)
@@ -104,6 +123,7 @@ class _Spy:
         self.active = []  # and its `active` (None: the dense step)
         self.plan_calls = []  # this step: the slots `_plan_doc` was called for
         self.planned = {}  # this step: slot -> (rows, dels) its host lane planned
+        self.replanned = {}  # a recovery's follow-up step: slot -> (rows, dels)
         self.decodes = 0
         self.recovering = False
         real_merge, real_apply = ingest_mod._merge_stream_jit, ingest_mod.apply_update_batch
@@ -123,8 +143,8 @@ class _Spy:
             got = real_plan(doc, incoming)
             if not self.recovering:
                 self.plan_calls.append(doc)
-            if incoming is not None and not self.recovering:
-                self.planned[doc] = got
+            if incoming is not None:
+                (self.replanned if self.recovering else self.planned)[doc] = got
             return got
 
         def recover(*a):  # the follow-up step plans its own rows: not the step's
@@ -138,12 +158,7 @@ class _Spy:
             stream, flags = real_decode(*a, **kw)
             self.decodes += 1
             if self.decodes == flag_decode:  # the device flags every lane of this call
-                import jax.numpy as jnp
-
-                flags = flags | jnp.full_like(flags, dk.FLAG_MALFORMED)
-                stream = stream._replace(
-                    valid=jnp.zeros_like(stream.valid), del_valid=jnp.zeros_like(stream.del_valid)
-                )
+                stream, flags = _flag_lanes(stream, flags)
             return stream, flags
 
         monkeypatch.setattr(ingest_mod, "_merge_stream_jit", merge)
@@ -273,7 +288,9 @@ def served():
             steps.append(
                 dict(
                     merged=spy.merged[merged:],
+                    merge_args=spy.merge_args[merged:],
                     applied=spy.applied[applied:],
+                    replanned=dict(spy.replanned),
                     counted=_counted(before),
                     bucket=bucket,
                     want=want,
@@ -298,6 +315,20 @@ def test_every_step_hands_merge_stream_the_parents_batch(served, step):
     # the host lane planned where the sequence says, and had rows to carry once
     assert s["host_lane"] == ([0] if step in (3, 4) else [])
     assert bool(np.asarray(unpack_batch_jit(s["merged"][0]).valid).any()) == (step == 4)
+
+
+@pytest.mark.parametrize("step", range(N_STEPS))
+def test_every_step_integrates_the_parents_merged_planes(served, step):
+    """What the merge hands the integrate call, two buffers, taken apart as
+    the program takes it apart: the parent's 27 planes, bit for bit (the
+    flagged step's too: its lanes' rows come invalid out of the decoder)."""
+    _, _, steps = served
+    s = steps[step]
+    ((stream, idx, prefix, base), kw), = s["merge_args"]
+    assert all(type(b) is PackedBatch for b in (s["merged"][0], stream, s["applied"][0]))
+    want = _parent_merged(s["want"], unpack_batch_jit(stream), idx, prefix, base, kw["width"])
+    assert _wrong_leaves(s["applied"][0], want) == []
+    assert np.asarray(idx).tolist() == ([1] if step in (3, 4) else [0, 1])  # the fast lanes' rows of the step
 
 
 def test_a_reuse_hands_over_the_arrays_of_the_last_build(served):
@@ -349,6 +380,11 @@ def test_the_flagged_lanes_recover_through_a_batch_of_their_own(served):
     assert [st["recovered"] for st in steps].count(0) == N_STEPS - 1
     assert len(s["applied"]) == 2  # the step's own integrate call, then the recovery's
     recovery = s["applied"][1]
+    assert type(recovery) is PackedBatch  # the pair goes straight in: the integrate program's one form
+    rows, dels = ([s["replanned"].get(d, ([], []))[i] for d in range(N_DOCS)] for i in (0, 1))
+    assert sorted(s["replanned"]) == [0, 1]
+    widest = lambda per_doc: max(1, max(len(entries) for entries in per_doc))  # unbucketed, as `_batch` pads
+    assert _wrong_leaves(recovery, _parent_planes(rows, dels, widest(rows), widest(dels))) == []
     assert np.asarray(unpack_batch_jit(recovery).valid).any(axis=1).tolist() == [True, True, False, False]
     kept = [a for batch in s["kept_arrays"].values() for a in batch]
     assert all(leaf is not k for leaf in recovery for k in kept)
@@ -417,8 +453,8 @@ def test_a_step_without_a_payload_takes_the_kept_batch(monkeypatch):
     before = _counts()
     ing.apply_bytes([None, None])
     assert _counted(before) == {"ingest.batch_builds": 0, "ingest.batch_reuses": 1}
-    # the kept pair, taken apart by the one small program: no merge ran to do it
-    assert _wrong_leaves(spy.applied[-1], [np.asarray(a) for a in unpack_batch_jit(spy.merged[-1])]) == []
+    # the kept pair itself: no merge ran, and no program of its own takes it apart
+    assert all(h is k for h, k in zip(spy.applied[-1], spy.merged[-1]))
     assert get_string(ing.state, 0, ing.payloads) == "one "
 
 
@@ -456,3 +492,57 @@ def test_a_restored_ingestor_builds_at_its_first_step(monkeypatch, tmp_path):
     want = room.oracle().get_text("text").get_string()
     assert get_string(restored.state, 0, restored.payloads) == want
     assert get_string(ing.state, 0, ing.payloads) == want
+
+
+def _four_and_four(doc, txn):
+    """Four delete ranges inside client 1's first block, none next to
+    another, then four one-character blocks at the head: an update as wide
+    as the (4, 4) bucket, so that its recovery pads to the bucket's shape."""
+    text = doc.get_text("text")
+    at = text.get_string().index("abcdefgh")
+    for i in range(4):
+        text.remove_range(txn, at + 1 + 2 * i, 1)
+    for ch in "wxyz":
+        text.insert(txn, 0, ch)
+
+
+def test_a_served_process_keeps_one_integrate_form_a_bucket(monkeypatch):
+    """A fast-lane-only step, a host-lane-only step, a step with both and a
+    flagged lane's recovery, all at the (4, 4) bucket: the integrate program
+    is handed the pair by every one of them (the merge's output, the upload
+    as it is, the recovery's `_batch`), so the process builds one form of
+    it; and a step's programs hand back 35 buffers where it merges (the
+    gather's 1, the decoder's 3, the merge's 2, the state's 29), 29 where
+    it does not."""
+    from ytpu.models.batch_doc import _apply_update_batch_jit
+    from ytpu.utils import progbudget
+
+    monkeypatch.setattr(progbudget, "_MAX", 10**9)  # no eviction under our feet
+    rooms = [_Room() for _ in range(N_DOCS)]
+    ing = BatchIngestor(n_docs=N_DOCS, capacity=CAPACITY // 2)  # a state shape of this test's own: a new form
+    spy = _Spy(monkeypatch, ing, flag_decode=3)  # steps 0, 2 and 3 decode
+    first = rooms[0].edit(1, _type("abcdefghijklmnop "))
+    early = rooms[0].edit(2, _type("two "))
+    late = rooms[0].edit(2, _type("more ", 2))
+    steps = [
+        ("fast lane only", [first, rooms[1].edit(50, _type("x0")), None, None], 35),
+        ("host lane only", [late, None, None, None], 29),  # stashed: the host lane's step, and nothing to carry
+        ("both", [early, rooms[1].edit(50, _type("x2")), None, None], 35),
+        ("flagged", [rooms[0].edit(1, _four_and_four), rooms[1].edit(50, _type("x3")), None, None], 35 + 29),
+    ]
+    forms = _apply_update_batch_jit._cache_size()
+    counter = metrics.counter("ingest.enqueue_outputs")
+    for what, payloads, outputs in steps:
+        merged, applied, before = len(spy.merged), len(spy.applied), counter.value
+        ing.apply_bytes(payloads)
+        assert counter.value - before == outputs, what
+        assert len(spy.merged) - merged == (what != "host lane only"), what
+        handed = spy.applied[applied:]
+        assert len(handed) == 1 + (what == "flagged"), what
+        for batch in handed:  # the bucket's one shape, the recovery's too
+            assert type(batch) is PackedBatch and (batch.rows.shape, batch.dels.shape) == ((N_DOCS, 4, 23), (N_DOCS, 4, 4)), what
+    assert ing.fast_recoveries == 2 and (ing.slow_docs, ing.fast_docs) == (2, 5)
+    assert _apply_update_batch_jit._cache_size() == forms + 1
+    assert not np.asarray(ing.state.error).any()
+    for d in (0, 1):
+        assert get_string(ing.state, d, ing.payloads) == rooms[d].oracle().get_text("text").get_string(), d

@@ -79,24 +79,32 @@ def _hbm_bytes(compiled) -> int:
 V5E_HBM = 16 * 1024**3
 
 
-def test_served_integrate_step_fits_one_v5e(one_chip):
-    """`apply_update_batch` as `flush_device` dispatches it: every slot,
-    the 4-row / 4-delete bucket the scenario's updates land in."""
-    from ytpu.models.batch_doc import (
-        BatchEncoder,
-        _apply_update_batch_jit,
-        scan_tier_plan,
-    )
+def _pair(slots, rows, dels=None):
+    """The `PackedBatch` that crosses the served step's program boundaries,
+    as host arrays: `[slots, rows, 23]` and `[slots, dels, 4]`."""
+    from ytpu.models.batch_doc import BatchEncoder
 
-    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, 4, 4)
+    return BatchEncoder().batch_packed([[]] * slots, [[]] * slots, rows, dels or rows)
+
+
+@pytest.mark.parametrize("rows", [4, 512], ids=["tick_bucket", "prefill_bucket"])
+def test_served_integrate_step_fits_one_v5e(one_chip, rows):
+    """`apply_update_batch` as `flush_device` dispatches it over every
+    slot: the 4-row / 4-delete bucket the scenario's updates land in, and
+    the 512-row bucket of the benchmark's prefill; the batch the pair it
+    is handed, taken apart inside the program."""
+    from ytpu.models.batch_doc import _apply_update_batch_jit, scan_tier_plan
+
     compiled = _apply_update_batch_jit.lower(
         _state(one_chip),
-        _shapes(batch, one_chip),
+        _shapes(_pair(N_DOCS, rows), one_chip),
         jax.ShapeDtypeStruct((N_CLIENTS,), jnp.int32, sharding=one_chip),
         scan_tier_plan(),
     ).compile()
+    m = compiled.memory_analysis()
+    print(f"dense step, {rows}-row bucket: temp bytes {m.temp_size_in_bytes}, argument bytes {m.argument_size_in_bytes}")
     # not donated: input and output state both live, plus temporaries
-    assert _hbm_bytes(compiled) < V5E_HBM // 2, compiled.memory_analysis()
+    assert _hbm_bytes(compiled) < V5E_HBM // 2, m
 
 
 def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
@@ -106,7 +114,6 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
-        BatchEncoder,
         _apply_update_batch_jit,
         init_state,
         scan_tier_plan,
@@ -119,7 +126,7 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
     )
     by_doc = lambda a: on(a, P(AXIS_BATCH, *([None] * (a.ndim - 1))))
     state = jax.tree.map(by_doc, jax.eval_shape(lambda: init_state(N_DOCS, CAPACITY)))
-    batch = BatchEncoder().batch_from_rows([[]] * N_DOCS, [[]] * N_DOCS, 4, 4)
+    batch = _pair(N_DOCS, 4)
     compiled = _apply_update_batch_jit.lower(
         state,
         jax.tree.map(lambda a: on(a, P()), batch),
@@ -137,16 +144,14 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     """`yws-rooms-4k-x4`, the dense step (the prefill's all-room
     dispatches): 4,096 rooms over four chips, 1,024 a chip. A doc-sharded
     ingestor uploads a step's inputs onto the mesh (`BatchIngestor._upload`):
-    the host lane's `PackedBatch` by room, the decoded stream and the
-    rank table whole on every chip. `merge_stream` must take the planes
-    apart where they lie and scatter into them there (no collective, 27
-    planes out, by room), and the step must take that output as it is,
-    gather no state plane and leave every plane of the state where it
-    was."""
+    the host lane's `PackedBatch` by room, the decoded stream (a pair too)
+    and the rank table whole on every chip. `merge_stream` must scatter
+    into the two arrays where they lie (no collective, two arrays out, by
+    room), and the step must take that output as it is, gather no state
+    plane and leave every plane of the state where it was."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
-        BatchEncoder,
         _apply_update_batch_jit,
         init_state,
         scan_tier_plan,
@@ -161,11 +166,10 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     )
     by_room, whole = on(P(AXIS_BATCH)), on(P())
     host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
-    batch = BatchEncoder().batch_from_rows([[]] * rooms, [[]] * rooms, rows, rows)
-    packed = BatchEncoder().batch_packed([[]] * rooms, [[]] * rooms, rows, rows)
+    packed = _pair(rooms, rows)
     merge = _merge_stream_jit.lower(
         jax.tree.map(by_room, packed),
-        jax.tree.map(whole, jax.tree.map(lambda a: a[:lanes], batch)),
+        jax.tree.map(whole, _pair(lanes, rows)),
         host(lanes),
         host(lanes),
         host(),
@@ -173,7 +177,7 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     ).compile()
     assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
     assert {s.spec for s in jax.tree.leaves(merge.output_shardings)} == {P(AXIS_BATCH)}
-    assert [(o.shape, o.dtype) for o in jax.tree.leaves(merge.out_info)] == [(a.shape, a.dtype) for a in batch]
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(merge.out_info)] == [(a.shape, a.dtype) for a in packed]
 
     merged = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
@@ -201,21 +205,21 @@ def test_compact_integrate_step_needs_a_fraction_of_the_dense_steps_memory(one_c
     """The step `apply_bytes` dispatches for a tick of at most 16 rooms:
     16 rooms gathered, integrated, scattered back. Not donated, so the
     state is there twice as in the dense step; the temporaries are those
-    of 16 rooms, not of 1,024."""
-    from ytpu.models.batch_doc import (
-        BatchEncoder,
-        _apply_update_batch_jit,
-        scan_tier_plan,
-    )
+    of 16 rooms, not of 1,024. Both forms are handed the pair and take the
+    planes apart inside themselves, and that costs the device no memory to
+    speak of: their temporaries stay within 1% of what the forms that
+    took 27 planes reported (696,401,920 and 14,161,920 B: PR 41's tree)."""
+    from ytpu.models.batch_doc import _apply_update_batch_jit, scan_tier_plan
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     # the batch is as wide as the step: every slot, or the tick's 16
-    batch = {w: _shapes(BatchEncoder().batch_from_rows([[]] * w, [[]] * w, 4, 4), one_chip) for w in (N_DOCS, COMPACT_WIDTH)}
+    batch = {w: _shapes(_pair(w, 4), one_chip) for w in (N_DOCS, COMPACT_WIDTH)}
     rest = (i32(N_CLIENTS), scan_tier_plan())
     dense = _apply_update_batch_jit.lower(_state(one_chip), batch[N_DOCS], *rest).compile().memory_analysis()
     compact = _apply_update_batch_jit.lower(_state(one_chip), batch[COMPACT_WIDTH], *rest, i32(COMPACT_WIDTH)).compile()
     m = compact.memory_analysis()
     print(f"temp bytes: dense step {dense.temp_size_in_bytes}, compact step {m.temp_size_in_bytes}")
+    assert dense.temp_size_in_bytes <= 1.01 * 696_401_920 and m.temp_size_in_bytes <= 1.01 * 14_161_920
     assert m.alias_size_in_bytes == 0, m  # the roofline counts a state read once, written once
     assert m.output_size_in_bytes == dense.output_size_in_bytes
     assert m.temp_size_in_bytes < dense.temp_size_in_bytes // 16, (m, dense)
@@ -225,8 +229,8 @@ def test_compact_integrate_step_needs_a_fraction_of_the_dense_steps_memory(one_c
 def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     """`yws-rooms-4k-x4`, a tick: 4,096 rooms by room over four chips, the
     host lane's `[16, ...]` `PackedBatch` whole on every chip (`_upload`),
-    `merge_stream` over it and the decoded lanes (nothing of it is laid by
-    room, so the program may hold no collective, and its 27 planes come
+    `merge_stream` over it and the decoded lanes' pair (nothing of it is laid by
+    room, so the program may hold no collective, and its two arrays come
     out whole on every chip), the rank table whole on every chip, `active` a numpy array the call takes
     up. The partitioner must answer the state's gather with each chip's own
     rooms and a sum of the `[16, ...]` pieces, never with a gathered plane,
@@ -234,7 +238,6 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
-        BatchEncoder,
         _apply_update_batch_jit,
         init_state,
         scan_tier_plan,
@@ -249,11 +252,10 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     )
     by_room, whole = on(P(AXIS_BATCH)), on(P())
     host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
-    empty = [[]] * COMPACT_WIDTH
-    batch = BatchEncoder().batch_from_rows(empty, empty, 4, 4)
+    batch = _pair(COMPACT_WIDTH, 4)
     merge = _merge_stream_jit.lower(
-        jax.tree.map(whole, BatchEncoder().batch_packed(empty, empty, 4, 4)),
-        jax.tree.map(whole, jax.tree.map(lambda a: a[:lanes], batch)),
+        jax.tree.map(whole, batch),
+        jax.tree.map(whole, _pair(lanes, 4)),
         host(lanes),
         host(lanes),
         host(),
@@ -286,26 +288,40 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     assert _hbm_bytes(step) < V5E_HBM // 8, m
 
 
-@pytest.mark.parametrize("lanes,max_sections", [(1, 2), (8, 2), (8, None)])
-def test_served_decode_compiles(one_chip, lanes, max_sections):
-    """`decode_updates_v1` over [S, 64] wire lanes with all four lookup
-    tables, as `_merge_fast_lane` calls it."""
+# the window's lane counts at the 4-row bucket, then the benchmark's prefill
+# step: every slot a lane, 6.6 KB of wire a lane, the 512-row bucket
+DECODE_SHAPES = [(1, 64, 4, 16, 2), (8, 64, 4, 16, 2), (8, 64, 4, 16, None), (N_DOCS, 8192, 512, 2048, 2)]
+
+
+@pytest.mark.parametrize("lanes,width,rows,n_steps,max_sections", DECODE_SHAPES)
+def test_served_decode_compiles(one_chip, lanes, width, rows, n_steps, max_sections):
+    """`decode_updates_v1` over [S, L] wire lanes with all four lookup
+    tables, as `_merge_fast_lane` calls it: the pair and the flags out,
+    three buffers, the planes stacked inside the program. The chip lays
+    the dense `[1024, 512, 23]` array out unpadded (the field axis
+    outermost): 48 MB, not the 268 a 23-wide minor axis would pad to."""
     from ytpu.ops.decode_kernel import _decode_updates_v1_jit
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     compiled = _decode_updates_v1_jit.lower(
-        jax.ShapeDtypeStruct((lanes, 64), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((lanes, width), jnp.uint8, sharding=one_chip),
         i32(lanes),
-        max_rows=4,
-        max_dels=4,
-        n_steps=16,
+        max_rows=rows,
+        max_dels=rows,
+        n_steps=n_steps,
         client_table=(i32(N_CLIENTS), i32(N_CLIENTS)),
         max_sections=max_sections,
         key_table=(i32(1), i32(1)),
         client_hash_table=(i32(0), i32(0)),
         primary_root_hash=i32(lanes),
+        packed=True,
     ).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
+    m = compiled.memory_analysis()
+    print(f"decode {lanes} x {width}: temp bytes {m.temp_size_in_bytes}, output bytes {m.output_size_in_bytes}")
+    assert m.temp_size_in_bytes < V5E_HBM // 16
+    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [(lanes, rows, 23), (lanes, rows, 4), (lanes,)]
+    if lanes == N_DOCS:  # what the arrays hold, the flags, and the tiles' padding: no lane padding of the field axis
+        assert m.output_size_in_bytes < 1.05 * 4 * lanes * (rows * 27 + 1)
 
 
 # (lanes, rows = deletes bucket, lane width, the step's width): the decode
@@ -317,41 +333,38 @@ MERGE_SHAPES = [(1, 4, 64, 16), (8, 4, 64, 16), (N_DOCS, 512, 8192, N_DOCS)]
 
 @pytest.mark.parametrize("lanes,rows,width,slots", MERGE_SHAPES)
 def test_served_merge_compiles(one_chip, lanes, rows, width, slots):
-    """`merge_stream` (unpack + rebase + every plane's scatter, one
+    """`merge_stream` (the rebase and the two arrays' scatters, one
     program) as `_merge_fast_lane` calls it: the host lane's `PackedBatch`
-    over the step's `slots`, the decoded stream over `lanes` of them."""
-    from ytpu.models.batch_doc import BatchEncoder
+    over the step's `slots`, the decoder's over `lanes` of them."""
     from ytpu.models.ingest import _merge_stream_jit
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    batch = BatchEncoder().batch_from_rows([[]] * slots, [[]] * slots, rows, rows)
-    stream = jax.tree.map(lambda a: a[:lanes], batch)
-    packed = BatchEncoder().batch_packed([[]] * slots, [[]] * slots, rows, rows)
+    packed = _pair(slots, rows)
     compiled = _merge_stream_jit.lower(
         _shapes(packed, one_chip),
-        _shapes(stream, one_chip),
+        _shapes(_pair(lanes, rows), one_chip),
         i32(lanes),
         i32(lanes),
         i32(),
         width=width,
     ).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
-    # one output per plane of the batch, each over the step's slots
-    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [
-        a.shape for a in jax.tree.leaves(batch)
-    ]
+    m = compiled.memory_analysis()
+    print(f"merge {lanes} lanes into {slots} x {rows}: temp bytes {m.temp_size_in_bytes}")
+    assert m.temp_size_in_bytes < V5E_HBM // 16
+    # two output buffers, the pair over the step's slots
+    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [a.shape for a in packed]
 
 
 @pytest.mark.parametrize("slots,rows", [(16, 4), (16, 512), (N_DOCS, 4)], ids=["tick", "load", "every_slot"])
 def test_served_unpack_compiles(one_chip, slots, rows):
-    """`unpack_batch` as a program of its own, for a step none of whose
-    rooms rode the fast lane: a tick's 16-wide batch at the 4-row bucket,
-    the record cell's load (16 rooms, the 512-row bucket), and every slot
-    (`apply()`, a dense recovery). 27 planes out, and no device memory
-    but the arrays in and out."""
-    from ytpu.models.batch_doc import BatchEncoder, UpdateBatch, unpack_batch_jit
+    """`unpack_batch` as a program of its own, for a caller that reads
+    planes (`BatchEncoder.batch_from_rows`, a test); no served step runs
+    it since PR 42. At a tick's 16-wide batch and the 4-row bucket, the
+    record cell's load (16 rooms, the 512-row bucket), and every slot: 27
+    planes out, and no device memory but the arrays in and out."""
+    from ytpu.models.batch_doc import UpdateBatch, unpack_batch_jit
 
-    packed = BatchEncoder().batch_packed([[]] * slots, [[]] * slots, rows, rows)
+    packed = _pair(slots, rows)
     compiled = unpack_batch_jit.lower(_shapes(packed, one_chip)).compile()
     out = jax.tree.leaves(compiled.out_info)
     assert len(out) == len(UpdateBatch._fields) == 27

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 import pytest
 
+from test_table_cache import _flag_lanes
 from ytpu.core import Doc
 from ytpu.models import ingest
 from ytpu.models.batch_doc import (
@@ -185,8 +186,6 @@ def test_compact_step_equals_the_dense_step_and_the_oracle(both_steps, name):
 def test_a_recovery_step_is_compact_too(both_steps, monkeypatch):
     """The device flags two lanes the host pre-scan had passed: their rooms
     replay through the host lane in a follow-up step, as wide as they are."""
-    import jax.numpy as jnp
-
     from ytpu.ops import decode_kernel as dk
 
     r, rooms, ing = _prefilled(29_000_101)
@@ -195,15 +194,7 @@ def test_a_recovery_step_is_compact_too(both_steps, monkeypatch):
     bad_lanes[[1, 3]] = True
 
     def sabotage(buf, lens, max_rows, max_dels, **kw):
-        stream, flags = real(buf, lens, max_rows, max_dels, **kw)
-        bad = jnp.asarray(bad_lanes)
-        return (
-            stream._replace(
-                valid=stream.valid & ~bad[:, None],
-                del_valid=stream.del_valid & ~bad[:, None],
-            ),
-            jnp.where(bad, flags | dk.FLAG_MALFORMED, flags),
-        )
+        return _flag_lanes(*real(buf, lens, max_rows, max_dels, **kw), bad_lanes)
 
     monkeypatch.setattr(dk, "decode_updates_v1", sabotage)
     del both_steps[:]

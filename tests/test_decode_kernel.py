@@ -6,6 +6,8 @@ device-decoded stream through the XLA integrate path must reproduce the
 host doc byte-for-byte (reference semantics: update.rs:714-749, :433-488).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -25,6 +27,22 @@ from ytpu.ops.decode_kernel import (
     identity_rank,
     pack_updates,
 )
+
+
+# every call the cases below make of the decoder, by the test that made it:
+# the packed entry is held to them at the end of the file
+_CALLS = {}
+_decode_planes = decode_updates_v1
+
+
+def _current_test() -> str:
+    return os.environ.get("PYTEST_CURRENT_TEST", "").split("::")[-1].split(" ")[0]
+
+
+def decode_updates_v1(*args, **kw):  # noqa: F811
+    out = _decode_planes(*args, **kw)
+    _CALLS.setdefault(_current_test(), []).append((args, kw, out))
+    return out
 
 
 def _edit_log(ops, client_id=1):
@@ -542,3 +560,42 @@ def test_map_tenant_object_values_ride_fast_lane():
         ing.state, 0, ing.payloads, ing.enc.keys, interner=ing.enc.interner
     )
     assert tree["map"]["config"] == {"theme": "dark", "size": 14}
+
+
+# the cases above that call the decoder themselves (the two `apply_bytes`
+# cases go through the served entry already)
+DECODING_CASES = [
+    test_insert_delete_field_parity, test_unicode_utf16_lengths, test_end_to_end_replay_matches_host,
+    test_merged_update_multi_block, test_multi_client_flagged_informational, test_content_any_scalars_decode_clean,
+    test_recursive_any_flags_unsupported, test_map_parent_sub_without_table_flags_unknown_key,
+    test_map_parent_sub_with_key_table_decodes, test_big_client_id_flags, test_truncated_update_flags_malformed,
+    test_row_overflow_flags, test_mixed_batch_bad_lane_emits_nothing, test_gc_rows_decode,
+    test_huge_string_length_varint_flags_malformed, test_content_type_nested_types_decode,
+    test_weak_type_flags_unsupported, test_content_move_rows_decode, test_flat_map_any_values_decode_clean,
+]
+
+
+@pytest.mark.parametrize("case", DECODING_CASES, ids=lambda fn: fn.__name__[len("test_"):])
+def test_the_served_entry_hands_back_the_same_batch_as_a_pair(case):
+    """`decode_updates_v1(..., packed=True)`, the entry the served step
+    calls: three output buffers (the pair and the flags), and the pair taken
+    apart is the planes entry's 27 planes bit for bit, clean lanes and
+    flagged ones alike, over every decode the case makes."""
+    import jax
+
+    from ytpu.models.batch_doc import PackedBatch, unpack_batch_jit
+
+    made = case.__name__
+    if made not in _CALLS:  # run on its own: the case's calls are made here, under this test's name
+        case()
+        made = _current_test()
+    assert _CALLS[made]
+    for args, kw, (planes, flags) in _CALLS[made]:
+        pair, pair_flags = _decode_planes(*args, **kw, packed=True)
+        assert type(pair) is PackedBatch and len(jax.tree.leaves((pair, pair_flags))) == 3
+        U, R = planes.valid.shape[-1], planes.del_valid.shape[-1]
+        assert pair.rows.shape == (len(flags), U, 23) and pair.dels.shape == (len(flags), R, 4)
+        assert np.asarray(pair_flags).tobytes() == np.asarray(flags).tobytes()
+        for name, got, want in zip(planes._fields, unpack_batch_jit(pair), planes):
+            got, want = np.asarray(got), np.asarray(want)
+            assert got.dtype == want.dtype and got.shape == want.shape and got.tobytes() == want.tobytes(), name

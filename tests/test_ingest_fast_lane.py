@@ -6,9 +6,11 @@ arrival, host-only content) take the exact host lane. Oracle: a host
 `Doc` replaying the same payloads, plus `apply()` equivalence.
 """
 
+import jax
 import numpy as np
 import pytest
 
+from test_table_cache import _flag_lanes
 from ytpu.core import Doc
 from ytpu.models.batch_doc import get_string
 from ytpu.models.ingest import BatchIngestor
@@ -362,8 +364,6 @@ def test_fast_lane_flag_recovery(monkeypatch):
     """If the device decoder flags a lane the host pre-scan validated, the
     ingestor must rewind the mirror SV and replay that doc through the
     host lane — converging instead of raising (ADVICE r1, medium)."""
-    import jax.numpy as jnp
-
     from ytpu.ops import decode_kernel as dk
 
     real = dk.decode_updates_v1
@@ -373,11 +373,7 @@ def test_fast_lane_flag_recovery(monkeypatch):
         stream, flags = real(buf, lens, max_rows, max_dels, **kw)
         if hits["n"] == 0:
             hits["n"] = 1
-            flags = flags | jnp.full_like(flags, dk.FLAG_MALFORMED)
-            stream = stream._replace(
-                valid=jnp.zeros_like(stream.valid),
-                del_valid=jnp.zeros_like(stream.del_valid),
-            )
+            stream, flags = _flag_lanes(stream, flags)
         return stream, flags
 
     monkeypatch.setattr(dk, "decode_updates_v1", sabotage)
@@ -650,12 +646,12 @@ def test_merge_stream_equals_numpy_reference(monkeypatch, case, ingest_mode):
     lanes = sorted(MERGE_CASES[case])  # the fast lanes' slots, in slot order
     assert ing.fast_docs - fast_before == len(lanes)
     ((batch, stream, idx, prefix, base), kw, merged), = calls
-    # the host lane's batch is handed over as the two arrays the host
-    # shipped; the program takes its planes apart, as here
+    # the host lane's batch, the decoded stream and what the merge makes of
+    # them all cross as the two packed arrays; compared as planes
     from ytpu.models.batch_doc import PackedBatch, unpack_batch_jit
 
-    assert isinstance(batch, PackedBatch)
-    batch = unpack_batch_jit(batch)
+    assert all(type(b) is PackedBatch and len(jax.tree.leaves(b)) == 2 for b in (batch, stream, merged))
+    batch, stream, merged = (unpack_batch_jit(b) for b in (batch, stream, merged))
     # what the step's payloads say the operands are
     keep = [MERGE_CASES[case][d] != DEL for d in lanes]
     kept_lens = [len(plan[d]) if k else 0 for d, k in zip(lanes, keep)]

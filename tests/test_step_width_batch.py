@@ -4,11 +4,12 @@ A step is as wide as the rooms that carry a payload (`_active_slots`: 16, 32,
 ... or every slot), and so is the batch its two lanes meet in: the host lane
 plans the rooms with a payload alone, `BatchEncoder.batch_packed` pads two
 int32 arrays to `[W, U, 23]` and `[W, R, 4]` (a `PackedBatch`), two uploads
-carry them and `unpack_batch` takes the 27 planes apart on the device: inside
-`merge_stream` where a room rode the fast lane, as one small program of its
-own (`jit_unpack_batch`) where none did, so that the integrate step is always
-handed planes. What a program sees is, row for row, what the parent built
-over every slot, gathered at `active`:
+carry them, and since PR 42 the pair is what crosses every program boundary:
+`merge_stream` lays the decoder's pair over it and hands on a pair, a step
+without a fast lane hands the upload (or the kept batch) straight to the
+integrate program, and `unpack_batch` takes the 27 planes apart inside that
+program, which so has one form a bucket. What a program works on is, row for
+row, what the parent built over every slot, gathered at `active`:
 
 (a) the packed form, unpacked by the device program, is bit-equal to the 27
     planes the parent padded (`test_batch_cache._parent_planes` is that code);
@@ -32,7 +33,7 @@ import pytest
 
 from benchmark import grammar as g
 from benchmark.generators import record_mix
-from test_batch_cache import _Spy, _parent_planes, _wrong_leaves
+from test_batch_cache import _Spy, _parent_merged, _parent_planes, _wrong_leaves
 from test_record_store import _canonical, _clean, _device_array
 from ytpu.core import Doc
 from ytpu.core.state_vector import StateVector
@@ -168,7 +169,7 @@ def served(request):
         ing.enc.interner.intern(c)
     sessions = {k: server.connect_frames(g.room_name(k))[0] for k in range(N_ROOMS)}
     monkeypatch = pytest.MonkeyPatch()
-    real_merge, real_apply_bytes = ingest_mod._merge_stream_jit, ing.apply_bytes
+    real_apply_bytes = ing.apply_bytes
     steps = []
     try:
         spy = _Spy(monkeypatch, ing)
@@ -189,11 +190,9 @@ def served(request):
             if step["merged"]:
                 (stream, idx, prefix, base), kw = spy.merge_args[-1]
                 step["merge_idx"] = np.asarray(idx)
-            if step["merged"] and (host_lane or tag[0] in ("prefill", "text")):
-                # what the parent's merge made of its dense batch (a program a shape: not in every step)
+                # what the parent's merge made of its dense batch: the decoder's lanes at their rooms' slots
                 fast = np.asarray([d for d in live if d not in host_lane], dtype=np.int32)
-                step["parent_merged"] = [np.asarray(a) for a in real_merge(
-                    UpdateBatch(*want), stream, fast, prefix, base, **kw)]
+                step["parent_merged"] = _parent_merged(want, unpack_batch_jit(stream), fast, prefix, base, kw["width"])
             steps.append(step)
             return out
 
@@ -220,7 +219,7 @@ def served(request):
 
 
 COUNTERS = ("ingest.compact_steps", "ingest.dense_steps", "ingest.batch_builds", "ingest.batch_reuses",
-            "ingest.fast_recoveries")
+            "ingest.fast_recoveries", "ingest.enqueue_outputs")
 
 
 def _gathered(planes, active):
@@ -260,11 +259,11 @@ def test_every_step_hands_merge_stream_the_parents_batch_gathered_at_active(serv
             assert set(s["live"]) <= set(active.tolist()), n
         fast = [d for d in s["live"] if d not in s["host_lane"]]
         assert len(s["merged"]) == bool(fast), n
-        assert isinstance(s["applied"], UpdateBatch), n  # the integrate program has one form
-        if not fast:  # no merge: the host lane's batch, taken apart, goes to the step as it is
+        assert type(s["applied"]) is PackedBatch, n  # the integrate program has one form: it is handed the pair
+        if not fast:  # no merge: the host lane's upload, or the kept batch, goes to the step as it is
             assert _wrong_leaves(s["applied"], _gathered(s["want"], active)) == [], n
             continue
-        assert isinstance(s["merged"][0], PackedBatch), n
+        assert type(s["merged"][0]) is PackedBatch, n
         assert _wrong_leaves(s["merged"][0], _gathered(s["want"], active)) == [], n
         # the decoded lanes land at their rooms' rows of the step
         at = fast if active is None else [active.tolist().index(d) for d in fast]
@@ -280,6 +279,7 @@ def test_every_step_integrates_the_parents_merged_batch_gathered_at_active(serve
         assert {a.shape[0] for a in s["applied"]} == {N_ROOMS if s["active"] is None else len(s["active"])}, n
         assert _wrong_leaves(s["applied"], _gathered(s["parent_merged"], s["active"])) == [], n
     assert sum(1 for s in steps if s["parent_merged"] is not None and s["host_lane"]) >= 5
+    assert sum(1 for s in steps if s["parent_merged"] is not None) == sum(1 for s in steps if s["merged"]) >= 8
 
 
 @EITHER
@@ -293,12 +293,14 @@ def test_plan_doc_runs_for_the_host_lane_rooms_alone(served):
 
 
 @EITHER
-def test_a_build_is_two_uploads_and_at_most_one_enqueue(served):
+def test_a_build_is_two_uploads_and_no_enqueue(served):
     """What the stage `ingest.plan.h2d` counts is the two packed arrays at
     the step's width (whole on every device of a doc-sharded server in a
     compact step, by room in a dense one), and a step that is handed a
-    kept batch sends nothing. `merge_stream` takes the planes apart; a step
-    without a fast lane enqueues the one small program that does."""
+    kept batch sends nothing. No served step enqueues `jit_unpack_batch`:
+    the pair goes to the integrate program as it is, so a step's programs
+    hand back 29 buffers (the state's) where no room rode the fast lane
+    and 35 where one did (the gather's 1, the decoder's 3, the merge's 2)."""
     server, _, steps, counted, recorded = served
     copies = len(jax.devices()) if server.ingestor._on_every_chip is not None else 1
     sent, kept = 0, set()
@@ -315,8 +317,11 @@ def test_a_build_is_two_uploads_and_at_most_one_enqueue(served):
     assert recorded["ingest.plan.h2d"]["h2d_bytes"] == sent
     assert builds == sum(1 for s in steps if s["host_lane"]) + len(kept)
     no_fast_lane = sum(1 for s in steps if not s["merged"])
-    assert recorded["ingest.plan.unpack"]["calls"] == no_fast_lane >= 8
+    assert "ingest.plan.unpack" not in recorded and not hasattr(ingest_mod, "unpack_batch_jit")
+    assert all(type(s["applied"]) is PackedBatch for s in steps) and no_fast_lane >= 8
     assert recorded["ingest.merge.scatter"]["calls"] == len(steps) - no_fast_lane
+    assert counted["ingest.enqueue_outputs"] == recorded["ingest.enqueue_outputs"]["value"]
+    assert counted["ingest.enqueue_outputs"] == 29 * no_fast_lane + 35 * (len(steps) - no_fast_lane)
     assert recorded["ingest.plan.h2d"]["calls"] == recorded["ingest.plan.host_rows"]["calls"] == len(steps)
 
 
@@ -353,3 +358,32 @@ def test_the_served_rooms_equal_the_oracle(served):
         assert _device_array(server, name, root) == array, k
         assert dict(server.device_state_vector(name).clocks) == sv == dict(server.ingestor.svs[k].clocks), k
         assert _canonical(diff, root) == (array, sv, _canonical(want.encode_state_as_update_v1(), root)[2]), k
+
+
+# --- the counter's reader ---------------------------------------------------------
+
+
+def test_the_benchmark_reads_the_output_buffers_a_step():
+    """`enqueue_outputs_per_step.flood`: the entry is appended to
+    `per_layer` and names all four cells; its reader divides the window's
+    count by the window's steps, from the counter deltas or the phase
+    recorder's copy, and has nothing to say of a program without the counter
+    (the parent)."""
+    from benchmark.run import applies, load_reader
+    from benchmark.window import Window
+
+    name = "enqueue_outputs_per_step.flood"
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert bench["per_layer"][-1] == {
+        "name": name, "unit": "buffers/step", "better": "lower", "source": "program_counter",
+        "layer": "ingest merge", "moves": "updates_per_s", "workloads": cells,
+    }
+    assert all(applies(bench["per_layer"][-1], c, {"updates_per_s", "setup_s"}) for c in cells)
+    read = load_reader("layers", name).read
+    window = lambda **kw: Window(rec=None, t_open=0.0, t_close=30.0, setup_s=1.0,
+                                 dispatch_spans=[(float(i), i + 0.5, 1) for i in range(10)], **kw)
+    assert read(window(counters={"ingest.enqueue_outputs": 350})) == 35.0
+    assert read(window(phases={"ingest.enqueue_outputs": {"value": 320.0}})) == 32.0
+    assert read(window()) is None and read(window(phases={"ingest.merge": {"calls": 10}})) is None

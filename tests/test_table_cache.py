@@ -18,7 +18,7 @@ import pytest
 
 from ytpu.core import Doc
 from ytpu.models import ingest as ingest_mod
-from ytpu.models.batch_doc import get_string
+from ytpu.models.batch_doc import PackedBatch, get_string
 from ytpu.models.ingest import BatchIngestor
 from ytpu.ops import decode_kernel as dk
 from ytpu.utils import metrics
@@ -86,6 +86,19 @@ def _bit_equal(handed, want) -> bool:
     )
 
 
+def _flag_lanes(stream, flags, bad=None):
+    """The served decoder's `(PackedBatch, flags)` as the device hands them
+    back when it flags the lanes `bad` ([S] bool; every lane by default): the
+    flag set, and none of their rows or ranges valid (the last column of
+    either array)."""
+    import jax.numpy as jnp
+
+    bad = jnp.ones(flags.shape, bool) if bad is None else jnp.asarray(bad)
+    keep = (~bad).astype(jnp.int32)[:, None]
+    stream = PackedBatch(stream.rows.at[..., -1].multiply(keep), stream.dels.at[..., -1].multiply(keep))
+    return stream, jnp.where(bad, flags | dk.FLAG_MALFORMED, flags)
+
+
 class _Spy:
     """What `decode_updates_v1` and `apply_update_batch` were handed, call
     by call, beside what the parent would have built at that moment."""
@@ -104,13 +117,7 @@ class _Spy:
             stream, flags = real_decode(buf, lens, max_rows, max_dels, **kw)
             self.decodes += 1
             if self.decodes == flag_decode:  # the device flags every lane of this call
-                import jax.numpy as jnp
-
-                flags = flags | jnp.full_like(flags, dk.FLAG_MALFORMED)
-                stream = stream._replace(
-                    valid=jnp.zeros_like(stream.valid),
-                    del_valid=jnp.zeros_like(stream.del_valid),
-                )
+                stream, flags = _flag_lanes(stream, flags)
             return stream, flags
 
         def apply(state, batch, client_rank, *rest):

@@ -61,6 +61,7 @@ __all__ = [
     "UpdateBatch",
     "PackedBatch",
     "unpack_batch",
+    "pack_batch",
     "unpack_batch_jit",
     "init_state",
     "CompactionPolicy",
@@ -184,11 +185,13 @@ _PAD_ROW[[10, 12, 14, 15, 18, 21]] = -1  # key, p_client, p_root, mv_sc, mv_ec, 
 
 
 class PackedBatch(NamedTuple):
-    """An `UpdateBatch` as the host builds and ships it
-    (`BatchEncoder.batch_packed`): two contiguous int32 arrays, so two
-    uploads, each field a column and each array's last column its
-    `valid` plane (1 a real entry, 0 padding). `unpack_batch` takes it
-    apart where it lands."""
+    """An `UpdateBatch` as it crosses a program boundary: two contiguous
+    int32 arrays, so two buffers where the planes are 27, each field a
+    column and each array's last column its `valid` plane (1 a real
+    entry, 0 padding). The host builds and ships it
+    (`BatchEncoder.batch_packed`), the served decoder and `merge_stream`
+    hand it on, and the integrate program takes it apart
+    (`unpack_batch`): the planes exist inside a program only."""
 
     rows: jax.Array  # [*, U, 23] client .. mv_prio, valid
     dels: jax.Array  # [*, R, 4] del_client, del_start, del_end, del_valid
@@ -197,8 +200,8 @@ class PackedBatch(NamedTuple):
 def unpack_batch(batch) -> UpdateBatch:
     """The 27 planes of a `PackedBatch`, sliced out on the device(s) its
     arrays are on and laid out as they are: inside the program that is
-    handed it (`ingest.merge_stream`: no enqueue of its own), or as the
-    small program `unpack_batch_jit` where no such program runs. An
+    handed it (`apply_update_batch`: no enqueue of its own), or as the
+    small program `unpack_batch_jit` for a caller that wants planes. An
     `UpdateBatch` passes through."""
     if isinstance(batch, UpdateBatch):
         return batch
@@ -211,7 +214,17 @@ def unpack_batch(batch) -> UpdateBatch:
     )
 
 
-# for a caller that has no program to take the planes apart in
+def pack_batch(batch: UpdateBatch) -> PackedBatch:
+    """`unpack_batch`'s inverse: the planes of an `UpdateBatch` stacked
+    into the two arrays, inside the program that made them (the served
+    decoder)."""
+    return PackedBatch(
+        jnp.stack([*batch[:22], batch.valid.astype(I32)], axis=-1),
+        jnp.stack([*batch[23:26], batch.del_valid.astype(I32)], axis=-1),
+    )
+
+
+# for a caller that wants planes and has no program to take them apart in
 unpack_batch_jit = jax.jit(unpack_batch)
 
 
@@ -1550,7 +1563,12 @@ def apply_update_batch(
     caller builds it no wider than the step (`BatchIngestor.apply_bytes`).
     One program either way, and the state is not donated: every plane is
     still read once and written once (PERF.md section 6, PR 29).
+
+    `batch` may be a `PackedBatch`, taken apart here: the served path
+    hands the pair from every call site, so a process builds one form of
+    this program a bucket and no step hands 27 buffers across.
     """
+    batch = unpack_batch(batch)
     step = jax.vmap(
         lambda s, b, cr: _apply_update_one_doc(s, b, cr, scan_plan),
         in_axes=(0, 0, None),
@@ -3481,7 +3499,7 @@ def apply_update_batch(
             "integrate.xla_batch",
             (
                 state.blocks.client.shape,
-                batch.client.shape,
+                batch[0].shape,  # `client` of planes, `rows` of a pair
                 scan_plan,
                 None if active is None else active.shape[0],
             ),

@@ -39,8 +39,6 @@ from ytpu.models.batch_doc import (
     UpdateBatch,
     apply_update_batch,
     init_state,
-    unpack_batch,
-    unpack_batch_jit,
 )
 from ytpu.ops.decode_kernel import (
     ChunkedWirePayloads,
@@ -91,12 +89,20 @@ _SLOW_REASONS = (
 )
 
 
+# columns of `PackedBatch.rows` the merge reads: the planes' order
+_REF = UpdateBatch._fields.index("content_ref")
+_VALID = UpdateBatch._fields.index("valid")
+
+
 @jax.named_scope("merge_stream")
-def merge_stream(batch, stream, idx, prefix, base, width: int):
+def merge_stream(batch, stream, idx, prefix, base, width: int) -> PackedBatch:
     """The fast lanes' decoded `stream` ([S, ...]) laid over the host
-    lane's `batch` ([W, ...], the step's width; a `PackedBatch` as the
-    host shipped it, taken apart here) at its rows `idx` ([S] i32: where
-    each lane's slot sits in the step, `_step_rows`).
+    lane's `batch` ([W, ...], the step's width) at its rows `idx` ([S]
+    i32: where each lane's slot sits in the step, `_step_rows`). Both
+    arrive as a `PackedBatch` (the host's upload or the kept batch, the
+    served decoder's output) and one leaves: two scatters on the room
+    axis over the two arrays, two output buffers, and the planes are
+    never made (`content_ref` and `valid` are two columns of `rows`).
 
     String rows leave the decoder with refs into the padded lane matrix
     (``s * width + start``); the step retained only the string-bearing
@@ -104,16 +110,17 @@ def merge_stream(batch, stream, idx, prefix, base, width: int):
     that chunk: lane s's bytes start at ``prefix[s]`` ([S] i32) of the
     chunk at `base` (0-d i32), and a wire ref is stored as ``-2 - ref``.
     `prefix` and `base` differ every step: operands, never statics."""
-    batch = unpack_batch(batch)
+    rows, dels = stream
+    ref = rows[..., _REF]
     lane = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None]
-    compact_ref = prefix[:, None] + (stream.content_ref - lane * width)
-    is_str_ref = stream.valid & (stream.content_ref >= 0)
-    stream = stream._replace(
-        content_ref=jnp.where(
-            is_str_ref, -2 - base - compact_ref, stream.content_ref
-        )
+    compact_ref = prefix[:, None] + (ref - lane * width)
+    is_str_ref = (rows[..., _VALID] != 0) & (ref >= 0)
+    rows = rows.at[..., _REF].set(
+        jnp.where(is_str_ref, -2 - base - compact_ref, ref)
     )
-    return jax.tree.map(lambda full, fast: full.at[idx].set(fast), batch, stream)
+    return PackedBatch(
+        batch.rows.at[idx].set(rows), batch.dels.at[idx].set(dels)
+    )
 
 
 # The merge's device-side glue is two programs of its own, not eager ops:
@@ -252,6 +259,8 @@ class BatchIngestor:
             r: metrics.counter("ingest.slow." + r) for r in _SLOW_REASONS
         }
         self._m_host_rows = metrics.counter("ingest.host_rows")
+        # output buffers of the programs a step enqueued (`_count_outputs`)
+        self._m_enqueue_outputs = metrics.counter("ingest.enqueue_outputs")
 
     def _reset_tables(self) -> None:
         """The device lookup tables' sources, empty, and nothing built of
@@ -418,11 +427,22 @@ class BatchIngestor:
         )
         return copies * sum(a.nbytes for a in jax.tree.leaves(host))
 
-    def _batch(self, all_rows, all_dels, by_doc: bool = True) -> UpdateBatch:
-        """`BatchEncoder.batch_from_rows` where the state is: the two
-        packed arrays uploaded (`_upload`), then `unpack_batch_jit`."""
-        packed = self.enc.batch_packed(all_rows, all_dels)
-        return unpack_batch_jit(self._upload(packed, by_doc))
+    def _batch(self, all_rows, all_dels, by_doc: bool = True) -> PackedBatch:
+        """`BatchEncoder.batch_packed` where the state is: the two packed
+        arrays uploaded (`_upload`), as the integrate program takes them."""
+        return self._upload(self.enc.batch_packed(all_rows, all_dels), by_doc)
+
+    def _count_outputs(self, *outs) -> None:
+        """`ingest.enqueue_outputs`: one count an output buffer (a leaf of
+        what the call returned) of the programs a step enqueued. What an
+        enqueue costs the host goes with them (PERF.md section 6, PR 42):
+        35 a step that merges (the gather's 1, the decoder's 3, the
+        merge's 2, the state's 29), an array over several chips one."""
+        from ytpu.utils.phases import phases
+
+        n = len(jax.tree.leaves(outs))
+        self._m_enqueue_outputs.inc(n)
+        phases.add_value(self._m_enqueue_outputs.name, n)
 
     def _decode_tables(self) -> dict:
         """`decode_updates_v1`'s tables of every interned client, key and
@@ -931,10 +951,12 @@ class BatchIngestor:
 
         Host stages (docs/observability.md, "Inside a dispatch"):
         `ingest.apply` ⊃ `ingest.plan` (⊃ `.prescan` (⊃ `.decode_host`, one
-        a host-lane payload), `.host_rows`, `.h2d`, `.unpack` (a step with
-        no fast lane)), `ingest.merge` (the uploads, then one enqueue each
-        under `.gather`, `decode.v1`, `.scatter`), `ingest.rank_table`,
-        `integrate.xla_batch`, `ingest.flags`, `ingest.recover`.
+        a host-lane payload), `.host_rows`, `.h2d`), `ingest.merge` (the
+        uploads, then one enqueue each under `.gather`, `decode.v1`,
+        `.scatter`), `ingest.rank_table`, `integrate.xla_batch`,
+        `ingest.flags`, `ingest.recover`. The batch crosses every program
+        boundary as a `PackedBatch`: the upload or the kept batch, the
+        decoder's output, the merge's, the integrate program's operand.
         """
         if len(payloads) != self.n_docs:
             raise ValueError(f"expected {self.n_docs} payload slots")
@@ -1058,13 +1080,6 @@ class BatchIngestor:
                 took = self._m_batch_builds if built else self._m_batch_reuses
                 took.inc()
                 phases.add_value(took.name, 1)  # the recorder's: a window's delta
-                if not fast_idx:
-                    # `merge_stream` takes the planes apart where a room
-                    # rides the fast lane; where none does, one small
-                    # program (`jit_unpack_batch`) does, so that the
-                    # integrate program has one form
-                    with phases.span("ingest.plan.unpack"):
-                        batch = unpack_batch_jit(batch)
             self._m_fast.inc(len(fast_idx))
             self._m_slow.inc(len(slow_updates))
             took = self._m_dense if active is None else self._m_compact
@@ -1087,10 +1102,13 @@ class BatchIngestor:
                 # after the prescan has interned what this step brought
                 client_rank = self._client_rank()
             # `active` rides up with the call, as `merge_stream`'s `idx`
-            # does; the batch is `[len(active), ...]` already
+            # does; the batch is `[len(active), ...]` already, and a pair
+            # whether a merge made it or the host lane's upload goes
+            # straight in: one form of the program a bucket
             self.state = apply_update_batch(
                 self.state, batch, client_rank, active
             )
+            self._count_outputs(self.state)
             if flags is not None:
                 # `_fast_eligible` proved these lanes decode clean, and flagged
                 # lanes integrate nothing (their rows are marked invalid), so a
@@ -1150,6 +1168,7 @@ class BatchIngestor:
             self._client_rank(),
             active,
         )
+        self._count_outputs(self.state)
 
     def _merge_fast_lane(
         self,
@@ -1169,7 +1188,8 @@ class BatchIngestor:
         After the uploads, at most three device programs: the raw lanes'
         gather, `decode_updates_v1`, `merge_stream`. Each is keyed by what
         keys the decode family (S, the wire bucket, L, `n_rows`, `n_dels`)
-        and by nothing else."""
+        and by nothing else. `batch` comes and goes as a `PackedBatch`,
+        and so does the decoded stream between the two programs."""
         from ytpu.ops.decode_kernel import decode_updates_v1, pack_updates
         from ytpu.utils.phases import phases
 
@@ -1266,11 +1286,12 @@ class BatchIngestor:
                 n_steps=n_steps,
                 max_sections=max_sections,
                 primary_root_hash=dev_prim_hash,
+                packed=True,
                 **tables,
             )
             with phases.span("ingest.merge.scatter"):
                 # one enqueue (`jit_merge_stream`: rebase + the scatter
-                # of every plane); idx, prefix and base ride up with it
+                # of both arrays); idx, prefix and base ride up with it
                 merged = _merge_stream_jit(
                     batch,
                     stream,
@@ -1279,4 +1300,5 @@ class BatchIngestor:
                     np.int32(base),
                     width=L,
                 )
+            self._count_outputs(dev_buf if raw else (), stream, flags, merged)
         return merged, flags, (base if keep.any() else None)

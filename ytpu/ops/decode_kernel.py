@@ -72,7 +72,7 @@ from ytpu.core.content import (
     CONTENT_STRING,
     CONTENT_TYPE,
 )
-from ytpu.models.batch_doc import UpdateBatch
+from ytpu.models.batch_doc import UpdateBatch, pack_batch
 
 __all__ = [
     "pack_updates",
@@ -388,12 +388,19 @@ def decode_updates_v1(
     key_table: Optional[Tuple[jax.Array, jax.Array]] = None,
     client_hash_table: Optional[Tuple[jax.Array, jax.Array]] = None,
     primary_root_hash: Optional[jax.Array] = None,
+    packed: bool = False,
 ) -> Tuple[UpdateBatch, jax.Array]:
     """Decode S updates into an ``[S, U] / [S, R]`` UpdateBatch stream.
 
     Returns ``(stream, flags)``; lanes with ``flags & FLAG_ERRORS`` decoded
     incompletely and must be re-decoded on host (their emitted rows are
     marked invalid so a mixed batch stays safe to apply).
+
+    ``packed`` (a static) hands the stream back as a `PackedBatch`, the
+    27 planes stacked inside the program: three output buffers with the
+    flags, not 28. The served step asks for it (`ingest._merge_fast_lane`);
+    a caller that traces this body into a program of its own, or reads
+    planes, does not.
 
     ``client_table=(sorted_ids, perm)`` maps raw client ids to interned
     indices on device (``perm[j]`` is the interned index of ``sorted_ids
@@ -1039,10 +1046,11 @@ def decode_updates_v1(
     regs, rows, dels = jax.lax.fori_loop(0, T, step, init_carry())
     flags = regs["flags"] | jnp.where(regs["st"] != ST_DONE, FLAG_MALFORMED, 0)
 
-    return _resolve_and_pack(
+    stream, flags = _resolve_and_pack(
         rows, dels, flags, client_table, key_table, client_hash_table,
         primary_root_hash,
     )
+    return (pack_batch(stream) if packed else stream), flags
 
 
 def _resolve_and_pack(
@@ -1542,7 +1550,9 @@ class ChunkedWirePayloads:
 _decode_updates_v1_impl = decode_updates_v1
 _decode_updates_v1_jit = partial(
     jax.jit,
-    static_argnames=("max_rows", "max_dels", "n_steps", "max_sections"),
+    static_argnames=(
+        "max_rows", "max_dels", "n_steps", "max_sections", "packed",
+    ),
 )(_decode_updates_v1_impl)
 
 
@@ -1557,6 +1567,7 @@ def decode_updates_v1(
     key_table=None,
     client_hash_table=None,
     primary_root_hash=None,
+    packed=False,
 ):
     from ytpu.utils.phases import NULL_SPAN, phases, program_memory
     from ytpu.utils.progbudget import tick
@@ -1577,10 +1588,11 @@ def decode_updates_v1(
             "decode.v1",
             (buf.shape, max_rows, max_dels, n_steps, max_sections,
              client_table is not None, key_table is not None,
-             client_hash_table is not None, primary_root_hash is not None),
+             client_hash_table is not None, primary_root_hash is not None,
+             packed),
             axes=("buf", "max_rows", "max_dels", "n_steps",
                   "max_sections", "client_table", "key_table",
-                  "client_hash_table", "primary_root_hash"),
+                  "client_hash_table", "primary_root_hash", "packed"),
             memory=program_memory(
                 _decode_updates_v1_jit,
                 buf,
@@ -1593,6 +1605,7 @@ def decode_updates_v1(
                 key_table=key_table,
                 client_hash_table=client_hash_table,
                 primary_root_hash=primary_root_hash,
+                packed=packed,
             ),
         )
     else:
@@ -1609,6 +1622,7 @@ def decode_updates_v1(
             key_table=key_table,
             client_hash_table=client_hash_table,
             primary_root_hash=primary_root_hash,
+            packed=packed,
         )
 
 
