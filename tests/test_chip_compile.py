@@ -288,6 +288,69 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     assert _hbm_bytes(step) < V5E_HBM // 8, m
 
 
+def _compact_rooms_operands(place):
+    """`compact_rooms`' host operands as `BatchIngestor._compact` uploads
+    them: the call's slots, their mask, the payload store's length."""
+    from ytpu.models.ingest import COMPACT_ROOMS_PER_CALL as k
+
+    return (
+        place(jnp.zeros((k,), jnp.int32)),
+        place(jnp.zeros((k,), bool)),
+        place(jnp.zeros((), jnp.int32)),
+    )
+
+
+def test_served_compaction_fits_one_v5e(one_chip):
+    """`compact_rooms` as `apply_bytes` enqueues it when a room nears its
+    capacity (PR 43): two rooms gathered (the room and an idle slot behind
+    a mask, or two rooms due at once), squashed, collected, defragmented
+    and scattered back. Not donated, as the integrate step; its
+    temporaries are those of two rooms, and the report the host re-homes
+    strings from is `[2, 4096, 6]`."""
+    from ytpu.ops.compaction import REHOME_FIELDS, compact_rooms
+
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    compiled = compact_rooms.lower(_state(one_chip), *_compact_rooms_operands(place)).compile()
+    m = compiled.memory_analysis()
+    print(f"compact_rooms: temp bytes {m.temp_size_in_bytes}, output bytes {m.output_size_in_bytes}")
+    assert m.alias_size_in_bytes == 0, m
+    report = jax.tree.leaves(compiled.out_info)[-2]
+    assert report.shape == (2, CAPACITY, len(REHOME_FIELDS))
+    assert m.temp_size_in_bytes < V5E_HBM // 64, m
+    assert _hbm_bytes(compiled) < V5E_HBM // 4, m
+
+
+def test_doc_sharded_compaction_moves_no_plane_between_chips(topo):
+    """`yws-rooms-4k-x4`'s server compacts a room the same way: the state
+    by room over four chips, the operands whole on every chip. As in the
+    compact integrate step, what crosses is the gathered rooms' pieces,
+    and every plane stays where it lies."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ytpu.models.batch_doc import init_state
+    from ytpu.ops.compaction import compact_rooms
+    from ytpu.parallel.mesh import AXIS_BATCH
+
+    mesh = Mesh(np.array(topo.devices), (AXIS_BATCH,))
+    on = lambda spec: lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
+    )
+    by_room, whole = on(P(AXIS_BATCH)), on(P())
+    state = jax.tree.map(by_room, jax.eval_shape(lambda: init_state(4 * N_DOCS, CAPACITY)))
+    compiled = compact_rooms.lower(state, *_compact_rooms_operands(whole)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"all-(gather|to-all)|collective-permute|reduce-scatter", text)
+    for ln in [ln for ln in text.splitlines() if re.search(r"\ball-reduce(-start)?\(", ln)]:
+        result = ln.split(" all-reduce", 1)[0]
+        for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+            assert np.prod([int(d) for d in dims.split(",")]) <= 2 * 32 * CAPACITY, ln[:200]  # two rooms' stacked planes
+    for out in jax.tree.leaves(compiled.output_shardings[0]):
+        assert out.spec[0] == AXIS_BATCH, out
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 0, m
+    assert _hbm_bytes(compiled) < V5E_HBM // 8, m
+
+
 # the window's lane counts at the 4-row bucket, then the benchmark's prefill
 # step: every slot a lane, 6.6 KB of wire a lane, the 512-row bucket
 DECODE_SHAPES = [(1, 64, 4, 16, 2), (8, 64, 4, 16, 2), (8, 64, 4, 16, None), (N_DOCS, 8192, 512, 2048, 2)]
@@ -425,8 +488,9 @@ def test_served_diff_pack_compiles_donated(one_chip, sub, rows):
 
 
 def test_batch_compaction_compiles_donated(one_chip):
-    """`compact_state` (donated): not on the served path today, but the only
-    way a full slot gets room back."""
+    """`compact_state` (donated, every slot at once): not on the served
+    path, which compacts the rooms that are due (`compact_rooms`, above);
+    tests are its callers."""
     from ytpu.ops.compaction import _compact_state_jit
 
     compiled = _compact_state_jit.lower(_state(one_chip)).compile()

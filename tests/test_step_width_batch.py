@@ -364,8 +364,8 @@ def test_the_served_rooms_equal_the_oracle(served):
 
 
 def test_the_benchmark_reads_the_output_buffers_a_step():
-    """`enqueue_outputs_per_step.flood`: the entry is appended to
-    `per_layer` and names all four cells; its reader divides the window's
+    """`enqueue_outputs_per_step.flood`: the entry is in `per_layer` and
+    names every cell; its reader divides the window's
     count by the window's steps, from the counter deltas or the phase
     recorder's copy, and has nothing to say of a program without the counter
     (the parent)."""
@@ -376,11 +376,12 @@ def test_the_benchmark_reads_the_output_buffers_a_step():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
-    assert bench["per_layer"][-1] == {
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)  # later PRs append after it
+    assert entry == {
         "name": name, "unit": "buffers/step", "better": "lower", "source": "program_counter",
         "layer": "ingest merge", "moves": "updates_per_s", "workloads": cells,
     }
-    assert all(applies(bench["per_layer"][-1], c, {"updates_per_s", "setup_s"}) for c in cells)
+    assert all(applies(entry, c, {"updates_per_s", "setup_s"}) for c in cells)
     read = load_reader("layers", name).read
     window = lambda **kw: Window(rec=None, t_open=0.0, t_close=30.0, setup_s=1.0,
                                  dispatch_spans=[(float(i), i + 0.5, 1) for i in range(10)], **kw)

@@ -186,6 +186,7 @@ def load_ingestor_with_extra(path: str) -> Tuple[BatchIngestor, dict]:
     ing._bind_counters()
     # rebuild the device hash tables from the restored interners
     ing._reset_tables()
+    ing._reset_rows(np.asarray(state.n_blocks))
     for key in ing.enc.keys.ids:
         ing._register_key(key)
     for cid in ing.enc.interner.from_idx:

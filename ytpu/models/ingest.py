@@ -89,6 +89,29 @@ _SLOW_REASONS = (
 )
 
 
+# --- when a room is compacted (`BatchIngestor._make_room`) -----------------
+# The server's own constants, as `_table_floor` is: a deployment passes none.
+#: free rows a room keeps, as a share of its capacity: a room that has
+#: fewer when a step brings it an update is squashed, collected and
+#: defragmented first (256 of 4,096: under the 440 the fullest room of the
+#: text cells keeps after a whole pool, PERF.md section 4)
+COMPACT_RESERVE_SHARE = 16
+#: hysteresis: a room whose compacted form sits inside the reserve is
+#: compacted again only once it has grown by this share of its capacity
+#: (128 rows of 4,096), or when its update might not fit at all
+COMPACT_REGROW_SHARE = 32
+#: rooms a call of `compact_rooms` gathers: one program whatever is due.
+#: The chip's time for a call goes with the rooms it gathers (65 ms at 16
+#: rooms of which one was due: PERF.md section 6, PR 43); at one room XLA
+#: makes the gather a dynamic slice, which a doc-sharded state answers by
+#: gathering whole planes (`compact_rooms`' docstring)
+COMPACT_ROOMS_PER_CALL = 2
+#: rows a planned row or a delete range can add at most: itself and a
+#: split at either anchor; a split at either edge
+#: (`batch_doc.stream_worst_case_adds`, the one accounting)
+ROWS_PER_ROW, ROWS_PER_DEL = 3, 2
+
+
 # columns of `PackedBatch.rows` the merge reads: the planes' order
 _REF = UpdateBatch._fields.index("content_ref")
 _VALID = UpdateBatch._fields.index("valid")
@@ -223,6 +246,7 @@ class BatchIngestor:
         metrics.gauge("ingest.state_shards").set_function(shards)
         self._last_fast_flags: Optional[np.ndarray] = None
         self._reset_tables()
+        self._reset_rows()
         # multi-root docs (doc.rs:156-228): the first named root seen per
         # doc maps onto the implicit device branch; others anchor through
         # BLOCK_ROOT_ANCHOR rows created before the apply
@@ -261,6 +285,15 @@ class BatchIngestor:
         self._m_host_rows = metrics.counter("ingest.host_rows")
         # output buffers of the programs a step enqueued (`_count_outputs`)
         self._m_enqueue_outputs = metrics.counter("ingest.enqueue_outputs")
+        # compaction on the served path (`_compact`): rooms compacted, the
+        # rows they held before and what that freed; bounds made exact by
+        # a read of `n_blocks` (`_recount`); rooms whose update might not
+        # fit although they were compacted
+        self._m_compactions = metrics.counter("ingest.room_compactions")
+        self._m_rows_before = metrics.counter("ingest.rows_before_compaction")
+        self._m_rows_reclaimed = metrics.counter("ingest.rows_reclaimed")
+        self._m_recounts = metrics.counter("ingest.row_recounts")
+        self._m_refusals = metrics.counter("ingest.capacity_refusals")
 
     def _reset_tables(self) -> None:
         """The device lookup tables' sources, empty, and nothing built of
@@ -301,6 +334,23 @@ class BatchIngestor:
         self._raw_clients = sum(1 for c in known if c <= _I32_MAX)
         # a key's or root name's device hash, worked out once
         self._name_hashes: Dict[str, int] = {}
+
+    def _reset_rows(self, n_blocks=None) -> None:
+        """The host's count of every slot's rows: an upper bound that a
+        step raises by what its update can add at most (`ROWS_PER_ROW`,
+        `ROWS_PER_DEL`) and no step reads from the device. `n_blocks`
+        ([n_docs], a restored state's) makes it exact; so does `_recount`
+        when the bound says a room nears its capacity, and `_compact` for
+        the rooms it compacted."""
+        self._rows_bound = (
+            np.zeros(self.n_docs, np.int64)
+            if n_blocks is None
+            else np.asarray(n_blocks, dtype=np.int64).copy()
+        )
+        # rows a room held after its last compaction (0: never compacted)
+        self._rows_compacted_to = np.zeros(self.n_docs, np.int64)
+        # rooms counted in `ingest.capacity_refusals`, once each
+        self._refused: set = set()
 
     def _key_hash(self, key: str) -> int:
         h = self._name_hashes.get(key)
@@ -535,6 +585,8 @@ class BatchIngestor:
         self._pending_ds[doc] = DeleteSet()
         self.primary_roots.pop(doc, None)
         self._anchored_roots[doc] = set()
+        self._rows_bound[doc] = self._rows_compacted_to[doc] = 0
+        self._refused.discard(doc)
 
     # --- introspection (parity: ytransaction_pending_update/_ds shape) -------
 
@@ -648,10 +700,14 @@ class BatchIngestor:
 
         tick()
         all_rows, all_dels = [], []
+        adds: Dict[int, int] = {}
         for d, u in enumerate(updates):
             rows, dels = self._plan_doc(d, u)
             all_rows.append(rows)
             all_dels.append(dels)
+            if rows or dels:
+                adds[d] = ROWS_PER_ROW * len(rows) + ROWS_PER_DEL * len(dels)
+        self._make_room(adds)
         self.state = apply_update_batch(
             self.state, self._batch(all_rows, all_dels), self._client_rank()
         )
@@ -869,6 +925,7 @@ class BatchIngestor:
             return  # full: leave unanchored; rows stash + retry
         kid = self.enc.keys.intern(name)
         self.state = ensure_root_anchor(self.state, doc, kid)
+        self._rows_bound[doc] += 1
         self._anchored_roots[doc].add(name)
 
     def _register_roots_from_cols(self, doc: int, cols) -> bool:
@@ -937,6 +994,196 @@ class BatchIngestor:
             width,
         )
 
+    # --- compaction on the served path ----------------------------------------
+
+    def _make_room(self, adds: Dict[int, int]) -> None:
+        """Before a step integrates: `adds` is slot -> the rows its update
+        can add at most. A room that is due (below) is compacted first
+        (`_compact`), decided from the host's bound alone:
+        a step reads nothing from the device. Only where the bound says a
+        room is due is it made exact (`_recount`: one read of `n_blocks`,
+        4 B a room), because it rises by the worst case a row and a room
+        is compacted for what it holds, not for what it might. Then every
+        slot's bound rises by its `adds`.
+
+        The policy, constants of this module: a room is due when it holds
+        more than `capacity - capacity // COMPACT_RESERVE_SHARE` rows (it
+        is inside its reserve), or when its update might not fit
+        (`rows + adds > capacity`: a bulk load's worst case is far above
+        what it adds, so `adds` does not count against the reserve); one
+        whose compacted form already sits inside the reserve is due again
+        only once it has grown by `capacity // COMPACT_REGROW_SHARE` rows
+        since, or when the update might not fit; one that has not grown
+        since it was compacted is left alone, and if its update might not
+        fit it is counted (`ingest.capacity_refusals`) and the device sets
+        `ERR_CAPACITY` should it not: a room whose squashed document does
+        not fit needs a larger slot (ROADMAP Reach A2)."""
+        if not adds:
+            return
+        from ytpu.utils.phases import phases
+
+        bound, floor = self._rows_bound, self._rows_compacted_to
+        cap = int(self.state.blocks.client.shape[-1])
+        reserve = cap // COMPACT_RESERVE_SHARE
+        regrow = cap // COMPACT_REGROW_SHARE
+
+        def due(d: int) -> bool:
+            fits = bound[d] + adds[d] <= cap
+            if bound[d] <= cap - reserve and fits:
+                return False
+            if floor[d] and bound[d] <= floor[d]:
+                return False  # it has not grown since it was compacted
+            return not (floor[d] and bound[d] <= floor[d] + regrow and fits)
+
+        rooms = [d for d in adds if due(d)]
+        if rooms:
+            with phases.span("ingest.recount"):
+                self._recount()
+            rooms = [d for d in rooms if due(d)]
+        if rooms:
+            with phases.span("ingest.compact"):
+                self._compact(rooms)
+        for d, a in adds.items():
+            if bound[d] + a > cap and d not in self._refused:
+                # compacted or not worth compacting, and it might not fit
+                self._refused.add(d)
+                self._tally(self._m_refusals)
+            bound[d] += a
+
+    @staticmethod
+    def _tally(counter, n: int = 1) -> None:
+        """Count `n` on a process-wide counter and on the phase recorder's
+        copy of it (stage value: what a window's delta is read from)."""
+        from ytpu.utils.phases import phases
+
+        counter.inc(n)
+        phases.add_value(counter.name, n)
+
+    def _recount(self) -> None:
+        """Make every slot's bound exact: one read of `n_blocks`."""
+        from ytpu.utils.phases import phases
+
+        n = np.asarray(self.state.n_blocks)
+        self._rows_bound[:] = n
+        self._tally(self._m_recounts)
+        if phases.enabled:
+            phases.transfer("ingest.recount", n.nbytes, "d2h")
+
+    def _compact(self, rooms: List[int]) -> None:
+        """Squash, collect and defragment the slots `rooms` on the
+        device(s), `COMPACT_ROOMS_PER_CALL` a call
+        (`ops.compaction.compact_rooms`: gather, compact, scatter back; a
+        lone room brings an idle slot along behind a mask), give the
+        squashed runs their content (`_rehome`) and make the rooms' bounds
+        exact from the `n_blocks` the program returns.
+
+        Host stages, leaves of `ingest.compact`, once a call: `.select`
+        (the call's slots), `.h2d`, `.enqueue` (one program), `.d2h` (the
+        counts and the report: the wait for the program is here),
+        `.rehome`."""
+        from ytpu.models.batch_doc import ensure_origin_slot
+        from ytpu.ops.compaction import compact_rooms
+        from ytpu.utils.phases import phases
+
+        rooms = sorted(rooms)
+        taken = set(rooms)
+        for i in range(0, len(rooms), COMPACT_ROOMS_PER_CALL):
+            with phases.span("ingest.compact.select"):
+                due = rooms[i : i + COMPACT_ROOMS_PER_CALL]
+                # a call short of rooms brings idle slots along, masked out
+                idle = (d for d in range(self.n_docs) if d not in taken)
+                pad = list(islice(idle, COMPACT_ROOMS_PER_CALL - len(due)))
+                called = np.sort(np.asarray(due + pad, dtype=np.int32))
+                mask = np.isin(called, due)
+                host = (called, mask, np.int32(len(self.enc.payloads.items)))
+            with phases.span("ingest.compact.h2d"):
+                operands = self._upload(host)
+                if phases.enabled:
+                    phases.transfer(
+                        "ingest.compact.h2d", self._uploaded_bytes(host), "h2d"
+                    )
+            with phases.span("ingest.compact.enqueue"):
+                out = compact_rooms(ensure_origin_slot(self.state), *operands)
+                self.state = out[0]
+                self._count_outputs(out)
+            with phases.span("ingest.compact.d2h"):
+                n_before, n_after, report, n_chains = jax.device_get(out[1:])
+                if phases.enabled:
+                    phases.transfer(
+                        "ingest.compact.d2h",
+                        n_before.nbytes + n_after.nbytes + report.nbytes
+                        + n_chains.nbytes,
+                        "d2h",
+                    )
+            with phases.span("ingest.compact.rehome"):
+                self._rehome(report[mask], int(n_chains.sum()))
+            slots = called[mask]
+            n_before, n_after = n_before[mask], n_after[mask]
+            self._rows_bound[slots] = self._rows_compacted_to[slots] = n_after
+            self._tally(self._m_compactions, len(slots))
+            self._tally(self._m_rows_before, int(n_before.sum()))
+            self._tally(self._m_rows_reclaimed, int((n_before - n_after).sum()))
+
+    def _rehome(self, report: np.ndarray, n_chains: int) -> None:
+        """Append the `n_chains` payloads `compact_rooms` numbered: the
+        content of every squashed run whose rows read different payloads,
+        assembled from `report` (`ops.compaction.REHOME_FIELDS`, one row
+        an old row of a compacted room). A chain's members come in clock
+        order and each sits `chain_off` units into the run, so a string
+        run is one UTF-16 buffer filled member by member; a keystroke's
+        member (one ASCII unit straight off the wire) is filled without a
+        Python loop. The head row already reads the new payload from
+        offset 0: nothing goes back to the device."""
+        store = self.enc.payloads
+        if not n_chains:
+            return
+        from ytpu.core.content import CONTENT_STRING
+        from ytpu.models.batch_doc import _wire_concat
+        from ytpu.ops.decode_kernel import utf8_slice_u16
+
+        rows = report.reshape(-1, report.shape[-1])
+        rows = rows[rows[:, 0] >= 0]
+        rows = rows[np.lexsort((rows[:, 1], rows[:, 0]))]
+        chain, at, kind, ref, off, length = rows.T
+        first = np.flatnonzero(np.r_[True, chain[1:] != chain[:-1]])
+        if len(first) != n_chains:
+            raise RuntimeError(
+                f"compact_rooms numbered {n_chains} re-homed chains, its "
+                f"report holds {len(first)}"
+            )
+        last = np.r_[first[1:], len(chain)] - 1
+        units = at[last] + length[last]  # a chain's extent in clock units
+        base = np.cumsum(units) - units  # where its units start in `text`
+        text = np.zeros(int(units.sum()), dtype=np.uint16)
+        where = base[np.cumsum(np.r_[True, chain[1:] != chain[:-1]]) - 1] + at
+        wire = _wire_concat(self.payloads)
+        is_str = kind == CONTENT_STRING
+        # one ASCII unit off the wire: the byte is the unit
+        start = np.where(ref <= -2, -(ref + 2), 0)
+        key = is_str & (ref <= -2) & (length == 1) & (off == 0)
+        key[key] = wire[start[key]] < 0x80
+        text[where[key]] = wire[start[key]]
+        for i in np.flatnonzero(is_str & ~key):
+            s = (
+                utf8_slice_u16(wire, start[i], int(off[i]), int(length[i]))
+                if ref[i] <= -2
+                else store.slice_text(int(ref[i]), int(off[i]), int(length[i]))
+            )
+            got = np.frombuffer(
+                s.encode("utf-16-le", "surrogatepass"), dtype=np.uint16
+            )
+            text[where[i] : where[i] + len(got)] = got
+        for c, (lo, hi) in enumerate(zip(first, last + 1)):
+            if is_str[lo]:
+                payload = text[base[c] : base[c] + units[c]].tobytes()
+            else:  # Any values, member by member
+                payload = []
+                for i in range(lo, hi):
+                    payload += self.payloads.slice_values(
+                        int(ref[i]), int(off[i]), int(length[i])
+                    )
+            store.add(int(kind[lo]), payload)
+
     def apply_bytes(self, payloads: List[Optional[bytes]]) -> DocStateBatch:
         """One batched step straight from V1 wire bytes.
 
@@ -982,6 +1229,8 @@ class BatchIngestor:
                 fast_sv_deltas: Dict[int, Dict[int, int]] = {}
                 fast_has_str: List[bool] = []
                 slow_updates: Dict[int, Update] = {}  # slot -> its update
+                # slot -> the rows its update can add at most (`_make_room`)
+                adds: Dict[int, int] = {}
                 max_fast_rows, max_fast_dels = 0, 0
                 max_sections, max_steps = 0, 0
                 with phases.span("ingest.plan.prescan"):
@@ -1026,6 +1275,9 @@ class BatchIngestor:
                         for i in range(cols.n_dels):
                             self.enc.interner.intern(int(cols.del_client[i]))
                         fast_has_str.append(str_here > 0)
+                        adds[d] = (
+                            ROWS_PER_ROW * rows_here + ROWS_PER_DEL * cols.n_dels
+                        )
                         max_fast_rows = max(max_fast_rows, rows_here)
                         max_fast_dels = max(max_fast_dels, cols.n_dels)
                         max_sections = max(max_sections, cols.n_client_sections)
@@ -1052,6 +1304,10 @@ class BatchIngestor:
                         [max_fast_dels, 1] + [len(d_) for _, d_ in planned.values()]
                     ))
                     bucket = (width, n_rows, n_dels)
+                    for d, (rows, dels) in planned.items():
+                        adds[d] = (
+                            ROWS_PER_ROW * len(rows) + ROWS_PER_DEL * len(dels)
+                        )
                     if planned:
                         host_rows = sum(len(r) for r, _ in planned.values())
                         self._m_host_rows.inc(host_rows)
@@ -1101,6 +1357,9 @@ class BatchIngestor:
             with phases.span("ingest.rank_table"):
                 # after the prescan has interned what this step brought
                 client_rank = self._client_rank()
+            # a room whose update might leave it short of rows is squashed,
+            # collected and defragmented first (`ingest.compact`)
+            self._make_room(adds)
             # `active` rides up with the call, as `merge_stream`'s `idx`
             # does; the batch is `[len(active), ...]` already, and a pair
             # whether a merge made it or the host lane's upload goes
@@ -1158,7 +1417,8 @@ class BatchIngestor:
                 else:
                     clocks[c] = old
             planned[d] = self._plan_doc(d, Update.decode_v1(payloads[d]))
-        # as wide as the flagged rooms, by the step's own rule
+        # as wide as the flagged rooms, by the step's own rule (the flagged
+        # lanes integrated nothing: the rows bound already holds their adds)
         active = self._active_slots(bad)
         self.state = apply_update_batch(
             self.state,

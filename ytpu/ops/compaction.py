@@ -25,6 +25,30 @@ blocks; this pass is the batched equivalent, run as one jitted program:
 
 Semantics parity is testable: replay -> compact -> keep replaying must
 match the host oracle exactly (tests/test_compaction.py).
+
+Three forms, and who calls each:
+
+- `compact_rooms` — **the served form** (PR 43). `BatchIngestor` calls it
+  from inside `apply_bytes` (so from `DeviceSyncServer.flush_device`) for
+  the rooms whose rows near their capacity: gather `[K, ...]`, compact,
+  scatter back, as the integrate step does, K = 2 a call (a lone room
+  brings an idle slot along, behind a mask); on one chip and on a
+  doc-sharded state alike. Its chain rule stands on structure alone, so
+  typed runs squash although every keystroke's string is a wire ref of
+  its own; the strings follow on the host (`BatchIngestor._rehome`).
+  Nothing inside its `vmap` scatters: chain heads, tails and sums come
+  from pointer doubling over gathers (`_set`'s docstring in `batch_doc.py`
+  says what a batched dropping scatter does on a v5e).
+- `compact_state` — every slot of a `DocStateBatch` at once, the state
+  donated, content merged only where the payload ref is shared. **No
+  server path and no replay lane calls it**: its callers are tests
+  (`tests/test_compaction.py`, `tests/test_capacity.py`,
+  `tests/test_origin_slot.py`, and `tests/test_chip_compile.py` compiles
+  it). It has never run on the chip, and its scatters are of the kind
+  `_set`'s docstring warns of.
+- `compact_packed` — the fused kernel's packed `[NC, D, C]` layout, for
+  the chunked replay drivers (`ops/integrate_kernel.py`). A second copy
+  of the same rule over another layout (ROADMAP Design 2).
 """
 
 from __future__ import annotations
@@ -46,7 +70,14 @@ from ytpu.core.content import (
 )
 from ytpu.models.batch_doc import COL_DEFAULTS, BlockCols, DocStateBatch
 
-__all__ = ["compact_state", "grow_state", "compact_packed", "grow_packed"]
+__all__ = [
+    "compact_state",
+    "compact_rooms",
+    "grow_state",
+    "compact_packed",
+    "grow_packed",
+    "REHOME_FIELDS",
+]
 
 I32 = jnp.int32
 
@@ -185,6 +216,223 @@ def _compact_one(state: DocStateBatch) -> DocStateBatch:
     return DocStateBatch(
         blocks=packed, start=start, n_blocks=n_new, error=state.error
     )
+
+
+#: columns of `compact_rooms`' per-row report (`[K, B, 6]` i32, one row an
+#: OLD row of the room, before the defragmentation): the chain a row's
+#: content is re-homed into (-1: none), the row's offset into that chain's
+#: content in clock units, and where its content was (`kind`, `content_ref`,
+#: `content_off`, `length`, after the GC conversion)
+REHOME_FIELDS = ("chain", "chain_off", "kind", "ref", "off", "length")
+
+
+def _compact_room(state: DocStateBatch):
+    """One room of `compact_rooms`: `_compact_one`'s three passes with the
+    served path's chain rule. Returns the compacted room, per NEW row the
+    room-local number of the re-homed chain it heads (-1: none), the
+    `[B, 6]` report (`REHOME_FIELDS`, room-local chain numbers) and the
+    count of re-homed chains.
+
+    A live string or Any row chains into its right neighbour under
+    `try_squash`'s structural conditions whatever payload each reads:
+    on the served path every update's string is its own wire ref, so the
+    old content rule (same ref, contiguous offsets) never held between two
+    keystrokes. A chain with a link the old rule refuses has its content
+    re-homed: its head reads a new payload from offset 0, which the host
+    assembles from the report.
+
+    Written for the chip. A batched scatter that drops rows is not to be
+    trusted there (`_set`'s docstring in `batch_doc.py`), so a chain's
+    head, its tail and whether any of its links needs re-homing come from
+    pointer doubling over gathers, and nothing scatters. A gather costs
+    the chip some 10 ns an element whatever it fetches (PERF.md section 6,
+    PR 43), so the right neighbour's columns and the packing are one
+    gather of rows each, not one a plane. (Sorting the rows by client and
+    clock finds the chains with no doubling at all, but a sort of several
+    operands takes the TPU's compiler 30-40 s to build.)"""
+    bl = state.blocks
+    B = bl.client.shape[-1]
+    slots = jnp.arange(B, dtype=I32)
+    n = state.n_blocks
+    active = slots < n
+
+    # --- 1. GC conversion (gc.rs:11-65) ------------------------------------
+    gcable = jnp.zeros((B,), bool)
+    for k in _GCABLE:
+        gcable = gcable | (bl.kind == k)
+    convert = active & bl.deleted & gcable
+    kind = jnp.where(convert, CONTENT_DELETED, bl.kind)
+    content_ref = jnp.where(convert, -1, bl.content_ref)
+    content_off = jnp.where(convert, 0, bl.content_off)
+
+    # --- 2. squash eligibility a -> b = right[a] (block.rs:775-799) --------
+    with jax.named_scope("room_squash"):
+        b = bl.right
+        mine = (
+            bl.client, bl.clock, bl.origin_client, bl.origin_clock,
+            bl.ror_client, bl.ror_clock, bl.deleted.astype(I32), bl.moved,
+            bl.key, bl.parent, bl.left, kind, content_ref, content_off,
+        )
+        theirs = jnp.stack(mine, axis=-1)[jnp.maximum(b, 0)]  # [B, 14]
+        (
+            b_client, b_clock, b_origin_client, b_origin_clock, b_ror_client,
+            b_ror_clock, b_deleted, b_moved, b_key, b_parent, b_left, b_kind,
+            b_ref, b_off,
+        ) = (theirs[:, i] for i in range(len(mine)))
+        spliceable = jnp.zeros((B,), bool)
+        for k in _SPLICEABLE:
+            spliceable = spliceable | (kind == k)
+        contentless = (kind == BLOCK_GC) | (kind == CONTENT_DELETED)
+        elig = (
+            active
+            & (b >= 0)
+            & (b < n)
+            & (bl.client == b_client)
+            & (b_clock == bl.clock + bl.length)
+            & (b_origin_client == bl.client)
+            & (b_origin_clock == bl.clock + bl.length - 1)
+            & (bl.ror_client == b_ror_client)
+            & ((bl.ror_client < 0) | (bl.ror_clock == b_ror_clock))
+            & (bl.deleted.astype(I32) == b_deleted)
+            & (bl.moved == b_moved)
+            & (bl.key == b_key)
+            & (bl.parent == b_parent)
+            & (b_left == slots)  # well-formed adjacency both ways
+            & (kind == b_kind)
+            & (contentless | spliceable)
+        )
+        # the link's content does not follow by itself: re-home the chain
+        same_run = (content_ref == b_ref) & (b_off == content_off + bl.length)
+        loose = elig & spliceable & ~same_run
+
+        sl = jnp.maximum(bl.left, 0)
+        merged_away = active & (bl.left >= 0) & elig[sl]
+        # leftwards: a row's chain head; rightwards: its chain tail, and
+        # whether any link from the row to the tail is loose
+        head = jnp.where(merged_away, bl.left, slots)
+        tail = jnp.where(elig, b, slots)
+        for _ in range(max(1, B.bit_length())):
+            head = head[head]
+            loose = loose | loose[tail]
+            tail = tail[tail]
+        keep = active & ~merged_away
+        # clocks run on through a chain: its length is its extent
+        length = jnp.where(
+            keep, bl.clock[tail] + bl.length[tail] - bl.clock, bl.length
+        )
+        right = jnp.where(keep, bl.right[tail], bl.right)
+        rehomed = keep & loose  # a head whose chain is re-homed
+        chain = jnp.cumsum(rehomed.astype(I32)) - 1
+        n_chains = jnp.sum(rehomed.astype(I32))
+        member = active & rehomed[head]
+        report = jnp.stack(
+            [
+                jnp.where(member, chain[head], -1),
+                bl.clock - bl.clock[head],
+                kind,
+                content_ref,
+                content_off,
+                bl.length,
+            ],
+            axis=-1,
+        )
+        bl = bl._replace(
+            kind=kind,
+            content_ref=content_ref,
+            content_off=jnp.where(rehomed, 0, content_off),
+            length=length,
+            right=right,
+        )
+
+    # --- 3. defragment: pack kept rows, remap index columns ----------------
+    with jax.named_scope("room_defrag"):
+        new_idx = jnp.cumsum(keep.astype(I32)) - 1
+        # pointers into absorbed rows redirect to their chain head
+        old2new = new_idx[head]
+        # left, right, parent, head, moved, and origin_slot: an absorbed
+        # origin row redirects to its chain head, whose widened clock
+        # range still contains the origin id
+        links = ("left", "right", "parent", "head", "moved", "origin_slot")
+        old = jnp.stack([getattr(bl, name) for name in links])
+        remapped = jnp.where(old >= 0, old2new[jnp.maximum(old, 0)], -1)
+        bl = bl._replace(**{name: remapped[i] for i, name in enumerate(links)})
+        n_new = jnp.sum(keep.astype(I32))
+        # kept rows first (slot order preserved), dropped rows after
+        order = jnp.argsort(jnp.where(keep, slots, B + slots))
+        blank = slots >= n_new
+        planes = jnp.stack(
+            [getattr(bl, name).astype(I32) for name in COL_DEFAULTS]
+            + [jnp.where(rehomed, chain, -1)],
+            axis=-1,
+        )[order]  # [B, 27]: one gather of rows
+        packed = BlockCols(
+            **{
+                name: jnp.where(
+                    blank, fill, planes[:, i].astype(getattr(bl, name).dtype)
+                )
+                for i, (name, fill) in enumerate(COL_DEFAULTS.items())
+            }
+        )
+        heads = jnp.where(blank, -1, planes[:, -1])
+        start = jnp.where(
+            state.start >= 0, old2new[jnp.maximum(state.start, 0)], -1
+        )
+    out = DocStateBatch(
+        blocks=packed, start=start, n_blocks=n_new, error=state.error
+    )
+    return out, heads, report, n_chains
+
+
+@jax.jit
+def compact_rooms(state: DocStateBatch, rooms, mask, ref_base):
+    """Squash + GC + defragment the rooms `rooms` ([K] i32, distinct and
+    in range) of `state` where `mask` ([K] bool) is set; the rest of
+    `rooms` is padding and every other room's planes are carried over. The
+    integrate step's form: gather `[K, ...]`, compact under `vmap`, one
+    plain scatter on the room axis outside it; not donated
+    (`apply_update_batch`'s docstring). The device's time goes with K (a
+    gather's cost is per element), so the served path calls it with
+    K = 2: one program, a third room due in the same step is a second
+    call, and a lone room brings an idle slot along (at K = 1 XLA turns
+    the room gather into a dynamic slice, which the partitioner answers
+    on a doc-sharded state by gathering every plane whole onto every
+    chip: `tests/test_chip_compile.py`).
+
+    A re-homed chain's head (`_compact_room`) reads payload
+    `ref_base + c`, `c` counting the chains of the call room by room:
+    the caller appends exactly those payloads to its store, in that
+    order, from the report. Returns `(state, n_before [K], n_after [K],
+    report [K, B, 6], n_chains [K])`, the report's chain numbers
+    call-wide and -1 in a room the mask leaves out."""
+    with jax.named_scope("compact_gather"):
+        sub = jax.tree.map(lambda a: a[rooms], state)
+    done, heads, report, n_chains = jax.vmap(_compact_room)(sub)
+    n_chains = jnp.where(mask, n_chains, 0)
+    first = jnp.cumsum(n_chains) - n_chains  # a room's first chain number
+    bl = done.blocks
+    bl = bl._replace(
+        content_ref=jnp.where(
+            heads >= 0, ref_base + first[:, None] + heads, bl.content_ref
+        )
+    )
+    done = done._replace(blocks=bl)
+    report = report.at[..., 0].set(
+        jnp.where(
+            mask[:, None] & (report[..., 0] >= 0),
+            first[:, None] + report[..., 0],
+            -1,
+        )
+    )
+
+    def pick(new, old):
+        return jnp.where(mask.reshape(mask.shape + (1,) * (new.ndim - 1)), new, old)
+
+    done = jax.tree.map(pick, done, sub)
+    with jax.named_scope("compact_scatter"):
+        out = jax.tree.map(
+            lambda full, part: full.at[rooms].set(part), state, done
+        )
+    return out, sub.n_blocks, done.n_blocks, report, n_chains
 
 
 @partial(jax.jit, donate_argnums=0)
@@ -564,6 +812,7 @@ def _register_programs():
 
     progbudget.register("compact_state", _compact_state_jit)
     progbudget.register("compact_packed", _compact_packed_jit)
+    progbudget.register("compact_rooms", compact_rooms)
 
 
 _register_programs()

@@ -64,6 +64,10 @@ class DeviceSyncServer(SyncServer):
     `n_docs` bounds the tenant count (one slot per tenant, assigned on
     first touch). Updates accumulate per slot and ship on `flush_device()`
     — call it per request batch, on a timer, or from the serving loop.
+    A slot holds `capacity` rows: a room that nears it is squashed,
+    collected and defragmented inside the step (`BatchIngestor._make_room`;
+    the policy is the server's own), and `ERR_CAPACITY` is left for a room
+    whose squashed document does not fit (a slot cannot grow).
     Multi-root tenants (doc.rs:156-228, the reference's normal doc shape)
     are device-resident: the first named root maps onto the implicit
     device branch, later ones anchor through per-doc BLOCK_ROOT_ANCHOR
@@ -175,7 +179,10 @@ class DeviceSyncServer(SyncServer):
     def capacity_snapshot(self) -> Dict:
         """Per-tenant slot-occupancy ledger: live / dead (tombstoned,
         GC-able) / free rows per assigned tenant slot, summing to the
-        slot capacity, plus batch-wide totals. Backs the ``capacity``
+        slot capacity, plus batch-wide totals and what compaction has
+        given back (a room's dead rows are collected and its typed runs
+        squashed when it nears its capacity, inside `flush_device`:
+        `BatchIngestor._make_room`). Backs the ``capacity``
         section of `/snapshot` and the per-tenant
         ``capacity.tenant_*_rows`` gauges."""
         from ytpu.utils import metrics
@@ -207,6 +214,13 @@ class DeviceSyncServer(SyncServer):
             "live_rows": int(sum(int(x) for x in live)),
             "dead_rows": int(sum(int(x) for x in dead)),
             "free_rows": int(sum(int(x) for x in free)),
+            # what the served path's compaction has done so far
+            # (`BatchIngestor._make_room`): the process's counters
+            "compactions": int(metrics.counter("ingest.room_compactions").value),
+            "rows_reclaimed": int(metrics.counter("ingest.rows_reclaimed").value),
+            "capacity_refusals": int(
+                metrics.counter("ingest.capacity_refusals").value
+            ),
             "tenants": tenants,
         }
 
