@@ -129,10 +129,14 @@ class _Spy:
         real_merge, real_apply = ingest_mod._merge_stream_jit, ingest_mod.apply_update_batch
         real_decode, real_plan, real_recover = dk.decode_updates_v1, ing._plan_doc, ing._recover_flagged
 
-        def merge(batch, *a, **kw):
+        def merge(batch, stream, table, **kw):
             self.merged.append(batch)
-            self.merge_args.append((a, kw))
-            return real_merge(batch, *a, **kw)
+            # the lanes' operands are rows of the lane table, a device array
+            # the step's first program made: nothing rides up with the call
+            assert isinstance(table, jax.Array)
+            t = np.asarray(table)
+            self.merge_args.append(((stream, t[dk.LANE_AT], t[dk.LANE_PREFIX], t[dk.LANE_BASE, 0]), kw))
+            return real_merge(batch, stream, table, **kw)
 
         def apply(state, batch, *rest):
             self.applied.append(batch)
@@ -511,9 +515,9 @@ def test_a_served_process_keeps_one_integrate_form_a_bucket(monkeypatch):
     flagged lane's recovery, all at the (4, 4) bucket: the integrate program
     is handed the pair by every one of them (the merge's output, the upload
     as it is, the recovery's `_batch`), so the process builds one form of
-    it; and a step's programs hand back 35 buffers where it merges (the
-    gather's 1, the decoder's 3, the merge's 2, the state's 29), 29 where
-    it does not."""
+    it; and a step's programs hand back 36 buffers where it merges (the
+    gather's 2 in these dense steps, the lane matrix and the lane table;
+    the decoder's 3, the merge's 2, the state's 29), 29 where it does not."""
     from ytpu.models.batch_doc import _apply_update_batch_jit
     from ytpu.utils import progbudget
 
@@ -525,10 +529,10 @@ def test_a_served_process_keeps_one_integrate_form_a_bucket(monkeypatch):
     early = rooms[0].edit(2, _type("two "))
     late = rooms[0].edit(2, _type("more ", 2))
     steps = [
-        ("fast lane only", [first, rooms[1].edit(50, _type("x0")), None, None], 35),
+        ("fast lane only", [first, rooms[1].edit(50, _type("x0")), None, None], 36),
         ("host lane only", [late, None, None, None], 29),  # stashed: the host lane's step, and nothing to carry
-        ("both", [early, rooms[1].edit(50, _type("x2")), None, None], 35),
-        ("flagged", [rooms[0].edit(1, _four_and_four), rooms[1].edit(50, _type("x3")), None, None], 35 + 29),
+        ("both", [early, rooms[1].edit(50, _type("x2")), None, None], 36),
+        ("flagged", [rooms[0].edit(1, _four_and_four), rooms[1].edit(50, _type("x3")), None, None], 36 + 29),
     ]
     forms = _apply_update_batch_jit._cache_size()
     counter = metrics.counter("ingest.enqueue_outputs")

@@ -87,6 +87,21 @@ def _pair(slots, rows, dels=None):
     return BatchEncoder().batch_packed([[]] * slots, [[]] * slots, rows, dels or rows)
 
 
+def _lane_table(lanes):
+    """The served step's lane table (`decode_kernel.LANE_*`), as a shape."""
+    from ytpu.ops.decode_kernel import LANE_FIELDS
+
+    return jax.ShapeDtypeStruct((LANE_FIELDS, lanes), jnp.int32)
+
+
+def _manifest(lanes, wire, step_width):
+    """A step's manifest (`ingest.pack_manifest`) over a wire arena of
+    `wire` bytes, as a shape."""
+    from ytpu.ops.decode_kernel import LANE_FIELDS
+
+    return jax.ShapeDtypeStruct((wire + 4 * (LANE_FIELDS * lanes + step_width),), jnp.uint8)
+
+
 @pytest.mark.parametrize("rows", [4, 512], ids=["tick_bucket", "prefill_bucket"])
 def test_served_integrate_step_fits_one_v5e(one_chip, rows):
     """`apply_update_batch` as `flush_device` dispatches it over every
@@ -165,14 +180,11 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
         a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
     )
     by_room, whole = on(P(AXIS_BATCH)), on(P())
-    host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
     packed = _pair(rooms, rows)
     merge = _merge_stream_jit.lower(
         jax.tree.map(by_room, packed),
         jax.tree.map(whole, _pair(lanes, rows)),
-        host(lanes),
-        host(lanes),
-        host(),
+        whole(_lane_table(lanes)),  # the gather's output: a device array
         width=64,
     ).compile()
     assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
@@ -231,8 +243,8 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     host lane's `[16, ...]` `PackedBatch` whole on every chip (`_upload`),
     `merge_stream` over it and the decoded lanes' pair (nothing of it is laid by
     room, so the program may hold no collective, and its two arrays come
-    out whole on every chip), the rank table whole on every chip, `active` a numpy array the call takes
-    up. The partitioner must answer the state's gather with each chip's own
+    out whole on every chip), the rank table whole on every chip, `active` the
+    device array the manifest's gather handed back. The partitioner must answer the state's gather with each chip's own
     rooms and a sum of the `[16, ...]` pieces, never with a gathered plane,
     and scatter into the planes where they lie."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -242,7 +254,8 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
         init_state,
         scan_tier_plan,
     )
-    from ytpu.models.ingest import _merge_stream_jit
+    from ytpu.models.ingest import _gather_manifest_jit, _merge_stream_jit
+    from ytpu.ops.decode_kernel import LANE_FIELDS
     from ytpu.parallel.mesh import AXIS_BATCH
 
     rooms, lanes = 4 * N_DOCS, 8
@@ -251,14 +264,21 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
         a.shape, a.dtype, sharding=NamedSharding(mesh, spec)
     )
     by_room, whole = on(P(AXIS_BATCH)), on(P())
-    host = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # numpy: jit places it
     batch = _pair(COMPACT_WIDTH, 4)
+    # the step's one upload, whole on every chip, taken apart where it lies:
+    # the lane matrix, the lane table and `active` come out whole on every chip
+    gather = _gather_manifest_jit.lower(
+        whole(_manifest(lanes, 256, COMPACT_WIDTH)), lanes=lanes, width=64, step_width=COMPACT_WIDTH
+    ).compile()
+    assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", gather.as_text())
+    assert {s.spec for s in jax.tree.leaves(gather.output_shardings)} == {P()}
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(gather.out_info)] == [
+        ((lanes, 64), jnp.uint8), ((LANE_FIELDS, lanes), jnp.int32), ((COMPACT_WIDTH,), jnp.int32)
+    ]
     merge = _merge_stream_jit.lower(
         jax.tree.map(whole, batch),
         jax.tree.map(whole, _pair(lanes, 4)),
-        host(lanes),
-        host(lanes),
-        host(),
+        whole(_lane_table(lanes)),
         width=64,
     ).compile()
     assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
@@ -269,7 +289,7 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
         jax.tree.map(whole, batch),
         whole(jnp.zeros((2 * N_CLIENTS,), jnp.int32)),
         scan_tier_plan(),
-        jax.ShapeDtypeStruct((COMPACT_WIDTH,), jnp.int32),  # numpy: jit places it
+        whole(jnp.zeros((COMPACT_WIDTH,), jnp.int32)),  # the gather's third output
     ).compile()
     text = step.as_text()
     assert not re.search(r"all-(gather|to-all)|collective-permute|reduce-scatter", text)
@@ -368,7 +388,7 @@ def test_served_decode_compiles(one_chip, lanes, width, rows, n_steps, max_secti
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     compiled = _decode_updates_v1_jit.lower(
         jax.ShapeDtypeStruct((lanes, width), jnp.uint8, sharding=one_chip),
-        i32(lanes),
+        None,
         max_rows=rows,
         max_dels=rows,
         n_steps=n_steps,
@@ -376,8 +396,8 @@ def test_served_decode_compiles(one_chip, lanes, width, rows, n_steps, max_secti
         max_sections=max_sections,
         key_table=(i32(1), i32(1)),
         client_hash_table=(i32(0), i32(0)),
-        primary_root_hash=i32(lanes),
         packed=True,
+        lane_table=_shapes(_lane_table(lanes), one_chip),
     ).compile()
     m = compiled.memory_analysis()
     print(f"decode {lanes} x {width}: temp bytes {m.temp_size_in_bytes}, output bytes {m.output_size_in_bytes}")
@@ -406,9 +426,7 @@ def test_served_merge_compiles(one_chip, lanes, rows, width, slots):
     compiled = _merge_stream_jit.lower(
         _shapes(packed, one_chip),
         _shapes(_pair(lanes, rows), one_chip),
-        i32(lanes),
-        i32(lanes),
-        i32(),
+        _shapes(_lane_table(lanes), one_chip),
         width=width,
     ).compile()
     m = compiled.memory_analysis()
@@ -440,20 +458,22 @@ def test_served_unpack_compiles(one_chip, slots, rows):
 
 @pytest.mark.parametrize("lanes,width", [(s, w) for s, _, w, _ in MERGE_SHAPES])
 def test_served_gather_compiles(one_chip, lanes, width):
-    """`gather_raw_lanes` through the merge's jit: a bucketed wire arena
-    and its offsets table in, the padded [S, L] lane matrix out."""
-    from ytpu.models.ingest import _bucket, _gather_raw_lanes_jit
+    """The step's first program (`gather_manifest_lanes`): the manifest in
+    (a bucketed wire arena, the lane table and, in a tick, the step's 16
+    `active` slots: one u8 array), the padded [S, L] lane matrix, the lane
+    table and `active` out; the prefill's dense step has no `active`."""
+    from ytpu.models.ingest import _bucket, _gather_manifest_jit
+    from ytpu.ops.decode_kernel import LANE_FIELDS
 
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     wire = _bucket(lanes * (width - 16) * 13 // 16, 256)  # lanes about 13/16 full
-    compiled = _gather_raw_lanes_jit.lower(
-        jax.ShapeDtypeStruct((wire,), jnp.uint8, sharding=one_chip),
-        i32(lanes),
-        i32(lanes),
-        width=width,
+    step_width = 0 if lanes == N_DOCS else COMPACT_WIDTH
+    compiled = _gather_manifest_jit.lower(
+        _shapes(_manifest(lanes, wire, step_width), one_chip), lanes=lanes, width=width, step_width=step_width
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM // 16
-    assert compiled.out_info.shape == (lanes, width)
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(compiled.out_info)] == [
+        ((lanes, width), jnp.uint8), ((LANE_FIELDS, lanes), jnp.int32)
+    ] + ([((step_width,), jnp.int32)] if step_width else [])
 
 
 def test_served_diff_selection_compiles(one_chip):

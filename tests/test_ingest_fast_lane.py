@@ -593,6 +593,18 @@ def _spy_on_merge(monkeypatch):
     return calls
 
 
+def _lane_operands(table):
+    """`idx`, `prefix`, `base` as `merge_stream` reads them out of the lane
+    table: a device array (the step's first program made it), no host
+    operand of the call."""
+    from ytpu.ops.decode_kernel import LANE_AT, LANE_BASE, LANE_FIELDS, LANE_PREFIX
+
+    assert isinstance(table, jax.Array) and table.dtype == np.int32 and table.shape[0] == LANE_FIELDS
+    table = np.asarray(table)
+    assert (table[LANE_BASE] == table[LANE_BASE, 0]).all()
+    return table[LANE_AT], table[LANE_PREFIX], table[LANE_BASE, 0]
+
+
 def _reference_merge(batch, stream, idx, prefix, base, width):
     """The merge in numpy: rebase the string refs, then `full[idx] = fast`."""
     full = {k: np.array(v) for k, v in batch._asdict().items()}
@@ -645,7 +657,8 @@ def test_merge_stream_equals_numpy_reference(monkeypatch, case, ingest_mode):
 
     lanes = sorted(MERGE_CASES[case])  # the fast lanes' slots, in slot order
     assert ing.fast_docs - fast_before == len(lanes)
-    ((batch, stream, idx, prefix, base), kw, merged), = calls
+    ((batch, stream, table), kw, merged), = calls
+    idx, prefix, base = _lane_operands(table)
     # the host lane's batch, the decoded stream and what the merge makes of
     # them all cross as the two packed arrays; compared as planes
     from ytpu.models.batch_doc import PackedBatch, unpack_batch_jit
@@ -697,7 +710,7 @@ def test_merge_programs_do_not_retrace_on_values(monkeypatch):
     monkeypatch.setattr(progbudget, "_MAX", 10**9)  # no eviction under our feet
     logs = [_edit_log(_MERGE_OPS(d), client_id=d + 1)[0] for d in range(8)]
     ing = BatchIngestor(n_docs=8, capacity=64)
-    programs = (ingest._gather_raw_lanes_jit, ingest._merge_stream_jit)
+    programs = (ingest._gather_manifest_jit, ingest._merge_stream_jit)
     for jit in programs:  # earlier tests of this process may hold the same keys
         jit.clear_cache()
     sizes = lambda: tuple(jit._cache_size() for jit in programs)
@@ -711,8 +724,8 @@ def test_merge_programs_do_not_retrace_on_values(monkeypatch):
     step({0: INS2, 1: INS2, 2: INS2})
     first = sizes()
     step({4: DEL, 6: INS2, 7: INS2})  # other slots, prefix, base and bytes; same shapes
-    (_, _, idx_a, prefix_a, base_a), _, _ = calls[1]
-    (_, _, idx_b, prefix_b, base_b), _, _ = calls[2]
+    idx_a, prefix_a, base_a = _lane_operands(calls[1][0][2])
+    idx_b, prefix_b, base_b = _lane_operands(calls[2][0][2])
     assert idx_a.tolist() != idx_b.tolist() and prefix_a.tolist() != prefix_b.tolist()
     assert int(base_a) != int(base_b)
     assert sizes() == first

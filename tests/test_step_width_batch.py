@@ -299,8 +299,10 @@ def test_a_build_is_two_uploads_and_no_enqueue(served):
     compact step, by room in a dense one), and a step that is handed a
     kept batch sends nothing. No served step enqueues `jit_unpack_batch`:
     the pair goes to the integrate program as it is, so a step's programs
-    hand back 29 buffers (the state's) where no room rode the fast lane
-    and 35 where one did (the gather's 1, the decoder's 3, the merge's 2)."""
+    hand back 29 buffers (the state's) where no room rode the fast lane,
+    and where one did 8 more in a compact step (the gather's 3: the lane
+    matrix, the lane table, `active`; the decoder's 3, the merge's 2) and 7
+    in a dense one, which has no `active`."""
     server, _, steps, counted, recorded = served
     copies = len(jax.devices()) if server.ingestor._on_every_chip is not None else 1
     sent, kept = 0, set()
@@ -321,7 +323,9 @@ def test_a_build_is_two_uploads_and_no_enqueue(served):
     assert all(type(s["applied"]) is PackedBatch for s in steps) and no_fast_lane >= 8
     assert recorded["ingest.merge.scatter"]["calls"] == len(steps) - no_fast_lane
     assert counted["ingest.enqueue_outputs"] == recorded["ingest.enqueue_outputs"]["value"]
-    assert counted["ingest.enqueue_outputs"] == 29 * no_fast_lane + 35 * (len(steps) - no_fast_lane)
+    assert counted["ingest.enqueue_outputs"] == 29 * len(steps) + sum(
+        7 + (s["active"] is not None) for s in steps if s["merged"]
+    )
     assert recorded["ingest.plan.h2d"]["calls"] == recorded["ingest.plan.host_rows"]["calls"] == len(steps)
 
 

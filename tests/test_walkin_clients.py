@@ -216,7 +216,7 @@ FILLERS = range(100_000, 100_000 + SLOTS - 8)  # interned from outside, so that 
 PROGRAMS = {
     "decode": dk._decode_updates_v1_jit,
     "integrate": bd._apply_update_batch_jit,
-    "gather": ingest_mod._gather_raw_lanes_jit,
+    "gather": ingest_mod._gather_manifest_jit,
     "merge": ingest_mod._merge_stream_jit,
 }
 SMALL, LARGE = 41, 3_000_000_041  # the two writers followed from step to step

@@ -41,6 +41,13 @@ from ytpu.models.batch_doc import (
     init_state,
 )
 from ytpu.ops.decode_kernel import (
+    LANE_AT,
+    LANE_BASE,
+    LANE_FIELDS,
+    LANE_LEN,
+    LANE_OFFSET,
+    LANE_PREFIX,
+    LANE_ROOT_HASH,
     ChunkedWirePayloads,
     gather_raw_lanes,
     steps_for_columns,
@@ -117,23 +124,89 @@ _REF = UpdateBatch._fields.index("content_ref")
 _VALID = UpdateBatch._fields.index("valid")
 
 
+def pack_lane_table(lens, root_hash, at, prefix, base: int) -> np.ndarray:
+    """The fast lanes' columns of a step as one host array, ``[LANE_FIELDS,
+    S]`` little-endian i32 (`decode_kernel.LANE_*` names the rows): each
+    lane's length, its primary root's hash, its row of the step's batch
+    (`_step_rows`), where its bytes start in the chunk the step retained,
+    and that chunk's `base`, the same in every column. The lanes' bytes
+    lie end to end in the wire arena, so `LANE_OFFSET` follows from the
+    lengths."""
+    table = np.empty((LANE_FIELDS, len(lens)), dtype="<i4")
+    table[LANE_OFFSET, 0] = 0
+    np.cumsum(lens[:-1], out=table[LANE_OFFSET, 1:])
+    table[LANE_LEN] = lens
+    table[LANE_ROOT_HASH] = root_hash
+    table[LANE_AT] = at
+    table[LANE_PREFIX] = prefix
+    table[LANE_BASE] = base
+    return table
+
+
+def pack_manifest(payloads, table: np.ndarray, active=None) -> np.ndarray:
+    """A merging step's one upload: the wire arena (the fast lanes' bytes
+    end to end, zero-padded to `_bucket(len, 256)`: the programs that take
+    it specialize on its length), then `table` (`pack_lane_table`), then the
+    step's `active` slots ([K] i32; a dense step has none), the int32
+    words as little-endian bytes. `gather_manifest_lanes` takes it apart
+    on the device."""
+    flat = b"".join(payloads)
+    arena = _bucket(len(flat), 256)
+    n_words = table.size + (0 if active is None else len(active))
+    manifest = np.zeros(arena + 4 * n_words, dtype=np.uint8)
+    manifest[: len(flat)] = np.frombuffer(flat, dtype=np.uint8)
+    words = manifest[arena:].view("<i4")  # the arena is a whole number of words
+    words[: table.size] = table.ravel()
+    if active is not None:
+        words[table.size :] = active
+    return manifest
+
+
+def gather_manifest_lanes(manifest, lanes: int, width: int, step_width: int):
+    """The step's first program: the manifest (`pack_manifest`, u8) taken
+    apart inside the traced body. Hands back the padded ``[lanes, width]``
+    lane matrix (`gather_raw_lanes` over the arena), the ``[LANE_FIELDS,
+    lanes]`` i32 lane table, and the step's `active` (``[step_width]``
+    i32, None where `step_width` is 0: the dense step). The statics say
+    where the arena ends; with the manifest's length they are what keyed
+    the lanes' gather before (S, the wire bucket, L) and, in a compact
+    step, the step's width. An int32 word is put together from its four
+    bytes by shifts, so no byte order but the manifest's own is assumed."""
+    n_words = LANE_FIELDS * lanes + step_width
+    arena = manifest.shape[0] - 4 * n_words
+    quads = manifest[arena:].reshape(n_words, 4).astype(jnp.int32)
+    words = (
+        quads[:, 0] | quads[:, 1] << 8 | quads[:, 2] << 16 | quads[:, 3] << 24
+    )
+    table = words[: LANE_FIELDS * lanes].reshape(LANE_FIELDS, lanes)
+    matrix = gather_raw_lanes(
+        manifest[:arena], table[LANE_OFFSET], table[LANE_LEN], width
+    )
+    active = words[LANE_FIELDS * lanes :] if step_width else None
+    return matrix, table, active
+
+
 @jax.named_scope("merge_stream")
-def merge_stream(batch, stream, idx, prefix, base, width: int) -> PackedBatch:
+def merge_stream(batch, stream, table, width: int) -> PackedBatch:
     """The fast lanes' decoded `stream` ([S, ...]) laid over the host
-    lane's `batch` ([W, ...], the step's width) at its rows `idx` ([S]
-    i32: where each lane's slot sits in the step, `_step_rows`). Both
-    arrive as a `PackedBatch` (the host's upload or the kept batch, the
-    served decoder's output) and one leaves: two scatters on the room
-    axis over the two arrays, two output buffers, and the planes are
-    never made (`content_ref` and `valid` are two columns of `rows`).
+    lane's `batch` ([W, ...], the step's width) at the rows the lane
+    `table` gives (its row `LANE_AT`, [S] i32: where each lane's slot sits
+    in the step, `_step_rows`). Both arrive as a `PackedBatch` (the host's
+    upload or the kept batch, the served decoder's output) and one leaves:
+    two scatters on the room axis over the two arrays, two output buffers,
+    and the planes are never made (`content_ref` and `valid` are two
+    columns of `rows`).
 
     String rows leave the decoder with refs into the padded lane matrix
     (``s * width + start``); the step retained only the string-bearing
     lanes' bytes, trimmed and concatenated, so each ref is rebased onto
-    that chunk: lane s's bytes start at ``prefix[s]`` ([S] i32) of the
-    chunk at `base` (0-d i32), and a wire ref is stored as ``-2 - ref``.
-    `prefix` and `base` differ every step: operands, never statics."""
+    that chunk: lane s's bytes start at the table's ``LANE_PREFIX`` of the
+    chunk at its ``LANE_BASE``, and a wire ref is stored as ``-2 - ref``.
+    The table differs every step and is a device array the step's first
+    program made (`gather_manifest_lanes`): an operand, never a static,
+    and nothing that rides up with the call."""
     rows, dels = stream
+    idx, prefix, base = table[LANE_AT], table[LANE_PREFIX], table[LANE_BASE, 0]
     ref = rows[..., _REF]
     lane = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None]
     compact_ref = prefix[:, None] + (ref - lane * width)
@@ -148,18 +221,20 @@ def merge_stream(batch, stream, idx, prefix, base, width: int) -> PackedBatch:
 
 # The merge's device-side glue is two programs of its own, not eager ops:
 # eagerly one plane's `at[idx].set` is about a dozen tiny programs, each
-# ~180 us of host time on the chip's host (PERF.md §6, PR 27).
-# `gather_raw_lanes` is jitted here, not at its definition: decode_v2,
-# integrate_kernel and replay call it inside programs of their own.
+# ~180 us of host time on the chip's host (PERF.md §6, PR 27). The first
+# is the only one the wire bucket keys: it is cheap to build (PERF.md §5),
+# the decoder and the integrate program are not.
 _merge_stream_jit = jax.jit(merge_stream, static_argnames=("width",))
-_gather_raw_lanes_jit = jax.jit(gather_raw_lanes, static_argnames=("width",))
+_gather_manifest_jit = jax.jit(
+    gather_manifest_lanes, static_argnames=("lanes", "width", "step_width")
+)
 
 
 def _register_programs():
     from ytpu.utils import progbudget
 
     progbudget.register("merge_stream", _merge_stream_jit)
-    progbudget.register("gather_raw_lanes", _gather_raw_lanes_jit)
+    progbudget.register("gather_manifest_lanes", _gather_manifest_jit)
 
 
 _register_programs()
@@ -285,6 +360,8 @@ class BatchIngestor:
         self._m_host_rows = metrics.counter("ingest.host_rows")
         # output buffers of the programs a step enqueued (`_count_outputs`)
         self._m_enqueue_outputs = metrics.counter("ingest.enqueue_outputs")
+        # host arrays a step sent to the device(s) (`_upload`)
+        self._m_step_uploads = metrics.counter("ingest.step_uploads")
         # compaction on the served path (`_compact`): rooms compacted, the
         # rows they held before and what that freed; bounds made exact by
         # a read of `n_blocks` (`_recount`); rooms whose update might not
@@ -461,7 +538,13 @@ class BatchIngestor:
         of a step sits on the first chip alone. Left there, jax carries
         each plane across inside the integrate call, a slicing program
         and a copy a chip: 32 ms of a 127 ms step on four v5e chips
-        (PERF.md §6, PR 28)."""
+        (PERF.md §6, PR 28).
+
+        Every host array a step sends goes through here, and none rides
+        up with a jitted call as a numpy operand: `ingest.step_uploads`
+        counts one a leaf. A transfer costs the host about what six
+        output buffers do, whatever its bytes (PERF.md section 6, PR 44)."""
+        self._tally(self._m_step_uploads, len(jax.tree.leaves(host)))
         if self._on_every_chip is None:
             return jax.tree.map(jnp.asarray, host)
         return jax.device_put(
@@ -486,13 +569,10 @@ class BatchIngestor:
         """`ingest.enqueue_outputs`: one count an output buffer (a leaf of
         what the call returned) of the programs a step enqueued. What an
         enqueue costs the host goes with them (PERF.md section 6, PR 42):
-        35 a step that merges (the gather's 1, the decoder's 3, the
-        merge's 2, the state's 29), an array over several chips one."""
-        from ytpu.utils.phases import phases
-
-        n = len(jax.tree.leaves(outs))
-        self._m_enqueue_outputs.inc(n)
-        phases.add_value(self._m_enqueue_outputs.name, n)
+        37 a compact step that merges (the gather's 3: the lane matrix,
+        the lane table, `active`; the decoder's 3, the merge's 2, the
+        state's 29), an array over several chips one."""
+        self._tally(self._m_enqueue_outputs, len(jax.tree.leaves(outs)))
 
     def _decode_tables(self) -> dict:
         """`decode_updates_v1`'s tables of every interned client, key and
@@ -1333,6 +1413,12 @@ class BatchIngestor:
                             )
                         if not planned:
                             self._keep_batch(bucket, batch)
+                    if not fast_idx:
+                        # no manifest to ride in (`_merge_fast_lane`): the
+                        # step's `active` goes up beside the batch. Its 4 B
+                        # a slot are not in the stage's bytes, which are
+                        # the batch's (`plan_h2d_kb.flood`)
+                        active = self._upload(active)
                 took = self._m_batch_builds if built else self._m_batch_reuses
                 took.inc()
                 phases.add_value(took.name, 1)  # the recorder's: a window's delta
@@ -1347,12 +1433,13 @@ class BatchIngestor:
             if fast_idx:
                 # retain wire bytes only for lanes that actually emitted string
                 # rows (delete/GC-only payloads hold no device-referenced spans)
-                batch, flags, chunk_base = self._merge_fast_lane(
+                batch, flags, chunk_base, active = self._merge_fast_lane(
                     batch, fast_idx, self._step_rows(active, fast_idx),
                     fast_payloads, n_rows, n_dels,
                     retain_lanes=fast_has_str,
                     n_steps=16 * ((max_steps + 15) // 16) or None,
                     max_sections=_bucket(max_sections, 2) if max_sections else None,
+                    active=active,
                 )
             with phases.span("ingest.rank_table"):
                 # after the prescan has interned what this step brought
@@ -1360,10 +1447,11 @@ class BatchIngestor:
             # a room whose update might leave it short of rows is squashed,
             # collected and defragmented first (`ingest.compact`)
             self._make_room(adds)
-            # `active` rides up with the call, as `merge_stream`'s `idx`
-            # does; the batch is `[len(active), ...]` already, and a pair
-            # whether a merge made it or the host lane's upload goes
-            # straight in: one form of the program a bucket
+            # `active` is on the device(s) already (the merge's first
+            # program handed it back, out of the step's manifest), as the
+            # batch is: `[len(active), ...]`, and a pair whether a merge
+            # made it or the host lane's upload goes straight in. One form
+            # of the program a bucket, and no host array crosses with it
             self.state = apply_update_batch(
                 self.state, batch, client_rank, active
             )
@@ -1426,7 +1514,7 @@ class BatchIngestor:
                 *self._planned_batch(active, planned), by_doc=active is None
             ),
             self._client_rank(),
-            active,
+            self._upload(active),
         )
         self._count_outputs(self.state)
 
@@ -1441,85 +1529,38 @@ class BatchIngestor:
         retain_lanes=None,
         n_steps=None,
         max_sections=None,
+        active=None,
     ):
         """Decode the fast lanes (the slots `fast_idx`) on the device and
         lay them over `batch` at its rows `fast_at` (`_step_rows`).
 
-        After the uploads, at most three device programs: the raw lanes'
-        gather, `decode_updates_v1`, `merge_stream`. Each is keyed by what
-        keys the decode family (S, the wire bucket, L, `n_rows`, `n_dels`)
-        and by nothing else. `batch` comes and goes as a `PackedBatch`,
-        and so does the decoded stream between the two programs."""
+        One upload, the step's manifest (`pack_manifest`: the wire bytes,
+        the lanes' columns and the step's `active`), then at most three
+        device programs: the manifest's gather, `decode_updates_v1`,
+        `merge_stream`. The first is keyed by what keys the decode family
+        (S, the wire bucket, L) and the step's width, the other two by S,
+        L, `n_rows`, `n_dels` and by nothing else; each is handed device
+        arrays alone. `batch` comes and goes as a `PackedBatch`, and so
+        does the decoded stream between the two programs; `active` comes
+        back as the device array the integrate call takes."""
         from ytpu.ops.decode_kernel import decode_updates_v1, pack_updates
         from ytpu.utils.phases import phases
 
         # the host stages of the merge, in order (docs/observability.md,
-        # "Inside a dispatch"): pack, h2d, gather, retain, tables,
+        # "Inside a dispatch"): retain, pack, h2d, gather, tables,
         # decode.v1, scatter — between them they are the host's share of
         # a served step that is neither planning nor integrate
         with phases.span("ingest.merge"):
-            maxlen = max(len(p) for p in fast_payloads)
             S = len(fast_payloads)
             raw = self.ingest == "raw"
-            with phases.span("ingest.merge.pack"):
-                if raw:
-                    # RAW lane: ship the actual wire bytes + offsets,
-                    # gather the padded [S, L] matrix on device
-                    # (byte-identical to the packed matrix —
-                    # gather_raw_lanes zero-masks past lens)
-                    L = _bucket(maxlen + 16, 64)
-                    lens = np.asarray(
-                        [len(p) for p in fast_payloads], dtype=np.int32
-                    )
-                    offsets = np.zeros(S, dtype=np.int32)
-                    if S > 1:
-                        offsets[1:] = np.cumsum(lens[:-1])
-                    flat = b"".join(fast_payloads)
-                    # the gather specializes on the arena LENGTH: pad it
-                    # to a bucket so a long soak's ever-varying flush
-                    # sizes reuse a handful of compiled gathers (the zero
-                    # tail is masked out, exactly like the padded
-                    # matrix's row tails)
-                    wire = np.zeros(_bucket(len(flat), 256), dtype=np.uint8)
-                    wire[: len(flat)] = np.frombuffer(flat, dtype=np.uint8)
-                    host_arrays = (wire, offsets, lens)
-                else:
-                    buf, lens = pack_updates(
-                        fast_payloads, pad_to=_bucket(maxlen + 16, 64)
-                    )
-                    S, L = buf.shape
-                    host_arrays = (buf, lens)
-                # the lanes' primary roots: the one table a step makes
-                prim_hash = np.full(S, -1, dtype=np.int32)
-                for s_i, d in enumerate(fast_idx):
-                    name = self.primary_roots.get(d)
-                    if name is not None:
-                        prim_hash[s_i] = self._key_hash(name)
-                host_arrays += (prim_hash,)
-            with phases.span("ingest.merge.h2d"):
-                # the wire bytes' one trip to HBM, counted here and
-                # nowhere else (decode.v1 is handed device arrays)
-                *dev_arrays, dev_prim_hash = self._upload(list(host_arrays))
-                dev_lens = dev_arrays[-1]
-                if phases.enabled:
-                    phases.transfer(
-                        "ingest.merge.h2d",
-                        self._uploaded_bytes(host_arrays),
-                        "h2d",
-                    )
-            with phases.span("ingest.merge.gather"):
-                # one enqueue (`jit_gather_raw_lanes`); the packed mode
-                # uploaded the matrix itself
-                dev_buf = (
-                    _gather_raw_lanes_jit(*dev_arrays, width=L)
-                    if raw
-                    else dev_arrays[0]
-                )
+            lens = np.asarray([len(p) for p in fast_payloads], dtype=np.int32)
+            L = _bucket(int(lens.max()) + 16, 64)
             # Retain only the wire bytes of lanes that emitted string rows
             # (lens-trimmed, concatenated) — `merge_stream` rebases refs
             # from the padded s*L layout onto the compact one. Lanes
             # without string rows have no device-referenced spans, so
-            # their bytes are never kept.
+            # their bytes are never kept. Host work all of it, and what
+            # it works out rides in the step's one upload
             with phases.span("ingest.merge.retain"):
                 keep = (
                     np.ones(S, dtype=bool)
@@ -1536,29 +1577,61 @@ class BatchIngestor:
                     base = self.payloads.add_chunk(
                         np.frombuffer(compact, dtype=np.uint8)
                     )
+            with phases.span("ingest.merge.pack"):
+                # the lanes' primary roots: the one table a step makes
+                prim_hash = np.full(S, -1, dtype=np.int32)
+                for s_i, d in enumerate(fast_idx):
+                    name = self.primary_roots.get(d)
+                    if name is not None:
+                        prim_hash[s_i] = self._key_hash(name)
+                table = pack_lane_table(lens, prim_hash, fast_at, prefix, base)
+                if raw:
+                    # RAW lane: ship the actual wire bytes and the lanes'
+                    # offsets, gather the padded [S, L] matrix on device
+                    # (byte-identical to the packed matrix: the gather
+                    # zero-masks past lens), all of it one host array
+                    host = pack_manifest(fast_payloads, table, active)
+                else:
+                    # the host-padded matrix, and leaves of its own
+                    host = (pack_updates(fast_payloads, pad_to=L)[0], table, active)
+            with phases.span("ingest.merge.h2d"):
+                # the step's one trip to HBM, the wire bytes in it counted
+                # here and nowhere else (every program below is handed
+                # device arrays)
+                dev = self._upload(host)
+                if phases.enabled:
+                    phases.transfer(
+                        "ingest.merge.h2d", self._uploaded_bytes(host), "h2d"
+                    )
+            with phases.span("ingest.merge.gather"):
+                # one enqueue (`jit_gather_manifest_lanes`); the packed
+                # mode uploaded the matrix itself
+                gathered = ()
+                if raw:
+                    gathered = dev = _gather_manifest_jit(
+                        dev,
+                        lanes=S,
+                        width=L,
+                        step_width=0 if active is None else len(active),
+                    )
+                dev_buf, dev_table, dev_active = dev
             with phases.span("ingest.merge.tables"):
                 tables = self._decode_tables()
             stream, flags = decode_updates_v1(
                 dev_buf,
-                dev_lens,
+                None,
                 n_rows,
                 n_dels,
                 n_steps=n_steps,
                 max_sections=max_sections,
-                primary_root_hash=dev_prim_hash,
                 packed=True,
+                lane_table=dev_table,
                 **tables,
             )
             with phases.span("ingest.merge.scatter"):
                 # one enqueue (`jit_merge_stream`: rebase + the scatter
-                # of both arrays); idx, prefix and base ride up with it
-                merged = _merge_stream_jit(
-                    batch,
-                    stream,
-                    fast_at,
-                    prefix,
-                    np.int32(base),
-                    width=L,
-                )
-            self._count_outputs(dev_buf if raw else (), stream, flags, merged)
-        return merged, flags, (base if keep.any() else None)
+                # of both arrays) and nothing else: the lanes' rows, the
+                # chunk's prefix and base are rows of the lane table
+                merged = _merge_stream_jit(batch, stream, dev_table, width=L)
+            self._count_outputs(gathered, stream, flags, merged)
+        return merged, flags, (base if keep.any() else None), dev_active

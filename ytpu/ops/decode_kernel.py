@@ -168,6 +168,25 @@ FLAG_ERRORS = (
 # key-hash window: parent_sub keys longer than this take the host lane
 KEY_HASH_BYTES = 32
 
+# --- the served step's lane table ---------------------------------------------
+# Rows of the ``[LANE_FIELDS, S]`` i32 table a served step's first program
+# takes out of the step's one upload and hands back beside the lane matrix
+# (`ingest.gather_manifest_lanes`), a column a fast lane: where the lane's
+# bytes start in the wire arena, how many they are, its primary root's hash
+# (-1: none), its row of the step's batch, where its bytes start in the
+# retained chunk, and the chunk's base (the same in every column). The
+# decoder reads `LANE_LEN` and `LANE_ROOT_HASH`; `ingest.merge_stream`
+# `LANE_AT`, `LANE_PREFIX` and `LANE_BASE`.
+(
+    LANE_OFFSET,
+    LANE_LEN,
+    LANE_ROOT_HASH,
+    LANE_AT,
+    LANE_PREFIX,
+    LANE_BASE,
+) = range(6)
+LANE_FIELDS = 6
+
 _PAD = 16  # gather guard past the longest update
 
 
@@ -389,6 +408,7 @@ def decode_updates_v1(
     client_hash_table: Optional[Tuple[jax.Array, jax.Array]] = None,
     primary_root_hash: Optional[jax.Array] = None,
     packed: bool = False,
+    lane_table: Optional[jax.Array] = None,
 ) -> Tuple[UpdateBatch, jax.Array]:
     """Decode S updates into an ``[S, U] / [S, R]`` UpdateBatch stream.
 
@@ -436,7 +456,16 @@ def decode_updates_v1(
     anchor key id (miss -> FLAG_UNKNOWN_KEY, name beyond the hash
     window -> FLAG_UNSUPPORTED). Without it every named root aliases to
     the primary branch — the pre-multi-root behavior.
+
+    ``lane_table`` (``[LANE_FIELDS, S]`` i32, in place of ``lens`` and
+    ``primary_root_hash``, which are then None) is the served step's: its
+    rows `LANE_LEN` and `LANE_ROOT_HASH` are read here, inside the traced
+    body, so the step hands over a device array its first program made
+    and no host array crosses with the call.
     """
+    if lane_table is not None:
+        lens = lane_table[LANE_LEN]
+        primary_root_hash = lane_table[LANE_ROOT_HASH]
     S, L = buf.shape
     U, R = max_rows, max_dels
     T = n_steps or default_steps(U, R)
@@ -1568,6 +1597,7 @@ def decode_updates_v1(
     client_hash_table=None,
     primary_root_hash=None,
     packed=False,
+    lane_table=None,
 ):
     from ytpu.utils.phases import NULL_SPAN, phases, program_memory
     from ytpu.utils.progbudget import tick
@@ -1588,7 +1618,8 @@ def decode_updates_v1(
             "decode.v1",
             (buf.shape, max_rows, max_dels, n_steps, max_sections,
              client_table is not None, key_table is not None,
-             client_hash_table is not None, primary_root_hash is not None,
+             client_hash_table is not None,
+             primary_root_hash is not None or lane_table is not None,
              packed),
             axes=("buf", "max_rows", "max_dels", "n_steps",
                   "max_sections", "client_table", "key_table",
@@ -1606,6 +1637,7 @@ def decode_updates_v1(
                 client_hash_table=client_hash_table,
                 primary_root_hash=primary_root_hash,
                 packed=packed,
+                lane_table=lane_table,
             ),
         )
     else:
@@ -1623,6 +1655,7 @@ def decode_updates_v1(
             client_hash_table=client_hash_table,
             primary_root_hash=primary_root_hash,
             packed=packed,
+            lane_table=lane_table,
         )
 
 
