@@ -272,8 +272,12 @@ class Update:
 
         merged: Dict[ClientID, Deque[Carrier]] = {}
         for client, carriers in all_blocks.items():
-            # stable order: by clock; prefer Items over Skips on ties
-            carriers.sort(key=lambda c: (c.id.clock, c.is_skip))
+            # an input's Skip says only that the input held nothing there:
+            # it must not shadow the blocks another input has for the same
+            # clocks (a pending update merged with a later arrival that fills
+            # its gap lost them for good). Gaps are synthesized anew below
+            carriers = [c for c in carriers if not c.is_skip]
+            carriers.sort(key=lambda c: c.id.clock)
             out: Deque[Carrier] = deque()
             current_end: Optional[int] = None  # clock after last emitted carrier
             for c in carriers:
@@ -299,9 +303,7 @@ class Update:
                 else:
                     # partial overlap: emit only the uncovered suffix
                     overlap = current_end - start  # > 0 here
-                    if c.is_skip:
-                        out.append(SkipRange(ID(client, current_end), length - overlap))
-                    elif isinstance(c, GCRange):
+                    if isinstance(c, GCRange):
                         out.append(GCRange(ID(client, current_end), length - overlap))
                     else:
                         # split a detached clone — merge() must never mutate
@@ -318,9 +320,6 @@ class Update:
                         right.left = None
                         out.append(right)
                     current_end = start + length
-            # drop trailing skips: they carry no information
-            while out and out[-1].is_skip:
-                out.pop()
             if out:
                 merged[client] = out
         return cls(merged, delete_set)

@@ -12,10 +12,27 @@ update goes pending must not stall its batch":
   (`BatchEncoder.partition_carriers`): the applicable prefix ships in this
   step's batch, the remainder is stashed per doc;
 - delete ranges beyond the mirror stash into a per-doc pending delete set;
-- every later step re-merges the stash with new arrivals, so blocks
-  integrate the moment their dependencies land — other doc slots in the
-  batch are never stalled, and the device never sees a missing-dep row
-  (`ERR_MISSING_DEP` stays 0 by construction).
+- a stash moves only when its own room gets an update: `_plan_doc` merges
+  the room's stash with the arrival (`_merge_with_stash`), partitions the
+  whole again and stashes what still waits, so blocks integrate in the
+  step that brings their last dependency — a room without an arrival
+  plans nothing, its stash stays as it is (its mirror only advances
+  through its own updates), other doc slots in the batch are never
+  stalled, and the device never sees a missing-dep row
+  (`ERR_MISSING_DEP` stays 0 by construction). While a room holds a
+  stash every update of it takes the host lane (`ingest.slow.pending`);
+  the step after the stash empties it is back on the fast lane;
+- what waits is counted (`_count_stash`): `ingest.stash_updates` (updates
+  of which a block or a delete range went into a stash),
+  `ingest.stash_released` (those wholly integrated since),
+  `ingest.stash_wait_steps` (summed over the released: served steps of
+  their room from the one that stashed them to the one that released
+  them), `ingest.stash_rooms` (summed over steps: rooms holding a stash
+  as the step plans), and the span `ingest.plan.stash` around
+  `_plan_doc` for a host-lane room of a served step that holds a stash
+  or whose update the prescan found waiting for another (the re-merge,
+  the partition, the deferred-delete split, the counts, and the rows of
+  what is released).
 """
 
 from __future__ import annotations
@@ -352,7 +369,7 @@ class BatchIngestor:
         self._m_first_seen_big = metrics.counter(
             "ingest.clients_first_seen_big"
         )
-        # why a payload took the host lane (`_fast_eligible`), and the
+        # why a payload took the host lane (`_host_lane_reason`), and the
         # rows the host lane planned for the step (`_plan_doc`)
         self._m_slow_reason = {
             r: metrics.counter("ingest.slow." + r) for r in _SLOW_REASONS
@@ -371,6 +388,18 @@ class BatchIngestor:
         self._m_rows_reclaimed = metrics.counter("ingest.rows_reclaimed")
         self._m_recounts = metrics.counter("ingest.row_recounts")
         self._m_refusals = metrics.counter("ingest.capacity_refusals")
+        # the stash (`_count_stash`): updates that went into one, those
+        # wholly integrated since, the served steps of their room they
+        # waited, and, a step, the rooms that hold one
+        self._m_stash_updates = metrics.counter("ingest.stash_updates")
+        self._m_stash_released = metrics.counter("ingest.stash_released")
+        self._m_stash_wait = metrics.counter("ingest.stash_wait_steps")
+        self._m_stash_rooms = metrics.counter("ingest.stash_rooms")
+        # slot -> its stashed updates, one `[steps waited, needs]` each,
+        # `needs` the (client, clock) ends the room's mirror must reach:
+        # what the four counts are kept from, and nothing else reads it (a
+        # restored stash has no tickets: its release is not counted)
+        self._stash_tickets: Dict[int, list] = {}
 
     def _reset_tables(self) -> None:
         """The device lookup tables' sources, empty, and nothing built of
@@ -663,6 +692,7 @@ class BatchIngestor:
         self.svs[doc] = StateVector()
         self._pending[doc] = {}
         self._pending_ds[doc] = DeleteSet()
+        self._stash_tickets.pop(doc, None)
         self.primary_roots.pop(doc, None)
         self._anchored_roots[doc] = set()
         self._rows_bound[doc] = self._rows_compacted_to[doc] = 0
@@ -731,7 +761,8 @@ class BatchIngestor:
         return Update(blocks, ds)
 
     def _plan_doc(self, doc: int, incoming: Optional[Update]) -> Tuple[list, list]:
-        """(rows, dels) applicable now; the rest returns to the stash."""
+        """(rows, dels) applicable now; the rest returns to the stash,
+        counted (`_count_stash`) where there was or is one."""
         if incoming is None:
             # a stuck stash cannot progress without new data for this doc:
             # its mirror SV only advances through its own incoming updates
@@ -757,12 +788,53 @@ class BatchIngestor:
                 else:  # split: tombstone what exists, defer the tail
                     dels.append((c, start, covered))
                     self._pending_ds[doc].insert_range(client, covered, end)
+        if (
+            doc in self._stash_tickets
+            or self._pending[doc]
+            or not self._pending_ds[doc].is_empty()
+        ):
+            self._count_stash(doc, incoming)
         return (
             self.enc.rows_from_carriers(
                 applicable, primary_root=self.primary_roots.get(doc)
             ),
             dels,
         )
+
+    def _count_stash(self, doc: int, incoming: Update) -> None:
+        """Keep the stash's counters for a room that has just planned
+        `incoming` (`_plan_doc`): every update that waited there has
+        waited one more served step of its room, and is released if the
+        mirror now covers all it brought; `incoming` waits from now on if
+        the mirror does not cover a block or a delete range of it."""
+        sv = self.svs[doc]
+        waiting = []
+        for ticket in self._stash_tickets.get(doc, ()):
+            ticket[0] += 1
+            if all(sv.get(c) >= end for c, end in ticket[1]):
+                self._tally(self._m_stash_released)
+                self._tally(self._m_stash_wait, ticket[0])
+            else:
+                waiting.append(ticket)
+        needs = [
+            (c.id.client, c.id.clock + c.len)
+            for q in incoming.blocks.values()
+            for c in q
+            if not c.is_skip
+        ]
+        needs += [
+            (client, end)
+            for client, ranges in incoming.delete_set.clients.items()
+            for _, end in ranges
+        ]
+        needs = [(c, end) for c, end in needs if sv.get(c) < end]
+        if needs:
+            waiting.append([0, needs])
+            self._tally(self._m_stash_updates)
+        if waiting:
+            self._stash_tickets[doc] = waiting
+        else:
+            self._stash_tickets.pop(doc, None)
 
     def apply(
         self, payloads: List[Optional[bytes]], v2: bool = False
@@ -796,19 +868,21 @@ class BatchIngestor:
     # --- raw-bytes fast lane ---------------------------------------------------
 
     def _fast_eligible(self, doc: int, cols) -> bool:
-        """Can this update's wire bytes go straight to the device? Where
-        they cannot, the first reason found is counted
-        (`ingest.slow.<reason>`, `_slow_reason`): a payload that can
-        counts nothing."""
-        reason = self._slow_reason(doc, cols)
-        if reason is None:
-            return True
-        from ytpu.utils.phases import phases
+        """Can this update's wire bytes go straight to the device?"""
+        return self._host_lane_reason(doc, cols) is None
 
-        took = self._m_slow_reason[reason]
-        took.inc()
-        phases.add_value(took.name, 1)  # the recorder's: a window's delta
-        return False
+    def _host_lane_reason(self, doc: int, cols) -> Optional[str]:
+        """`_slow_reason`, counted: where the update's wire bytes cannot
+        go straight to the device, the first reason found
+        (`ingest.slow.<reason>`); a payload that can counts nothing."""
+        reason = self._slow_reason(doc, cols)
+        if reason is not None:
+            from ytpu.utils.phases import phases
+
+            took = self._m_slow_reason[reason]
+            took.inc()
+            phases.add_value(took.name, 1)  # the recorder's: a window's delta
+        return reason
 
     def _slow_reason(self, doc: int, cols) -> Optional[str]:
         """Why this update's wire bytes cannot go straight to the device
@@ -1309,17 +1383,26 @@ class BatchIngestor:
                 fast_sv_deltas: Dict[int, Dict[int, int]] = {}
                 fast_has_str: List[bool] = []
                 slow_updates: Dict[int, Update] = {}  # slot -> its update
+                # those of them whose room holds a stash (`pending`) or gains
+                # one (`dependency`: the update waits for another)
+                stash_rooms = set()
                 # slot -> the rows its update can add at most (`_make_room`)
                 adds: Dict[int, int] = {}
                 max_fast_rows, max_fast_dels = 0, 0
                 max_sections, max_steps = 0, 0
+                if self._stash_tickets:
+                    # rooms holding a stash as the step plans
+                    self._tally(self._m_stash_rooms, len(self._stash_tickets))
                 with phases.span("ingest.plan.prescan"):
                     for d, p in enumerate(payloads):
                         if p is None:
                             continue
                         live.append(d)
                         cols = decode_update_columns(p) if native else None
-                        if cols is None or not self._fast_eligible(d, cols):
+                        why = None if cols is None else self._host_lane_reason(d, cols)
+                        if cols is None or why is not None:
+                            if why in ("pending", "dependency"):
+                                stash_rooms.add(d)
                             with phases.span("ingest.plan.decode_host"):
                                 slow_updates[d] = Update.decode_v1(p)
                             continue
@@ -1374,9 +1457,13 @@ class BatchIngestor:
                 # row at all: its batch is `batch_packed`'s padding, kept
                 # on the device by bucket
                 with phases.span("ingest.plan.host_rows"):
-                    planned = {
-                        d: self._plan_doc(d, u) for d, u in slow_updates.items()
-                    }
+                    planned = {}
+                    for d, u in slow_updates.items():
+                        if d in stash_rooms:
+                            with phases.span("ingest.plan.stash"):
+                                planned[d] = self._plan_doc(d, u)
+                        else:
+                            planned[d] = self._plan_doc(d, u)
                     n_rows = _bucket(max(
                         [max_fast_rows, 1] + [len(r) for r, _ in planned.values()]
                     ))
