@@ -126,7 +126,7 @@ class _Spy:
         self.replanned = {}  # a recovery's follow-up step: slot -> (rows, dels)
         self.decodes = 0
         self.recovering = False
-        real_merge, real_apply = ingest_mod._merge_stream_jit, ingest_mod.apply_update_batch
+        real_merge, real_apply = ingest_mod._merge_stream_jit, ingest_mod.apply_update_batch_in_place
         real_decode, real_plan, real_recover = dk.decode_updates_v1, ing._plan_doc, ing._recover_flagged
 
         def merge(batch, stream, table, **kw):
@@ -166,7 +166,7 @@ class _Spy:
             return stream, flags
 
         monkeypatch.setattr(ingest_mod, "_merge_stream_jit", merge)
-        monkeypatch.setattr(ingest_mod, "apply_update_batch", apply)
+        monkeypatch.setattr(ingest_mod, "apply_update_batch_in_place", apply)
         monkeypatch.setattr(dk, "decode_updates_v1", decode)
         monkeypatch.setattr(ing, "_plan_doc", plan)
         monkeypatch.setattr(ing, "_recover_flagged", recover)
@@ -518,7 +518,7 @@ def test_a_served_process_keeps_one_integrate_form_a_bucket(monkeypatch):
     it; and a step's programs hand back 36 buffers where it merges (the
     gather's 2 in these dense steps, the lane matrix and the lane table;
     the decoder's 3, the merge's 2, the state's 29), 29 where it does not."""
-    from ytpu.models.batch_doc import _apply_update_batch_jit
+    from ytpu.models.batch_doc import _apply_update_batch_in_place_jit
     from ytpu.utils import progbudget
 
     monkeypatch.setattr(progbudget, "_MAX", 10**9)  # no eviction under our feet
@@ -534,7 +534,7 @@ def test_a_served_process_keeps_one_integrate_form_a_bucket(monkeypatch):
         ("both", [early, rooms[1].edit(50, _type("x2")), None, None], 36),
         ("flagged", [rooms[0].edit(1, _four_and_four), rooms[1].edit(50, _type("x3")), None, None], 36 + 29),
     ]
-    forms = _apply_update_batch_jit._cache_size()
+    forms = _apply_update_batch_in_place_jit._cache_size()
     counter = metrics.counter("ingest.enqueue_outputs")
     for what, payloads, outputs in steps:
         merged, applied, before = len(spy.merged), len(spy.applied), counter.value
@@ -546,7 +546,7 @@ def test_a_served_process_keeps_one_integrate_form_a_bucket(monkeypatch):
         for batch in handed:  # the bucket's one shape, the recovery's too
             assert type(batch) is PackedBatch and (batch.rows.shape, batch.dels.shape) == ((N_DOCS, 4, 23), (N_DOCS, 4, 4)), what
     assert ing.fast_recoveries == 2 and (ing.slow_docs, ing.fast_docs) == (2, 5)
-    assert _apply_update_batch_jit._cache_size() == forms + 1
+    assert _apply_update_batch_in_place_jit._cache_size() == forms + 1
     assert not np.asarray(ing.state.error).any()
     for d in (0, 1):
         assert get_string(ing.state, d, ing.payloads) == rooms[d].oracle().get_text("text").get_string(), d

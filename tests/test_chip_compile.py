@@ -79,6 +79,29 @@ def _hbm_bytes(compiled) -> int:
 V5E_HBM = 16 * 1024**3
 
 
+def _assert_updates_state_in_place(compiled, state, chips=1, copies_no_plane=True):
+    """What a served form that donates its state compiles to: every
+    buffer of the state aliased to its output (a chip's share of it, laid
+    by room over `chips`), and no `copy` whose result is a whole plane a
+    chip holds (`[1024, 4096]` at the benchmark's sizes). Undonated, the
+    compact step held 26 such copies, 52 us each a step on the chip
+    (PERF.md section 6, PR 46). `copies_no_plane=False` for a dense step
+    over 256 rooms a chip, which changes every room and whose loops stage
+    a chip's 4 MB plane through fast memory (`S(1)`): not the carry-over."""
+    m = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert m.alias_size_in_bytes == held // chips, (m, held)
+    if not copies_no_plane:
+        return
+    plane = f"[{state.start.shape[0] // chips},{CAPACITY}]"
+    copies = [
+        ln.strip()[:160]
+        for ln in compiled.as_text().splitlines()
+        if re.search(r"= \w+" + re.escape(plane) + r"\S* copy\(", ln)
+    ]
+    assert not copies, copies
+
+
 def _pair(slots, rows, dels=None):
     """The `PackedBatch` that crosses the served step's program boundaries,
     as host arrays: `[slots, rows, 23]` and `[slots, dels, 4]`."""
@@ -104,21 +127,23 @@ def _manifest(lanes, wire, step_width):
 
 @pytest.mark.parametrize("rows", [4, 512], ids=["tick_bucket", "prefill_bucket"])
 def test_served_integrate_step_fits_one_v5e(one_chip, rows):
-    """`apply_update_batch` as `flush_device` dispatches it over every
-    slot: the 4-row / 4-delete bucket the scenario's updates land in, and
+    """`apply_update_batch`'s served form (`apply_update_batch_in_place`:
+    the state donated) as `flush_device` dispatches it over every slot: the 4-row / 4-delete bucket the scenario's updates land in, and
     the 512-row bucket of the benchmark's prefill; the batch the pair it
     is handed, taken apart inside the program."""
-    from ytpu.models.batch_doc import _apply_update_batch_jit, scan_tier_plan
+    from ytpu.models.batch_doc import _apply_update_batch_in_place_jit, scan_tier_plan
 
-    compiled = _apply_update_batch_jit.lower(
-        _state(one_chip),
+    state = _state(one_chip)
+    compiled = _apply_update_batch_in_place_jit.lower(
+        state,
         _shapes(_pair(N_DOCS, rows), one_chip),
         jax.ShapeDtypeStruct((N_CLIENTS,), jnp.int32, sharding=one_chip),
         scan_tier_plan(),
     ).compile()
     m = compiled.memory_analysis()
     print(f"dense step, {rows}-row bucket: temp bytes {m.temp_size_in_bytes}, argument bytes {m.argument_size_in_bytes}")
-    # not donated: input and output state both live, plus temporaries
+    # donated: the state is there once, plus temporaries
+    _assert_updates_state_in_place(compiled, state)
     assert _hbm_bytes(compiled) < V5E_HBM // 2, m
 
 
@@ -129,7 +154,7 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
-        _apply_update_batch_jit,
+        _apply_update_batch_in_place_jit,
         init_state,
         scan_tier_plan,
     )
@@ -142,7 +167,7 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
     by_doc = lambda a: on(a, P(AXIS_BATCH, *([None] * (a.ndim - 1))))
     state = jax.tree.map(by_doc, jax.eval_shape(lambda: init_state(N_DOCS, CAPACITY)))
     batch = _pair(N_DOCS, 4)
-    compiled = _apply_update_batch_jit.lower(
+    compiled = _apply_update_batch_in_place_jit.lower(
         state,
         jax.tree.map(lambda a: on(a, P()), batch),
         on(jnp.zeros((N_CLIENTS,), jnp.int32), P()),
@@ -151,7 +176,8 @@ def test_doc_sharded_integrate_step_compiles_for_four_chips(topo):
     assert "all-gather" not in compiled.as_text()
     for out in jax.tree.leaves(compiled.output_shardings):
         assert out.spec[0] == AXIS_BATCH, out
-    # per chip: a quarter of the state in and out, plus temporaries
+    # per chip: a quarter of the state, in place, plus temporaries
+    _assert_updates_state_in_place(compiled, state, chips=4, copies_no_plane=False)
     assert _hbm_bytes(compiled) < V5E_HBM // 4, compiled.memory_analysis()
 
 
@@ -167,7 +193,7 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
-        _apply_update_batch_jit,
+        _apply_update_batch_in_place_jit,
         init_state,
         scan_tier_plan,
     )
@@ -197,7 +223,7 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
         merge.output_shardings,
     )
     state = jax.tree.map(by_room, jax.eval_shape(lambda: init_state(rooms, CAPACITY)))
-    step = _apply_update_batch_jit.lower(
+    step = _apply_update_batch_in_place_jit.lower(
         state,
         merged,
         whole(jnp.zeros((2 * N_CLIENTS,), jnp.int32)),
@@ -206,7 +232,8 @@ def test_merge_output_feeds_the_doc_sharded_step_at_4096_rooms(topo):
     assert "all-gather" not in step.as_text()
     for out in jax.tree.leaves(step.output_shardings):
         assert out.spec[0] == AXIS_BATCH, out
-    # per chip: 1,024 rooms of state in and out, plus temporaries
+    # per chip: 1,024 rooms of state, in place, plus temporaries
+    _assert_updates_state_in_place(step, state, chips=4)
     assert _hbm_bytes(step) < V5E_HBM // 4, step.memory_analysis()
 
 
@@ -215,24 +242,26 @@ COMPACT_WIDTH = 16  # `BatchIngestor._active_slots`: a tick of at most 16 rooms
 
 def test_compact_integrate_step_needs_a_fraction_of_the_dense_steps_memory(one_chip):
     """The step `apply_bytes` dispatches for a tick of at most 16 rooms:
-    16 rooms gathered, integrated, scattered back. Not donated, so the
-    state is there twice as in the dense step; the temporaries are those
-    of 16 rooms, not of 1,024. Both forms are handed the pair and take the
-    planes apart inside themselves, and that costs the device no memory to
-    speak of: their temporaries stay within 1% of what the forms that
-    took 27 planes reported (696,401,920 and 14,161,920 B: PR 41's tree)."""
-    from ytpu.models.batch_doc import _apply_update_batch_jit, scan_tier_plan
+    16 rooms gathered, integrated, scattered back. The state is donated,
+    as in the dense step: every buffer of it is the output's and no plane
+    is copied; the temporaries are those of 16 rooms, not of 1,024.
+    Donated, the dense form holds 744,856,576 B of temporaries (696,401,920
+    undonated) and no second state, 1.16 GB in all where it took 1.52; the
+    compact form 7,972,352 B (14,032,896 undonated). Both are handed the
+    pair and take the planes apart inside themselves."""
+    from ytpu.models.batch_doc import _apply_update_batch_in_place_jit, scan_tier_plan
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     # the batch is as wide as the step: every slot, or the tick's 16
     batch = {w: _shapes(_pair(w, 4), one_chip) for w in (N_DOCS, COMPACT_WIDTH)}
     rest = (i32(N_CLIENTS), scan_tier_plan())
-    dense = _apply_update_batch_jit.lower(_state(one_chip), batch[N_DOCS], *rest).compile().memory_analysis()
-    compact = _apply_update_batch_jit.lower(_state(one_chip), batch[COMPACT_WIDTH], *rest, i32(COMPACT_WIDTH)).compile()
+    state = _state(one_chip)
+    dense = _apply_update_batch_in_place_jit.lower(state, batch[N_DOCS], *rest).compile().memory_analysis()
+    compact = _apply_update_batch_in_place_jit.lower(state, batch[COMPACT_WIDTH], *rest, i32(COMPACT_WIDTH)).compile()
     m = compact.memory_analysis()
     print(f"temp bytes: dense step {dense.temp_size_in_bytes}, compact step {m.temp_size_in_bytes}")
-    assert dense.temp_size_in_bytes <= 1.01 * 696_401_920 and m.temp_size_in_bytes <= 1.01 * 14_161_920
-    assert m.alias_size_in_bytes == 0, m  # the roofline counts a state read once, written once
+    assert dense.temp_size_in_bytes <= 1.01 * 744_856_576 and m.temp_size_in_bytes <= 1.01 * 7_972_352
+    _assert_updates_state_in_place(compact, state)
     assert m.output_size_in_bytes == dense.output_size_in_bytes
     assert m.temp_size_in_bytes < dense.temp_size_in_bytes // 16, (m, dense)
     assert _hbm_bytes(compact) < V5E_HBM // 4, m
@@ -250,7 +279,7 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ytpu.models.batch_doc import (
-        _apply_update_batch_jit,
+        _apply_update_batch_in_place_jit,
         init_state,
         scan_tier_plan,
     )
@@ -284,8 +313,9 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     assert not re.search(r"all-(gather|reduce|to-all)|collective-permute", merge.as_text())
     assert {s.spec for s in jax.tree.leaves(merge.output_shardings)} == {P()}
     assert [(o.shape, o.dtype) for o in jax.tree.leaves(merge.out_info)] == [(a.shape, a.dtype) for a in batch]
-    step = _apply_update_batch_jit.lower(
-        jax.tree.map(by_room, jax.eval_shape(lambda: init_state(rooms, CAPACITY))),
+    state = jax.tree.map(by_room, jax.eval_shape(lambda: init_state(rooms, CAPACITY)))
+    step = _apply_update_batch_in_place_jit.lower(
+        state,
         jax.tree.map(whole, batch),
         whole(jnp.zeros((2 * N_CLIENTS,), jnp.int32)),
         scan_tier_plan(),
@@ -304,7 +334,7 @@ def test_doc_sharded_compact_step_moves_no_plane_between_chips(topo):
     for out in jax.tree.leaves(step.output_shardings):
         assert out.spec[0] == AXIS_BATCH, out
     m = step.memory_analysis()
-    assert m.alias_size_in_bytes == 0, m
+    _assert_updates_state_in_place(step, state, chips=4)
     assert _hbm_bytes(step) < V5E_HBM // 8, m
 
 
@@ -324,16 +354,18 @@ def test_served_compaction_fits_one_v5e(one_chip):
     """`compact_rooms` as `apply_bytes` enqueues it when a room nears its
     capacity (PR 43): two rooms gathered (the room and an idle slot behind
     a mask, or two rooms due at once), squashed, collected, defragmented
-    and scattered back. Not donated, as the integrate step; its
+    and scattered back. Donated, as the integrate step: the scatter
+    writes two rooms where they are and no plane is copied; its
     temporaries are those of two rooms, and the report the host re-homes
     strings from is `[2, 4096, 6]`."""
     from ytpu.ops.compaction import REHOME_FIELDS, compact_rooms
 
     place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-    compiled = compact_rooms.lower(_state(one_chip), *_compact_rooms_operands(place)).compile()
+    state = _state(one_chip)
+    compiled = compact_rooms.lower(state, *_compact_rooms_operands(place)).compile()
     m = compiled.memory_analysis()
     print(f"compact_rooms: temp bytes {m.temp_size_in_bytes}, output bytes {m.output_size_in_bytes}")
-    assert m.alias_size_in_bytes == 0, m
+    _assert_updates_state_in_place(compiled, state)
     report = jax.tree.leaves(compiled.out_info)[-2]
     assert report.shape == (2, CAPACITY, len(REHOME_FIELDS))
     assert m.temp_size_in_bytes < V5E_HBM // 64, m
@@ -367,7 +399,7 @@ def test_doc_sharded_compaction_moves_no_plane_between_chips(topo):
     for out in jax.tree.leaves(compiled.output_shardings[0]):
         assert out.spec[0] == AXIS_BATCH, out
     m = compiled.memory_analysis()
-    assert m.alias_size_in_bytes == 0, m
+    _assert_updates_state_in_place(compiled, state, chips=4)
     assert _hbm_bytes(compiled) < V5E_HBM // 8, m
 
 

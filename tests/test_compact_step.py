@@ -105,7 +105,7 @@ def both_steps(monkeypatch):
             assert np.array_equal(got[idle], was[idle])
         return compact
 
-    monkeypatch.setattr(ingest, "apply_update_batch", checked)
+    monkeypatch.setattr(ingest, "apply_update_batch_in_place", checked)
     return widths
 
 

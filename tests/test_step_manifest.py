@@ -28,7 +28,7 @@ import pytest
 from test_batch_cache import _four_and_four
 from test_table_cache import _Room, _cut, _flag_lanes, _type
 from ytpu.models import ingest as ingest_mod
-from ytpu.models.batch_doc import _apply_update_batch_jit, get_string
+from ytpu.models.batch_doc import _apply_update_batch_in_place_jit, get_string
 from ytpu.models.ingest import BatchIngestor, _bucket, pack_lane_table, pack_manifest
 from ytpu.ops import decode_kernel as dk
 from ytpu.utils import metrics
@@ -137,7 +137,7 @@ def served(native_lib):
 
     monkeypatch.setattr(dk, "decode_updates_v1", decode)
     uploads, outputs = metrics.counter("ingest.step_uploads"), metrics.counter("ingest.enqueue_outputs")
-    forms = _apply_update_batch_jit._cache_size()
+    forms = _apply_update_batch_in_place_jit._cache_size()
     got = {}
     phases.reset()
     phases.enable()
@@ -152,7 +152,7 @@ def served(native_lib):
         phases.disable()
         monkeypatch.undo()
     want = [(what, uploads, outputs) for what, _, uploads, outputs in steps]
-    return ing, rooms, got, want, recorded, _apply_update_batch_jit._cache_size() - forms
+    return ing, rooms, got, want, recorded, _apply_update_batch_in_place_jit._cache_size() - forms
 
 
 @pytest.mark.parametrize("step", range(8))
@@ -196,7 +196,7 @@ def test_no_host_array_rides_up_with_a_jitted_call(native_lib, monkeypatch):
     monkeypatch.setattr(ingest_mod, "_merge_stream_jit", watch("merge", ingest_mod._merge_stream_jit))
     import ytpu.models.batch_doc as bd
 
-    monkeypatch.setattr(bd, "_apply_update_batch_jit", watch("integrate", bd._apply_update_batch_jit))
+    monkeypatch.setattr(bd, "_apply_update_batch_in_place_jit", watch("integrate", bd._apply_update_batch_in_place_jit))
     rooms = [_Room() for _ in range(3)]
     ing = BatchIngestor(n_docs=N_DOCS, capacity=CAPACITY)
     ing.apply_bytes([rooms[d].edit(d + 1, _type("abc ")) if d < 3 else None for d in range(N_DOCS)])
@@ -221,7 +221,7 @@ def test_a_sweep_over_lane_counts_builds_the_forms_it_built_before(native_lib, m
 
     monkeypatch.setattr(progbudget, "_MAX", 10**9)  # no eviction under our feet
     programs = {
-        "integrate": _apply_update_batch_jit,
+        "integrate": _apply_update_batch_in_place_jit,
         "decode": dk._decode_updates_v1_jit,
         "merge": ingest_mod._merge_stream_jit,
         "gather": ingest_mod._gather_manifest_jit,

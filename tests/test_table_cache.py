@@ -108,7 +108,7 @@ class _Spy:
         self.handed = []  # per program call: {table: device arrays}
         self.wanted = []  # per program call: {table: the parent's host arrays}
         self.decodes = 0
-        real_decode, real_apply = dk.decode_updates_v1, ingest_mod.apply_update_batch
+        real_decode, real_apply = dk.decode_updates_v1, ingest_mod.apply_update_batch_in_place
 
         def decode(buf, lens, max_rows, max_dels, **kw):
             want = _parent_tables(self.ing)
@@ -126,7 +126,7 @@ class _Spy:
             return real_apply(state, batch, client_rank, *rest)
 
         monkeypatch.setattr(dk, "decode_updates_v1", decode)
-        monkeypatch.setattr(ingest_mod, "apply_update_batch", apply)
+        monkeypatch.setattr(ingest_mod, "apply_update_batch_in_place", apply)
 
     def since(self, call: int) -> dict:
         """The tables handed over from program call `call` on, by name."""

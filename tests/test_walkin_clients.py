@@ -215,7 +215,7 @@ SLOTS = 1024  # entries a table starts with (`BatchIngestor._table_floor` of a s
 FILLERS = range(100_000, 100_000 + SLOTS - 8)  # interned from outside, so that a doubling is a few writers away
 PROGRAMS = {
     "decode": dk._decode_updates_v1_jit,
-    "integrate": bd._apply_update_batch_jit,
+    "integrate": bd._apply_update_batch_in_place_jit,
     "gather": ingest_mod._gather_manifest_jit,
     "merge": ingest_mod._merge_stream_jit,
 }

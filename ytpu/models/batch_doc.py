@@ -68,6 +68,7 @@ __all__ = [
     "DEFAULT_COMPACTION_POLICY",
     "stream_worst_case_adds",
     "apply_update_batch",
+    "apply_update_batch_in_place",
     "apply_update_stream_raw",
     "ClientInterner",
     "KeyInterner",
@@ -1537,7 +1538,6 @@ def _apply_update_one_doc(
     return _recompute_moves(state, moves_dirty, client_rank), scan_hist
 
 
-@partial(jax.jit, static_argnums=3)
 def apply_update_batch(
     state: DocStateBatch,
     batch: UpdateBatch,
@@ -1561,8 +1561,13 @@ def apply_update_batch(
     the dense step's identity on an all-invalid slot gives. `batch` is
     then `[K, ...]` already, row i the update of slot `active[i]`: the
     caller builds it no wider than the step (`BatchIngestor.apply_bytes`).
-    One program either way, and the state is not donated: every plane is
-    still read once and written once (PERF.md section 6, PR 29).
+    One traced body either way, in two programs that differ in who owns
+    the state's buffers. This entry keeps value semantics: `state` is
+    still readable after the call, at the price of every plane read once
+    and written once. The state's owner calls `apply_update_batch_in_place`
+    (`BatchIngestor`), which donates `state` and nothing else: XLA aliases
+    each of its buffers to its output, so a compact step writes K rooms
+    where they are (PERF.md section 6, PR 46).
 
     `batch` may be a `PackedBatch`, taken apart here: the served path
     hands the pair from every call site, so a process builds one form of
@@ -1590,6 +1595,17 @@ def apply_update_batch(
         return jax.tree.map(
             lambda full, sub: full.at[active].set(sub), state, sub_state
         )
+
+
+# One traced function, two programs: the profiler names both module
+# `jit_apply_update_batch` and their ops `jit(apply_update_batch)/...`
+# (what the benchmark's readers look for). `scan_plan` is static in both.
+_apply_update_batch_jit = jax.jit(apply_update_batch, static_argnums=3)
+# the owner's: operand 0 is consumed, every other operand (the kept batch,
+# the rank table, `active`) outlives the call
+_apply_update_batch_in_place_jit = jax.jit(
+    apply_update_batch, static_argnums=3, donate_argnums=0
+)
 
 
 def _apply_update_stream_hist_body(
@@ -3454,7 +3470,6 @@ def get_values(state: DocStateBatch, doc: int, payloads: PayloadStore) -> list:
 # internal hooks alone missed direct callers — the r5 no-crutch suite
 # segfaulted at ~73% compiling an unregistered giant program).
 
-_apply_update_batch_jit = apply_update_batch
 _apply_update_stream_jit = apply_update_stream
 
 
@@ -3472,13 +3487,42 @@ _apply_update_stream_state_jit = partial(
 )
 
 
+def _xla_batch_span(program, state, batch, client_rank, scan_plan, active):
+    """The `integrate.xla_batch` span around a call of `program`, one of
+    the two jits of `apply_update_batch`. The span key carries the scan
+    plan, so the sentinel attributes a retrace to a changed knob."""
+    from ytpu.utils.phases import NULL_SPAN, phases, program_memory
+
+    if not phases.enabled:
+        return NULL_SPAN
+    return phases.span(
+        "integrate.xla_batch",
+        (
+            state.blocks.client.shape,
+            batch[0].shape,  # `client` of planes, `rows` of a pair
+            scan_plan,
+            None if active is None else active.shape[0],
+        ),
+        axes=("state", "batch", "scan_plan", "active"),
+        # reads shapes alone, on the first sighting and after the call:
+        # a donated state still answers (`phases.program_memory`)
+        memory=program_memory(
+            program, state, batch, client_rank, scan_plan, active
+        ),
+    )
+
+
+# The two entries below are one body twice, and not a helper that takes
+# the program: a Python frame between a caller and a jitted call makes
+# every trace of a new shape dearer (`BatchIngestor.apply_bytes`).
+
+
 def apply_update_batch(
     state: DocStateBatch,
     batch: UpdateBatch,
     client_rank: jax.Array,
     active: Optional[jax.Array] = None,
 ) -> DocStateBatch:
-    from ytpu.utils.phases import NULL_SPAN, phases
     from ytpu.utils.progbudget import tick
 
     tick()
@@ -3489,31 +3533,37 @@ def apply_update_batch(
     state = ensure_origin_slot(state)
     # two-tier scan plan: env re-read per CALL and threaded as a static
     # (same discipline as the chunk programs) — a changed knob retraces
-    # instead of silently reusing the old unroll, and the span key
-    # carries the plan so the sentinel attributes the retrace to it
+    # instead of silently reusing the old unroll
     scan_plan = scan_tier_plan()
-    from ytpu.utils.phases import program_memory
-
-    span = (
-        phases.span(
-            "integrate.xla_batch",
-            (
-                state.blocks.client.shape,
-                batch[0].shape,  # `client` of planes, `rows` of a pair
-                scan_plan,
-                None if active is None else active.shape[0],
-            ),
-            axes=("state", "batch", "scan_plan", "active"),
-            memory=program_memory(
-                _apply_update_batch_jit, state, batch, client_rank,
-                scan_plan, active,
-            ),
-        )
-        if phases.enabled
-        else NULL_SPAN
-    )
-    with span:
+    with _xla_batch_span(
+        _apply_update_batch_jit, state, batch, client_rank, scan_plan, active
+    ):
         return _apply_update_batch_jit(
+            state, batch, client_rank, scan_plan, active
+        )
+
+
+def apply_update_batch_in_place(
+    state: DocStateBatch,
+    batch: UpdateBatch,
+    client_rank: jax.Array,
+    active: Optional[jax.Array] = None,
+) -> DocStateBatch:
+    """`apply_update_batch` for the state's one owner: `state` is DONATED,
+    its buffers become the result's and the tree handed in is deleted, so
+    the caller rebinds what it holds to the result and nothing else may
+    keep the old tree (`BatchIngestor`). No plane the step does not change
+    is copied. `batch`, `client_rank` and `active` are read, not consumed."""
+    from ytpu.utils.progbudget import tick
+
+    tick()
+    state = ensure_origin_slot(state)  # as `apply_update_batch`
+    scan_plan = scan_tier_plan()
+    with _xla_batch_span(
+        _apply_update_batch_in_place_jit, state, batch, client_rank,
+        scan_plan, active,
+    ):
+        return _apply_update_batch_in_place_jit(
             state, batch, client_rank, scan_plan, active
         )
 
@@ -3571,6 +3621,9 @@ def _register_programs():
     from ytpu.utils import progbudget
 
     progbudget.register("apply_update_batch", _apply_update_batch_jit)
+    progbudget.register(
+        "apply_update_batch_in_place", _apply_update_batch_in_place_jit
+    )
     progbudget.register("apply_update_stream", _apply_update_stream_jit)
     progbudget.register(
         "apply_update_stream_state", _apply_update_stream_state_jit

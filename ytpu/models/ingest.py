@@ -54,7 +54,8 @@ from ytpu.models.batch_doc import (
     DocStateBatch,
     PackedBatch,
     UpdateBatch,
-    apply_update_batch,
+    apply_update_batch_in_place,
+    ensure_origin_slot,
     init_state,
 )
 from ytpu.ops.decode_kernel import (
@@ -379,6 +380,10 @@ class BatchIngestor:
         self._m_enqueue_outputs = metrics.counter("ingest.enqueue_outputs")
         # host arrays a step sent to the device(s) (`_upload`)
         self._m_step_uploads = metrics.counter("ingest.step_uploads")
+        # calls that replaced the state (integrate, compaction), and those
+        # of them that consumed the buffers handed in (`_count_state_step`)
+        self._m_state_steps = metrics.counter("ingest.state_steps")
+        self._m_state_in_place = metrics.counter("ingest.state_in_place")
         # compaction on the served path (`_compact`): rooms compacted, the
         # rows they held before and what that freed; bounds made exact by
         # a read of `n_blocks` (`_recount`); rooms whose update might not
@@ -475,8 +480,9 @@ class BatchIngestor:
 
         The tables hold every interned client or key, not a step's, and a
         server interns when a client or a key is first seen, not per
-        keystroke. The programs that take them donate no operand, so one
-        upload serves every step until its source changes. A first-seen
+        keystroke. The programs that take them only read them (the
+        integrate step donates the state, operand 0, and nothing else), so
+        one upload serves every step until its source changes. A first-seen
         writer changes what a table holds and not its shape: `width` is
         `_table_floor` until `size` passes it and doubles then, a counted
         and spanned event (`ingest.table_grows`, `ingest.table_grow`)
@@ -510,9 +516,10 @@ class BatchIngestor:
         """The host lane's empty batch of this bucket, if a step left it
         on the device(s): what `batch_packed` pads to when no slot plans a
         row, a constant of `(width, n_rows, n_dels)`, the step's width
-        among them (`_active_slots`). `merge_stream` and
-        `apply_update_batch` donate no operand, so one upload serves every
-        step without a host-lane room until it is evicted (`_keep_batch`)."""
+        among them (`_active_slots`). `merge_stream` donates no operand
+        and the integrate step only the state (`_integrate`), so one upload
+        serves every step without a host-lane room until it is evicted
+        (`_keep_batch`)."""
         kept = self._batch_cache.get(bucket)
         if kept is not None:
             self._batch_cache.move_to_end(bucket)
@@ -860,9 +867,7 @@ class BatchIngestor:
             if rows or dels:
                 adds[d] = ROWS_PER_ROW * len(rows) + ROWS_PER_DEL * len(dels)
         self._make_room(adds)
-        self.state = apply_update_batch(
-            self.state, self._batch(all_rows, all_dels), self._client_rank()
-        )
+        self._integrate(self._batch(all_rows, all_dels), self._client_rank())
         return self.state
 
     # --- raw-bytes fast lane ---------------------------------------------------
@@ -1213,6 +1218,29 @@ class BatchIngestor:
         counter.inc(n)
         phases.add_value(counter.name, n)
 
+    def _count_state_step(self, old) -> None:
+        """Count a call that replaced the state, `old` the tree it was
+        handed (the integrate step, `compact_rooms`: both donate it):
+        `ingest.state_steps`, and `ingest.state_in_place` where every
+        buffer handed in was consumed (a host attribute of the arrays, no
+        sync). A donation jax finds no output for only warns and copies
+        as before; the two counts then part.
+
+        The ingestor is the state's one owner: it rebinds `self.state` to
+        the call's result at once, and whoever took the tree before the
+        call (`ingestor.state`, a leaf of it) holds deleted arrays after."""
+        self._tally(self._m_state_steps)
+        if all(a.is_deleted() for a in jax.tree.leaves(old)):
+            self._tally(self._m_state_in_place)
+
+    def _integrate(self, batch, client_rank, active=None) -> None:
+        """The integrate step on the state where it is
+        (`apply_update_batch_in_place`): `batch`, `client_rank` and
+        `active` are read and may be handed to a later step again."""
+        old = ensure_origin_slot(self.state)
+        self.state = apply_update_batch_in_place(old, batch, client_rank, active)
+        self._count_state_step(old)
+
     def _recount(self) -> None:
         """Make every slot's bound exact: one read of `n_blocks`."""
         from ytpu.utils.phases import phases
@@ -1235,7 +1263,6 @@ class BatchIngestor:
         (the call's slots), `.h2d`, `.enqueue` (one program), `.d2h` (the
         counts and the report: the wait for the program is here),
         `.rehome`."""
-        from ytpu.models.batch_doc import ensure_origin_slot
         from ytpu.ops.compaction import compact_rooms
         from ytpu.utils.phases import phases
 
@@ -1257,8 +1284,10 @@ class BatchIngestor:
                         "ingest.compact.h2d", self._uploaded_bytes(host), "h2d"
                     )
             with phases.span("ingest.compact.enqueue"):
-                out = compact_rooms(ensure_origin_slot(self.state), *operands)
+                old = ensure_origin_slot(self.state)
+                out = compact_rooms(old, *operands)
                 self.state = out[0]
+                self._count_state_step(old)
                 self._count_outputs(out)
             with phases.span("ingest.compact.d2h"):
                 n_before, n_after, report, n_chains = jax.device_get(out[1:])
@@ -1539,9 +1568,12 @@ class BatchIngestor:
             # batch is: `[len(active), ...]`, and a pair whether a merge
             # made it or the host lane's upload goes straight in. One form
             # of the program a bucket, and no host array crosses with it
-            self.state = apply_update_batch(
-                self.state, batch, client_rank, active
+            # (`_integrate`'s three lines, kept in this frame: see above)
+            old = ensure_origin_slot(self.state)
+            self.state = apply_update_batch_in_place(
+                old, batch, client_rank, active
             )
+            self._count_state_step(old)
             self._count_outputs(self.state)
             if flags is not None:
                 # `_fast_eligible` proved these lanes decode clean, and flagged
@@ -1595,8 +1627,7 @@ class BatchIngestor:
         # as wide as the flagged rooms, by the step's own rule (the flagged
         # lanes integrated nothing: the rows bound already holds their adds)
         active = self._active_slots(bad)
-        self.state = apply_update_batch(
-            self.state,
+        self._integrate(
             self._batch(
                 *self._planned_batch(active, planned), by_doc=active is None
             ),

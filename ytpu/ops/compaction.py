@@ -383,14 +383,17 @@ def _compact_room(state: DocStateBatch):
     return out, heads, report, n_chains
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=0)
 def compact_rooms(state: DocStateBatch, rooms, mask, ref_base):
     """Squash + GC + defragment the rooms `rooms` ([K] i32, distinct and
     in range) of `state` where `mask` ([K] bool) is set; the rest of
     `rooms` is padding and every other room's planes are carried over. The
     integrate step's form: gather `[K, ...]`, compact under `vmap`, one
-    plain scatter on the room axis outside it; not donated
-    (`apply_update_batch`'s docstring). The device's time goes with K (a
+    plain scatter on the room axis outside it. `state` is DONATED, as
+    the served integrate step's is (`apply_update_batch_in_place`): the
+    scatter writes K rooms where they are, the tree handed in is deleted,
+    and its one caller rebinds to the result (`BatchIngestor._compact`);
+    `rooms`, `mask` and `ref_base` are read. The device's time goes with K (a
     gather's cost is per element), so the served path calls it with
     K = 2: one program, a third room due in the same step is a second
     call, and a lone room brings an idle slot along (at K = 1 XLA turns
