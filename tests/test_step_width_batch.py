@@ -175,10 +175,10 @@ def served(request):
         spy = _Spy(monkeypatch, ing)
         tag = [None]
 
-        def apply_bytes(payloads):
+        def apply_bytes(payloads, columns=None):
             merged, applied = len(spy.merged), len(spy.applied)
             spy.plan_calls = []
-            out = real_apply_bytes(payloads)
+            out = real_apply_bytes(payloads, columns)
             bucket, want, host_lane = spy.parent_planes(payloads)
             live = [d for d, p in enumerate(payloads) if p is not None]
             assert len(spy.applied) == applied + 1  # no lane was flagged: one integrate call

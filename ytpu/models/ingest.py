@@ -380,6 +380,11 @@ class BatchIngestor:
         self._m_enqueue_outputs = metrics.counter("ingest.enqueue_outputs")
         # host arrays a step sent to the device(s) (`_upload`)
         self._m_step_uploads = metrics.counter("ingest.step_uploads")
+        # payloads a step's prescan planned, and those of them whose
+        # native columns came with them, decoded where the update arrived
+        # (`apply_bytes`' `columns`)
+        self._m_prescan_payloads = metrics.counter("ingest.prescan_payloads")
+        self._m_prescan_carried = metrics.counter("ingest.prescan_carried")
         # calls that replaced the state (integrate, compaction), and those
         # of them that consumed the buffers handed in (`_count_state_step`)
         self._m_state_steps = metrics.counter("ingest.state_steps")
@@ -1367,8 +1372,18 @@ class BatchIngestor:
                     )
             store.add(int(kind[lo]), payload)
 
-    def apply_bytes(self, payloads: List[Optional[bytes]]) -> DocStateBatch:
+    def apply_bytes(
+        self, payloads: List[Optional[bytes]], columns: Optional[list] = None
+    ) -> DocStateBatch:
         """One batched step straight from V1 wire bytes.
+
+        `columns`, where the caller has decoded a payload already (the
+        server does, where an update arrives: `_note_roots`), is a list
+        as long as `payloads` of each payload's native columns
+        (`ytpu.native.decode_update_columns(p)`, a pure function of the
+        bytes), None where a slot has none: the prescan walks those and
+        decodes the rest itself. What it decides from them it decides
+        here, as the step plans.
 
         Eligible docs (no stash, in-order, device-decodable content) ship
         raw bytes to HBM and decode on device; the rest take the exact
@@ -1390,6 +1405,8 @@ class BatchIngestor:
         """
         if len(payloads) != self.n_docs:
             raise ValueError(f"expected {self.n_docs} payload slots")
+        if columns is not None and len(columns) != self.n_docs:
+            raise ValueError(f"expected {self.n_docs} column slots")
         from ytpu.utils.phases import phases
 
         # keyless spans: phases.span() itself returns the shared no-op
@@ -1422,12 +1439,17 @@ class BatchIngestor:
                 if self._stash_tickets:
                     # rooms holding a stash as the step plans
                     self._tally(self._m_stash_rooms, len(self._stash_tickets))
+                carried = 0  # payloads whose columns were handed in
                 with phases.span("ingest.plan.prescan"):
                     for d, p in enumerate(payloads):
                         if p is None:
                             continue
                         live.append(d)
-                        cols = decode_update_columns(p) if native else None
+                        cols = None if columns is None else columns[d]
+                        if cols is not None:
+                            carried += 1
+                        elif native:
+                            cols = decode_update_columns(p)
                         why = None if cols is None else self._host_lane_reason(d, cols)
                         if cols is None or why is not None:
                             if why in ("pending", "dependency"):
@@ -1476,6 +1498,10 @@ class BatchIngestor:
                         max_steps = max(max_steps, steps_for_columns(cols))
                 self.fast_docs += len(fast_idx)
                 self.slow_docs += len(slow_updates)
+                if live:
+                    self._tally(self._m_prescan_payloads, len(live))
+                if carried:
+                    self._tally(self._m_prescan_carried, carried)
 
                 # a slot without a payload plans no row (`_plan_doc`): the
                 # step, and the batch both lanes meet in, need be no wider

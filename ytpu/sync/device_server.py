@@ -138,6 +138,14 @@ class DeviceSyncServer(SyncServer):
         self._queue_traces: List[List[tuple]] = [
             [] for _ in range(ingestor.n_docs)
         ]
+        # per-queued-update native columns, in lockstep with _queues: an
+        # update is decoded once, where it arrives (`_note_roots`), and
+        # its columns ride to the step that integrates it (`apply_bytes`
+        # takes them for its prescan). None where nothing decoded it: no
+        # native library, or an update queued by another path
+        self._queue_columns: List[list] = [
+            [] for _ in range(ingestor.n_docs)
+        ]
         # queued updates over all slots of the process's live servers,
         # worked out when `/metrics` or `/healthz` reads it: a walk over
         # every slot is no work for a flush
@@ -224,11 +232,13 @@ class DeviceSyncServer(SyncServer):
             "tenants": tenants,
         }
 
-    def _enqueue(self, slot: int, payload: bytes) -> None:
+    def _enqueue(self, slot: int, payload: bytes, columns=None) -> None:
         """Queue one update for a slot, recording the ambient request
-        trace id (None outside a traced request) and the instant in
-        lockstep."""
+        trace id (None outside a traced request), the instant and the
+        update's native columns (`payload` decoded, where its sender has
+        them) in lockstep."""
         self._queues[slot].append(payload)
+        self._queue_columns[slot].append(columns)
         self._queue_traces[slot].append(
             (current_trace_id(), time.perf_counter())
         )
@@ -365,10 +375,13 @@ class DeviceSyncServer(SyncServer):
                     # wire primary); non-primary roots stay device-resident
                     # via the ingestor's BLOCK_ROOT_ANCHOR rows — multi-root
                     # tenants are served from the batch like any other
-                    # (doc.rs:156-228 is the reference's normal doc shape)
+                    # (doc.rs:156-228 is the reference's normal doc shape).
+                    # This is the update's one columnar decode: its columns
+                    # are queued beside the bytes, and the planning of the
+                    # step that integrates it walks them (`apply_bytes`)
                     with phases.span("sync.receive.roots"):
-                        self._note_roots(session.tenant, sub.payload)
-                    self._enqueue(slot, sub.payload)
+                        cols = self._note_roots(session.tenant, sub.payload)
+                    self._enqueue(slot, sub.payload, cols)
                     self._applied.inc()
                     t.applied.inc()
                     self.applied_local += 1
@@ -392,14 +405,13 @@ class DeviceSyncServer(SyncServer):
         return replies
 
     @staticmethod
-    def _scan_root_names(payload: bytes) -> List[str]:
-        """Distinct root-parent names in a wire update, in block order.
-        Uses the native columnar prescan (the same C++ pass the ingest
-        fast lane runs — microseconds), falling back to the host decoder
-        when the native library is absent."""
-        from ytpu.native import decode_update_columns
-
-        cols = decode_update_columns(payload)
+    def _scan_root_names(payload: bytes, cols) -> List[str]:
+        """Distinct root-parent names in a wire update, in block order:
+        read off its native columns `cols` (a loop of a microsecond or
+        two; the decode that made them is what costs, ~100 us an update
+        on the chip machine's host: `roots_us.flood`), falling back to
+        the host decoder when the native library is absent (`cols` is
+        None) or could not read the update."""
         names: List[str] = []
         if cols is not None and not cols.error:
             for i in range(cols.n_blocks):
@@ -420,23 +432,25 @@ class DeviceSyncServer(SyncServer):
                     names.append(p)
         return names
 
-    def _note_roots(self, tenant: str, payload: bytes) -> bool:
-        """Record the tenant's root names from one inbound update; True
-        when the tenant just turned multi-root (observability only — the
+    def _note_roots(self, tenant: str, payload: bytes):
+        """Decode one inbound update into its native columns (the C++
+        pass the ingest fast lane plans from), record the tenant's root
+        names from them, and hand the columns back for the queue: None
+        without the native library. A tenant that turns multi-root is
+        counted (`sync.multi_root_tenants`; observability only: the
         batch engine anchors non-primary roots per doc, so multi-root
         tenants stay device-resident)."""
-        names = self._scan_root_names(payload)
-        if not names:
-            return False
-        known = self._root_names.get(tenant)
-        if known is None:
-            self._root_names[tenant] = known = names[0]
-        if any(n != known for n in names):
-            from ytpu.utils import metrics
+        from ytpu.native import decode_update_columns
 
-            metrics.counter("sync.multi_root_tenants").inc()
-            return True
-        return False
+        cols = decode_update_columns(payload)
+        names = self._scan_root_names(payload, cols)
+        if names:
+            known = self._root_names.setdefault(tenant, names[0])
+            if any(n != known for n in names):
+                from ytpu.utils import metrics
+
+                metrics.counter("sync.multi_root_tenants").inc()
+        return cols
 
     def _demote_to_host(self, tenant: str) -> None:
         """Escape hatch: move a tenant from its device slot to the host
@@ -723,6 +737,7 @@ class DeviceSyncServer(SyncServer):
                 # enqueue.
                 with phases.span("sync.dispatch.peek"):
                     payloads = [q[0] if q else None for q in self._queues]
+                    columns = [c[0] if c else None for c in self._queue_columns]
                 if phases.enabled:
                     start = time.perf_counter()
                     carried = [t[0][1] for t in self._queue_traces if t]
@@ -734,16 +749,19 @@ class DeviceSyncServer(SyncServer):
                     )
                 try:
                     with self._apply_hist.time():
-                        self.ingestor.apply_bytes(payloads)
+                        self.ingestor.apply_bytes(payloads, columns)
                 except Exception as e:
                     tracer.dump_on_error(error=e)
                     raise
                 with phases.span("sync.dispatch.pop"):
-                    for q in self._queues:
+                    # the three lists are in lockstep (`_enqueue`): one
+                    # walk over the slots pops all three
+                    for q, c, t in zip(
+                        self._queues, self._queue_columns, self._queue_traces
+                    ):
                         if q:
                             q.pop(0)
-                    for t in self._queue_traces:
-                        if t:
+                            c.pop(0)
                             t.pop(0)
             steps += 1
         if steps:
