@@ -118,7 +118,7 @@ def test_compile_retrace_fault_site():
 
 
 def _synthetic_stages(rec):
-    with rec.span("replay.stage"):
+    with rec.span("ingest.plan"):
         time.sleep(0.02)
     with rec.span("encode.finish"):
         time.sleep(0.01)
@@ -175,7 +175,7 @@ def test_profile_fractions_self_consistent(stages):
 
 def test_profile_window_is_deltas_not_cumulative():
     rec = PhaseRecorder(enabled=True)
-    with rec.span("replay.stage"):
+    with rec.span("ingest.plan"):
         time.sleep(0.01)
     w = ProfileWindow(recorder=rec)
     w.begin()  # window opens AFTER the stage time above
@@ -191,7 +191,7 @@ def test_profile_endpoint_serves_fractions():
     rec = PhaseRecorder(enabled=True)
     w = ProfileWindow(recorder=rec)
     w.begin()
-    with rec.span("replay.chunk"):
+    with rec.span("integrate.xla_batch"):
         time.sleep(0.01)
     srv = TelemetryServer(port=0)
     srv.set_profile_source(w.report)

@@ -2,9 +2,8 @@
 capture diffing with per-metric tolerance and directional regression
 semantics — the tool that turns "no worse than" from eyeball work into
 an exit code. The tool itself is gated here: synthetic captures pin the
-direction/tolerance rules, committed BENCH captures pin self-comparison
-as a zero diff, and a slow-marked test runs a real `bench.py --dry-run`
-and self-compares its output through the CLI entry."""
+direction/tolerance rules, and fixture captures pin self-comparison as
+a zero diff through the CLI entry."""
 
 import json
 import os
@@ -357,30 +356,3 @@ def test_capture_shaped_file_self_compares_clean(tmp_path):
         )
     )
     assert bc.main([str(cap), str(cap)]) == 0
-
-
-@pytest.mark.slow
-def test_dry_run_self_compare_through_cli(tmp_path):
-    """Satellite acceptance: a real `bench.py --dry-run` output compared
-    against itself through the CLI is a zero diff with exit 0."""
-    env = dict(os.environ, YTPU_BENCH_DRY_OPS="120", JAX_PLATFORMS="cpu")
-    res = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"), "--dry-run"],
-        capture_output=True,
-        text=True,
-        timeout=600,  # the ISSUE-17 observatory leg adds a real ~15s retrace
-        cwd=ROOT,
-        env=env,
-    )
-    assert res.returncode == 0, res.stderr[-800:]
-    out = tmp_path / "dry.json"
-    out.write_text(res.stdout)
-    tool = os.path.join(ROOT, "benches", "bench_compare.py")
-    cmp_res = subprocess.run(
-        [sys.executable, tool, str(out), str(out), "--json"],
-        capture_output=True,
-        text=True,
-    )
-    assert cmp_res.returncode == 0, cmp_res.stdout + cmp_res.stderr
-    diff = json.loads(cmp_res.stdout)
-    assert diff["regressions"] == [] and diff["changes"] == []

@@ -1,16 +1,9 @@
 """Capacity observatory (ISSUE-18): occupancy/fragmentation ledger,
-device-memory attribution, the headroom forecaster, and the typed
-`grow.oom` denial.
-
-Early-alphabet-named on purpose: these assertions pin the readout-word
-layout (`LEDGER_WORDS` riding `N_READOUT`) and the zero-new-syncs
-contract, so they should fail FIRST — before the heavier replay suites
-whose drivers depend on the same words.
+device-memory attribution and the headroom forecaster.
 """
 
 import json
 import urllib.request
-from functools import lru_cache
 
 import numpy as np
 import pytest
@@ -24,7 +17,6 @@ from ytpu.utils.capacity import (
     memory_budget_bytes,
     packed_resident_bytes,
 )
-from ytpu.utils.faults import FaultError, FaultSpec, faults
 from ytpu.utils.phases import phases, program_memory
 
 
@@ -118,152 +110,7 @@ def test_ingestor_ledger_matches_state_and_compaction_reclaims():
 # --- packed replay: ledger words ride the existing lazy readout -------------
 
 
-@lru_cache(maxsize=1)
-def _replay_workload():
-    import bench as _bench
-    from ytpu.models.replay import plan_replay
-
-    ops = []
-    length = 0
-    for _ in range(6):
-        for i in range(20):
-            ops.append(("i", length, "abcdef"[i % 6]))
-            length += 1
-        ops.append(("d", length - 18, 18))
-        length -= 18
-    log, expect = _bench.build_updates(ops)
-    return log, expect, plan_replay(log)
-
-
-def test_ledger_rides_readout_with_zero_new_syncs():
-    """The 3 ledger words ride the SAME [N_READOUT] future the driver
-    already drains: `replay.readout` d2h attribution stays pinned at 12
-    bytes per readout (unchanged since ISSUE-5), the new words charge
-    under their own `capacity.ledger` stage at 4*LEDGER_WORDS per
-    readout, and the sync count of a plain chunked run is unchanged."""
-    from ytpu.models.replay import FusedReplay
-    from ytpu.ops.integrate_kernel import LEDGER_WORDS
-
-    log, expect, plan = _replay_workload()
-    phases.reset()
-    phases.enable()
-    try:
-        r = FusedReplay(
-            n_docs=2, plan=plan, capacity=256, max_capacity=256,
-            d_block=2, chunk=16, lane="xla",
-        )
-        stats = r.run(log)
-        snap = phases.snapshot()
-    finally:
-        phases.disable()
-        phases.reset()
-    assert r.get_string(0) == expect
-    readouts = snap["replay.readout"]["d2h_bytes"] // 12
-    assert readouts >= stats.chunks
-    assert snap["replay.readout"]["d2h_bytes"] == 12 * readouts
-    assert (
-        snap["capacity.ledger"]["d2h_bytes"] == 4 * LEDGER_WORDS * readouts
-    ), snap["capacity.ledger"]
-    # the drained ledger landed in stats and the occupancy gauges
-    assert stats.occupied_rows >= 0 and stats.dead_rows >= 0
-    assert "capacity.occupied_rows" in snap
-    assert snap["capacity.dead_fraction"]["value"] <= 1.0
-
-
-def test_compact_efficacy_rides_driver_stats():
-    """A tombstone-heavy replay that compacts must report reclaimed
-    rows and the chunk gap since the previous compaction."""
-    from ytpu.models.replay import FusedReplay
-
-    log, expect, plan = _replay_workload()
-    r = FusedReplay(
-        n_docs=2, plan=plan, capacity=64, max_capacity=64,
-        d_block=2, chunk=16, lane="xla",
-    )
-    stats = r.run(log)
-    assert r.get_string(0) == expect
-    assert stats.compactions >= 1
-    assert stats.reclaimed_rows > 0, stats
-    assert stats.occupied_rows + stats.dead_rows <= 2 * 64
-
-
 # --- headroom forecaster + typed grow.oom denial ----------------------------
-
-
-def test_forecaster_flags_degraded_before_grow_oom():
-    """The acceptance ordering: on an incompressible head-insert log the
-    forecaster must flip `degraded` from ledger observations BEFORE the
-    armed `grow.oom` moves the `memory.grow_denied` counter."""
-    import bench as _bench
-    from ytpu.models.replay import FusedReplay, plan_replay
-    from ytpu.ops import integrate_kernel as ik
-
-    ops = [("i", 0, "abcdef"[i % 6]) for i in range(120)]
-    log, expect = _bench.build_updates(ops)
-    plan = plan_replay(log)
-    ik.reset_lane_health()
-    faults.clear()
-    faults.arm("grow.oom")
-    try:
-        denied0 = metrics.counter("memory.grow_denied").value
-        fc = HeadroomForecaster(
-            budget_bytes=ik.packed_state_bytes(2, 48), watermark=0.5
-        )
-        flagged_pre_denial = []
-        observe = fc.observe
-
-        def scored(**kw):
-            observe(**kw)
-            if fc.report()["degraded"]:
-                flagged_pre_denial.append(
-                    metrics.counter("memory.grow_denied").value == denied0
-                )
-
-        fc.observe = scored
-        r = FusedReplay(
-            n_docs=2, plan=plan, capacity=32, max_capacity=1024,
-            d_block=2, chunk=4, lane="xla", forecaster=fc,
-        )
-        stats = r.run(log)
-    finally:
-        faults.clear()
-        ik.reset_lane_health()
-    assert r.get_string(0) == expect
-    assert stats.growths >= 1 and stats.recoveries >= 1, stats
-    assert metrics.counter("memory.grow_denied").value > denied0
-    assert flagged_pre_denial and flagged_pre_denial[0] is True, (
-        flagged_pre_denial
-    )
-    rep = fc.report()
-    assert rep["grow_exceeds_budget"] and rep["degraded"]
-    assert rep["headroom_fraction"] < 0  # next grow overshoots the budget
-
-
-def test_grow_oom_error_reports_attempted_vs_available_bytes():
-    """The typed denial carries the numbers an operator needs, stays a
-    FaultError (site catalogue), and stays on the checkpoint-resume
-    recovery path (`is_device_fault`)."""
-    from ytpu.ops.integrate_kernel import (
-        GrowOomError,
-        is_device_fault,
-        packed_state_bytes,
-    )
-
-    spec = FaultSpec("grow.oom")
-    e = GrowOomError(
-        spec,
-        capacity=32,
-        new_capacity=64,
-        n_docs=2,
-        attempted_bytes=packed_state_bytes(2, 64),
-        available_bytes=10_000,
-    )
-    assert isinstance(e, FaultError)
-    assert is_device_fault(e)
-    assert e.attempted_bytes == packed_state_bytes(2, 64)
-    assert e.available_bytes == 10_000
-    assert str(e.attempted_bytes) in str(e) and "budget" in str(e)
-    assert "32 -> 64" in str(e)
 
 
 def test_memory_budget_env_override(monkeypatch):
@@ -271,7 +118,22 @@ def test_memory_budget_env_override(monkeypatch):
     assert memory_budget_bytes() == 12345
     monkeypatch.setenv("YTPU_MEMORY_BUDGET_BYTES", "junk")
     assert memory_budget_bytes() == 16 << 30
-    assert packed_resident_bytes(2, 64) > 0
+
+
+def test_resident_bytes_are_the_states_column_planes():
+    """The formula counts `BlockCols`' 26 planes at 4 bytes a row and 32
+    words a room: what PERF.md calls "26 planes of 16 MB" at the
+    benchmark's size, and never under what a state really holds (two of
+    the planes are stored as bool)."""
+    import jax
+
+    from ytpu.models.batch_doc import BlockCols, init_state
+
+    assert len(BlockCols._fields) == 26
+    assert packed_resident_bytes(1024, 4096) == 26 * (16 << 20) + 32 * 4 * 1024
+    state = init_state(2, 64)
+    held = sum(a.nbytes for a in jax.tree.leaves(state))
+    assert held <= packed_resident_bytes(2, 64) <= held * 1.1
 
 
 def test_forecaster_report_math():

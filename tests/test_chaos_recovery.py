@@ -1,15 +1,9 @@
-"""Fault-injected resilience (ISSUE-6): the lane-demotion ladder,
-checkpointed replay recovery, poison-update quarantine, and the hardened
-sync transport, all exercised through `ytpu.utils.faults` so the failure
-paths run deterministically on CPU.
-
-Every replay in this file reuses test_async_overlap's workload and its
-one (n_docs=2, capacity=256, chunk=16) shape family — the compiled
-decode/chunk-step/compaction programs are shared with that file (which
-sorts immediately before this one), so no test here pays a fresh
-big-program trace.  The fused interpret test routes through
-`tests/_fused_interpret.run_or_skip` (this container's jax cannot
-interpret the Pallas kernel — seed behavior) and runs LAST.
+"""Fault-injected resilience (ISSUE-6): the fault injector's grammar, the
+overlap engine's shutdown when its producer raises, and the hardened sync
+transport, all exercised through `ytpu.utils.faults` so the failure paths
+run deterministically on CPU. The encode pipeline's own fault sites
+(`diff.d2h_fail`, `finisher.raise`, `stage.raise`) are in
+`tests/test_diff_overlap.py`.
 """
 
 import asyncio
@@ -18,71 +12,42 @@ import time
 
 import pytest
 
-from ytpu.native import available as native_available
-from ytpu.ops import integrate_kernel as ik
 from ytpu.utils import metrics
-from ytpu.utils.faults import FaultError, FaultSpec, faults
-
-from _fused_interpret import run_or_skip
-from test_async_overlap import CAPACITY, CHUNK, D_BLOCK, N_DOCS, _workload
-
-needs_native = pytest.mark.usefixtures("native_lib")
-
+from ytpu.utils.faults import FaultSpec, faults
 
 @pytest.fixture(autouse=True)
 def _clean_slate():
-    """Armed faults and sticky lane demotions are process-global: every
-    test starts and ends with both cleared so no state leaks into the
-    rest of the suite."""
+    """Armed faults are process-global: every test starts and ends with
+    them cleared so no state leaks into the rest of the suite."""
     faults.clear()
-    ik.reset_lane_health()
     yield
     faults.clear()
-    ik.reset_lane_health()
-
-
-def _make(lane="xla", overlap=False, interpret=False, **kw):
-    from ytpu.models.replay import FusedReplay
-
-    _, _, plan = _workload()
-    return FusedReplay(
-        n_docs=N_DOCS,
-        plan=plan,
-        capacity=CAPACITY,
-        max_capacity=CAPACITY,
-        d_block=D_BLOCK,
-        chunk=CHUNK,
-        lane=lane,
-        interpret=interpret,
-        overlap=overlap,
-        **kw,
-    )
 
 
 # --------------------------------------------------------- fault injector
 
 
 def test_faults_grammar_and_determinism():
-    faults.configure("dispatch.fail:lane=fused,after=2;net.delay:ms=7,n=3")
+    faults.configure("stage.raise:prefix=encode,after=2;net.delay:ms=7,n=3")
     specs = faults._specs
-    assert [s.after for s in specs["dispatch.fail"]] == [2]
-    assert specs["dispatch.fail"][0].args == {"lane": "fused"}
+    assert [s.after for s in specs["stage.raise"]] == [2]
+    assert specs["stage.raise"][0].args == {"prefix": "encode"}
     assert specs["net.delay"][0].n == 3
     # context mismatch is not an eligible pass; match fires after `after`
-    assert faults.fire("dispatch.fail", lane="xla") is None
-    assert faults.fire("dispatch.fail", lane="fused") is None  # pass 1
-    assert faults.fire("dispatch.fail", lane="fused") is None  # pass 2
-    assert faults.fire("dispatch.fail", lane="fused") is not None  # fires
-    assert faults.fire("dispatch.fail", lane="fused") is None  # n=1 spent
+    assert faults.fire("stage.raise", prefix="chaos") is None
+    assert faults.fire("stage.raise", prefix="encode") is None  # pass 1
+    assert faults.fire("stage.raise", prefix="encode") is None  # pass 2
+    assert faults.fire("stage.raise", prefix="encode") is not None  # fires
+    assert faults.fire("stage.raise", prefix="encode") is None  # n=1 spent
     # p-draws are seeded: same seed → same decision sequence
     a = FaultSpec("x", n=0, p=0.5, seed=7)
     b = FaultSpec("x", n=0, p=0.5, seed=7)
     assert [a._decide() for _ in range(32)] == [b._decide() for _ in range(32)]
     # suspended(): nothing fires inside the clean-run baseline
-    faults.arm("grow.oom")
+    faults.arm("diff.d2h_fail")
     with faults.suspended():
-        assert faults.fire("grow.oom") is None
-    assert faults.fire("grow.oom") is not None
+        assert faults.fire("diff.d2h_fail") is None
+    assert faults.fire("diff.d2h_fail") is not None
     # two specs armed on one site: the pass's winner spends its fire
     # budget, the loser keeps its `n` for a later pass — so
     # "net.drop;net.drop" drops TWO frames, not one
@@ -93,190 +58,6 @@ def test_faults_grammar_and_determinism():
     assert faults.fire("net.drop") is None
 
 
-# ------------------------------------------------- lane-demotion ladder
-
-
-@needs_native
-def test_dispatch_fault_demotes_with_parity():
-    """An injected fused-lane dispatch failure demotes the family one
-    rung and retries the SAME chunk in place: the run completes on the
-    packed-XLA lane with byte parity vs the serial host oracle, and the
-    demotion is sticky — a later fused-lane replay of the same family
-    skips the known-bad lane without any fault armed."""
-    log, expect, _ = _workload()
-    base = metrics.counter("lane.demotions").value
-    faults.arm("dispatch.fail", lane="fused")
-    r = _make(lane="fused")
-    r.run(log)
-    assert r.get_string(0) == expect
-    assert r.stats.demotions >= 1 and r.stats.recoveries >= 1
-    assert r.stats.final_lane == "xla"
-    assert metrics.counter("lane.demotions").value >= base + 1
-    # sticky floor: the family remembers without any armed fault
-    fam = ik.lane_family(N_DOCS, D_BLOCK)
-    assert ik.effective_lane(fam, "fused") == "xla"
-    faults.clear()
-    r2 = _make(lane="fused")
-    r2.run(log)
-    assert r2.get_string(0) == expect
-    assert r2.stats.final_lane == "xla"
-    assert r2.stats.demotions == 0  # no new failure: floor did the routing
-
-
-@needs_native
-def test_ladder_bottoms_out_on_host_oracle():
-    """Demoting past the packed-XLA rung lands on the serial host
-    oracle: slow, but the replay still completes with parity."""
-    log, expect, _ = _workload()
-    faults.arm("dispatch.fail", lane="xla")
-    r = _make(lane="xla")
-    r.run(log)
-    assert r.stats.final_lane == "host"
-    assert r.get_string(0) == expect
-    assert r.get_string(1) == expect  # the stream is broadcast: all slots
-
-
-# --------------------------------------------- checkpointed replay recovery
-
-
-@needs_native
-def test_kill_mid_replay_resumes_from_checkpoint():
-    log, expect, _ = _workload()
-    faults.arm("replay.kill", after=3)
-    r = _make(checkpoint_every=2)
-    r.run(log)
-    assert r.get_string(0) == expect
-    assert r.stats.checkpoints >= 1
-    assert r.stats.resumes and r.stats.resumes[0] > 0, (
-        "kill resumed from scratch, not from a chunk-boundary checkpoint"
-    )
-
-
-@needs_native
-def test_kill_without_checkpoints_restarts_from_scratch():
-    log, expect, _ = _workload()
-    faults.arm("replay.kill", after=2)
-    r = _make()  # checkpoint_every=0: healthy path stays zero-sync
-    r.run(log)
-    assert r.get_string(0) == expect
-    assert r.stats.resumes == [0]
-
-
-@needs_native
-def test_kill_mid_overlap_resumes_with_parity():
-    log, expect, _ = _workload()
-    faults.arm("replay.kill", after=2)
-    r = _make(overlap=True, checkpoint_every=2)
-    r.run(log)
-    assert r.get_string(0) == expect
-    assert r.stats.resumes and r.stats.resumes[0] > 0
-
-
-@needs_native
-def test_continuation_fault_with_checkpoints_resumes_entry_state():
-    """A second run() on a state that already carries content takes an
-    entry snapshot (pos=0) when checkpointing is on: a fault before the
-    first chunk-boundary checkpoint resumes from the carried state, not
-    from empty (re-applying the same stream is idempotent, so parity
-    proves the carried content survived)."""
-    log, expect, _ = _workload()
-    r = _make(checkpoint_every=4)
-    r.run(log)
-    assert r.get_string(0) == expect
-    faults.arm("replay.kill")
-    r.run(log)  # idempotent continuation: same updates re-applied
-    assert r.get_string(0) == expect
-    # resumed from THIS run's entry snapshot, not a stale ckpt of run 1
-    assert r.stats.resumes == [0]
-
-
-@needs_native
-def test_continuation_fault_without_checkpoints_refuses_silent_reset():
-    """With checkpointing off there is no entry snapshot: recovering a
-    continuation run by rebuilding an EMPTY state would silently discard
-    the content integrated before this run() — the fault must surface
-    instead."""
-    log, _, _ = _workload()
-    r = _make()  # checkpoint_every=0
-    r.run(log)
-    faults.arm("replay.kill")
-    with pytest.raises(ik.ReplayFault):
-        r.run(log)
-
-
-@needs_native
-def test_recovery_budget_bounds_repeated_faults():
-    """An unbounded fault (n=0) must not loop forever: after
-    `max_recoveries` resume attempts the fault propagates."""
-    log, _, _ = _workload()
-    faults.arm("replay.kill", n=0)
-    r = _make(max_recoveries=2)
-    with pytest.raises(ik.ReplayFault):
-        r.run(log)
-    assert r.stats.recoveries == 2
-
-
-# ------------------------------------------------ poison-update quarantine
-
-
-@needs_native
-def test_poison_update_quarantined_not_aborted():
-    """A corrupted (truncated) update trips the decoder's error flags;
-    with quarantine on, the update is recorded and skipped — the rest of
-    the stream integrates.  The poison target is the LAST update so no
-    healthy update depends on it (skipping a mid-chain update voids its
-    causal dependents — that still aborts, by design)."""
-    from ytpu.core import Doc
-
-    log, _, _ = _workload()
-    poison = len(log) - 1
-    oracle = Doc()
-    for p in log[:poison]:
-        oracle.apply_update_v1(p)
-    expect_m1 = oracle.get_text("text").get_string()
-    base = metrics.counter("replay.quarantined").value
-    faults.arm("update.corrupt", after=poison)
-    r = _make(quarantine=True)
-    r.run(log)
-    assert r.stats.quarantined == [poison]
-    assert r.get_string(0) == expect_m1
-    assert metrics.counter("replay.quarantined").value == base + 1
-
-    # same stream through the overlap lane's deferred sticky-error path
-    # on the RAW ingest lane (ISSUE-7): the corruption lands in the wire
-    # table, the ON-DEVICE varint decode flags the lane into the sticky
-    # scalar, and deferred host re-identification quarantines the same
-    # update index the serial loop names
-    faults.clear()
-    ik.reset_lane_health()
-    faults.arm("update.corrupt", after=poison)
-    r2 = _make(overlap=True, ingest="raw", quarantine=True)
-    r2.run(log)
-    assert r2.stats.ingest == "raw", r2.stats
-    assert r2.stats.quarantined == [poison]
-    assert r2.get_string(0) == expect_m1
-
-    # and through the host-packed fallback rung (ingest="packed" — the
-    # PR-5 staging the PR-6 ladder keeps): identical quarantine outcome
-    faults.clear()
-    ik.reset_lane_health()
-    faults.arm("update.corrupt", after=poison)
-    r3 = _make(overlap=True, ingest="packed", quarantine=True)
-    r3.run(log)
-    assert r3.stats.ingest == "packed", r3.stats
-    assert r3.stats.quarantined == [poison]
-    assert r3.get_string(0) == expect_m1
-
-
-@needs_native
-def test_poison_update_without_quarantine_still_aborts():
-    log, _, _ = _workload()
-    faults.arm("update.corrupt", after=len(log) - 1)
-    r = _make()
-    with pytest.raises(RuntimeError, match="flagged updates"):
-        r.run(log)
-
-
 # ------------------------------------------- overlap engine fault paths
 
 
@@ -285,7 +66,7 @@ def test_raising_producer_never_strands_consumer():
     cleanly: the error re-raises on the caller promptly (no deadlock on
     a full queue), the staged backlog is abandoned, and the engine is
     reusable afterwards."""
-    from ytpu.models.replay import OverlapPipeline
+    from ytpu.models.overlap import OverlapPipeline
 
     pipe = OverlapPipeline(depth=2, stage_prefix="chaos")
     consumed = []
@@ -305,17 +86,6 @@ def test_raising_producer_never_strands_consumer():
     # the engine survives for the retry the recovery path performs
     stats = pipe.run(iter([10, 11]), consumed.append)
     assert stats.consumed == 2 and consumed[-2:] == [10, 11]
-
-
-def test_injected_staging_fault_recovers_end_to_end():
-    if not native_available():
-        pytest.skip("native codec unavailable (plan pre-scan)")
-    log, expect, _ = _workload()
-    faults.arm("stage.raise", prefix="replay")
-    r = _make(overlap=True)
-    r.run(log)
-    assert r.get_string(0) == expect
-    assert r.stats.recoveries >= 1
 
 
 # ------------------------------------------------- hardened transport
@@ -458,28 +228,3 @@ def test_serve_loop_survives_poisoned_session():
         await srv.wait_closed()
 
     _run(main())
-
-
-# ----------------------------------------------- fused interpret (LAST)
-
-
-@needs_native
-def test_fused_interpret_dispatch_fault_demotes():
-    """The ladder under interpret-mode Pallas: the injected fault fires
-    BEFORE the kernel, so this exercises the same demote-and-retry path
-    the TPU worker takes on a hostile shape family.  Skips (memoized)
-    where this jax build cannot interpret the fused kernel."""
-    log, expect, _ = _workload()
-
-    def thunk():
-        # after=1: chunk 0 really runs the interpreted fused kernel
-        # (surfacing this build's NotImplementedError for the memoized
-        # skip), chunk 1 faults and demotes
-        faults.arm("dispatch.fail", lane="fused", after=1)
-        r = _make(lane="fused", interpret=True)
-        r.run(log)
-        return r
-
-    r = run_or_skip(thunk)
-    assert r.get_string(0) == expect
-    assert r.stats.demotions >= 1

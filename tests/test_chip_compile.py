@@ -7,8 +7,7 @@ results or times — `chip_smoke.py` on the chip does.
 
 Shapes are the ones `chip_smoke.py` drives: 1,024 rooms x capacity 4,096,
 and the decode / pack buckets its scenario produces (journal of a CPU run
-of the full scenario, PR 24). The fused Pallas kernel is compiled at the
-two tiles `bench.py` names; it is NOT part of the served path.
+of the full scenario, PR 24).
 
 Rules (on-chip-measurement guide, section 2): the topology is described
 inside the module-scoped fixture below and nowhere else; nothing here
@@ -549,35 +548,3 @@ def test_batch_compaction_compiles_donated(one_chip):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes > 0, m  # the donation took
     assert _hbm_bytes(compiled) < V5E_HBM // 2, m
-
-
-# (docs, capacity, d_block): bench.py's quick lane, and its full-B4 lane
-# (d_block=8) at the largest capacity whose tile the compiler grants, two
-# grid steps each so the pipeline's double buffers are in the count. At
-# bench.py's C=65,536 even ONE grid step is refused: "Ran out of memory in
-# memory space vmem. Used 130.90M of 128.00M vmem" (CHANGES.md, PR 24).
-FUSED_TILES = [(256, 2048, 128), (16, 32768, 8)]
-
-
-@pytest.mark.parametrize("docs,capacity,d_block", FUSED_TILES)
-def test_fused_integrate_kernel_compiles(one_chip, docs, capacity, d_block):
-    """The one Pallas kernel. Interpret mode raises NotImplementedError in
-    this jax, so this is the only compiler the kernel meets off the chip.
-    Compiled, never run: the fused lane has no chip result."""
-    from ytpu.ops import integrate_kernel as ik
-
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    compiled = ik._run.lower(
-        i32(ik.NC, docs, capacity),
-        i32(docs, ik.M_PAD),
-        (i32(16, 4, 23), i32(16, 8, 4), i32(64)),
-        d_block,
-        False,  # interpret
-        3,
-        4,
-        ik.fused_vmem_mb(d_block, capacity),
-        ik.scan_tier_plan(),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    m = compiled.memory_analysis()
-    assert m.alias_size_in_bytes >= np.prod((ik.NC, docs, capacity)) * 4, m

@@ -189,13 +189,10 @@ def test_compact_plus_grow_sustains_small_capacity():
     assert get_string(state, 0, enc.payloads) == expect
 
 
-def test_compact_packed_preserves_move_columns():
-    """compact_packed must carry all NC columns, remapping `moved` slot
-    indices through the defragment permutation (regression: the packed
-    compactor once emitted only the 17 pre-move columns)."""
-    from ytpu.ops.compaction import compact_packed, grow_packed
-    from ytpu.ops.integrate_kernel import NC, MV, MPR, pack_state, unpack_state
-
+def test_compact_then_grow_keeps_moves_and_pads_unowned():
+    """`compact_state` remaps `moved` slot indices through the defragment
+    permutation and `grow_state` pads every move column with "no owner":
+    a padded slot must never read as owned by slot 0."""
     doc = Doc(client_id=1)
     log = []
     doc.observe_update_v1(lambda p, o, t: log.append(p))
@@ -215,17 +212,19 @@ def test_compact_packed_preserves_move_columns():
         state = apply_update_batch(state, batch, enc.interner.rank_table())
     expect = get_values(state, 0, enc.payloads)
 
-    cols, meta = pack_state(state)
-    assert cols.shape[0] == NC
-    cols2, meta2 = compact_packed(cols, meta)
-    assert cols2.shape[0] == NC
-    cols3, meta3 = grow_packed(cols2, meta2, 128)
-    # padded slots must read as unowned, not "owned by slot 0"
-    assert int(np.asarray(cols3[MV]).max(initial=-1)) < 64
-    assert int(np.asarray(cols3[MV][0, 64:]).max(initial=-1)) == -1
-    assert int(np.asarray(cols3[MPR][0, 64:]).max(initial=-1)) == -1
-    out = unpack_state(cols3, meta3, state)
-    assert get_values(out, 0, enc.payloads) == expect
-    # a live move row still owns its range after defrag
+    out = grow_state(compact_state(state), 128)
     moved = np.asarray(out.blocks.moved[0])
-    assert (moved >= 0).any()
+    assert moved.shape == (128,) and int(moved.max(initial=-1)) < 64
+    for name in ("moved", "mv_sc", "mv_ec", "mv_prio"):
+        assert int(np.asarray(getattr(out.blocks, name))[0, 64:].max(initial=-1)) == -1, name
+    assert get_values(out, 0, enc.payloads) == expect
+    assert (moved >= 0).any()  # a live move row still owns its range after defrag
+
+
+def test_compaction_policy_watermark():
+    from ytpu.models.batch_doc import DEFAULT_COMPACTION_POLICY as P
+
+    assert P.should_compact(90, 20, 100)  # projected overflow
+    assert P.should_compact(86, 1, 100)  # high-watermark tripped
+    assert not P.should_compact(50, 20, 100)
+    assert P.chunk_add_budget(32768) == int(0.15 * 32768)

@@ -599,3 +599,89 @@ def test_the_served_entry_hands_back_the_same_batch_as_a_pair(case):
         for name, got, want in zip(planes._fields, unpack_batch_jit(pair), planes):
             got, want = np.asarray(got), np.asarray(want)
             assert got.dtype == want.dtype and got.shape == want.shape and got.tobytes() == want.tobytes(), name
+
+
+@pytest.mark.usefixtures("native_lib")
+def test_pack_updates_into_reuse_is_clean():
+    """Slot reuse can never alias stale bytes into a later decode: after
+    re-packing a shorter payload over a longer one, the tail up to the
+    previous occupant's length + guard is zeroed."""
+    from ytpu.ops.decode_kernel import _PAD, pack_updates_into
+
+    buf = np.zeros((4, 64), dtype=np.uint8)
+    lens = np.zeros((4,), dtype=np.int32)
+    pack_updates_into([b"\x01" * 40, b"\x02" * 8], buf, lens)
+    assert lens.tolist() == [40, 8, 2, 2]  # short rows pad as EMPTY_UPDATE
+    pack_updates_into([b"\x03" * 6], buf, lens)
+    assert lens[0] == 6
+    assert buf[0, :6].tolist() == [3] * 6
+    assert not buf[0, 6 : 40 + _PAD].any(), "stale bytes survived reuse"
+    with pytest.raises(ValueError, match="exceeds staging width"):
+        pack_updates_into([b"\x04" * 60], buf, lens)
+
+
+@pytest.mark.usefixtures("native_lib")
+def test_gather_raw_lanes_matches_pack_updates_with_moves():
+    """The device lane-gather materializes a byte-IDENTICAL matrix to
+    host `pack_updates` — including the zero mask past each lane's
+    length that the decoder's prefix sums and gather guard read. Driven
+    on a stream with LIVE MOVES, map rows, and Any content, this pins
+    raw-vs-packed decode parity for every content kind the V1 decoder
+    supports without compiling a second decode program."""
+    import jax.numpy as jnp
+
+    from ytpu.core import Doc
+    from ytpu.ops.decode_kernel import (
+        gather_raw_lanes,
+        pack_raw_updates_into,
+        pack_updates,
+    )
+
+    doc = Doc(client_id=1)
+    log = []
+    doc.observe_update_v1(lambda p, o, t: log.append(p))
+    arr = doc.get_array("a")
+    with doc.transact() as txn:
+        for v in range(12):
+            arr.push_back(txn, v)
+    for r in range(4):
+        with doc.transact() as txn:
+            arr.move_range_to(txn, 1, 3, len(arr) - 1)  # live moves
+        with doc.transact() as txn:
+            arr.insert(txn, 2, {"k": 100 + r})  # map-shaped Any content
+        with doc.transact() as txn:
+            arr.remove_range(txn, 3, 2)
+    width = max(len(p) for p in log) + 16
+    buf, lens = pack_updates(log, pad_to=width)
+    # the flat arena and its prefix table, with room for a padding tail
+    wire = np.frombuffer(b"".join(log), dtype=np.uint8)
+    woffs = np.zeros(len(log) + 1, dtype=np.int64)
+    np.cumsum([len(p) for p in log], out=woffs[1:])
+    chunk = len(log)
+    cap = -(-(int(woffs[-1]) + 2) // 64) * 64
+    raw = np.zeros(cap, dtype=np.uint8)
+    offs = np.zeros(chunk, dtype=np.int32)
+    rlens = np.zeros(chunk, dtype=np.int32)
+    pack_raw_updates_into(wire, woffs, 0, chunk, raw, offs, rlens, width=width)
+    assert rlens.tolist() == lens.tolist()
+    gathered = np.asarray(
+        gather_raw_lanes(
+            jnp.asarray(raw), jnp.asarray(offs), jnp.asarray(rlens), width
+        )
+    )
+    assert (gathered == buf).all(), "gathered lane matrix != host-packed"
+    # a short tail chunk decodes as EMPTY_UPDATE at the compiled shape
+    pack_raw_updates_into(
+        wire, woffs, 1, chunk, raw, offs, rlens, width=width
+    )
+    assert rlens[chunk - 1] == 2 and offs[chunk - 1] == int(
+        woffs[chunk] - woffs[1]
+    )
+    with pytest.raises(ValueError, match="exceeds staging width"):
+        pack_raw_updates_into(
+            wire, woffs, 0, chunk, raw, offs, rlens, width=8
+        )
+    with pytest.raises(ValueError, match="exceeds staging capacity"):
+        pack_raw_updates_into(
+            wire, woffs, 0, chunk, raw[:8], offs, rlens, width=width
+        )

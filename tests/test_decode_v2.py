@@ -322,26 +322,12 @@ def test_b4_trace_prefix_rides_device_lane():
     """VERDICT r2 #5 'done' criterion: a V2-encoded B4 editing-trace stream
     decodes on the device lane with ZERO host fallbacks, and the decoded
     stream integrates to the same text as the host replay."""
-    import sys, os
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
+    from _traces import load_b4_log
 
     from ytpu.models.batch_doc import apply_update_stream, get_string, init_state
     from ytpu.ops.decode_kernel import RawPayloadView, identity_rank
 
-    if not os.path.exists(bench.TRACE_PATH):
-        pytest.skip(f"B4 trace asset not in this container: {bench.TRACE_PATH}")
-    ops = bench.load_b4_ops(400)
-    doc = Doc(client_id=1)
-    log = []
-    doc.observe_update_v1(lambda p, o, t: log.append(p))
-    t = doc.get_text("text")
-    for tag, pos, payload in ops:
-        with doc.transact() as txn:
-            if tag == "i":
-                t.insert(txn, pos, payload)
-            else:
-                t.remove_range(txn, pos, payload)
+    log, expect = load_b4_log(400)
     v2 = [v1_to_v2(p) for p in log]
     buf, lens, spans, side = pack_updates_v2(v2)
     stream, flags = decode_updates_v2(buf, lens, spans, 4, 4, sidecar=side)
@@ -352,7 +338,7 @@ def test_b4_trace_prefix_rides_device_lane():
     state = apply_update_stream(state, stream, identity_rank(2))
     assert int(np.asarray(state.error).max()) == 0
     got = get_string(state, 0, RawPayloadView(np.asarray(buf)))
-    assert got == doc.get_text("text").get_string()
+    assert got == expect
 
 
 def test_big_client_ids_resolve_through_hash_table():
@@ -430,15 +416,12 @@ def test_b4_full_trace_rides_v2_device_lane():
     editing trace, V2-encoded, decodes on the V2 device lane with ZERO
     host fallbacks (chunked; every lane's flags clean), and a sampled
     chunk integrates to text parity with the host replay."""
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
+    from _traces import load_b4_log
 
     from ytpu.models.batch_doc import apply_update_stream, get_string, init_state
     from ytpu.ops.decode_kernel import RawPayloadView, identity_rank
 
-    log, expect, trace = bench.load_full_log()
+    log, expect = load_b4_log()
     v2 = [v1_to_v2(p) for p in log]
     CHUNK = 8192
     total_flagged = 0
@@ -724,3 +707,46 @@ def test_rich_text_stream_rides_v2_device_lane():
     assert [(r.insert, r.attributes) for r in got] == [
         (r.insert, r.attributes) for r in want
     ]
+
+
+@pytest.mark.usefixtures("native_lib")
+def test_pack_updates_v2_raw_matches_packed():
+    """The V2 raw pack ships the same bytes the padded V2 matrix holds:
+    gathering the flat arena at the staged row extents reproduces
+    `pack_updates_v2`'s matrix byte-for-byte (cold sidecars included —
+    their refs point PAST the payload length, so the gather mask uses
+    the staged extent, not the decode length)."""
+    import jax.numpy as jnp
+
+    from ytpu.core import Doc, Update
+    from ytpu.ops.decode_kernel import gather_raw_lanes
+    from ytpu.ops.decode_v2 import pack_updates_v2, pack_updates_v2_raw
+
+    doc = Doc(client_id=5)
+    log = []
+    doc.observe_update_v1(lambda p, o, t: log.append(p))
+    txt = doc.get_text("text")
+    for i in range(4):
+        with doc.transact() as txn:
+            txt.insert(txn, i, "abcd"[i])
+    with doc.transact() as txn:
+        # Format content is a COLD kind: exercises the sidecar extent
+        txt.format(txn, 0, 2, {"bold": True})
+    v2 = [Update.decode_v1(p).encode_v2() for p in log]
+    buf, lens, spans, side = pack_updates_v2(v2)
+    wire, offs, row_lens, rlens, rspans, rside, width = pack_updates_v2_raw(v2)
+    assert width == buf.shape[1]
+    assert rlens.tolist() == lens.tolist()
+    assert (rspans == spans).all()
+    assert (side is None) == (rside is None)
+    if side is not None:
+        assert (rside == side).all()
+    gathered = np.asarray(
+        gather_raw_lanes(
+            jnp.asarray(wire),
+            jnp.asarray(offs),
+            jnp.asarray(row_lens),
+            width,
+        )
+    )
+    assert (gathered == buf).all(), "V2 gathered matrix != host-packed"

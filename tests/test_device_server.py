@@ -1,5 +1,7 @@
 """Device-backed sync server: protocol tenants mirrored into batch slots."""
 
+import pytest
+
 from ytpu.core import Doc
 from ytpu.sync.device_server import DeviceSyncServer
 from ytpu.sync.protocol import Message, SyncMessage
@@ -439,3 +441,34 @@ def test_unflushed_queue_survives_checkpoint(tmp_path):
     save_device_server(str(tmp_path / "pod"), pod)
     restored = load_device_server(str(tmp_path / "pod"))
     assert restored.device_text("pad") == "acked"
+
+
+# the apply stack that left in PR 48: the replay drivers, the fused kernel
+GONE = ("ytpu.ops.integrate_kernel", "ytpu.models.replay", "ytpu.models.pipeline")
+
+
+@pytest.mark.parametrize("module", ["ytpu", "ytpu.models", "ytpu.sync.device_server"])
+def test_one_apply_stack_is_all_an_import_finds(module):
+    """The package, its models and the served server load none of the
+    replay stack's modules, and none of them can be imported: the served
+    path (`DeviceSyncServer` -> `BatchIngestor` -> `apply_update_batch`) is
+    the one apply stack. In a process of its own, so what other tests
+    imported is not in the way."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import importlib, importlib.util, sys\n"
+        f"importlib.import_module({module!r})\n"
+        f"gone = {GONE!r}\n"
+        "print([m for m in gone if m in sys.modules], "
+        "[m for m in gone if importlib.util.find_spec(m) is not None])"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, env=env, capture_output=True, text=True, timeout=300
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "[] []"

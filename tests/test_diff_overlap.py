@@ -6,8 +6,9 @@ mixed into a sub-batch — a wire-ref Embed/Format doc the native core
 punts on), the zero-extra-device-syncs contract (counted host
 materializations + exact D2H byte accounting), the stall/overlap gauge
 contract, the pow2 recompile bound on the packed widths, the rows-based
-finisher threading heuristic, and the `diff.d2h_fail`/`finisher.raise`
-degradation classes.
+finisher threading heuristic, the `diff.d2h_fail`/`finisher.raise`
+degradation classes, and the overlap engine's own `stage.raise` site
+reached through its one user.
 
 Suite-cost hygiene: ONE compiled shape family for the whole file — the
 (n_docs=4, capacity=256) ingest family test_device_server.py already
@@ -284,6 +285,33 @@ def test_fault_degrades_sub_batch_to_serial_path_with_parity(site):
     assert out == fam["serial"], f"{site}: degraded sub-batch lost parity"
     assert pipe.stats.demotions >= 1
     assert metrics.counter("encode.demotions").value - base >= 1
+
+
+@needs_native
+@pytest.mark.parametrize("prefix,fires", [("encode", True), ("replay", False)])
+def test_a_staging_fault_surfaces_and_the_pipeline_runs_again(prefix, fires):
+    """`stage.raise` lives in `OverlapPipeline`'s staging worker, and
+    `DiffPipeline` is the engine's one user (`stage_prefix="encode"`): an
+    armed fault re-raises on the caller at once, with no sub-batch left
+    waiting on a queue, and the next run of the same pipeline ships the
+    serial finisher's bytes. A spec armed for another prefix is no
+    eligible pass there."""
+    import time
+
+    from ytpu.utils.faults import FaultError
+
+    fam = _family()
+    spec = faults.arm("stage.raise", prefix=prefix)
+    sel = list(range(N_DOCS))
+    t0 = time.perf_counter()
+    if fires:
+        with pytest.raises(FaultError, match="stage.raise"):
+            _run_pipe(sel)
+        assert time.perf_counter() - t0 < 30.0, "the caller was stranded"
+    assert spec.fired == int(fires)
+    pipe, out = _run_pipe(sel)  # the spec is spent, or never matched
+    assert out == fam["serial"]
+    assert pipe.stats.n_sub > 1 and pipe.stats.demotions == 0
 
 
 @needs_native

@@ -4,16 +4,13 @@ state commitments, partition/heal chaos, forced failover.
 Layering: the protocol/commitment/mesh tests are HOST-ONLY (no jax —
 `SyncServer` replicas; milliseconds), the device-backed mesh test reuses
 the suite-wide (n_docs=4, capacity=256) `DeviceSyncServer` family
-compiled by test_device_server/test_serving_soak, and the commitment
-lane-agreement test reuses test_async_overlap's (2, 256, 16) replay
-family (fused interpret via `_fused_interpret.run_or_skip`).
+compiled by test_device_server/test_serving_soak, and the device
+commitment test folds the block columns of a (2, 256) `BatchIngestor`.
 """
 
 import urllib.request
 
 import pytest
-
-from _fused_interpret import run_or_skip
 
 from ytpu.core import Doc
 from ytpu.serving import (
@@ -442,69 +439,36 @@ def _multi_client_log():
     ).get_string()
 
 
-def _replay(log, lane, interpret=False):
-    from ytpu.models.replay import FusedReplay, plan_replay
+@pytest.mark.parametrize("compacted", [False, True], ids=["as_integrated", "compacted"])
+def test_device_commitment_fold_matches_sv_closed_form(_multi_client_log, compacted):
+    """`commit_fold_blocks` over the block columns the served ingestor
+    built equals the pure-Python closed form over the final state vector
+    (in the ingestor's interned client ids): the block rows tile each
+    client's lattice, so the row-wise fold collapses to
+    `device_commit_of_clocks`, before and after the squash, GC conversion
+    and defragmentation of `compact_state`."""
+    import numpy as np
 
-    return FusedReplay(
-        n_docs=2,
-        plan=plan_replay(log),
-        capacity=256,
-        max_capacity=256,
-        d_block=2,
-        chunk=16,
-        lane=lane,
-        interpret=interpret,
-        overlap=True,
-    )
+    from ytpu.models.batch_doc import commit_fold_blocks, get_string
+    from ytpu.models.ingest import BatchIngestor
+    from ytpu.ops.compaction import compact_state
 
-
-def test_commitment_readout_word_matches_sv_closed_form(_multi_client_log):
-    """The device commitment word (the new last word of the lazy
-    readout) equals the pure-Python closed form over the final state
-    vector — the block rows tile each client's lattice, so the
-    row-wise fold collapses to `device_commit_of_clocks`."""
-    from ytpu.native import available as native_available
-
-    if not native_available():
-        pytest.skip("native codec unavailable (plan pre-scan)")
     log, sv, expect_text = _multi_client_log
-    r = _replay(log, "xla")
-    stats = r.run(log)
-    assert r.get_string(0) == expect_text
-    per_doc = device_commit_of_clocks(sv)
-    assert stats.commit_word == (2 * per_doc) & MASK32, (
-        stats.commit_word, per_doc, sv,
-    )
+    ing = BatchIngestor(2, 256)
+    for p in log:
+        ing.apply_bytes([p, p])
+    state = compact_state(ing.state) if compacted else ing.state
+    assert int(np.asarray(state.error).max()) == 0
+    assert get_string(state, 0, ing.payloads) == expect_text
+    bl = state.blocks
+    slots = np.arange(bl.client.shape[-1])[None, :]
+    valid = (slots < np.asarray(state.n_blocks)[:, None]) & (np.asarray(bl.client) >= 0)
+    words = np.asarray(commit_fold_blocks(bl.client, bl.clock, bl.length, valid))
+    interned = {ing.enc.interner.to_idx[c]: n for c, n in sv.items()}
+    per_doc = device_commit_of_clocks(interned)
+    assert [int(w) for w in words] == [per_doc, per_doc], (words, per_doc)
+    assert per_doc == per_doc & MASK32
     # the host federation mirror folds the SAME lattice (64-bit params,
     # same clock coverage): its incremental and full values agree on it
     tc = TenantCommitments()
     assert tc.refresh("t", sv.items()) == commitment_of_clocks(sv)
-
-
-def test_commitment_readout_word_agrees_across_lanes(_multi_client_log):
-    """serial-oracle (closed form) / packed-XLA / fused-interpret land
-    the identical commitment word; `packed_commitments` exposes the
-    per-doc words behind the aggregate."""
-    import numpy as np
-
-    from ytpu.native import available as native_available
-    from ytpu.ops.integrate_kernel import packed_commitments
-
-    if not native_available():
-        pytest.skip("native codec unavailable (plan pre-scan)")
-    log, sv, _ = _multi_client_log
-    per_doc = device_commit_of_clocks(sv)
-    xla = _replay(log, "xla")
-    s_xla = xla.run(log)
-
-    def fused():
-        r = _replay(log, "fused", interpret=True)
-        return r.run(log)
-
-    s_fused = run_or_skip(fused)
-    assert s_xla.commit_word == s_fused.commit_word == (2 * per_doc) & MASK32
-    # per-doc pull: both docs carry the identical broadcast stream
-    words = np.asarray(packed_commitments(xla.cols, xla.meta)).astype(
-        np.uint32
-    )
-    assert list(words) == [per_doc, per_doc], (words, per_doc)

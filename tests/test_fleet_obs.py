@@ -1,8 +1,7 @@
 """Fleet observability plane (ISSUE-15): the wire trace-context
 extension's codec + backward compatibility, cross-replica trace
 propagation through the in-proc mesh, the merged `/fleet` exposition
-under concurrent live scrapes, canary probing semantics, and the
-`--compare-baseline` verdict embedding.
+under concurrent live scrapes, and canary probing semantics.
 
 Compatibility is the load-bearing surface here: trace frames are a
 PROTOCOL_VERSION 2 extension, so an old (version-1) peer must (a) never
@@ -264,26 +263,3 @@ def test_timeline_records_ownership_and_migration():
     assert "ownership" in kinds and "migration" in kinds, kinds
     seqs = [ev["seq"] for ev in mesh.timeline_events()]
     assert seqs == sorted(seqs)
-
-
-# -------------------------------------------------- --compare-baseline
-
-
-def test_compare_baseline_embeds_directional_verdict():
-    import bench
-
-    base = {"value": 1000.0, "soak": {"apply_p99_ms": 2.0}}
-    same = bench._compare_baseline(dict(base), baseline=base)
-    assert same["status"] == "compared" and same["exit_status"] == 0
-    assert same["regressions"] == []
-    worse = bench._compare_baseline(
-        {"value": 500.0, "soak": {"apply_p99_ms": 9.0}}, baseline=base
-    )
-    assert worse["exit_status"] == 1
-    keys = {r["key"] for r in worse["regressions"]}
-    assert keys == {"value", "soak.apply_p99_ms"}
-    # the verdict must degrade, never raise
-    broken = bench._compare_baseline(
-        {"value": object()}, baseline=base
-    )
-    assert broken["exit_status"] in (0, 1, 2)

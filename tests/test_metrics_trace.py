@@ -3,8 +3,6 @@
 import json
 import os
 import re
-import subprocess
-import sys
 
 import pytest
 
@@ -15,7 +13,7 @@ from ytpu.utils import MetricsRegistry, Tracer
 def fresh_registry():
     """An empty process-wide registry for one test, the families put back
     after it: `metrics.reset()` alone orphans every family a module cached
-    at import (`net.py`, `replica.py`, `integrate_kernel.py`, ...) for the
+    at import (`net.py`, `replica.py`, `admission.py`, ...) for the
     rest of the worker's life, and a later test that reads one through the
     registry then sees a namesake at 0 (ROADMAP Design 13)."""
     from ytpu.utils import metrics
@@ -454,35 +452,3 @@ def test_ingest_metrics_counters_mirror_lane_stats(fresh_registry):
     assert snap["ingest.fast_docs"] + snap["ingest.slow_docs"] == 1
     assert snap["ingest.fast_docs"] == ing.fast_docs
     assert snap["ingest.slow_docs"] == ing.slow_docs
-
-
-# --- bench exporter smoke (CI guard; excluded from the tier-1 gate) ---------
-
-
-@pytest.mark.slow
-def test_bench_dry_run_emits_phases_and_metrics():
-    """`bench.py --dry-run` is host-only (no jax, no device child) and
-    must print exactly one JSON line carrying the `phases` + `metrics`
-    keys — the exporter-regression guard before a real bench round."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, YTPU_BENCH_DRY_OPS="120", JAX_PLATFORMS="cpu")
-    res = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"), "--dry-run"],
-        capture_output=True,
-        text=True,
-        timeout=240,
-        cwd=root,
-        env=env,
-    )
-    assert res.returncode == 0, res.stderr[-800:]
-    lines = [ln for ln in res.stdout.strip().splitlines() if ln.strip()]
-    assert len(lines) == 1, f"expected ONE JSON line, got {len(lines)}"
-    out = json.loads(lines[0])
-    assert out["dry_run"] is True
-    assert "value" in out and out["host_oracle_updates_per_sec"] > 0
-    ph = out["phases"]
-    assert "host.replay" in ph
-    for st in ph.values():
-        for k in ("compile_s", "execute_s", "transfer_bytes", "calls"):
-            assert k in st
-    assert isinstance(out["metrics"], dict)

@@ -175,13 +175,9 @@ def test_map_and_nested_xml_parity():
 
 @needs_native
 def test_concurrent_array_parity():
-    import importlib
-    import os
-    import sys
+    from _traces import build_array_relay_stream
 
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benches"))
-    dev = importlib.import_module("device")
-    log, expect = dev.stream_workload_array(n_clients=24, ops_per_client=2, seed=3)
+    log, expect = build_array_relay_stream(n_clients=24, ops_per_client=2, seed=3)
     eng = NativeEngine()
     for p in log:
         eng.apply_update_v1(p)
@@ -191,13 +187,11 @@ def test_concurrent_array_parity():
 
 @needs_native
 def test_b4_trace_prefix_parity():
-    import bench
+    from _traces import build_updates, load_b4_log, synthetic_ops
 
-    try:
-        ops = bench.load_b4_ops(3000)
-    except FileNotFoundError:
-        ops = bench.synthetic_ops(3000)
-    log, expect = bench.build_updates(ops)
+    log, expect = load_b4_log(3000)
+    assert native_replay_v1(log) == expect
+    log, expect = build_updates(synthetic_ops(3000))
     assert native_replay_v1(log) == expect
 
 

@@ -4,27 +4,13 @@ in docs/observability.md — the doc PRs 7/9/10 each had to patch by hand
 after the fact. The test fails naming exactly the missing entries, so
 adding a metric without documenting it is a one-line fix at review time,
 not doc drift discovered two PRs later.
-
-Also the home of the conflict-scan-width assertions (ISSUE-11 tentpole
-a): the exercise below runs a real XLA-lane overlap replay, so the same
-compiled (2, 256, 16) family serves the lint's phase-key collection AND
-the scan-width behavior pins.
-
-Ordering note: this file sorts between test_metrics_trace and
-test_pallas_*, after test_async_overlap / test_device_server have
-compiled the shared shape families — the exercise re-uses their cached
-programs and adds none.
 """
 
 import os
-import re
-import sys
 
 import pytest
 
 from ytpu.utils import metrics, phases
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 DOCS = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -32,24 +18,11 @@ DOCS = os.path.join(
     "observability.md",
 )
 
-# phase-key normalization: per-lane suffixed gauges document as the base
-# name; rehearsal namespaces are bench-simulation-only by contract
-_LANE_SUFFIX = re.compile(r"\.(fused|xla|host)$")
-
-
-def _normalize_phase(key: str):
-    if key.startswith("rehearsal"):
-        return None  # documented as the rehearsal.* namespace rule
-    return _LANE_SUFFIX.sub("", key)
-
-
 def _exercise():
-    """A compact dry-run-shaped workout touching every subsystem that
-    registers series: transport + device serving + soak + admission +
-    async replay + telemetry. Reuses the suite's compiled families."""
+    """A compact workout touching every subsystem that registers series:
+    transport + device serving + soak + admission + telemetry. Reuses the
+    suite's compiled (4, 256) device-server family."""
     pytest.importorskip("jax")
-    import bench as _bench
-    from ytpu.models.replay import FusedReplay, plan_replay
     from ytpu.serving import (
         AdmissionController,
         Scenario,
@@ -73,29 +46,6 @@ def _exercise():
             flush_every=4,
         ).run()
 
-        # replay leg: the async XLA-lane pipeline (scan-width surface)
-        ops = []
-        length = 0
-        for _ in range(14):
-            for i in range(20):
-                ops.append(("i", length, "abcdef"[i % 6]))
-                length += 1
-            ops.append(("d", length - 18, 18))
-            length -= 18
-        log, expect = _bench.build_updates(ops)
-        r = FusedReplay(
-            n_docs=2,
-            plan=plan_replay(log),
-            capacity=256,
-            max_capacity=256,
-            d_block=2,
-            chunk=16,
-            lane="xla",
-            overlap=True,
-        )
-        stats = r.run(log)
-        assert r.get_string(0) == expect
-
         # telemetry leg: one scrape registers the plane's own series
         with TelemetryServer(port=0) as t:
             import urllib.request
@@ -107,46 +57,11 @@ def _exercise():
     finally:
         phases.disable()
         phases.reset()
-    return stats, snap
-
-
-def test_scan_width_histogram_rides_the_readout():
-    """Tentpole (a) pins: the scan record materializes with the
-    existing readout (totals + max + bucket-quantiles + the ISSUE-12
-    tier/trip words), the gauges land in phases (base + lane-suffixed),
-    and the bucket math is coherent."""
-    from ytpu.models.batch_doc import SCAN_REC_WORDS, SCAN_WIDTH_BUCKETS
-
-    stats, snap = _exercise()
-    assert len(stats.scan_hist) == SCAN_WIDTH_BUCKETS
-    total = sum(stats.scan_hist)
-    assert total > 0, "no conflict scans recorded over a 294-update replay"
-    assert 0 <= stats.scan_p50 <= stats.scan_p99 <= max(stats.scan_max, 1)
-    # ISSUE-12 tier occupancy: every scan resolved in exactly one tier,
-    # and the two-tier dispatch can never pay MORE trips than the
-    # serial-equivalent loop (the accounting words ride the same record)
-    assert stats.scan_tier_cheap + stats.scan_tier_wide == total, stats
-    # (a scan can legitimately visit zero candidates — its entry slot is
-    # already the resolved neighbor — so the trip words may both be 0)
-    assert (
-        0 <= stats.scan_trips_two_tier <= stats.scan_trips_serial
-    ), stats
-    # gauges: base keys + the per-lane twins, all in the phases snapshot
-    for q in ("width_p50", "width_p99", "width_max", "tier_cheap",
-              "tier_wide", "trips_serial", "trips_two_tier"):
-        assert f"integrate.scan_{q}" in snap, sorted(snap)
-        assert f"integrate.scan_{q}.xla" in snap
-    # the record words rode the SAME readout future: their d2h bytes
-    # are accounted under integrate.scan_hist, while replay.readout kept
-    # its historical 12-bytes-per-readout accounting (the zero-sync
-    # invariant test in test_async_overlap passes unchanged)
-    assert snap["integrate.scan_hist"]["d2h_bytes"] == (
-        4 * SCAN_REC_WORDS * (snap["replay.readout"]["d2h_bytes"] // 12)
-    )
+    return snap
 
 
 def test_every_emitted_metric_and_phase_name_is_documented():
-    _, snap = _exercise()
+    snap = _exercise()
     with open(DOCS) as f:
         doc = f.read()
     # metric families: every registered family name (the exercise above
@@ -158,8 +73,7 @@ def test_every_emitted_metric_and_phase_name_is_documented():
         if name not in doc:
             missing.append(f"metric: {name}")
     for key in sorted(snap):
-        base = _normalize_phase(key)
-        if base is not None and base not in doc:
+        if key not in doc:
             missing.append(f"phase: {key}")
     assert not missing, (
         "undocumented observability names (add them to "

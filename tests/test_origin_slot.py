@@ -16,15 +16,13 @@ contract, asserted here against a brute-force recompute:
 Maintenance sites covered: insert (link-in), block splits (clean start/
 end + delete-range + move-bound repair), squash/defragment compaction,
 capacity growth, checkpoint save/load (incl. pre-origin_slot format-2
-checkpoints), sharded link-in and rebalance, fused-lane unpack.
+checkpoints), and the lazy refresh of a state marked stale.
 """
 
 from __future__ import annotations
 
 import numpy as np
 import pytest
-
-from _fused_interpret import run_or_skip
 
 from ytpu.core import Doc
 from ytpu.core.update import Update
@@ -196,10 +194,10 @@ def test_checkpoint_roundtrip_and_format2_backcompat(tmp_path):
 
 def test_lazy_origin_slot_refresh_machinery():
     """ADVICE r5 #1: the O(D·B²) wholesale rebuild is LAZY — a state
-    marked stale (the fused lane's unpack does this) is refreshed by
-    `ensure_origin_slot`, and the XLA apply entry points do it
+    marked stale (by a producer that does not maintain the cache) is
+    refreshed by `ensure_origin_slot`, and the apply entry points do it
     implicitly before their conflict scan reads the cache. Verified
-    here kernel-free by wiping + marking an XLA-lane state."""
+    here by wiping + marking a state the apply step built."""
     pytest.importorskip("jax")
     import jax.numpy as jnp
 
@@ -216,7 +214,7 @@ def test_lazy_origin_slot_refresh_machinery():
     rank = enc.interner.rank_table()
     state, _enc2 = _replay(log, capacity=512, rows=16, dels=16)
 
-    # simulate the fused unpack: cache plane wiped, state marked stale
+    # a producer that does not maintain the cache: plane wiped, state marked stale
     wiped = state._replace(
         blocks=state.blocks._replace(
             origin_slot=jnp.full_like(state.blocks.origin_slot, -1)
@@ -246,33 +244,3 @@ def test_lazy_origin_slot_refresh_machinery():
     )
     chained = apply_update_stream(wiped, noop, rank)
     assert _invariant_violations(chained) == []
-
-
-def test_fused_lane_default_defers_and_marks_stale():
-    """End-to-end fused contract: default refresh_cache=False marks the
-    unpacked state stale; refresh_cache=True keeps the eager rebuild.
-    Skips where interpret-mode Pallas cannot run (jax builds missing
-    discharge rules — the kernel itself is hardware-validated)."""
-    pytest.importorskip("jax")
-    from ytpu.models.batch_doc import (
-        ensure_origin_slot,
-        origin_slot_is_stale,
-    )
-    from ytpu.ops.integrate_kernel import apply_update_stream_fused
-
-    log, _ = _concurrent_log(seed=19, n_ops=24)
-    enc = BatchEncoder()
-    steps = [enc.build_step(Update.decode_v1(p), 16, 16) for p in log]
-    stream = BatchEncoder.stack_steps(steps)
-    rank = enc.interner.rank_table()
-    fused = run_or_skip(lambda: apply_update_stream_fused(
-        init_state(4, 512), stream, rank, d_block=2, interpret=True
-    ))
-    assert origin_slot_is_stale(fused)
-    assert _invariant_violations(ensure_origin_slot(fused)) == []
-    eager = apply_update_stream_fused(
-        init_state(4, 512), stream, rank, d_block=2, interpret=True,
-        refresh_cache=True,
-    )
-    assert not origin_slot_is_stale(eager)
-    assert _invariant_violations(eager) == []

@@ -1,90 +1,87 @@
-"""Two-tier conflict scan (ISSUE-12 tentpole): adversarial deep-conflict
-streams — N concurrent clients inserting at ONE origin, with interleaved
-deletes and live moves — must integrate at byte parity with the serial
-host oracle on the packed-XLA lane (and fused-interpret, where this jax
-can run it), with the vectorized WIDE tier demonstrably firing (tier
-counters > 0) and the dispatch-trip accounting coherent: the two-tier
-dispatch never pays more serial `while_loop` trips than the
-one-candidate-per-trip loop it replaces, and the scan-WIDTH record keeps
-its pre-ISSUE-12 meaning (width still counts visited candidates, so the
-histogram is tier-plan-invariant).
+"""Two-tier conflict scan (ISSUE-12) on the served integrate step:
+adversarial deep-conflict streams — N concurrent clients inserting at ONE
+origin, with interleaved deletes and live moves — must integrate through
+`apply_update_batch` (the program `BatchIngestor.apply_bytes` runs) at
+parity with the serial host oracle, with the vectorized WIDE tier
+demonstrably firing (tier counters > 0) and the dispatch-trip accounting
+coherent: the two-tier dispatch never pays more serial `while_loop` trips
+than the one-candidate-per-trip loop it replaces, and the scan-WIDTH record
+keeps its meaning (width still counts visited candidates, so the histogram
+is tier-plan-invariant).
 
-Every replay reuses the suite-wide (n_docs=2, capacity=256, chunk=16)
-shape family — the compiled decode/chunk-step/compaction programs are
-shared with test_async_overlap/test_chaos_recovery (distinct big
-programs are the suite's scarce resource, conftest.py LLVM-arena note).
-The tier-knob test necessarily compiles ONE extra plan variant (that is
-the knob's documented retrace contract). The fused interpret test routes
-through `tests/_fused_interpret.run_or_skip` and runs LAST.
+`apply_update_batch` drops the scan record inside its jit; the same traced
+row body returns it through `apply_update_stream_raw` (state and a
+`[D, SCAN_REC_WORDS]` record), so every test integrates its stream twice:
+step by step through the served function for the state, once through the
+stream body for the record, and holds the two states to the same reads.
+
+One shape family (n_docs=2, capacity=256, 4 rows and 4 delete ranges an
+update). The tier-knob test compiles ONE extra plan variant of each program
+(that is the knob's documented retrace contract).
 """
 
 from functools import lru_cache
 
 import numpy as np
-import pytest
 
-from ytpu.core import Doc, Update
+from _traces import build_conflict_stream, build_move_storm
+from ytpu.core import Update
 from ytpu.models.batch_doc import (
+    SCAN_REC_CHEAP,
+    SCAN_REC_CHEAP_TRIPS,
+    SCAN_REC_MAX,
+    SCAN_REC_WIDE,
+    SCAN_REC_WIDE_TRIPS,
+    SCAN_REC_WIDTH_SUM,
     SCAN_TIER_CHEAP_DEFAULT,
+    SCAN_WIDTH_BUCKETS,
     BatchEncoder,
+    apply_update_batch,
+    apply_update_stream_raw,
     get_string,
     get_values,
     init_state,
     scan_tier_plan,
 )
-from ytpu.ops import integrate_kernel as ik
-from ytpu.ops.integrate_kernel import replay_stream_fused
-from ytpu.utils.faults import faults
+from ytpu.models.ingest import BatchIngestor
+from ytpu.utils import metrics
 
-from _fused_interpret import run_or_skip
-
-# the ONE adversarial-stream generator, shared with the bench so the
-# acceptance stream (benches/scan_tiers.py dry-run leg) and this file's
-# parity streams can never drift apart (conftest puts the repo root on
-# sys.path; benches/ is a namespace package)
-from benches.scan_tiers import build_conflict_stream
-
-needs_native = pytest.mark.usefixtures("native_lib")
-
-# the one shape family of this file (shared suite-wide)
-N_DOCS, CAPACITY, CHUNK, D_BLOCK = 2, 256, 16, 2
+N_DOCS, CAPACITY = 2, 256
+ROWS, DELS = 4, 4
 
 
-@pytest.fixture(autouse=True)
-def _clean_slate():
-    """Armed faults and sticky lane demotions are process-global."""
-    faults.clear()
-    ik.reset_lane_health()
-    yield
-    faults.clear()
-    ik.reset_lane_health()
-
-
-def _capture(doc):
-    log = []
-    doc.observe_update_v1(lambda p, o, t: log.append(p))
-    return log
-
-
-def _stack(payloads, root_name="text"):
+def _served_steps(payloads, root_name="text", capacity=CAPACITY):
+    """Every update to every room, a call of `apply_update_batch` each."""
     enc = BatchEncoder(root_name=root_name)
-    steps = [enc.build_step(Update.decode_v1(p), 4, 4) for p in payloads]
-    return BatchEncoder.stack_steps(steps), enc
+    state = init_state(N_DOCS, capacity)
+    for p in payloads:
+        batch = enc.build_batch([Update.decode_v1(p)] * N_DOCS, ROWS, DELS)
+        state = apply_update_batch(state, batch, enc.interner.rank_table())
+    assert int(np.asarray(state.error).max()) == 0
+    return state, enc
 
 
-def _replay(stream, rank, lane="xla", interpret=False,
-            max_capacity=4 * CAPACITY, policy=None):
-    return replay_stream_fused(
-        init_state(N_DOCS, CAPACITY),
-        stream,
-        rank,
-        chunk_steps=CHUNK,
-        d_block=D_BLOCK,
-        lane=lane,
-        interpret=interpret,
-        max_capacity=max_capacity,
-        policy=policy,
+def _scan_record(payloads, root_name="text", capacity=CAPACITY):
+    """The same stream through the stream body, for the record it returns:
+    (state, encoder, room 0's words as a dict)."""
+    enc = BatchEncoder(root_name=root_name)
+    steps = [enc.build_step(Update.decode_v1(p), ROWS, DELS) for p in payloads]
+    state, rec = apply_update_stream_raw(
+        init_state(N_DOCS, capacity), BatchEncoder.stack_steps(steps),
+        enc.interner.rank_table(), scan_tier_plan(),
     )
+    assert int(np.asarray(state.error).max()) == 0
+    rec = np.asarray(rec)
+    assert (rec[0] == rec[1]).all()  # both rooms took the same stream
+    words = {
+        "hist": rec[0, :SCAN_WIDTH_BUCKETS].tolist(),
+        "max": int(rec[0, SCAN_REC_MAX]),
+        "cheap": int(rec[0, SCAN_REC_CHEAP]),
+        "wide": int(rec[0, SCAN_REC_WIDE]),
+        "trips_two_tier": int(rec[0, SCAN_REC_CHEAP_TRIPS] + rec[0, SCAN_REC_WIDE_TRIPS]),
+        "trips_serial": int(rec[0, SCAN_REC_WIDTH_SUM]),
+    }
+    return state, enc, words
 
 
 @lru_cache(maxsize=1)
@@ -92,180 +89,94 @@ def _deep():
     """The file's main adversarial stream: 10 clients × 12 same-origin
     inserts (~120 concurrent siblings — widths ramp well past the
     default cheap bound of 32) + interleaved deletes."""
-    payloads, expect = build_conflict_stream(
-        10, 12, erase_every=5, erase_len=11
-    )
-    stream, enc = _stack(payloads)
-    return payloads, expect, stream, enc
+    return build_conflict_stream(10, 12, erase_every=5, erase_len=11)
 
 
 def test_deep_conflicts_wide_tier_fires_at_oracle_parity():
-    """Tentpole acceptance: on an adversarial same-origin storm the
-    packed-XLA lane stays byte-exact vs the serial host oracle AND the
-    wide tier demonstrably fires — tier counters > 0, every scan lands
-    in exactly one tier, and the two-tier dispatch pays strictly fewer
-    serial while trips than the single-tier loop would have."""
-    _, expect, stream, enc = _deep()
-    st, stats = _replay(stream, enc.interner.rank_table())
-    assert int(np.asarray(st.error).max()) == 0
+    """On an adversarial same-origin storm the served step stays exact
+    against the serial host oracle AND the wide tier demonstrably fires —
+    tier counters > 0, every scan lands in exactly one tier, and the
+    two-tier dispatch pays strictly fewer serial while trips than the
+    single-tier loop would have."""
+    payloads, expect = _deep()
+    state, enc = _served_steps(payloads)
     for d in range(N_DOCS):
-        assert get_string(st, d, enc.payloads) == expect
+        assert get_string(state, d, enc.payloads) == expect
+    by_stream, enc_s, rec = _scan_record(payloads)
+    assert get_string(by_stream, 0, enc_s.payloads) == expect
     cheap_bound, _ = scan_tier_plan()
     assert cheap_bound == SCAN_TIER_CHEAP_DEFAULT  # suite runs defaults
-    assert stats.scan_tier_wide > 0, stats
-    assert stats.scan_tier_cheap > 0, stats  # the shallow mass stays cheap
-    assert stats.scan_max > cheap_bound, stats
-    assert stats.scan_tier_cheap + stats.scan_tier_wide == sum(
-        stats.scan_hist
-    ), stats
-    assert (
-        0 < stats.scan_trips_two_tier < stats.scan_trips_serial
-    ), stats
+    assert rec["wide"] > 0, rec
+    assert rec["cheap"] > 0, rec  # the shallow mass stays cheap
+    assert rec["max"] > cheap_bound, rec
+    assert rec["cheap"] + rec["wide"] == sum(rec["hist"]), rec
+    assert 0 < rec["trips_two_tier"] < rec["trips_serial"], rec
 
 
 def test_width_record_is_tier_plan_invariant(monkeypatch):
-    """`scan_width_*` must keep its meaning (acceptance): replaying the
-    SAME stream with the tier knob degenerated to the pre-ISSUE-12 loop
-    (cheap=0, unroll=1 — every candidate is one while trip) yields an
-    IDENTICAL width histogram/max, identical serial-trip accounting, and
-    the degenerate plan pays exactly the serial trip count. Also pins
-    the knob's documented env path: the driver re-reads it per chunk, so
-    a changed value takes effect (via retrace) without a process
-    restart."""
-    _, expect, stream, enc = _deep()
-    st_a, a = _replay(stream, enc.interner.rank_table())
+    """`scan_width_*` must keep its meaning: the SAME stream with the tier
+    knob degenerated to the pre-ISSUE-12 loop (cheap=0, unroll=1 — every
+    candidate is one while trip) yields an IDENTICAL width histogram/max,
+    identical serial-trip accounting, and the degenerate plan pays exactly
+    the serial trip count. Also pins the knob's documented env path:
+    `apply_update_batch` re-reads it per call, so a changed value takes
+    effect (via retrace) without a process restart."""
+    payloads, expect = _deep()
+    _, _, a = _scan_record(payloads)
     monkeypatch.setenv("YTPU_SCAN_TIER_CHEAP", "0")
     monkeypatch.setenv("YTPU_SCAN_WIDE_UNROLL", "1")
     assert scan_tier_plan() == (0, 1)
-    st_b, b = _replay(stream, enc.interner.rank_table())
-    assert get_string(st_b, 0, enc.payloads) == expect
-    assert b.scan_hist == a.scan_hist, (a, b)
-    assert b.scan_max == a.scan_max
-    assert (b.scan_p50, b.scan_p99) == (a.scan_p50, a.scan_p99)
-    assert b.scan_trips_serial == a.scan_trips_serial
+    state, enc = _served_steps(payloads)
+    assert get_string(state, 0, enc.payloads) == expect
+    _, _, b = _scan_record(payloads)
+    assert b["hist"] == a["hist"], (a, b)
+    assert b["max"] == a["max"]
+    assert b["trips_serial"] == a["trips_serial"]
     # degenerate plan = the old dispatch: one candidate per while trip
-    assert b.scan_trips_two_tier == b.scan_trips_serial, b
+    assert b["trips_two_tier"] == b["trips_serial"], b
+    assert b["cheap"] + b["wide"] == sum(b["hist"])
     # the real plan strictly compresses the same workload
-    assert a.scan_trips_two_tier < a.scan_trips_serial
+    assert a["trips_two_tier"] < a["trips_serial"]
 
 
-def test_compaction_midstream_keeps_parity_and_tier_counts():
-    """A tight-capacity storm (raw rows > capacity, growth disabled)
-    must be carried by BETWEEN-CHUNK compaction while the wide tier is
-    firing — the tier/trip meta words ride the packed meta through
-    `compact_packed` untouched, so the record survives compaction."""
+def test_compaction_midstream_keeps_parity():
+    """A tight-capacity storm (raw rows > capacity; a slot cannot grow) is
+    carried by the served compaction (`compact_rooms`, from
+    `BatchIngestor._make_room`) firing in the middle of it while the wide
+    tier is firing."""
     payloads, expect = build_conflict_stream(
         8, 6, erase_every=1, rounds=6, typed=True, erase_len=5
     )
-    stream, enc = _stack(payloads)
-    raw_rows = int(np.asarray(stream.valid).sum())
+    by_stream, enc_s, rec = _scan_record(payloads, capacity=4 * CAPACITY)
+    raw_rows = int(np.asarray(by_stream.n_blocks)[0])
     assert raw_rows > CAPACITY, "workload must not fit without compaction"
-    st, stats = _replay(
-        stream, enc.interner.rank_table(), max_capacity=CAPACITY
-    )
-    assert stats.compactions >= 1, stats
-    assert stats.growths == 0, stats
-    assert int(np.asarray(st.error).max()) == 0
+    assert get_string(by_stream, 0, enc_s.payloads) == expect
+    assert rec["wide"] > 0, rec
+    assert rec["cheap"] + rec["wide"] == sum(rec["hist"]), rec
+
+    compactions = metrics.counter("ingest.room_compactions")
+    before = compactions.value
+    ing = BatchIngestor(N_DOCS, CAPACITY)
+    for p in payloads:
+        ing.apply_bytes([p] * N_DOCS)
+    assert compactions.value - before >= N_DOCS
+    assert int(np.asarray(ing.state.error).max()) == 0
+    assert int(np.asarray(ing.state.n_blocks).max()) <= CAPACITY
     for d in range(N_DOCS):
-        assert get_string(st, d, enc.payloads) == expect
-    assert stats.scan_tier_wide > 0, stats
-    assert stats.scan_tier_cheap + stats.scan_tier_wide == sum(
-        stats.scan_hist
-    ), stats
+        assert get_string(ing.state, d, ing.payloads) == expect
 
 
 def test_live_moves_with_deep_conflicts_parity():
     """Concurrent same-origin ARRAY inserts + live `move_range_to`
     ranges + deletes: the scan walks move rows and tombstones in the
-    conflict neighborhood, and move-claim recomputes run between chunks
-    — parity vs the host oracle with the wide tier firing."""
-    base = Doc(client_id=1)
-    base_log = _capture(base)
-    arr = base.get_array("a")
-    with base.transact() as txn:
-        for v in range(12):
-            arr.push_back(txn, v)
-    base_update = base.encode_state_as_update_v1()
+    conflict neighborhood, and move claims are recomputed a step —
+    parity vs the host oracle with the wide tier firing."""
+    payloads, expect = build_move_storm()
 
-    per_client = []
-    for k in range(8):
-        doc = Doc(client_id=10 + k)
-        doc.apply_update_v1(base_update)
-        log = _capture(doc)
-        a = doc.get_array("a")
-        for i in range(6):  # concurrent same-origin inserts at index 3
-            with doc.transact() as txn:
-                a.insert(txn, 3, 1000 * k + i)
-        with doc.transact() as txn:  # a live move spanning the storm
-            a.move_range_to(txn, 1, 3, len(a) - 1)
-        if k % 3 == 0:
-            with doc.transact() as txn:
-                a.remove_range(txn, 2, 3)
-        per_client.append(log)
-
-    payloads = list(base_log)
-    for i in range(max(len(log) for log in per_client)):
-        for log in per_client:
-            if i < len(log):
-                payloads.append(log[i])
-    oracle = Doc(client_id=2)
-    for p in payloads:
-        oracle.apply_update_v1(p)
-    expect = oracle.get_array("a").to_json()
-
-    stream, enc = _stack(payloads, root_name="a")
-    st, stats = _replay(stream, enc.interner.rank_table())
-    assert int(np.asarray(st.error).max()) == 0
-    assert get_values(st, 0, enc.payloads) == expect
-    assert get_values(st, 1, enc.payloads) == expect
-    assert stats.scan_tier_wide > 0, stats
-    assert stats.scan_trips_two_tier < stats.scan_trips_serial, stats
-
-
-@needs_native
-def test_demotion_ladder_carries_deep_conflicts_to_host_oracle():
-    """PR-6 ladder under the reworked scan: an injected packed-XLA
-    dispatch failure on the deep-conflict stream demotes past the
-    driver's rungs to the serial host oracle, which completes the storm
-    at byte parity (the ladder is scan-implementation-agnostic)."""
-    from ytpu.models.replay import FusedReplay, plan_replay
-
-    payloads, expect, _, _ = _deep()
-    faults.arm("dispatch.fail", lane="xla")
-    r = FusedReplay(
-        n_docs=N_DOCS,
-        plan=plan_replay(payloads),
-        capacity=CAPACITY,
-        max_capacity=4 * CAPACITY,
-        d_block=D_BLOCK,
-        chunk=CHUNK,
-        lane="xla",
-    )
-    r.run(payloads)
-    assert r.stats.final_lane == "host"
-    assert r.get_string(0) == expect
-    assert r.get_string(1) == expect
-
-
-def test_fused_interpret_matches_xla_on_deep_conflicts():
-    """Both lanes share the tier-plan statics and the meta record: where
-    this jax build can interpret the Pallas kernel, the fused lane must
-    byte-match the packed-XLA lane on the storm AND produce the same
-    tier/trip words (the record is lane-agnostic by construction)."""
-    _, expect, stream, enc = _deep()
-    rank = enc.interner.rank_table()
-    _, a = _replay(stream, rank)
-
-    def go():
-        return _replay(stream, rank, lane="fused", interpret=True)
-
-    st_f, b = run_or_skip(go)
-    assert get_string(st_f, 0, enc.payloads) == expect
-    assert b.scan_hist == a.scan_hist
-    assert b.scan_max == a.scan_max
-    assert (b.scan_tier_cheap, b.scan_tier_wide) == (
-        a.scan_tier_cheap, a.scan_tier_wide
-    )
-    assert (b.scan_trips_two_tier, b.scan_trips_serial) == (
-        a.scan_trips_two_tier, a.scan_trips_serial
-    )
+    state, enc = _served_steps(payloads, root_name="a")
+    for d in range(N_DOCS):
+        assert get_values(state, d, enc.payloads) == expect
+    by_stream, enc_s, rec = _scan_record(payloads, root_name="a")
+    assert get_values(by_stream, 0, enc_s.payloads) == expect
+    assert rec["wide"] > 0, rec
+    assert rec["trips_two_tier"] < rec["trips_serial"], rec

@@ -99,22 +99,16 @@ def test_scenario_preserves_per_session_order_and_mixes_kinds():
 # ------------------------------------------------------------ admission
 
 
-def test_token_bucket_and_throttle_are_deterministic():
+def test_token_bucket_is_deterministic():
     from ytpu.serving import AdmissionController, QueueFull, RateLimited
 
     now = [0.0]
-    slept = []
 
     def clock():
         return now[0]
 
-    def sleep(s):
-        slept.append(s)
-        now[0] += s
-
     adm = AdmissionController(
-        max_queue=2, rate=10.0, burst=2.0, policy="defer",
-        clock=clock, sleep=sleep,
+        max_queue=2, rate=10.0, burst=2.0, policy="defer", clock=clock,
     )
     adm.admit("t", queue_depth=0)
     adm.admit("t", queue_depth=1)
@@ -125,44 +119,8 @@ def test_token_bucket_and_throttle_are_deterministic():
     assert ri.value.retry_after_s == pytest.approx(0.1)
     now[0] += 0.1  # one token refills
     adm.admit("t", queue_depth=0)
-    # producer-side throttle blocks (via injected sleep) instead of raising
-    waited = adm.throttle(3)
-    assert waited == pytest.approx(sum(slept))
-    assert adm.throttle(0) == 0.0
-
-
-def test_update_pipeline_staging_throttles_through_admission():
-    """The backpressure hook (ISSUE-9): the staging producer consults the
-    controller per chunk — asserted on the generator alone, no device
-    dispatch."""
-    from ytpu.models.batch_doc import BatchEncoder
-    from ytpu.models.pipeline import UpdatePipeline
-
-    class Recorder:
-        def __init__(self):
-            self.calls = []
-
-        def throttle(self, n):
-            self.calls.append(n)
-            return 0.0
-
-    from ytpu.core import Doc
-
-    doc = Doc(client_id=3)
-    log = []
-    doc.observe_update_v1(lambda p, o, t: log.append(p))
-    txt = doc.get_text("text")
-    for i in range(5):
-        with doc.transact() as txn:
-            txt.insert(txn, 0, "ab")
-    rec = Recorder()
-    pipe = UpdatePipeline(
-        BatchEncoder(), n_rows=4, n_dels=4, chunk_steps=2, admission=rec
-    )
-    pipe._staged_bytes = 0
-    chunks = list(pipe._chunks(log))
-    assert len(chunks) == 3  # 2+2+1 (padded tail)
-    assert rec.calls == [2, 2, 1]
+    with pytest.raises(RateLimited):
+        adm.admit("t", queue_depth=0)  # the refilled token is spent again
 
 
 # ------------------------------------------------------------- the soak

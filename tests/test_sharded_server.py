@@ -17,7 +17,8 @@ family (24 x 512: three rooms a device) on one device and doc-sharded: maps,
 XML, several roots, GC carriers, the stash and a store of nested JSON records
 on a sharded state, which text rooms never reach.
 Then `ytpu/parallel/mesh.py` itself on the 8 devices, and what the server's
-import loads.
+import loads. `tests/test_served_wire_logs.py` serves more logs through the
+same harness (array moves, deep conflict scans, rooms that compact).
 """
 
 import functools
@@ -188,7 +189,13 @@ def _check_against_oracle(server, connect, stages, ticks):
 
 
 def _spans_every_device(server) -> None:
-    """What `chip_smoke.py --chips 4` checks after its last flush."""
+    """What `chip_smoke.py --chips 4` checks after its last flush; the
+    gauge is the newest ingestor's, so only where `server` is the newest."""
+    _assert_spans_every_device(server)
+    assert metrics.gauge("ingest.state_shards").value == len(jax.devices())
+
+
+def _assert_spans_every_device(server) -> None:
     n_dev = len(jax.devices())
     assert n_dev == 8  # tests/conftest.py
     local = [
@@ -196,7 +203,6 @@ def _spans_every_device(server) -> None:
         if len(a.sharding.device_set) != n_dev
     ]
     assert not local, f"state planes {local} no longer span {n_dev} devices"
-    assert metrics.gauge("ingest.state_shards").value == n_dev
     assert server._telemetry_provider()["state_shards"] == n_dev
 
 
@@ -793,18 +799,17 @@ def _log(scenario):
     return scenario()
 
 
-@functools.lru_cache(maxsize=None)
-def _scenario_server(shard_docs: bool) -> DeviceSyncServer:
-    """The layout's one server. Clients, map keys and root names of every
-    log are registered before the first frame, as the benchmark registers
-    its clients: a first-seen one grows a lookup table, and every table
-    size is a program family of its own."""
+def _log_server(logs, shard_docs: bool, n_rooms: int, capacity: int) -> DeviceSyncServer:
+    """A server for `logs`, a room each. Clients, map keys and root names
+    of every log are registered before the first frame, as the benchmark
+    registers its clients: a first-seen one grows a lookup table, and every
+    table size is a program family of its own."""
     server = DeviceSyncServer(
-        n_docs=SCENARIO_ROOMS, capacity=CAPACITY, device_authoritative=True, shard_docs=shard_docs
+        n_docs=n_rooms, capacity=capacity, device_authoritative=True, shard_docs=shard_docs
     )
     clients, names = set(), set()
-    for scenario in SCENARIOS:
-        for payload in _log(scenario):
+    for log in logs:
+        for payload in log:
             for client, carriers in Update.decode_v1(payload).blocks.items():
                 clients.add(client)
                 for c in carriers:
@@ -815,6 +820,12 @@ def _scenario_server(shard_docs: bool) -> DeviceSyncServer:
     for name in sorted(names):
         assert server.ingestor._register_key(name)
     return server
+
+
+@functools.lru_cache(maxsize=None)
+def _scenario_server(shard_docs: bool) -> DeviceSyncServer:
+    """The layout's one server."""
+    return _log_server([_log(s) for s in SCENARIOS], shard_docs, SCENARIO_ROOMS, CAPACITY)
 
 
 def _device_reads(server, room, reads, primary) -> dict:
@@ -841,43 +852,62 @@ def _oracle_reads(oracle: Doc, reads) -> dict:
     return {(root, kind): get[kind](root) for root, kinds in reads.items() for kind in kinds}
 
 
-@functools.lru_cache(maxsize=None)
-def _serve_log(shard_docs: bool, scenario) -> dict:
-    """One log to one room, an update a step, and what the room then reads.
-    Kept, so the doc-sharded case compares with the one-device case's bytes
-    without serving the log again."""
-    server = _scenario_server(shard_docs)
-    ing, room, log = server.ingestor, scenario.__name__, _log(scenario)
+def _serve_one(server, room, log, reads, guarded=True, settles=True, diff=True) -> dict:
+    """One log to one room of `server`, an update a step, and what the room
+    then reads. `settles`: nothing of the log is left waiting in the room's
+    stash; `diff`: the room's full state is encoded too."""
+    ing = server.ingestor
     session, _ = server.connect_frames(room)
     slot = server.slot_of(room)
     fast, slow, recovered = ing.fast_docs, ing.slow_docs, ing.fast_recoveries
     stashed = 0
     for update in log:
         assert server.receive_frames(session, _frame(update)) == []
-        if scenario in UNGUARDED:
-            assert server.flush_device(max_steps=1) == 1
-        else:
+        if guarded:
             with jax.transfer_guard_device_to_device("disallow"):
                 assert server.flush_device(max_steps=1) == 1
+        else:
+            assert server.flush_device(max_steps=1) == 1
         stashed += ing.pending_update(slot) is not None
     jax.block_until_ready(ing.state)
     server.disconnect(session)
     assert not server.pending_device_updates()
     assert int(ing.state.error[slot]) == 0
-    assert ing.pending_update(slot) is None and ing.pending_ds(slot) is None
+    waiting = ing.pending_update(slot) is not None or ing.pending_ds(slot) is not None
+    assert waiting != settles
     assert room not in server._host_tenants and ing.fast_recoveries == recovered
-    primary = ing.primary_roots[slot]
-    reads = READS.get(scenario, {ROOT: ("text",)})
-    got = {
+    return {
         "lanes": (ing.fast_docs - fast, ing.slow_docs - slow),
         "stashed": stashed,
+        **_read_room(server, room, reads, diff),
+    }
+
+
+def _read_room(server, room, reads, diff=True) -> dict:
+    """What a served room reads: state vector, its roots, and (`diff`) its
+    full state encoded, with the rooms the native finisher handed back to
+    the Python one."""
+    primary = server.ingestor.primary_roots[server.slot_of(room)]
+    return {
         "sv": dict(server.device_state_vector(room).clocks),
         "reads": _device_reads(server, room, reads, primary),
         # `device_text` reads the primary root as a text, whatever it is
         "text": (primary, server.device_text(room)) if "text" in reads.get(primary, ()) else None,
-        "diff": server.device_encode_diff(room, StateVector()),
+        "diff": server.device_encode_diff(room, StateVector()) if diff else None,
+        "finisher_fallbacks": server._diff_pipeline.stats.fallback_docs if diff else 0,
     }
-    assert server._diff_pipeline.stats.fallback_docs == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _serve_log(shard_docs: bool, scenario) -> dict:
+    """Kept, so the doc-sharded case compares with the one-device case's
+    bytes without serving the log again."""
+    reads = READS.get(scenario, {ROOT: ("text",)})
+    got = _serve_one(
+        _scenario_server(shard_docs), scenario.__name__, _log(scenario), reads,
+        guarded=scenario not in UNGUARDED,
+    )
+    assert got["finisher_fallbacks"] == 0
     return got
 
 
@@ -907,6 +937,21 @@ def _canonical_bytes(update: bytes) -> bytes:
 SERVED_CASES = [(s, sharded) for sharded in (False, True) for s in SCENARIOS]
 
 
+def _assert_equals_the_oracle(got, log, reads) -> None:
+    """What `_read_room` read of a room against the host CRDT fed the same
+    log: state vector, reads, canonical full-state bytes."""
+    oracle = Doc(client_id=99)
+    for update in log:
+        oracle.apply_update_v1(update)
+    assert got["sv"] == dict(oracle.state_vector().clocks)
+    assert got["reads"] == _oracle_reads(oracle, reads)
+    if got["text"] is not None:
+        primary, text = got["text"]
+        assert text == oracle.get_text(primary).get_string()
+    if got["diff"] is not None:
+        assert _canonical_bytes(got["diff"]) == _canonical_bytes(oracle.encode_state_as_update_v1())
+
+
 @pytest.mark.parametrize(
     "scenario,shard_docs",
     SERVED_CASES,
@@ -915,33 +960,17 @@ SERVED_CASES = [(s, sharded) for sharded in (False, True) for s in SCENARIOS]
 def test_a_served_wire_log_equals_the_oracle(scenario, shard_docs):
     got = _serve_log(shard_docs, scenario)
     log = _log(scenario)
-    oracle = Doc(client_id=99)
-    for update in log:
-        oracle.apply_update_v1(update)
     host_lane = HOST_LANE.get(scenario, 0)
     assert got["lanes"] == (len(log) - host_lane, host_lane)
     assert (got["stashed"] > 0) == (scenario is stash_of_text_and_map)
-    assert got["sv"] == dict(oracle.state_vector().clocks)
-    reads = READS.get(scenario, {ROOT: ("text",)})
-    assert got["reads"] == _oracle_reads(oracle, reads)
-    if got["text"] is not None:
-        primary, text = got["text"]
-        assert text == oracle.get_text(primary).get_string()
-    assert _canonical_bytes(got["diff"]) == _canonical_bytes(oracle.encode_state_as_update_v1())
+    _assert_equals_the_oracle(got, log, READS.get(scenario, {ROOT: ("text",)}))
     if shard_docs:
         assert got["diff"] == _serve_log(False, scenario)["diff"]  # the same bytes
-        server = _scenario_server(True)
-        n_dev = len(jax.devices())
-        assert n_dev == 8  # tests/conftest.py
-        assert all(
-            len(a.sharding.device_set) == n_dev for a in jax.tree.leaves(server.ingestor.state)
-        )
-        assert server._telemetry_provider()["state_shards"] == n_dev
+        _assert_spans_every_device(_scenario_server(True))
 
 
 # --------------------------------------------------------------------------
-# `ytpu/parallel/mesh.py` on the suite's 8 devices (`test_subbatch.py` holds
-# the one-device no-ops).
+# `ytpu/parallel/mesh.py` on the suite's 8 devices.
 
 
 def test_shard_docs_put_splits_an_even_doc_axis_in_contiguous_blocks():
@@ -987,14 +1016,6 @@ def test_batch_sharding_spec(doc_axis, ndim, spec):
 def test_batch_mesh_takes_the_first_n_devices_and_none_for_one():
     assert list(doc_mesh.batch_mesh(4).devices.flat) == jax.devices()[:4]
     assert doc_mesh.batch_mesh(1) is None
-
-
-def test_subbatch_devices_go_round_the_mesh():
-    devices = jax.devices()
-    assert doc_mesh.subbatch_devices(11) == [devices[i % 8] for i in range(11)]
-    assert doc_mesh.subbatch_devices(3, doc_mesh.batch_mesh(2)) == [
-        devices[0], devices[1], devices[0]
-    ]
 
 
 def test_state_shards_reads_the_fewest_over_the_planes():

@@ -47,7 +47,7 @@ def test_endpoints_serve_metrics_snapshot_healthz():
         status, body = _get(t.port, "/healthz")
         h = json.loads(body)
         assert h["status"] == "ok" and h["uptime_s"] >= 0
-        assert "lane_ladder" in h
+        assert "lane_ladder" not in h  # the replay lanes' gauge left with them (PR 48)
         with pytest.raises(urllib.error.HTTPError) as err:
             _get(t.port, "/nope")
         assert err.value.code == 404
@@ -369,79 +369,24 @@ def test_trace_context_nesting_and_disabled_cost():
     assert new_trace_id() != new_trace_id()
 
 
-def test_overlap_slots_carry_staged_update_ranges():
-    """The async replay's staging slots carry the staged update id range
-    (and the ambient trace id) into the dispatch spans — the thread
-    hand-off leg of the request-tracing tentpole."""
-    pytest.importorskip("jax")
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
-    import bench as _bench
-    from ytpu.models.replay import FusedReplay, plan_replay
-    from ytpu.utils import trace_context
-
-    ops = []
-    length = 0
-    for _ in range(4):
-        for i in range(20):
-            ops.append(("i", length, "abcdef"[i % 6]))
-            length += 1
-        ops.append(("d", length - 18, 18))
-        length -= 18
-    log, _ = _bench.build_updates(ops)
-    plan = plan_replay(log)
-    tracer.clear()
-    tracer.enable()
-    try:
-        with trace_context(trace="treplay", tenant="bulk"):
-            r = FusedReplay(
-                n_docs=2,
-                plan=plan,
-                capacity=256,
-                max_capacity=256,
-                d_block=2,
-                chunk=16,
-                lane="xla",
-                overlap=True,
-            )
-            r.run(log)
-        events = json.loads(tracer.export_chrome_trace())["traceEvents"]
-    finally:
-        tracer.disable()
-        tracer.clear()
-    stages = [e for e in events if e["name"] == "replay.stage_slot"]
-    dispatches = [e for e in events if e["name"] == "replay.dispatch_slot"]
-    assert stages and dispatches
-    # every span names its update range; the ambient trace id crossed
-    # both thread hand-offs (staging worker AND consumer)
-    for e in stages + dispatches:
-        assert e["args"]["trace"] == "treplay"
-        assert 0 <= e["args"]["first"] <= e["args"]["last"] < len(log)
-    covered = {(e["args"]["first"], e["args"]["last"]) for e in dispatches}
-    assert covered == {(e["args"]["first"], e["args"]["last"]) for e in stages}
-
-
 def test_healthz_reports_never_before_first_dispatch():
-    """ISSUE-15 satellite regression: with BOTH last-dispatch gauges at
-    their 0.0 default (no dispatch ever happened), `/healthz` must say
+    """ISSUE-15 satellite regression: with the last-dispatch gauge at
+    its 0.0 default (no dispatch ever happened), `/healthz` must say
     ``last_dispatch: "never"`` and OMIT ``last_dispatch_age_s`` — an age
-    computed from epoch 0 reads ~56 years of false alarm.  The gauges
-    are saved/zeroed/restored in place (`metrics.reset()` would orphan
+    computed from epoch 0 reads ~56 years of false alarm.  The gauge
+    is saved/zeroed/restored in place (`metrics.reset()` would orphan
     every cached metric object in the process)."""
     sync_g = metrics.gauge("sync.last_dispatch_unix")
-    integ_g = metrics.gauge("integrate.last_dispatch_unix")
-    saved = (sync_g.value, integ_g.value)
+    saved = sync_g.value
     try:
         sync_g.set(0.0)
-        integ_g.set(0.0)
         with TelemetryServer(port=0) as t:
             status, body = _get(t.port, "/healthz")
         assert status == 200
         hz = json.loads(body)
         assert hz["last_dispatch"] == "never", hz
         assert "last_dispatch_age_s" not in hz, hz
-        # and once either gauge moves, the age replaces the marker
+        # and once the gauge moves, the age replaces the marker
         sync_g.set(time.time())
         with TelemetryServer(port=0) as t:
             _, body = _get(t.port, "/healthz")
@@ -449,5 +394,4 @@ def test_healthz_reports_never_before_first_dispatch():
         assert "last_dispatch" not in hz, hz
         assert 0.0 <= hz["last_dispatch_age_s"] < 60.0, hz
     finally:
-        sync_g.set(saved[0])
-        integ_g.set(saved[1])
+        sync_g.set(saved)

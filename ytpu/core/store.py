@@ -36,7 +36,7 @@ from .update import PendingUpdate, Update
 
 __all__ = ["DocStore"]
 
-# Optional perf probe (benches/device.py config #3 diagnostic): when set
+# Optional perf probe (a diagnostic for conflict-heavy traffic): when set
 # to a list, every YATA conflict scan appends its candidate-walk length.
 # The device engine runs the SAME scan as a while_loop whose iteration
 # count this distribution bounds — the p99 here explains conflict-heavy

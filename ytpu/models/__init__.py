@@ -59,6 +59,5 @@ __all__ = [
 ]
 
 from .ingest import BatchIngestor  # noqa: E402
-from .pipeline import UpdatePipeline  # noqa: E402
 
-__all__ += ["BatchIngestor", "UpdatePipeline"]
+__all__ += ["BatchIngestor"]

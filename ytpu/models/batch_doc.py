@@ -301,7 +301,7 @@ class CompactionPolicy(NamedTuple):
     - ``high_watermark``: occupancy fraction above which a between-chunk
       compaction fires even when the next chunk would still fit.
     - ``chunk_budget``: fraction of capacity a single chunk's WORST-CASE
-      adds may consume — the chunk planner (`replay.plan_chunks`) sizes
+      adds may consume — a chunk planner sizes
       chunks so one compaction's headroom (1 - high_watermark is the
       floor it restores when content is mostly tombstones) always admits
       the next chunk.
@@ -334,9 +334,9 @@ def stream_worst_case_adds(stream: UpdateBatch) -> np.ndarray:
     """[S] worst-case block-slot growth per step of a stacked stream.
 
     Each valid row can cost 3 slots (itself + two anchor splits), each
-    valid delete range 2 (edge splits) — the same accounting as
-    `replay.ReplayPlan.adds` (the one other place it is written). Drives
-    the chunk planner's occupancy projection; host-side (numpy) so the
+    valid delete range 2 (edge splits): the one accounting, which the
+    served ingestor's bound on a room's rows shares (`models/ingest.py`).
+    For an occupancy projection; host-side (numpy) so the
     projection never touches the device."""
     rows = np.asarray(stream.valid).sum(axis=-1).astype(np.int64)
     dels = np.asarray(stream.del_valid).sum(axis=-1).astype(np.int64)
@@ -473,9 +473,9 @@ def recompute_origin_slot(state: DocStateBatch) -> DocStateBatch:
     containment search per row; the incremental maintenance lives in
     `_split` / `_integrate_row` / compaction's remap).
 
-    Used at boundaries where the cache cannot ride along: fused-kernel
-    unpack (the packed domain CARRIES an OS plane, but the kernel itself
-    never maintains it — see integrate_kernel.OS), pre-origin_slot
+    Used at boundaries where the cache cannot ride along: a state some
+    producer marked stale (`mark_origin_slot_stale`: the packed replay
+    lane did until it left in PR 48; nothing does today), pre-origin_slot
     checkpoint restore: those two and no other. Docs are processed
     sequentially (`lax.map`) so the [B, B] containment compare never
     materializes across the whole batch."""
@@ -624,13 +624,13 @@ def _origins_equal(ha, ca, ka, hb, cb, kb):
 
 
 # --- conflict-scan-width attribution (ISSUE-11) ------------------------------
-# Fixed pow2 histogram shared by BOTH integrate lanes (the fused Pallas
-# kernel accumulates the same buckets into its meta tile): bucket 0 holds
+# Fixed pow2 histogram the integrate row body folds as it runs (the
+# served step drops it in-jit; the stream body returns it): bucket 0 holds
 # widths 0-1, bucket k holds [2^k, 2^{k+1}) for k < SCAN_WIDTH_BUCKETS-1,
 # the last bucket is unbounded above (the p99=337 tail lands there; the
 # separate max word records the true extreme). Counting is pure vector
-# arithmetic folded into the integrate program — never a device sync; the
-# totals ride the replay driver's existing lazy readout.
+# arithmetic folded into the integrate program — never a device sync
+# (docs/observability.md, "Conflict-tail attribution").
 
 SCAN_WIDTH_BUCKETS = 8
 SCAN_WIDTH_THRESHOLDS = (2, 4, 8, 16, 32, 64, 128)
@@ -649,14 +649,14 @@ SCAN_WIDTH_UPPER = (1, 3, 7, 15, 31, 63, 127)
 # a width-337 scan costs 32 + ceil(305/8) = 71 trips instead of 337.
 #
 # Knob + retrace implications: the (cheap, unroll) pair is a TRACE-TIME
-# static — the chunk programs and the fused kernel thread it as a static
-# argument (like YTPU_FUSED_VMEM_MB), so the driver re-reads the env
-# per chunk and a changed value forces a retrace of the dispatch
-# programs; the bare `apply_update_batch`/`apply_update_stream` wrappers
+# static — `apply_update_stream` takes it as a static argument, so a
+# caller that re-reads the env and passes the pair on gets a retrace of
+# its dispatch programs when the value changes (the replay drivers that
+# did so left in PR 48); the bare `apply_update_batch`/`apply_update_stream` wrappers
 # (a direct caller of `apply_update_batch`, which the served path is)
 # read it once at first trace and keep the baked value for
-# already-compiled shapes (set the env before first dispatch, or go
-# through the replay drivers). Width SEMANTICS are tier-independent:
+# already-compiled shapes (set the env before first dispatch, or pass
+# `scan_plan` yourself). Width SEMANTICS are tier-independent:
 # `width` counts visited candidates exactly as the single-tier loop did,
 # so the scan-width histogram and `scan_width_p50/p99/max` keep their
 # meaning.
@@ -680,8 +680,8 @@ def scan_tier_plan() -> tuple:
     return (max(0, cheap), max(1, unroll))
 
 
-# per-doc scan-record word layout (rides the chunk programs' meta tile
-# at integrate_kernel.M_HIST0.. and the lazy readout): pow2 bucket
+# per-doc scan-record word layout (`_apply_update_stream_hist_body`
+# returns it beside the state, `[D, SCAN_REC_WORDS]`): pow2 bucket
 # counts, the observed max width, then the ISSUE-12 tier-occupancy and
 # trip-accounting words. All words ADD under merge except the max.
 SCAN_REC_MAX = SCAN_WIDTH_BUCKETS  # observed max width
@@ -740,9 +740,9 @@ def merge_scan_records(a, b):
 # A homomorphic per-doc digest of the op lattice the federation layer's
 # anti-entropy compares in O(1) per tenant per round (ytpu/sync/
 # commitment.py holds the 64-bit host mirror and the full rationale).
-# The device word is a vectorized reduction over the packed block
-# columns, materialized ONLY as one extra word on the existing lazy
-# readout (integrate_kernel._readout_words) — zero new device syncs.
+# The device word is a vectorized reduction over the block columns
+# (`commit_fold_blocks`). No served path reads it yet: the replay lane
+# whose lazy readout carried it left in PR 48 (tests fold it directly).
 
 
 def _commit_mix_u32(x):
@@ -1624,10 +1624,10 @@ def _apply_update_stream_hist_body(
     Returns ``(state, scan_hist)``: scan_hist is the per-doc
     ``[D, SCAN_REC_WORDS]`` conflict-scan record (bucket counts, tier
     occupancy and trip words summed over the stream; per-doc max width —
-    ISSUE-11/12). The public wrapper discards it; the replay chunk
-    programs fold it into the meta tile so it rides the lazy readout.
+    ISSUE-11/12). The public wrapper discards it; `apply_update_stream_raw`
+    hands it back (tests/test_scan_tiers.py reads the tier words there).
     `scan_plan` is the two-tier static (None = `scan_tier_plan()` at
-    trace time; the chunk programs thread their own static through).
+    trace time; a caller may thread its own static through).
     """
     D = state.start.shape[0]
     if scan_plan is None:
@@ -1645,11 +1645,11 @@ def _apply_update_stream_hist_body(
     return state, scan_hist
 
 
-# the tuple-returning jit: its ONLY callers trace through it inside the
-# chunk programs (`xla_chunk_step`, `replay_chunk_program*`), so no
-# standalone executable compiles for it in practice. `scan_plan` is a
-# STATIC argument (a changed tier plan must recompile, same discipline
-# as YTPU_FUSED_VMEM_MB).
+# the tuple-returning jit: tests call it for the scan record; the chunk
+# programs that traced through it left with the replay drivers (PR 48),
+# and the served path runs `apply_update_batch`. `scan_plan` is a
+# STATIC argument (a changed tier plan must recompile: it shapes the
+# traced loops).
 apply_update_stream = partial(jax.jit, donate_argnums=0, static_argnums=3)(
     _apply_update_stream_hist_body
 )
@@ -1722,8 +1722,8 @@ def state_capacity_ledger(state: DocStateBatch):
     rows per doc are ``capacity - live - dead``, so the per-tenant
     occupancy gauges always sum to the slot capacity. NOT a hot-path
     call: scrape-time `/snapshot` sections and tests materialize it on
-    demand (the batch replay lane gets the same words for free on the
-    lazy readout — `integrate_kernel._readout_words`)."""
+    demand (one reduction over the block columns, one pull of
+    two `[D]` vectors)."""
     bl = state.blocks
     B = bl.client.shape[-1]
     slots = jnp.arange(B, dtype=jnp.int32)
@@ -2426,8 +2426,8 @@ def finish_encode_diff_batch(
 @dataclass(frozen=True)
 class DiffPlan:
     """Host-checkable sub-batch plan of a pipelined encode/diff run —
-    the dry-run assertion surface (`bench.py --dry-run`'s `diff_overlap`
-    rehearsal), mirroring `replay.OverlapPlan` for the apply side."""
+    what `plan_diff_pipeline` returns and tests assert on before a
+    device run (sub-batch bounds, depth, buffer reuse)."""
 
     n_docs: int
     sub: int  # docs per sub-batch = the compiled doc width (pow2)
@@ -2537,7 +2537,7 @@ class DiffPipeline:
     ) -> List[bytes]:
         """Drop-in replacement for `finish_encode_diff_batch` over the
         same selection outputs; byte-identical payloads, pipelined."""
-        from ytpu.models.replay import OverlapPipeline
+        from ytpu.models.overlap import OverlapPipeline
         from ytpu.utils import metrics
         from ytpu.utils.faults import faults
         from ytpu.utils.phases import phases
@@ -3605,8 +3605,8 @@ def apply_update_stream(
 apply_update_batch.__doc__ = _apply_update_batch_jit.__doc__
 apply_update_stream.__doc__ = _apply_update_stream_jit.__doc__
 
-# Raw, uninstrumented body for IN-JIT composition (integrate_kernel's
-# xla_chunk_step and the async replay chunk program trace through it).
+# Raw, uninstrumented body for IN-JIT composition (a program that
+# traces the stream step inside its own jit; none is left since PR 48).
 # Tracing through the instrumented wrapper above records a phantom
 # `integrate.xla_stream` compile_s entry keyed on tracer shapes — the
 # bench-JSON double-count flagged by the PR-4 review — and its

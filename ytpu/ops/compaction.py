@@ -26,7 +26,7 @@ blocks; this pass is the batched equivalent, run as one jitted program:
 Semantics parity is testable: replay -> compact -> keep replaying must
 match the host oracle exactly (tests/test_compaction.py).
 
-Three forms, and who calls each:
+Two forms, and who calls each:
 
 - `compact_rooms` — **the served form** (PR 43). `BatchIngestor` calls it
   from inside `apply_bytes` (so from `DeviceSyncServer.flush_device`) for
@@ -46,9 +46,11 @@ Three forms, and who calls each:
   `tests/test_origin_slot.py`, and `tests/test_chip_compile.py` compiles
   it). It has never run on the chip, and its scatters are of the kind
   `_set`'s docstring warns of.
-- `compact_packed` — the fused kernel's packed `[NC, D, C]` layout, for
-  the chunked replay drivers (`ops/integrate_kernel.py`). A second copy
-  of the same rule over another layout (ROADMAP Design 2).
+
+`grow_state` widens every slot's capacity by a host-side repad; like
+`compact_state` it has tests for callers and no server path: growing a
+served slot starts from it (ROADMAP Reach A1). The packed `[NC, D, C]`
+form of the rule left with the replay drivers it served (PR 48).
 """
 
 from __future__ import annotations
@@ -74,8 +76,6 @@ __all__ = [
     "compact_state",
     "compact_rooms",
     "grow_state",
-    "compact_packed",
-    "grow_packed",
     "REHOME_FIELDS",
 ]
 
@@ -448,282 +448,6 @@ def compact_state(state: DocStateBatch) -> DocStateBatch:
     return jax.vmap(_compact_one)(state)
 
 
-def _compact_packed_one(cols, meta, unit_refs: bool, gc_ranges: bool):
-    """Squash + GC one doc in the fused kernel's packed domain.
-
-    `cols` is the kernel's [NC, C] column stack, `meta` its [M_PAD] row.
-    The full fused-lane schema is honored — map keys, nested parents,
-    move ownership/range planes and the origin_slot cache plane all
-    survive (slot-valued planes remap through the defrag permutation) —
-    so this pass is safe to run at a CHUNK BOUNDARY of the chunked
-    replay driver (`integrate_kernel.PackedReplayDriver`): rows the NEXT
-    chunk will split (an origin landing mid-block of a squashed run) or
-    claim (a live move whose range spans the boundary) keep every
-    invariant the kernel's find_slot/claim walks rely on, because merges
-    preserve clock-range containment and never cross a difference in
-    deleted/moved/key/parent state.
-
-    Two rules beyond `_compact_one`:
-    - `gc_ranges`: tombstones become origin-free BLOCK_GC ranges and merge
-      under clock contiguity + sequence adjacency alone — the reference's
-      default-GC behavior (gc.rs:11-65 drops the item wholesale;
-      squash_left_range_compaction block_store.rs:155-235 collapses runs),
-      vs the softer skip_gc-style CONTENT_DELETED conversion. A
-      tombstoned MOVE row converts like any other: its range planes clear
-      with it (the reference drops the move item wholesale), so it can
-      merge into adjacent GC runs instead of lingering as an unmergeable
-      pseudo-move — safe because the end-of-chunk `recompute_moves` never
-      leaves a live claim pointing at a tombstoned owner.
-    - `unit_refs`: string content refs are absolute UTF-16-unit offsets
-      into a content arena, so runs from *different* updates merge when
-      `b.ref + b.off == a.ref + a.off + a.len` — the device equivalent of
-      the reference's string concat in try_squash (block.rs:775-799).
-    """
-    from ytpu.ops.integrate_kernel import (
-        CK,
-        CL,
-        CN,
-        DL,
-        HD,
-        KD,
-        KEY,
-        LN,
-        LT,
-        M_NBLOCKS,
-        M_START,
-        MEA,
-        MEC,
-        MEK,
-        MPR,
-        MSA,
-        MSC,
-        MSK,
-        MV,
-        OC,
-        OF,
-        OK,
-        OS,
-        PA,
-        RC,
-        RF,
-        RK,
-        RT,
-    )
-
-    C = cols.shape[1]
-    slots = jnp.arange(C, dtype=I32)
-    n = meta[M_NBLOCKS]
-    active = slots < n
-
-    deleted = cols[DL] == 1
-    if gc_ranges:
-        convert = active & deleted & (cols[KD] != BLOCK_GC)
-    else:
-        gcable = jnp.zeros((C,), bool)
-        for k in _GCABLE:
-            gcable = gcable | (cols[KD] == k)
-        convert = active & deleted & gcable
-    new_kind = I32(BLOCK_GC) if gc_ranges else I32(CONTENT_DELETED)
-    kind = jnp.where(convert, new_kind, cols[KD])
-    rf = jnp.where(convert, -1, cols[RF])
-    of = jnp.where(convert, 0, cols[OF])
-    oc = jnp.where(convert & gc_ranges, -1, cols[OC])
-    ok = jnp.where(convert & gc_ranges, 0, cols[OK])
-    rc = jnp.where(convert & gc_ranges, -1, cols[RC])
-    rk = jnp.where(convert & gc_ranges, 0, cols[RK])
-    # origin cleared -> cached origin slot cleared with it (cache contract)
-    os_c = jnp.where(convert & gc_ranges, -1, cols[OS])
-    # converted dead moves drop their range planes (see docstring): the
-    # MPR >= 0 squash veto below then no longer pins them apart from the
-    # surrounding GC run
-    msc = jnp.where(convert & gc_ranges, -1, cols[MSC])
-    msk = jnp.where(convert & gc_ranges, 0, cols[MSK])
-    msa = jnp.where(convert & gc_ranges, 0, cols[MSA])
-    mec = jnp.where(convert & gc_ranges, -1, cols[MEC])
-    mek = jnp.where(convert & gc_ranges, 0, cols[MEK])
-    mea = jnp.where(convert & gc_ranges, 0, cols[MEA])
-    mpr = jnp.where(convert & gc_ranges, -1, cols[MPR])
-
-    cl, ck, ln, lt, rt = cols[CL], cols[CK], cols[LN], cols[LT], cols[RT]
-
-    # --- squash eligibility a -> b = right[a] ------------------------------
-    b = rt
-    sb = jnp.maximum(b, 0)
-
-    def g(col):
-        return col[sb]
-
-    key_c, pa_c = cols[KEY], cols[PA]
-    base = (
-        active
-        & (b >= 0)
-        & (b < n)
-        & (cl == g(cl))
-        & (g(ck) == ck + ln)
-        & (g(lt) == slots)
-        & (deleted == g(deleted))
-        & (key_c == g(key_c))
-        & (pa_c == g(pa_c))
-        # try_squash parity (block.rs:775-799): `self.moved == other.moved`
-        # — rows owned by different moves (or one owned, one not) never
-        # merge, and move rows themselves (length-1 ranges) don't either
-        & (cols[MV] == g(cols[MV]))
-        & (mpr < 0)
-        & (mpr[sb] < 0)
-    )
-    gcish = kind == BLOCK_GC
-    # ContentType rows carry live child-sequence heads even when deleted;
-    # never merge them away
-    no_head = (cols[HD] < 0) & (g(cols[HD]) < 0)
-    gc_merge = base & gcish & g(gcish) & no_head
-
-    origin_chain = (g(oc) == cl) & (g(ok) == ck + ln - 1)
-    ror_eq = (rc == g(rc)) & ((rc < 0) | (rk == g(rk)))
-    if unit_refs:
-        content_contig = (g(rf) >= 0) & (rf >= 0) & (
-            g(rf) + g(of) == rf + of + ln
-        )
-    else:
-        content_contig = (rf == g(rf)) & (g(of) == of + ln)
-    spliceable = jnp.zeros((C,), bool)
-    for k in _SPLICEABLE:
-        spliceable = spliceable | (kind == k)
-    live_merge = (
-        base
-        & ~deleted
-        & spliceable
-        & (kind == g(kind))
-        & origin_chain
-        & ror_eq
-        & content_contig
-    )
-    dead_merge = (
-        base
-        & (kind == CONTENT_DELETED)
-        & (g(kind) == CONTENT_DELETED)
-        & origin_chain
-        & ror_eq
-    )
-    elig = gc_merge | live_merge | dead_merge
-
-    sl = jnp.maximum(lt, 0)
-    merged_away = active & (lt >= 0) & elig[sl]
-
-    rep = jnp.where(merged_away, lt, slots)
-    for _ in range(max(1, C.bit_length())):
-        rep = rep[jnp.maximum(rep, 0)]
-
-    seg_len = jax.ops.segment_sum(
-        jnp.where(active, ln, 0), jnp.maximum(rep, 0), num_segments=C
-    )
-    tail = active & ~elig
-    tail_w = jnp.where(tail, rep, C)
-    chain_right = jnp.full((C,), -1, I32).at[tail_w].set(rt, mode="drop")
-
-    keep = active & ~merged_away
-    length = jnp.where(keep, seg_len, ln)
-    right = jnp.where(keep, chain_right, rt)
-
-    # --- defragment --------------------------------------------------------
-    new_idx = jnp.cumsum(keep.astype(I32)) - 1
-    old2new = jnp.where(keep, new_idx, new_idx[jnp.maximum(rep, 0)])
-
-    def remap(col):
-        return jnp.where(col >= 0, old2new[jnp.maximum(col, 0)], -1)
-
-    n_new = jnp.sum(keep.astype(I32))
-    order = jnp.argsort(jnp.where(keep, slots, C + slots))
-    blank = slots >= n_new
-
-    def pack(col, fill):
-        return jnp.where(blank, fill, col[order])
-
-    out = jnp.stack(
-        [
-            pack(cl, -1),  # CL
-            pack(ck, 0),  # CK
-            pack(length, 0),  # LN
-            pack(oc, -1),  # OC
-            pack(ok, 0),  # OK
-            pack(rc, -1),  # RC
-            pack(rk, 0),  # RK
-            pack(remap(lt), -1),  # LT
-            pack(remap(right), -1),  # RT
-            pack(cols[DL], 0),  # DL
-            pack(jnp.where(convert, 0, cols[CN]), 0),  # CN
-            pack(kind, 0),  # KD
-            pack(rf, -1),  # RF
-            pack(of, 0),  # OF
-            pack(key_c, -1),  # KEY
-            pack(remap(pa_c), -1),  # PA
-            pack(remap(cols[HD]), -1),  # HD
-            pack(remap(cols[MV]), -1),  # MV (slot index: defrag remap)
-            pack(msc, -1),  # MSC
-            pack(msk, 0),  # MSK
-            pack(msa, 0),  # MSA
-            pack(mec, -1),  # MEC
-            pack(mek, 0),  # MEK
-            pack(mea, 0),  # MEA
-            pack(mpr, -1),  # MPR
-            pack(remap(os_c), -1),  # OS (slot index: defrag remap)
-        ]
-    )
-    start = meta[M_START]
-    start = jnp.where(start >= 0, old2new[jnp.maximum(start, 0)], -1)
-    meta = meta.at[M_START].set(start).at[M_NBLOCKS].set(n_new)
-    return out, meta
-
-
-@partial(jax.jit, static_argnums=(2, 3), donate_argnums=(0, 1))
-def compact_packed(cols, meta, unit_refs: bool = False, gc_ranges: bool = False):
-    """Squash + GC + defragment a packed [NC, D, C] state (fused-kernel
-    domain, NC=26 incl. the origin_slot plane) without materializing the
-    unpacked schema — the full-trace replay compacts at high-water marks
-    where holding both layouts would double HBM."""
-    f = partial(_compact_packed_one, unit_refs=unit_refs, gc_ranges=gc_ranges)
-    return jax.vmap(f, in_axes=(1, 0), out_axes=(1, 0))(cols, meta)
-
-
-def grow_packed(cols, meta, new_capacity: int):
-    """Widen a packed state's capacity (slot indices survive unchanged)."""
-    from ytpu.ops.integrate_kernel import (
-        CL,
-        HD,
-        KEY,
-        LT,
-        MEC,
-        MPR,
-        MSC,
-        MV,
-        OC,
-        OS,
-        PA,
-        RC,
-        RF,
-        RT,
-    )
-
-    NC_, D, C = cols.shape
-    if new_capacity < C:
-        raise ValueError(f"cannot shrink capacity {C} -> {new_capacity}")
-    if new_capacity == C:
-        return cols, meta
-    pad = jnp.zeros((NC_, D, new_capacity - C), I32)
-    # -1-filled columns: client/origin/ror clients, links, content ref,
-    # move ownership/bound clients/priority (COL_DEFAULTS parity)
-    neg = (
-        jnp.zeros((NC_,), I32)
-        .at[
-            jnp.array(
-                [CL, OC, RC, LT, RT, RF, KEY, PA, HD, MV, MSC, MEC, MPR, OS]
-            )
-        ]
-        .set(-1)
-    )
-    pad = pad + neg[:, None, None]
-    return jnp.concatenate([cols, pad], axis=2), meta
-
-
 def grow_state(state: DocStateBatch, new_capacity: int) -> DocStateBatch:
     """Widen every doc's block capacity (host-side repad; index columns are
     slot-based so they survive unchanged). A stale origin_slot flag
@@ -758,7 +482,6 @@ def grow_state(state: DocStateBatch, new_capacity: int) -> DocStateBatch:
 # attribute check, no allocation (SURVEY §5.5 hot-path rule).
 
 _compact_state_jit = compact_state
-_compact_packed_jit = compact_packed
 
 
 def compact_state(state: DocStateBatch) -> DocStateBatch:
@@ -787,34 +510,13 @@ def compact_state(state: DocStateBatch) -> DocStateBatch:
     return out
 
 
-def compact_packed(cols, meta, unit_refs: bool = False, gc_ranges: bool = False):
-    from ytpu.utils.phases import NULL_SPAN, phases, program_memory
-
-    span = (
-        phases.span(
-            "compact.packed",
-            (cols.shape, unit_refs, gc_ranges),
-            axes=("cols", "unit_refs", "gc_ranges"),
-            memory=program_memory(
-                _compact_packed_jit, cols, meta, unit_refs, gc_ranges
-            ),
-        )
-        if phases.enabled
-        else NULL_SPAN
-    )
-    with span:
-        return _compact_packed_jit(cols, meta, unit_refs, gc_ranges)
-
-
 compact_state.__doc__ = _compact_state_jit.__doc__
-compact_packed.__doc__ = _compact_packed_jit.__doc__
 
 
 def _register_programs():
     from ytpu.utils import progbudget
 
     progbudget.register("compact_state", _compact_state_jit)
-    progbudget.register("compact_packed", _compact_packed_jit)
     progbudget.register("compact_rooms", compact_rooms)
 
 

@@ -8,9 +8,6 @@ four-chip cell (`yws-rooms-4k-x4.edit-flood`) measures. A step's small
 inputs (wire bytes, lookup tables, the rank table) go whole onto every chip
 (`replicated`); XLA's partitioner places the collectives, and no
 hand-written one exists.
-
-`subbatch_devices` belongs to the packed replay stack
-(`ops/integrate_kernel.py`): round-robin placement of its sub-batches.
 """
 
 from __future__ import annotations
@@ -26,7 +23,6 @@ __all__ = [
     "batch_mesh",
     "batch_sharding",
     "replicated",
-    "subbatch_devices",
     "shard_docs_put",
     "require_doc_mesh",
     "state_shards",
@@ -65,17 +61,6 @@ def batch_sharding(mesh: Mesh, doc_axis: int = 0, ndim: int = 1) -> NamedShardin
     spec = [None] * max(int(ndim), doc_axis + 1)
     spec[doc_axis] = AXIS_BATCH
     return NamedSharding(mesh, P(*spec))
-
-
-def subbatch_devices(n_sub: int, mesh: Optional[Mesh] = None):
-    """Round-robin device placement for ``n_sub`` integrate sub-batches;
-    None on a single-device host so the dispatch loop skips device_put."""
-    if mesh is None:
-        mesh = batch_mesh()
-    if mesh is None:
-        return None
-    devs = list(mesh.devices.flat)
-    return [devs[i % len(devs)] for i in range(int(n_sub))]
 
 
 def shard_docs_put(arr, mesh: Optional[Mesh] = None, doc_axis: int = 0):

@@ -2,14 +2,13 @@
 
 The sync servers accept whatever arrives: a hot tenant can grow its
 device queue without bound and a reconnect storm can outrun the flush
-loop.  This module is the valve in front of `UpdatePipeline` /
-`flush_device`:
+loop.  This module is the valve in front of `flush_device`:
 
 - **bounded per-tenant queues** — an update whose tenant already has
   ``max_queue`` updates waiting for the device is not enqueued;
 - **token-bucket rate limiting** — a global updates/s budget with a
-  burst allowance (deterministic given an injected clock, so tests and
-  the bench rehearsal can assert exact decisions);
+  burst allowance (deterministic given an injected clock, so tests can
+  assert exact decisions);
 - **typed overload errors** — `QueueFull` / `RateLimited` (both
   `Overload`) carry the tenant, the reason, and a ``retry_after_s``
   hint, and surface to clients as protocol-level **Busy replies**
@@ -28,10 +27,7 @@ Three policies decide what an overloaded update costs:
 ============  ===============================================================
 
 The controller is transport-agnostic: `SyncServer.receive_frames`
-consults it per inbound update (queue depth comes from the server), and
-`UpdatePipeline` calls `throttle()` from its staging producer so a bulk
-replay's staging thread blocks instead of overrunning the device
-(producer-side backpressure).
+consults it per inbound update (queue depth comes from the server).
 
 Fault site (docs/robustness.md): ``admission.reject`` forces the next
 admit() to raise `QueueFull` — soak chaos runs use it to exercise the
@@ -39,7 +35,7 @@ Busy path without actually saturating a queue.
 
 Runtime retuning (ISSUE-16): `set_rate` / `set_queue_bound` (global) and
 `set_tenant_rate` / `set_tenant_queue_bound` (per-tenant overrides) are
-thread-safe and take effect on the NEXT admit/throttle call — the fleet
+thread-safe and take effect on the NEXT admit call — the fleet
 autopilot's adaptive-admission actuator, also usable by an operator
 against a live server.  Every change bumps ``admission.policy_changes``.
 """
@@ -63,8 +59,6 @@ __all__ = [
 
 _ADMITTED = metrics.counter("admission.admitted")
 _REJECTED = metrics.counter("admission.rejected", labelnames=("reason",))
-_THROTTLE_WAITS = metrics.counter("admission.throttle_waits")
-_THROTTLE_WAIT_HIST = metrics.histogram("admission.throttle_wait")
 _POLICY_CHANGES = metrics.counter("admission.policy_changes")
 
 
@@ -143,17 +137,6 @@ class TokenBucket:
             self.burst = float(burst if burst is not None else rate)
             self._tokens = min(self._tokens, self.burst)
 
-    def take_debt(self, n: float = 1.0) -> float:
-        """Consume ``n`` unconditionally (tokens may go NEGATIVE — debt)
-        and return the seconds the caller should sleep to amortize it.
-        This is the producer-throttle primitive: waiting for ``n`` whole
-        tokens can never finish when ``n > burst``, whereas debt keeps
-        long-run throughput converging to ``rate`` for any chunk size."""
-        with self._lock:
-            self._refill_locked()
-            self._tokens -= n
-            return max(0.0, -self._tokens) / self.rate
-
 
 class AdmissionController:
     """Per-tenant queue bounds + a global token bucket, one policy.
@@ -161,7 +144,7 @@ class AdmissionController:
     ``max_queue``: per-tenant device-queue depth bound (None = unbounded).
     ``rate``/``burst``: global token bucket (None = no rate limit).
     ``policy``: "defer" | "drop" | "shed" (see module docstring).
-    ``clock``/``sleep``: injectable for deterministic tests.
+    ``clock``: injectable for deterministic tests.
     """
 
     def __init__(
@@ -171,7 +154,6 @@ class AdmissionController:
         burst: Optional[float] = None,
         policy: str = "defer",
         clock: Callable[[], float] = time.monotonic,
-        sleep: Callable[[float], None] = time.sleep,
     ):
         if policy not in ("defer", "drop", "shed"):
             raise ValueError(f"policy must be defer/drop/shed, got {policy!r}")
@@ -181,7 +163,6 @@ class AdmissionController:
             TokenBucket(rate, burst, clock) if rate is not None else None
         )
         self._clock = clock
-        self._sleep = sleep
         # per-tenant overrides (ISSUE-16): tenant -> bucket / queue bound,
         # consulted INSTEAD of the globals for that tenant.  Guarded by a
         # lock so a controller retune from the autopilot (or an operator
@@ -299,23 +280,6 @@ class AdmissionController:
                         retry_after_s=wait,
                     )
             _ADMITTED.inc(n)
-
-    # --- producer-side backpressure (UpdatePipeline staging hook) -------------
-
-    def throttle(self, n: int = 1) -> float:
-        """Block the calling producer until ``n`` updates fit the rate
-        budget; returns the seconds waited.  Queue bounds don't apply —
-        a staging producer IS the queue; slowing it is the point.
-        Debt-based (`TokenBucket.take_debt`), so a chunk larger than the
-        burst sleeps proportionally instead of spinning forever."""
-        if self.bucket is None:
-            return 0.0
-        wait = self.bucket.take_debt(n)
-        if wait > 0.0:
-            _THROTTLE_WAITS.inc()
-            self._sleep(wait)
-            _THROTTLE_WAIT_HIST.observe(wait)
-        return wait
 
     # --- reply rendering ------------------------------------------------------
 

@@ -1,7 +1,7 @@
 """Seeded, replayable serving-traffic scenarios (ISSUE-9 tentpole).
 
-Everything benched before this module is replay-shaped — one big trace
-pushed through `FusedReplay`.  A serving system is driven by *sessions*:
+A replay pushes one big trace through an engine.  A serving system is
+driven by *sessions*:
 many concurrent clients fanning mixed apply / diff / awareness traffic at
 a multi-tenant server, with hot documents, a long tail, churn and
 reconnects.  `Scenario` generates that traffic as a deterministic event

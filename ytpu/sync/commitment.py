@@ -17,10 +17,9 @@ delta ``[old, new)`` folds in as ``A·(T(new)−T(old)) + B·(new−old)``
 without revisiting history, and the same value is reached regardless of
 how the ops were chunked, split, or merged on the way in.
 
-The device twin (``batch_doc.commit_fold_blocks`` → the
-``integrate_kernel`` readout word) computes the identical fold, 32-bit
-over the packed block columns, as a vectorized reduction inside the
-already-dispatched lazy readout — per-block ``A(c)·(s·l + T(l)) + B(c)·l``
+The device twin (``batch_doc.commit_fold_blocks``) computes the
+identical fold, 32-bit over the block columns, as a vectorized
+reduction — per-block ``A(c)·(s·l + T(l)) + B(c)·l``
 sums to the per-client closed form exactly because block rows tile the
 lattice (splits/merges/GC conversions preserve ``(client, clock, len)``
 coverage).  ``device_commit_of_clocks`` is its pure-Python oracle.
@@ -115,11 +114,11 @@ def commitment_of_clocks(clocks: Mapping[int, int]) -> int:
 
 
 def device_commit_of_clocks(clocks: Mapping[int, int]) -> int:
-    """Pure-Python oracle of the DEVICE commitment readout word
-    (`integrate_kernel.N_READOUT`'s last word): the 32-bit fold
-    ``Σ_c mix32(2c+1)·T(n_c) + mix32(2c+2)·n_c`` over the packed
-    state's client id space (raw ids on the identity-rank replay path,
-    interned indices on the ingest path)."""
+    """Pure-Python oracle of the DEVICE commitment word
+    (`batch_doc.commit_fold_blocks`): the 32-bit fold
+    ``Σ_c mix32(2c+1)·T(n_c) + mix32(2c+2)·n_c`` over the state's
+    client id space (raw ids under an identity rank, interned indices
+    on the ingest path)."""
     total = 0
     for client, clock in clocks.items():
         a = mix32(2 * client + 1)

@@ -1,30 +1,28 @@
 """Capacity observatory: resident-bytes model + headroom forecaster.
 
 The third leg of the flight recorder (ISSUE-18). PR-17 attributed
-*time* (compile vs execute vs transfer); the doc-axis ceiling that
-kills the fused lane at 1024-doc shapes (ROADMAP item 1) is a *memory*
-problem, and until now nothing in the telemetry plane modeled it. This
-module owns the host-side math:
+*time* (compile vs execute vs transfer); how many rooms of what capacity
+a chip holds is a *memory* question. This module owns the host-side
+math:
 
 - ``packed_resident_bytes(n_docs, capacity)``: the analytic resident
-  size of one packed ``[NC, D, C]`` + ``[D, M_PAD]`` state — the
-  dominant term of the replay working set and the exact cost of the
-  NEXT ``grow_packed`` (capacity doubles per grow).
+  size of one state: the 26 column planes of ``DocStateBatch.blocks``
+  at 4 bytes a row (PERF.md's "26 planes of 16 MB" at 1,024 rooms x
+  4,096 rows) plus 32 words a room. It is what a grow to
+  ``capacity`` would have to allocate (capacity doubles per grow).
 - ``memory_budget_bytes()``: the device budget the forecaster scores
   against (``YTPU_MEMORY_BUDGET_BYTES``, default 16 GiB of HBM).
-- ``HeadroomForecaster``: fed at every materialized capacity-ledger
-  readout (`PackedReplayDriver._record_capacity_ledger` — zero new
-  device syncs), it linearly models resident bytes as a function of
-  (docs·capacity, docs, clients) over the observed samples (analytic
-  targets by default; callers with measured ``memory_analysis()``
-  numbers — the doc-ceiling sweep — feed those instead, so the model
-  tracks reality, not just the formula) and projects the occupancy
-  trend to answer: *will the next grow exceed the budget, and in about
-  how many chunks will the watermark force it?* The answer flips a
-  degraded ``/capacity`` + ``/healthz`` section BEFORE ``grow.oom``
-  fires — the chaos leg proves the ordering against the typed
-  `GrowOomError` (its ``attempted_bytes`` is this module's
-  ``packed_resident_bytes`` at the denied capacity).
+- ``HeadroomForecaster``: fed ledger readouts
+  (`batch_doc.state_capacity_ledger`'s counts) by whoever owns a state
+  — tests alone today (ROADMAP, named debts) — it linearly models
+  resident bytes as a function of (docs·capacity, docs, clients) over
+  the observed samples (analytic targets by default; a caller with
+  measured ``memory_analysis()`` numbers feeds those instead, so the
+  model tracks reality, not just the formula) and projects the
+  occupancy trend to answer: *will the next grow exceed the budget, and
+  in about how many chunks will the watermark force it?* The answer
+  flips a degraded ``/capacity`` + ``/healthz`` section before the grow
+  is attempted.
 
 Pure host-side arithmetic: no jax imports at module level, no device
 syncs, safe to call from the telemetry thread.
@@ -49,8 +47,8 @@ _DEFAULT_BUDGET_BYTES = 16 << 30
 
 def memory_budget_bytes() -> int:
     """Device memory budget the observatory scores against.
-    ``YTPU_MEMORY_BUDGET_BYTES`` overrides (tests and the doc-ceiling
-    sweep pin small budgets to make the ceiling reachable on CPU);
+    ``YTPU_MEMORY_BUDGET_BYTES`` overrides (tests pin small budgets to
+    make the ceiling reachable on CPU);
     unset/invalid falls back to 16 GiB of HBM."""
     try:
         return int(
@@ -63,11 +61,12 @@ def memory_budget_bytes() -> int:
 
 
 def packed_resident_bytes(n_docs: int, capacity: int) -> int:
-    """Analytic resident bytes of one packed state (lazy import — the
-    column/meta widths live with the kernel that owns the layout)."""
-    from ytpu.ops.integrate_kernel import packed_state_bytes
-
-    return packed_state_bytes(n_docs, capacity)
+    """Analytic resident bytes of one state at a given capacity: the 26
+    column planes of ``DocStateBatch.blocks`` (``BlockCols``' 26 fields,
+    ``[n_docs, capacity]`` each) counted at 4 bytes a row, plus 32 words
+    a room for the per-room vectors. Never under what a state holds: two
+    of the planes (``deleted``, ``countable``) are stored as bool."""
+    return 4 * (26 * n_docs * capacity + 32 * n_docs)
 
 
 class HeadroomForecaster:
@@ -122,7 +121,7 @@ class HeadroomForecaster:
     ) -> None:
         """Fold one ledger readout (or one measured sweep point) in.
         ``resident_bytes=None`` targets the analytic model — the fit
-        then reproduces the formula; the doc-ceiling sweep passes the
+        then reproduces the formula; a caller that has them passes the
         MEASURED ``memory_analysis()`` bytes so forecaster-vs-measured
         stays an assertable delta."""
         if resident_bytes is None:

@@ -3,8 +3,8 @@ programs costs.
 
 The cache directory is part of the cache key's lookup, so a directory that
 moves (tempfile, pid, time) never hits. `enable_compile_cache()` is the one
-place the repo's entry points (`chip_smoke.py`, `bench.py`'s device child,
-`benchmark/run.py`) turn the cache on.
+place the repo's entry points (`chip_smoke.py`, `benchmark/run.py`) turn
+the cache on.
 
 It also puts the programs' metadata into the cache key. jax leaves it out
 by default, so a cache warmed by a tree without a `jax.named_scope` hands

@@ -1,7 +1,7 @@
 """Deterministic fault injection for the host→device pipeline (ISSUE-6).
 
-The resilience machinery (lane-demotion ladder, checkpointed replay
-recovery, hardened sync transport) is only trustworthy if its failure
+The resilience machinery (the encode pipeline's demotions, the hardened
+sync transport, the mesh's failover) is only trustworthy if its failure
 paths run under test — and real dispatch crashes, staging exceptions, or
 stalled peers cannot be produced on demand.  This module plants *named
 injection sites* at the hot path's failure points; each site is a single
@@ -25,40 +25,23 @@ Reserved keys (all optional):
   (default 0: the first eligible pass fires);
 - ``p``     — per-pass fire probability in [0, 1] (default: fire
   deterministically once ``after`` is exhausted);
-- ``seed``  — RNG seed for ``p`` draws and payload corruption
+- ``seed``  — RNG seed for ``p`` draws
   (default 0; the site name is folded in, so two sites armed with the
   same seed draw independent sequences).
 
 Any other key is a free-form *site argument* (string or number) — e.g.
-``lane=fused`` restricts ``dispatch.fail`` to fused-lane dispatches,
-``mode=flip`` selects byte-flip corruption, ``kill=1`` makes a dispatch
-fault unrecoverable in place (simulated worker death: state buffers are
-treated as lost, forcing the checkpoint-resume path), ``ms=50`` sets the
-``net.delay`` stall.  A site argument that names a *context* key the
-call site passes (e.g. ``lane``) must match for the pass to be eligible.
+``prefix=encode`` restricts ``stage.raise`` to the encode pipeline's
+staging thread, ``ms=50`` sets the ``net.delay`` stall.  A site argument
+that names a *context* key the call site passes (e.g. ``prefix``) must
+match for the pass to be eligible.
 
 Standard sites (see docs/robustness.md for the full catalogue):
 
 ====================  =======================================================
-``update.corrupt``    truncate/flip one staged update's wire bytes — fires
-                      on BOTH ingest lanes: per-chunk in the host-packed
-                      staging, and at the wire-table build of the raw
-                      lane (same once-per-update stream order, so an
-                      ``after=k`` spec poisons the same update either way;
-                      on-device varint decode flags the corrupt lane)
-``dispatch.fail``     raise before a device chunk dispatch (args: ``lane``,
-                      ``kill``)
-``replay.kill``       raise after a chunk dispatch with state treated as
-                      lost (mid-replay worker death → checkpoint resume)
 ``stage.raise``       raise inside the overlap staging thread (args:
-                      ``prefix`` = OverlapPipeline stage_prefix; covers
-                      the raw memcpy staging and the packed staging alike
-                      — the site lives in the shared engine's worker)
-``grow.oom``          deny the next capacity grow as a device OOM — the
-                      driver raises the typed `GrowOomError` (ISSUE-18)
-                      naming attempted vs available bytes and counting
-                      ``memory.grow_denied`` (args: ``budget`` caps the
-                      reported available bytes)
+                      ``prefix`` = OverlapPipeline stage_prefix, "encode"
+                      for `DiffPipeline`'s — the site lives in the
+                      engine's worker)
 ``net.drop``          swallow one outbound frame
 ``net.truncate``      write a frame header + half the payload (stalls the
                       reader mid-frame)
@@ -284,8 +267,7 @@ class FaultInjector:
             return None
         if site not in self._specs:
             # GIL-atomic dict read: sites with nothing armed stay
-            # lock-free even while OTHER sites are (e.g. the per-update
-            # update.corrupt pass during transport-only chaos)
+            # lock-free even while OTHER sites are
             return None
         with self._lock:
             specs = self._specs.get(site)
@@ -313,20 +295,6 @@ class FaultInjector:
         spec = self.fire(site, **ctx)
         if spec is not None:
             raise FaultError(site, spec)
-
-    def corrupt(self, site: str, payload: bytes, **ctx) -> bytes:
-        """Pass one update's wire bytes through `site`; a firing spec
-        returns a corrupted copy (mode=truncate cuts the payload in
-        half — the decoder's FLAG_MALFORMED shape; mode=flip XORs one
-        deterministic byte)."""
-        spec = self.fire(site, **ctx)
-        if spec is None:
-            return payload
-        mode = str(spec.args.get("mode", "truncate"))
-        if mode == "flip" and payload:
-            i = spec._rng.randrange(len(payload))
-            return payload[:i] + bytes([payload[i] ^ 0xFF]) + payload[i + 1:]
-        return payload[: max(1, len(payload) // 2)]
 
     def delay_s(self, site: str, **ctx) -> float:
         """Seconds the caller should stall (0.0 = not firing)."""

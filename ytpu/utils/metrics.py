@@ -16,8 +16,8 @@ is unchanged.
 
 Exporters:
 
-- `snapshot()` — flat JSON-safe dict (bench.py embeds it in the one-line
-  result so BENCH_r*.json records where time went);
+- `snapshot()` — flat JSON-safe dict (`/snapshot` and the soak report
+  embed it);
 - `prometheus_text()` — Prometheus text exposition format 0.0.4
   (`# TYPE` headers, `_total` counters, cumulative `_bucket{le=...}`
   histogram series) for scraping a serving process.

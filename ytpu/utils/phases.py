@@ -4,9 +4,9 @@ The host→device pipeline's wall time hides three very different costs:
 first-call XLA/Mosaic compilation, steady-state dispatch/execute, and
 host↔device transfers. Kernel-optimization rounds kept bisecting them
 from ad-hoc logs; this recorder separates them at the jit boundaries
-(`ops/decode_kernel`, `ops/integrate_kernel`, `ops/compaction`,
-`models/batch_doc`, `models/ingest`, `models/pipeline`) so `bench.py`
-can embed a per-stage breakdown in its one-line JSON.
+(`ops/decode_kernel`, `ops/compaction`, `models/batch_doc`,
+`models/ingest`) so the benchmark's readers and `/snapshot` can read a
+per-stage breakdown.
 
 Attribution model: every instrumented call passes a hashable ``key``
 describing the compiled-program identity (static args + operand shapes).
@@ -92,12 +92,10 @@ summed into the stage of its name here, and either way it is written
 into the profiler's trace as ``ytpu.<name>``. Stages nest by
 containment; ``self_s`` is a stage's time less its nested spans'.
 
-Stage namespaces: ``replay.*`` is the async apply pipeline (stage /
-stall / overlap_ratio / inflight_depth / stage_bytes...), ``encode.*``
-the pipelined diff finisher (select / drain / finish / stall /
-overlap_ratio / d2h_bytes — ISSUE-10, docs/observability.md §Encode
-pipeline); ``rehearsal*.*`` keys come from bench dry-run simulations,
-never from real runs.
+Stage namespaces: ``encode.*`` is the pipelined diff finisher (select /
+stage / drain / finish / stall / overlap_ratio / d2h_bytes — ISSUE-10,
+docs/observability.md §Encode pipeline); ``ingest.*`` and ``sync.*``
+the served step's stages.
 """
 
 from __future__ import annotations
@@ -689,7 +687,7 @@ class PhaseRecorder:
 
     def set_value(self, stage: str, value: float) -> None:
         """Record a scalar gauge under `stage` (snapshot key "value") —
-        e.g. ``replay.overlap_ratio``, ``replay.inflight_depth``."""
+        e.g. ``encode.overlap_ratio``, ``encode.inflight_depth``."""
         if not self.enabled:
             return
         with self._lock:
@@ -700,12 +698,9 @@ class PhaseRecorder:
 
     def add_value(self, stage: str, delta: float) -> None:
         """Accumulate a scalar gauge (snapshot key "value") — e.g.
-        ``replay.stage_bytes``, the raw-ingest lane's total staged
-        payload bytes. Unlike `transfer` this counts HOST-side copy
-        volume (staging is a host memcpy, not an h2d transfer — the
-        chunk programs count their own h2d bytes), and unlike
-        `set_value` it survives multi-run accumulation (a checkpoint
-        resume re-enters the overlap loop)."""
+        ``encode.d2h_bytes``, or the recorder's copy of an ingest
+        counter, whose delta over a window the benchmark's readers
+        take. Unlike `set_value` it survives multi-run accumulation."""
         if not self.enabled:
             return
         with self._lock:

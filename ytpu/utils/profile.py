@@ -11,11 +11,10 @@ report time, attributes the elapsed wall into seven exclusive buckets:
 - ``compile``   — first-sighting trace+compile wall at the jit
   boundaries (the sentinel's ``compile_s`` deltas);
 - ``device``    — steady-state dispatch/execute of the device programs
-  (chunk replay lanes, integrate/decode/compact, diff selection/pack);
+  (integrate/decode/compact, diff selection/pack);
 - ``staging``   — host-side staging memcpys + ingest planning (the
   overlap engines' ``*.stage`` gauges, ``ingest.plan``);
-- ``drain``     — device→host readout/checkpoint drains (``*.drain``,
-  ``replay.readout``, ``replay.checkpoint``);
+- ``drain``     — device→host drains (``*.drain``);
 - ``finisher``  — the host/native diff finisher (``encode.finish``);
 - ``net``       — serving-loop residual: `sync.apply_update` histogram
   wall not explained by the instrumented stages nested inside the apply
@@ -37,14 +36,10 @@ rounding); when measured busy exceeds the wall (overlapped threads
 legitimately over-commit), the excess is surfaced as ``overcommit_s``
 instead of silently deflating a bucket.
 
-``rehearsal*``/``host.*`` stages are excluded — those are bench
-dry-run simulation wrappers whose spans enclose entire legs and would
-double-count everything inside them.
-
 Attach points: `TelemetryServer` serves ``profile_report()`` at
 ``/profile`` (and per-replica fractions merge under ``/fleet`` via
 `replica_snapshot`); `SoakDriver` embeds a windowed report in its run
-report; bench lifts ``profile_device_fraction`` into the one-line JSON.
+report.
 """
 
 from __future__ import annotations
@@ -78,13 +73,10 @@ _BUCKETS = (
 #: encode/pipeline stage gauges are listed before the broad device
 #: prefixes. Suffix rules (`.stall` / `.drain`) run before these.
 _PREFIX_RULES = (
-    ("staging", ("replay.stage", "encode.stage", "pipeline.stage",
-                 "ingest.plan")),
-    ("drain", ("replay.readout", "replay.checkpoint")),
+    ("staging", ("encode.stage", "ingest.plan")),
     ("finisher", ("encode.finish",)),
-    ("device", ("replay.chunk", "integrate.", "decode.", "compact.",
-                "encode.select", "encode.pack", "encode.diff",
-                "pipeline.decode")),
+    ("device", ("integrate.", "decode.", "compact.",
+                "encode.select", "encode.pack", "encode.diff")),
 )
 
 
@@ -100,11 +92,8 @@ _WAITS = frozenset({"sync.queue_wait"})
 
 
 def classify_stage(name: str) -> Optional[str]:
-    """Bucket for one phases stage name; None = excluded (bench
-    rehearsal wrappers, double-counted encode gauges, queue waits),
-    ``"stall"`` = informational only."""
-    if name.startswith("rehearsal") or name.startswith("host."):
-        return None
+    """Bucket for one phases stage name; None = excluded (double-counted
+    encode gauges, queue waits), ``"stall"`` = informational only."""
     if name in _DOUBLE_COUNTED or name in _WAITS:
         return None
     if name.endswith(".stall"):

@@ -2,7 +2,7 @@
 recorder (ISSUE-11; docs/observability.md §Live telemetry).
 
 Everything the repo measured before this module was post-hoc: metrics and
-phase timers only surfaced in `bench.py`'s one-line JSON after the run
+phase timers only surfaced in a result line after the run
 ended. `TelemetryServer` is the missing listener — a stdlib
 `http.server` on its OWN daemon thread, so a soak, a serving pod, or a
 long replay is watchable live while the main thread stays on the data
@@ -23,9 +23,8 @@ path. Five endpoints:
   staging / drain / finisher / net / host / idle fractions summing to
   1) from `ytpu.utils.profile`, or whatever windowed source the
   current run installed via `set_profile_source`;
-- ``/healthz`` — liveness + the degradation surface: the sticky
-  lane-demotion ladder (`integrate_kernel.lane_health()`) and the age
-  of the last device dispatch. A wedged device shows as a growing
+- ``/healthz`` — liveness + the degradation surface: the age of the
+  last device dispatch and every registered health provider. A wedged device shows as a growing
   ``last_dispatch_age_s`` while this endpoint keeps answering (its
   thread never touches the data path), which is exactly what a probe
   wants to distinguish "slow" from "dead";
@@ -42,8 +41,7 @@ Design constraints honored:
 - **zero data-path cost**: nothing here is called from the hot path;
   handlers read the same lock-protected registries the exporters always
   read.
-- **no heavy imports**: `/healthz` reads the lane ladder only when
-  `ytpu.ops.integrate_kernel` is ALREADY loaded (`sys.modules` probe) —
+- **no heavy imports**: `/healthz` reads one gauge and the providers —
   a host-only process scraping its telemetry never drags jax in.
 - **ephemeral by default**: ``port=0`` binds any free port (the bound
   port is on `server.port` after `start()`), so parallel soaks/tests
@@ -64,7 +62,6 @@ or standalone::
 from __future__ import annotations
 
 import json
-import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -410,37 +407,24 @@ class TelemetryServer:
         return out
 
     def healthz(self) -> Dict:
-        """The `/healthz` JSON body. Never imports jax: the lane ladder
-        is read only when the kernel module is already loaded."""
+        """The `/healthz` JSON body. Never imports jax."""
         out: Dict = {
             "status": "ok",
             "uptime_s": round(time.time() - self._t0, 3),
-            "lane_ladder": {},
         }
-        ik = sys.modules.get("ytpu.ops.integrate_kernel")
-        if ik is not None:
-            try:
-                out["lane_ladder"] = ik.lane_health()
-            except Exception as e:
-                out["lane_ladder"] = {
-                    "error": f"{type(e).__name__}: {e}"[:200]
-                }
-        # last-dispatch age: the freshest of the serving-loop flush
-        # (sync.last_dispatch_unix) and the replay driver's chunk
-        # dispatch (integrate.last_dispatch_unix); absent until either
-        # path dispatched once. Read the two gauges directly — /healthz
-        # is the highest-frequency probe and must stay O(1), not
-        # O(registry) (gauge() get-or-creates, so reading before the
-        # serving layer registers them just sees 0)
-        last = 0.0
-        for key in ("sync.last_dispatch_unix", "integrate.last_dispatch_unix"):
-            last = max(last, float(metrics.gauge(key).value))
+        # last-dispatch age: the serving-loop flush's gauge
+        # (sync.last_dispatch_unix); absent until it dispatched once.
+        # Read the gauge directly — /healthz is the highest-frequency
+        # probe and must stay O(1), not O(registry) (gauge()
+        # get-or-creates, so reading before the serving layer registers
+        # it just sees 0)
+        last = float(metrics.gauge("sync.last_dispatch_unix").value)
         if last > 0:
             out["last_dispatch_age_s"] = round(
                 max(0.0, time.time() - last), 3
             )
         else:
-            # the gauges default to 0 when NO dispatch ever happened —
+            # the gauge defaults to 0 when NO dispatch ever happened —
             # an age computed from that epoch would read ~56 years.  Say
             # "never" explicitly and omit the age (ISSUE-15 satellite)
             out["last_dispatch"] = "never"

@@ -25,11 +25,11 @@ paths write it out:
 - ``YTPU_TRACE=<path>`` in the environment enables the process-wide
   tracer at import and registers an atexit Chrome-trace dump to that
   path (``%p`` in the path expands to the pid — use it when parent and
-  child processes share the variable, e.g. bench.py's device child).
+  child processes share the variable).
   Processes that recorded nothing skip the write, so an instrumented
   child's dump is not clobbered by an idle parent.
-- ``tracer.dump_on_error(error=e)`` — the hook the bench device child
-  and `DeviceSyncServer.flush_device` call from exception paths: appends
+- ``tracer.dump_on_error(error=e)`` — the hook
+  `DeviceSyncServer.flush_device` calls from exception paths: appends
   an instant "error" event and writes immediately (atexit never runs
   when a process is SIGKILLed by a timeout), so a lost-device or
   kernel-abort round leaves a replayable trace instead of a stderr tail.
